@@ -27,9 +27,11 @@ Config provenance (measured on v5e, round 4): ResNet batch 256 +
 space-to-depth stem (256 > 128/512/1024; s2d +1.5%); BERT batch 26 +
 flash attention (26 > 24/27/28/30/32 after the single-chip
 fusion-bucket skip freed HBM). Steps execute through AOT-compiled
-executables with >= 12-batch timing windows — the per-call jit
-dispatch and per-window host sync cost ~5-8% through remote-TPU
-paths (see docs/benchmarks.md).
+executables with >= 12-batch timing windows.
+
+One process per chip: the eager probe is a child that needs the chip,
+so it runs (and exits) before this process first touches JAX, and a
+failed child fails the run.
 """
 
 import json
@@ -38,6 +40,7 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.utils.script_loader import load_example
 
 BASELINE_IMG_PER_SEC_PER_CHIP = 1656.82 / 16  # docs/benchmarks.rst:40-43
@@ -69,16 +72,14 @@ def _eager_path_block():
 
     env = dict(os.environ)
     env["HVD_TPU_NATIVE"] = "1"
-    try:
-        out = subprocess.run(
-            [sys.executable,
-             os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "scripts", "eager_path_bench.py")],
-            capture_output=True, text=True, timeout=900, env=env,
-        ).stdout
-        return json.loads(out[out.index("{"):])
-    except Exception as e:  # the headline metrics must still emit
-        return {"error": repr(e)[:200]}
+    out = subprocess.run(
+        [sys.executable,
+         os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                      "scripts", "eager_path_bench.py")],
+        stdout=subprocess.PIPE, text=True, timeout=900, env=env,
+        check=True,
+    ).stdout
+    return json.loads(out[out.index("{"):])
 
 
 def main():
@@ -86,8 +87,10 @@ def main():
     bert = load_example("bert_pretraining")
     gpt = load_example("gpt2_pretraining")
 
+    # before this process touches JAX (the child needs the chip) and
     # before the big models allocate: the eager-vs-SPMD ratio probe
     eager_path = _eager_path_block()
+    compile_cache.enable()
 
     rs, bs, gs, is_, vs = {}, {}, {}, {}, {}
     img_per_chip, resnet_mfu = resnet.main(

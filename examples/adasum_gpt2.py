@@ -25,9 +25,10 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import shard_map
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.models.transformer import (
     GPT2_SMALL,
     Transformer,
@@ -52,6 +53,7 @@ def main(argv=None):
                    help="vocab-blocked fused LM-head cross-entropy")
     args = p.parse_args(argv)
 
+    compile_cache.enable()
     hvd.init()
     n = hvd.size()
     mesh = hvd.mesh()

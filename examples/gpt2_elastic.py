@@ -29,9 +29,10 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import shard_map
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.models.transformer import (
     GPT2_SMALL,
     Transformer,
@@ -76,6 +77,7 @@ def main(argv=None):
                         "causal tile-skipping, ~2x attention at T>=1k)")
     args = p.parse_args(argv)
 
+    compile_cache.enable()
     hvd.init()
 
     cfg = dataclasses.replace(

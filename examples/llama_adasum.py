@@ -31,15 +31,16 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.models.transformer import (
     LLAMA2_7B,
     Transformer,
     causal_lm_loss,
 )
 from horovod_tpu.utils.mfu import count_params
-from horovod_tpu.compat import shard_map
 
 
 def main(argv=None):
@@ -62,6 +63,7 @@ def main(argv=None):
                    help="bfloat16 wire compression for the adasum path")
     args = p.parse_args(argv)
 
+    compile_cache.enable()
     hvd.init()
     n = hvd.size()
     mesh = hvd.mesh()

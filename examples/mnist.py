@@ -27,9 +27,10 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import shard_map
+from horovod_tpu.utils import compile_cache
 
 
 class ConvNet(nn.Module):
@@ -73,6 +74,7 @@ def main(argv=None):
     p.add_argument("--save", default="", help="rank-0 checkpoint path")
     args = p.parse_args(argv)
 
+    compile_cache.enable()
     hvd.init()
     n = hvd.size()
     mesh = hvd.mesh()

@@ -33,6 +33,7 @@ import numpy as np
 import optax
 
 import horovod_tpu as hvd
+from horovod_tpu.utils import compile_cache
 from horovod_tpu.models.transformer import (
     GPT2_SMALL,
     Transformer,
@@ -64,6 +65,7 @@ def main(argv=None):
         p.error("--steps must be >= 2 (step 0 is the compile step and "
                 "is excluded from the timed window)")
 
+    compile_cache.enable()
     hvd.init()
     n = hvd.size()
     assert n % args.pp == 0, (n, args.pp)
@@ -88,19 +90,11 @@ def main(argv=None):
     # program trips XLA's partitioner (dedup_meshes sub-axis check).
     # The batch shards over dp (the pipeline shard_maps only make "pp"
     # manual, so XLA auto-partitions the dp dimension — real data
-    # parallelism, not dp-replicated redundant compute). On legacy jax
-    # the pipeline runs on a pp-only sub-mesh (compat.shard_map's
-    # legacy_submesh fallback), so commit to THAT mesh — jit rejects
-    # arguments on a different device set than an inner shard_map's —
-    # and drop the dp sharding it cannot express.
+    # parallelism, not dp-replicated redundant compute).
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.compat import placement_mesh
-
-    pmesh = placement_mesh(mesh)
-    batch_spec = P("dp") if "dp" in pmesh.axis_names else P()
-    params = jax.device_put(params, NamedSharding(pmesh, P()))
-    toks = jax.device_put(toks, NamedSharding(pmesh, batch_spec))
+    params = jax.device_put(params, NamedSharding(mesh, P()))
+    toks = jax.device_put(toks, NamedSharding(mesh, P("dp")))
     opt = optax.adam(args.lr)
     state = opt.init(params)
     M = args.microbatches

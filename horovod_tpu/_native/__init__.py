@@ -9,6 +9,8 @@ pure-Python/XLA SPMD path never needs it.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 import threading
@@ -16,6 +18,7 @@ from typing import List, Optional, Sequence
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "libhvd_tpu_core.so")
+_STAMP_PATH = _LIB_PATH + ".srchash"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
@@ -46,32 +49,41 @@ DONE = 2
 FAILED = -1
 
 
-def _sources_newer_than_lib() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
+def _source_hash() -> str:
+    """sha256 over the Makefile and every hvd/*.cc|*.h, names included."""
     src_dir = os.path.join(_DIR, "hvd")
-    candidates = [os.path.join(_DIR, "Makefile")]
-    if os.path.isdir(src_dir):
-        candidates += [
-            os.path.join(src_dir, f) for f in os.listdir(src_dir)
-        ]
-    return any(
-        os.path.getmtime(p) > lib_mtime
-        for p in candidates if os.path.isfile(p)
-    )
+    paths = [os.path.join(_DIR, "Makefile")] + sorted(
+        os.path.join(src_dir, f) for f in os.listdir(src_dir)
+        if f.endswith((".cc", ".h")))
+    h = hashlib.sha256()
+    for p in paths:
+        h.update(os.path.basename(p).encode() + b"\0")
+        with open(p, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
 
 
 def build(force: bool = False) -> str:
-    """Compile libhvd_tpu_core.so. Rebuilds when any native source is
-    newer than the library — a stale .so with an old batch wire format
-    would crash the Python-side reader."""
-    with _lock:
-        if force or _sources_newer_than_lib():
+    """Compile libhvd_tpu_core.so. The rebuild is keyed on the CONTENT
+    of the committed sources (a hash stamp beside the library), not on
+    mtimes: a copied working tree carries stale git-ignored *.so/*.o
+    with fresh mtimes, and a stale .so with an old batch wire format
+    would crash the Python-side reader. A mismatch rebuilds from clean
+    so no stale object file is linked either."""
+    with _lock, open(_STAMP_PATH + ".lock", "w") as lock_file:
+        fcntl.flock(lock_file, fcntl.LOCK_EX)  # ranks share the checkout
+        want = _source_hash()
+        have = ""
+        if os.path.exists(_LIB_PATH) and os.path.exists(_STAMP_PATH):
+            with open(_STAMP_PATH) as f:
+                have = f.read().strip()
+        if force or have != want:
             subprocess.check_call(
-                ["make", "-C", _DIR] + (["clean", "all"] if force else []),
+                ["make", "-C", _DIR, "clean", "all"],
                 stdout=subprocess.DEVNULL,
             )
+            with open(_STAMP_PATH, "w") as f:
+                f.write(want + "\n")
     return _LIB_PATH
 
 

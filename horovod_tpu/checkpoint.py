@@ -404,12 +404,7 @@ def load_model(
     # ONE data read: parameter shapes come from checkpoint metadata (no
     # array bytes), and the rebuilt optimizer's own init supplies the
     # authoritative opt_state structure for the restore template
-    meta = ckptr.metadata(tree_path)
-    # orbax >= 0.9 wraps the tree in CheckpointMetadata.item_metadata.tree;
-    # 0.7.x returns the metadata tree itself
-    meta_tree = (
-        meta.item_metadata.tree if hasattr(meta, "item_metadata") else meta
-    )
+    meta_tree = ckptr.metadata(tree_path).item_metadata.tree
     params_tmpl = jax.tree_util.tree_map(
         lambda m: jax.ShapeDtypeStruct(tuple(m.shape), m.dtype),
         meta_tree["params"],

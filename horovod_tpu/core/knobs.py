@@ -182,10 +182,10 @@ class Knobs:
     # are bitwise-identical either way, plain and int8+EF wires alike.
     fsdp_regather: bool = True
     # Host-RAM offload of stage-boundary activations for the regather
-    # step's long-stage tail: carries move to pinned host memory at
-    # each stage boundary on forward and prefetch back one stage ahead
-    # on backward. Regather mode only; identity (no-op, still bitwise)
-    # on backends without an addressable host memory space.
+    # step's long-stage tail: carries move to host memory at each
+    # stage boundary on forward and prefetch back one stage ahead on
+    # backward. Regather mode only; a backend that cannot place them
+    # in host memory fails the compile.
     fsdp_offload: bool = False
     # Bounded offload duty: the fraction of eligible stage-boundary
     # carries actually offloaded, earliest stages first (they wait

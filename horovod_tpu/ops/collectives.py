@@ -328,7 +328,7 @@ def _build_perrank_program(op_kind: str, mesh, axes, op: int,
     """jit(shard_map) program treating a [world, ...] stack as 'rank i's
     tensor on device i'. `root` is an index along `axes`. Shared by the
     global eager path and the process-set sub-mesh path."""
-    from ..compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     # The per-rank stack is laid out [world, ...] and sharded on dim 0, so

@@ -1695,7 +1695,7 @@ class XlaExecutor:
         prog = self._programs.get(key)
         if prog is None:
             import jax
-            from ..compat import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             def body(*stacked):
@@ -1875,7 +1875,7 @@ class XlaExecutor:
         # buffer and issues one ncclAllReduce,
         # nccl_operations.cc:175-246) AND one device dispatch per batch
         # — host-side packing of device-resident gradients would pull
-        # every tensor through the host (fatal on remote-TPU paths),
+        # every tensor through the host,
         # and per-tensor result slicing would pay one dispatch per
         # gradient instead of per batch.
         # The bucket signature is memoized ON the batch: a cached-plan

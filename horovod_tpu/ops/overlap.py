@@ -821,31 +821,6 @@ def _run_fsdp_staged(stages: Sequence[Stage], layout, rows, info: dict,
                               new_residuals=new_res if ef else None)
 
 
-_HOST_OFFLOAD_OK = None
-
-
-def _host_offload_supported() -> bool:
-    """Whether this backend accepts memory-kind-annotated device_put in
-    traced code (TPU/GPU pinned_host; XLA:CPU tolerates the annotation
-    as an identity). Probed once per process by LOWERING a tiny round
-    trip — no execution, safe to call mid-trace — so
-    HOROVOD_FSDP_OFFLOAD degrades to keeping carries resident on
-    backends that reject the annotation, never to an error."""
-    global _HOST_OFFLOAD_OK
-    if _HOST_OFFLOAD_OK is None:
-        try:
-            from jax._src.sharding_impls import TransferToMemoryKind
-
-            jax.jit(lambda v: jax.device_put(
-                jax.device_put(v, TransferToMemoryKind("pinned_host")),
-                TransferToMemoryKind("device"))).lower(
-                jax.ShapeDtypeStruct((1,), jnp.float32))
-            _HOST_OFFLOAD_OK = True
-        except Exception:
-            _HOST_OFFLOAD_OK = False
-    return _HOST_OFFLOAD_OK
-
-
 def _offload_stage_set(n_stages: int, duty: float):
     """Which stage-boundary carries move to host under
     HOROVOD_FSDP_OFFLOAD: the eligible set excludes stage 0 (its carry
@@ -861,13 +836,12 @@ def _offload_stage_set(n_stages: int, duty: float):
     return set(eligible[:k])
 
 
-def _carry_put(c, kind: str):
-    """tree-wide device_put onto a memory kind ('pinned_host' out,
-    'device' back)."""
-    from jax._src.sharding_impls import TransferToMemoryKind
-
+def _carry_put(c, space):
+    """tree-wide device_put into a memory space (`jax.memory.Space.Host`
+    out, `.Device` back). A backend that cannot do it raises at compile
+    time — HOROVOD_FSDP_OFFLOAD either offloads or fails."""
     return jax.tree_util.tree_map(
-        lambda x: jax.device_put(x, TransferToMemoryKind(kind)), c)
+        lambda x: jax.device_put(x, space), c)
 
 
 def _carry_bytes(c) -> int:
@@ -988,9 +962,7 @@ def _run_fsdp_regather(stages: Sequence[Stage], layout, rows,
                 gathered[bi], off, sz).reshape(shp))
         return jax.tree_util.tree_unflatten(sub_def, leaves)
 
-    offload_set = (
-        _offload_stage_set(S, duty)
-        if offload and _host_offload_supported() else set())
+    offload_set = _offload_stage_set(S, duty) if offload else set()
     offload_bytes = 0
 
     # ---- forward: stages 0..S-2 primal-only; nothing but the
@@ -1009,7 +981,7 @@ def _run_fsdp_regather(stages: Sequence[Stage], layout, rows,
                 if bi not in gathered:
                     gathered[bi] = _gather(bi, carry if s else None)
         if s in offload_set:
-            carries[s] = _carry_put(carry, "pinned_host")
+            carries[s] = _carry_put(carry, jax.memory.Space.Host)
             offload_bytes += _carry_bytes(carry)
         else:
             carries[s] = carry
@@ -1065,7 +1037,8 @@ def _run_fsdp_regather(stages: Sequence[Stage], layout, rows,
 
     def _restore(si):
         c = carries[si]
-        return _carry_put(c, "device") if si in offload_set else c
+        return (_carry_put(c, jax.memory.Space.Device)
+                if si in offload_set else c)
 
     for step_i, si in enumerate(backward_stage_order):
         # step 0's gathers carry the saved-mode last-stage pin (the

@@ -18,8 +18,8 @@ blockwise online-softmax attention (Flash Attention) tiled for MXU/VMEM:
   materialized in HBM in either direction, so training-time HBM traffic
   stays O(T·D) instead of O(T²).
 
-Falls back to `interpret=True` off-TPU so the CPU test mesh runs the same
-code path.
+Compiled by Mosaic on TPU; `interpret=True` only on the CPU test mesh, which
+runs the same kernel bodies (ops/_pallas.py).
 """
 
 from __future__ import annotations
@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU backend)
+
+from ._pallas import interpret
 
 NEG_INF = -1e30
 
@@ -274,9 +276,6 @@ def _pad_to(x, axis, multiple):
     return jnp.pad(x, widths)
 
 
-def _interpret():
-    return jax.default_backend() != "tpu"
-
 
 def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
                 key_offset, block_q, block_k):
@@ -310,7 +309,7 @@ def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
             jax.ShapeDtypeStruct((b, h, tq_p, d), qq.dtype),
             jax.ShapeDtypeStruct((b, h, 1, tq_p), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(qq, kk, vv)
 
 
@@ -383,7 +382,7 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
         out_specs=pl.BlockSpec((None, None, block_q, d),
                                lambda b, h, j: (b, h, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, tq_p, d), q.dtype),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(qq, do, lse_p, delta_p, kk, vv)
 
     dkv_kernel = functools.partial(
@@ -418,7 +417,7 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
             jax.ShapeDtypeStruct((b, h, tk_p, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, tk_p, d), v.dtype),
         ],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(kk, vv, qq, do, lse_p, delta_p)
 
     return dq[:, :, :tq], dk[:, :, :tk], dv[:, :, :tk]

@@ -44,9 +44,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
+from ._pallas import interpret
 
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def _ceil_to(n, m):
@@ -201,7 +200,7 @@ def _run_stats(x2, view):
         in_specs=in_specs,
         out_specs=[vec, vec],
         out_shape=[jax.ShapeDtypeStruct((1, view.c2), jnp.float32)] * 2,
-        interpret=_interpret(),
+        interpret=interpret(),
     )(x2)
     return view.unvec(out[0]), view.unvec(out[1])
 
@@ -221,7 +220,7 @@ def _run_apply(x2, s2, t2, res2, relu, view, out_dtype):
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=big_out,
         out_shape=jax.ShapeDtypeStruct((view.n2, view.c2), out_dtype),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(*args)
 
 
@@ -245,7 +244,7 @@ def _run_bwd_reduce(x2, dy2, s2, t2, u2, w2, res2, relu, view):
     out = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=[vec, vec],
         out_shape=[jax.ShapeDtypeStruct((1, view.c2), jnp.float32)] * 2,
-        interpret=_interpret(),
+        interpret=interpret(),
     )(*args)
     return view.unvec(out[0]), view.unvec(out[1])
 
@@ -261,7 +260,7 @@ def _run_bwd_dx(x2, dy2, s2, t2, a2, b2, c2v, res2, relu, view, dtype):
         return pl.pallas_call(
             kernel, grid=grid, in_specs=specs, out_specs=big_out,
             out_shape=jax.ShapeDtypeStruct((view.n2, view.c2), dtype),
-            interpret=_interpret(),
+            interpret=interpret(),
         )(x2, dy2, s2, t2, a2, b2, c2v), None
 
     def kernel(x_ref, dy_ref, s_ref, t_ref, a_ref, b_ref, c_ref,
@@ -273,7 +272,7 @@ def _run_bwd_dx(x2, dy2, s2, t2, a2, b2, c2v, res2, relu, view, dtype):
         kernel, grid=grid, in_specs=specs + [big],
         out_specs=[big_out, big_out],
         out_shape=[jax.ShapeDtypeStruct((view.n2, view.c2), dtype)] * 2,
-        interpret=_interpret(),
+        interpret=interpret(),
     )(x2, dy2, s2, t2, a2, b2, c2v, res2)
     return dx, dres
 

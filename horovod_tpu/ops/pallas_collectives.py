@@ -52,6 +52,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ..optim import compression as _comp
+from ._pallas import interpret
 
 __all__ = [
     "fused_enabled",
@@ -62,12 +63,6 @@ __all__ = [
     "matmul_reduce_scatter",
     "decode_append_attend",
 ]
-
-
-def _interpret() -> bool:
-    # pallas_attention.py discipline: compiled on TPU, interpreted (and
-    # therefore testable, bitwise) everywhere else
-    return jax.default_backend() != "tpu"
 
 
 def fused_enabled(knobs=None) -> bool:
@@ -175,7 +170,7 @@ def _quantize_rows(rows, block: int):
                    pl.BlockSpec((1, nb), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.int8),
                    jax.ShapeDtypeStruct((R, nb), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(rows)
 
 
@@ -194,7 +189,7 @@ def _quantize_ef_rows(rows, block: int):
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.int8),
                    jax.ShapeDtypeStruct((R, nb), jnp.float32),
                    jax.ShapeDtypeStruct((R, C), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(rows)
 
 
@@ -205,7 +200,7 @@ def _accum_rows(q, s, block: int):
     out = pl.pallas_call(
         functools.partial(_accum_kernel, block=block),
         out_shape=jax.ShapeDtypeStruct((1, C), jnp.float32),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q, s)
     return out.reshape(C)
 
@@ -217,7 +212,7 @@ def _dequantize_flat(q, s, block: int):
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, block=block),
         out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(q.reshape(1, m), s.reshape(1, -1))
     return out.reshape(m)
 
@@ -308,7 +303,7 @@ def pack_rows_fused(bucket, n: int):
     out = pl.pallas_call(
         _pack_kernel,
         out_shape=jax.ShapeDtypeStruct((1, n * k), b.dtype),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(b.reshape(1, L))
     return out.reshape(n, k)
 
@@ -334,7 +329,7 @@ def _matmul_pack(a, b, n: int):
     packed = pl.pallas_call(
         _matmul_pack_kernel,
         out_shape=jax.ShapeDtypeStruct((1, n * k), jnp.float32),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(a, b)
     return packed.reshape(n, k)
 
@@ -515,7 +510,7 @@ def decode_append_attend(cache, layer: int, q, k_new, v_new,
             in_specs=[spec_b(a.shape) for a in args],
             out_specs=[spec_b(s.shape) for s in outs],
             out_shape=outs,
-            interpret=_interpret(),
+            interpret=interpret(),
         )(*args)
         cache.buffers["k"] = cache.buffers["k"].at[:, layer].set(mk)
         cache.buffers["v"] = cache.buffers["v"].at[:, layer].set(mv)
@@ -536,7 +531,7 @@ def decode_append_attend(cache, layer: int, q, k_new, v_new,
         in_specs=[spec_b(a.shape) for a in args],
         out_specs=[spec_b(s.shape) for s in outs],
         out_shape=outs,
-        interpret=_interpret(),
+        interpret=interpret(),
     )(*args)
     cache.buffers["k"] = cache.buffers["k"].at[:, layer].set(mk)
     cache.buffers["v"] = cache.buffers["v"].at[:, layer].set(mv)

@@ -36,9 +36,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
+from ._pallas import interpret
 
-def _interpret():
-    return jax.default_backend() != "tpu"
 
 
 def _row_block(c: int) -> int:
@@ -125,7 +124,7 @@ def _run_fwd(x2, g2, b2, eps, rms, c_true):
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=big,
         out_shape=jax.ShapeDtypeStruct((n2, c2), x2.dtype),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(*args)
 
 
@@ -156,7 +155,7 @@ def _run_bwd(x2, dy2, g2, eps, rms, c_true, with_beta):
         ]
     return pl.pallas_call(
         kernel, grid=grid, in_specs=[big, big, vec], out_specs=out_specs,
-        out_shape=out_shape, interpret=_interpret(),
+        out_shape=out_shape, interpret=interpret(),
     )(x2, dy2, g2)
 
 

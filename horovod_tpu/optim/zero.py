@@ -33,7 +33,7 @@ DistributedOptimizer):
         upd, s = opt.update(g, s, p)
         return optax.apply_updates(p, upd), s, ...
 
-    from horovod_tpu.compat import shard_map  # version-portable jax.shard_map
+    from jax import shard_map
     jax.jit(shard_map(step, mesh=mesh,
                       in_specs=(P(), specs, P("hvd"), P("hvd")),
                       out_specs=(P(), specs, ...), check_vma=False))

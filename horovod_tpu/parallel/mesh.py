@@ -62,10 +62,12 @@ def make_mesh(
         raise ValueError(f"mesh {sizes} needs {total} devices, have {n}")
 
     shape = tuple(sizes[a] for a in AXIS_ORDER)
-    try:
+    if devices[0].platform == "tpu":
+        # topology-aware placement; a shape the physical topology cannot
+        # host raises here — never a silent change of layout
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        # virtual CPU meshes / odd topologies: plain reshape
+    else:
+        # virtual CPU meshes have no topology: plain reshape
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 

@@ -36,9 +36,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from ..compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.transformer import TransformerConfig
@@ -205,10 +203,6 @@ def pipeline_lm_apply(
         out_specs=P(),
         axis_names=frozenset({"pp"}),
         check_vma=False,
-        # the enclosing jit never shards over the non-pp axes, so legacy
-        # jax may run on the pp-only sub-mesh (full-mesh fully-manual
-        # miscompiles under jit when idle axes exist — see compat.py)
-        legacy_submesh=True,
     )
     h = pipelined(stacked, h, pos_mb)
     return _HeadOnly(cfg).apply({"params": head_params}, h)
@@ -434,8 +428,6 @@ def pipeline_lm_train_step_1f1b(
         out_specs=(P(), P(), P("pp"), P(), P()),
         axis_names=frozenset({"pp"}),
         check_vma=False,
-        # see gpipe entry point: pp-only sub-mesh on legacy jax
-        legacy_submesh=True,
     )
     loss_sum, count, d_stacked, d_head, d_xs = pipelined(
         stacked, h, tokens, head_params, pos_mb)

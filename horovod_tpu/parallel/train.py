@@ -18,7 +18,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ..compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.transformer import Transformer, TransformerConfig, causal_lm_loss
@@ -250,7 +250,7 @@ def _maybe_fsdp_step_fn(cfg, model, optimizer, mesh, batch_spec,
     import functools
 
     from ..core.state import global_state
-    from ..compat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
     from ..ops import collectives as _coll
     from ..ops import overlap as overlap_mod
     from ..optim import fsdp as fsdp_mod
@@ -361,7 +361,7 @@ def _maybe_staged_step_fn(model, optimizer, mesh, batch_spec,
     scheduler can't drive falls back to the monolithic auto-pjit step
     unchanged (bit-for-bit today's trace), so flipping the knob is
     always safe."""
-    from ..compat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
     from ..ops import collectives as _coll
     from ..ops import overlap as overlap_mod
 

@@ -19,7 +19,7 @@ host-gap / idle** buckets that feed the live registry:
   device really hid under compute;
 * ``hvd_mfu`` — model-FLOPs utilization every step (not only sampled
   ones), once :func:`set_step_flops` declares the model's per-step
-  cost (utils/mfu.py owns the peak tables).
+  cost (utils/mfu.py owns the peak tables); published on a TPU only.
 
 Cost discipline (the PR-6 replicator's duty-cycle model): sampling is
 OFF by default; when off, the per-step hook is a single predicted
@@ -112,7 +112,7 @@ def set_step_flops(flops: float, n_chips: int = 0) -> None:
     global _step_flops, _n_chips, _peak_total
     _step_flops = float(flops)
     _n_chips = int(n_chips)
-    _peak_total = 0.0  # chip count may have changed; recompute lazily
+    _peak_total = None  # chip count may have changed; recompute lazily
     if _configured:
         _update_activation()
 
@@ -121,27 +121,28 @@ def step_flops() -> float:
     return _step_flops
 
 
-_peak_total = 0.0  # cached chips x peak FLOP/s (fixed per process)
+_peak_total: Optional[float] = None  # cached chips x peak FLOP/s
 
 
 def _peak_total_flops() -> float:
-    """chips x peak per-chip FLOP/s — resolved once (jax device query +
-    device-kind parsing are not per-step costs) and cached until
-    set_step_flops/reset invalidates."""
+    """chips x peak per-chip FLOP/s, 0.0 off a TPU (hvd_mfu is then not
+    published: a ratio against an assumed peak is not a measurement;
+    an unknown TPU generation raises in utils/mfu.py) — resolved once
+    (jax device query + device-kind parsing are not per-step costs) and
+    cached until set_step_flops/reset invalidates."""
     global _peak_total
-    if _peak_total > 0:
+    if _peak_total is not None:
         return _peak_total
     from . import mfu as _mfu
 
-    n = _n_chips
-    if n <= 0:
-        try:
-            import jax
+    peak = _mfu.tpu_peak_or_none()
+    if peak is None:
+        _peak_total = 0.0
+    else:
+        import jax
 
-            n = jax.device_count()
-        except Exception:
-            n = 1
-    _peak_total = max(n, 1) * _mfu.peak_flops_per_chip()
+        n = _n_chips if _n_chips > 0 else jax.device_count()
+        _peak_total = n * peak
     return _peak_total
 
 
@@ -230,7 +231,7 @@ def _begin_step() -> _Token:
 
 def _end_step(token: _Token) -> None:
     dt = _clock() - token.t0
-    if _step_flops > 0 and dt > 0:
+    if _step_flops > 0 and dt > 0 and _peak_total_flops() > 0:
         # stamped on the token too: the async parse must attach THIS
         # step's MFU to the attribution record, not whatever later
         # step last updated the global by the time parsing finishes
@@ -524,7 +525,7 @@ def reset() -> None:
     _dir = ""
     _step_flops = 0.0
     _n_chips = 0
-    _peak_total = 0.0
+    _peak_total = None
     _counter = 0
     _samples = 0
     _next_ok_t = 0.0

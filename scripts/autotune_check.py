@@ -207,7 +207,7 @@ def _mlp_factory(mesh, params, state, dopt, compile_log):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.compat import shard_map
+    from jax import shard_map
 
     def build_step(overrides):
         compile_log.append(dict(overrides))

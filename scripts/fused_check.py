@@ -44,8 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
 
-from horovod_tpu.compat import shard_map
 
 _ARTIFACT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -131,7 +131,7 @@ def _train(hvd, sync_spec, steps=STEPS, lr=0.1, wire=None):
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.compat import shard_map
+    from jax import shard_map
     from horovod_tpu.multipod.localsgd import (
         LocalSGD, OuterState, local_sgd_active, parse_sync_mode)
     from horovod_tpu.multipod.topology import PodTopology

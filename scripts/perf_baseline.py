@@ -13,7 +13,8 @@ surface in a manual bench run. This script is the 9th
   - **structural** numbers (machine-independent) gate tightly:
     fast-path hit rate, steady-state negotiated bytes (must be 0),
     profiler duty-cycle bound, off-path step-hook cost, attribution
-    sanity (fractions in [0,1], compute > 0), MFU present;
+    sanity (fractions in [0,1], compute > 0) — no MFU: this is a CPU
+    loopback and utils/prof.py publishes hvd_mfu on a TPU only;
   - **timing** gates loosely (the committed baseline comes from a
     different machine): step-time p50 must stay under
     ``baseline x HOROVOD_PERF_TOLERANCE`` (default 4.0).
@@ -85,7 +86,7 @@ def measure() -> dict:
     import jax.numpy as jnp
 
     from horovod_tpu.ops.eager_runtime import EagerRuntime
-    from horovod_tpu.utils import metrics, mfu, prof
+    from horovod_tpu.utils import metrics, prof
 
     # -- off-path cost first: nothing armed, the step hook must be
     # a branch + a couple of loads (the always-on discipline every
@@ -139,12 +140,7 @@ def measure() -> dict:
 
     total_collectives = STEPS * TENSORS_PER_STEP
     hit_rate = snap.get("fast_path_hits", 0) / total_collectives
-    reg = metrics.registry.snapshot()
     psum = prof.summary()
-
-    def _gauge(name):
-        fam = reg.get(name) or {}
-        return fam.get("", None)
 
     artifact = {
         "what": "perf baseline (loopback instrumented step loop)",
@@ -163,8 +159,6 @@ def measure() -> dict:
             "steady_bytes_negotiated": int(sum(steady_bytes)),
             "active": int(snap.get("fast_path_active", 0)),
         },
-        "mfu": _gauge("hvd_mfu"),
-        "peak_flops_per_chip": mfu.peak_flops_per_chip(),
         "attribution": psum.get("attribution"),
         "prof": {
             "every": PROF_EVERY,
@@ -203,9 +197,6 @@ def structural_failures(art: dict) -> list:
             f"steady-state negotiated bytes "
             f"{fp['steady_bytes_negotiated']} != 0 (negotiation not "
             "bypassed after warmup)")
-    if not art.get("mfu") or art["mfu"] <= 0:
-        fails.append(f"hvd_mfu gauge missing/non-positive: "
-                     f"{art.get('mfu')}")
     attr = art.get("attribution")
     if not attr:
         fails.append("no sampled-step attribution produced")
@@ -489,7 +480,6 @@ def main(argv=None) -> int:
         "measured": {
             "step_time_ms_p50": art["step_time_ms"]["p50"],
             "fast_path_hit_rate": art["fast_path"]["hit_rate"],
-            "mfu": art["mfu"],
             "compute_frac": (art.get("attribution") or {}).get(
                 "compute_frac"),
             "exposed_wire_frac": (art.get("attribution") or {}).get(

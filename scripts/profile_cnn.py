@@ -24,10 +24,10 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
 from horovod_tpu.models import InceptionV3, ResNet50, VGG16
-from horovod_tpu.compat import shard_map
 
 _MODELS = {
     "resnet50": (ResNet50, 224),

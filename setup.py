@@ -1,8 +1,9 @@
 """Package build for horovod_tpu.
 
 Reference: /root/reference/setup.py builds three CMake native extensions;
-here the native runtime (native/ C++ core) builds as a plain shared
-library loaded via ctypes — see horovod_tpu/native/build.py — so `pip
+here the native runtime (horovod_tpu/_native/hvd C++ core) builds as a
+plain shared library loaded via ctypes — `make` driven lazily by
+horovod_tpu/_native/__init__.py:build() — so `pip
 install -e .` needs no compiler until the eager multi-process runtime is
 first used (and the pure-Python/XLA path never needs it).
 """

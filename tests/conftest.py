@@ -18,6 +18,9 @@ if "xla_force_host_platform_device_count" not in _existing:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+# hermetic: a test run neither reads nor writes a persistent compilation
+# cache (the examples' main() would otherwise point one at the checkout)
+jax.config.update("jax_enable_compilation_cache", False)
 
 import pytest  # noqa: E402
 

@@ -12,12 +12,12 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
 from horovod_tpu.core.knobs import Knobs
 from horovod_tpu.core.state import global_state
 from horovod_tpu.ops.autotune import ParameterManager, SPMDStepTuner
-from horovod_tpu.compat import shard_map
 
 
 def _mlp_world():

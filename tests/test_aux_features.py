@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from horovod_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd

@@ -13,9 +13,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import pytest
-from horovod_tpu.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd

@@ -16,10 +16,10 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import optax
 import pytest
-from horovod_tpu.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd

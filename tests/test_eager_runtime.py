@@ -111,7 +111,7 @@ def test_hier_reduce_leaf_matches_flat_psum(hvd8):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from horovod_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     import horovod_tpu as hvd

@@ -47,7 +47,28 @@ def test_bert_pretraining_tiny():
          "--num-batches-per-iter", "2", "--num-warmup-batches", "1"]
     )
     assert per_chip > 0
-    assert 0 <= mfu < 1
+    assert mfu is None  # CPU mesh: MFU is not measured
+
+
+def test_gpt2_pretraining_tiny(capsys):
+    """chip_smoke.py's vehicle on the CPU mesh: same main(), tiny
+    shape, flash kernels interpreted; MFU is reported as not measured
+    and the stats chip_smoke.py inspects are filled."""
+    stats = {}
+    per_chip, mfu = _load("gpt2_pretraining").main(
+        ["--layers", "2", "--hidden", "128", "--seq-len", "64",
+         "--batch-size", "1", "--num-iters", "2",
+         "--num-batches-per-iter", "1", "--num-warmup-batches", "1",
+         "--flash", "--fused-ce"],
+        stats=stats,
+    )
+    assert per_chip > 0 and mfu is None
+    assert "MFU not measured" in capsys.readouterr().out
+    assert len(stats["losses"]) == 2 and np.isfinite(stats["losses"]).all()
+    assert stats["compile_seconds"] > 0
+    assert "all-reduce" in stats["compiled"].as_text()
+    assert len(stats["batch_sharding"].device_set) == 8
+    assert len(stats["loss"].addressable_shards) == 8
 
 
 @pytest.mark.slow  # ~65s of ResNet-50 AOT compile — the single

@@ -24,9 +24,9 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import shard_map
 from horovod_tpu.models import Transformer
 from horovod_tpu.models.transformer import TransformerConfig, causal_lm_loss
 from horovod_tpu.optim import fsdp as fsdp_mod

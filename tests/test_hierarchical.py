@@ -12,9 +12,9 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import pytest
-from horovod_tpu.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import horovod_tpu as hvd

@@ -4,10 +4,10 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import optax
 import pytest
-from horovod_tpu.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
@@ -196,7 +196,7 @@ def test_synthetic_benchmark_model_flag():
          "--num-batches-per-iter", "1", "--num-iters", "1",
          "--num-classes", "10"]
     )
-    assert per_chip > 0 and mfu > 0
+    assert per_chip > 0 and mfu is None  # CPU: not measured
 
 
 def test_resnet_space_to_depth_stem():

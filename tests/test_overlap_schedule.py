@@ -31,13 +31,13 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 _REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 import horovod_tpu as hvd
 from horovod_tpu.models import Transformer
 from horovod_tpu.models.transformer import TransformerConfig
-from horovod_tpu.compat import shard_map
 
 CFG = TransformerConfig(
     vocab_size=512, num_layers=4, num_heads=8, hidden_size=512,

@@ -7,9 +7,9 @@ to subsets of ranks, with non-members unaffected).
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import pytest
-from horovod_tpu.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd

@@ -348,11 +348,46 @@ def test_sampling_respects_every_n(clean_prof, monkeypatch, tmp_path):
     assert prof.sample_count() == 3  # steps 3, 6, 9
 
 
-def test_mfu_gauge_and_jsonl(clean_prof, tmp_path):
+class _FakeV5e:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+
+def test_peak_flops_resolves_from_device_kind_only():
+    from horovod_tpu.utils import mfu
+
+    assert mfu.peak_flops_per_chip("TPU v5 lite") == 197e12
+    with pytest.raises(ValueError, match="Quantum 9000"):
+        mfu.peak_flops_per_chip("Quantum 9000")
+    # no default generation: the CPU test world is an unknown device
+    with pytest.raises(ValueError, match="cpu"):
+        mfu.peak_flops_per_chip()
+    assert mfu.mfu_or_none(1e12) is None
+    assert mfu.format_mfu(None) == "MFU not measured"
+
+
+def test_mfu_not_published_off_tpu(clean_prof):
+    """A CPU world has no published peak: declaring the model cost arms
+    the step wrapper but no hvd_mfu ever appears."""
+    clock = [0.0]
+    metrics.enable()
+    prof.configure(every=0, clock=lambda: clock[0])
+    prof.set_step_flops(1e9, n_chips=1)
+    with metrics.step():
+        clock[0] += 0.010
+    assert prof.last_mfu() is None
+    assert "hvd_mfu" not in metrics.registry.snapshot()
+
+
+def test_mfu_gauge_and_jsonl(clean_prof, tmp_path, monkeypatch):
+    import jax
+
     from horovod_tpu.utils import mfu
 
     clock = [50.0]
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeV5e()])
     peak = mfu.peak_flops_per_chip()
+    assert peak == 197e12
     metrics.enable()
     log = str(tmp_path / "steps.jsonl")
     metrics.step_stats.open_log(log)
@@ -593,7 +628,7 @@ def test_profiler_e2e_real_capture(clean_prof, tmp_path):
     assert attr and attr["compute_frac"] > 0
     assert 0.0 <= attr["exposed_wire_frac"] <= 1.0
     assert attr["sampled_step"] == 2
-    assert prof.last_mfu() and prof.last_mfu() > 0
+    assert prof.last_mfu() is None  # CPU world: MFU is not measured
     # the sidecar anchors the capture for trace_merge
     sample_dirs = []
     for root, _dirs, files in os.walk(str(tmp_path)):

@@ -8,9 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import shard_map
 from horovod_tpu.ops.sparse import (
     IndexedSlices,
     dense_to_sparse,

@@ -13,9 +13,9 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 import horovod_tpu as hvd
-from horovod_tpu.compat import shard_map
 
 
 def _world():
