@@ -1,0 +1,443 @@
+"""Data-parallel training of a ``transformer_lm`` configuration.
+
+The step is built the way ``examples/gpt2_pretraining.py`` and
+``examples/bert_pretraining.py`` build theirs (a copy: their loops run a
+fixed number of iterations inside ``main()`` and cannot be timed for
+``--seconds``): ``hvd.init`` → ``hvd.DistributedOptimizer(optax.adamw)``
+→ ``hvd.broadcast_parameters`` → a ``shard_map`` step over the ``hvd``
+axis, AOT-compiled with ``xla_tpu_scoped_vmem_limit_kib=65536`` and
+called as an executable. Nothing here sets a ``HOROVOD_*`` variable or
+a knob: a cell runs the program's defaults.
+
+Everything the cell's parameters select is in its traffic file
+(``objective``, ``seq_len``, ``batch_per_chip``, ``attention``,
+``loss_head``, ``learning_rate``); the model's sizes are in its
+configuration file and are built as written. The loop's numbers below
+define the metrics and are the same in every cell. One process drives
+every chip of the cell.
+"""
+
+from __future__ import annotations
+
+import math
+import statistics
+import time
+
+import numpy as np
+
+from benchmarks import harness
+from benchmarks.reference import transformer_lm as reference
+
+# Agreement of the system's loss function (bf16 activations, flash
+# attention, fused or dense cross entropy, fp32 parameters) with the
+# float32 reference at the published width and depth, two sequences.
+# Measured on the chip (PR 22, TPU v5 lite, seeds 1-3): the loss agreed
+# to 1.2e-5 (GPT-2-medium) and 1.2e-4 (BERT-Large) relative, the
+# gradient's global norm to 1.23e-2 .. 1.30e-2. bf16 keeps 8 bits, so
+# each activation is off by up to 2^-9 = 0.2% and 24 layers of them add
+# to about 1% in the gradient. The limits are four times the loss error
+# and a little over twice the gradient error seen: activations in an
+# 8-bit float (2^-4 a value) or gradients accumulated in bf16 are many
+# times past them, and float32 activations would pass far inside.
+LOSS_RTOL = 5e-4
+GRAD_RTOL = 3e-2
+# step 0 on the whole global batch against the reference forward pass
+# over the same batch in blocks: measured 3e-6 and 6e-6 relative (more
+# positions average the rounding out), limit 1e-4. It holds the compiled
+# step's forward pass at the real batch shape and the average over
+# chips (a loss summed over chips, or a chip's share left out of the
+# mean, is far outside). At random weights every sequence has nearly
+# the same loss, so a sequence on the wrong chip is NOT seen here: the
+# placement checks below see that.
+GLOBAL_LOSS_RTOL = 1e-4
+# tokens a chip takes in one reference call: [8192, V] float32 logits
+# are 1.6 GB at V=50k
+REFERENCE_BLOCK_TOKENS = 8192
+
+# The loop. These are part of what the metrics mean, so no cell sets
+# them: ``tokens_per_s_per_chip`` is the median over chunks of
+# CHUNK_STEPS calls, each chunk closed by one host sync;
+# ``loss_step_16`` is the loss step LOSS_STEP returns, counted from the
+# seed's initial parameters with the warm-up steps included, so a run
+# makes at least LOSS_STEP + 1 steps whatever ``--seconds`` says; the
+# profiler sees TRACED_STEPS steps after the window.
+WARMUP_STEPS = 4
+CHUNK_STEPS = 4
+LOSS_STEP = 16
+TRACED_STEPS = 6
+
+
+def make_model(model_sizes: dict, traffic: dict):
+    """The program's ``Transformer`` at the configuration's sizes, with
+    the attention the traffic names; also returned without the kernel,
+    for parameter init (the same tree, no kernel compiled at [1, T])."""
+    from horovod_tpu.models.transformer import (
+        Transformer, TransformerConfig)
+
+    cfg = TransformerConfig(**model_sizes)
+    if traffic["seq_len"] > cfg.max_seq_len:
+        raise ValueError(
+            f"the traffic's seq_len {traffic['seq_len']} is longer than "
+            f"the configuration's max_seq_len {cfg.max_seq_len}")
+    attention_fn = None
+    if traffic["attention"] == "flash":
+        from horovod_tpu.ops.pallas_attention import (
+            make_flash_attention_fn)
+        attention_fn = make_flash_attention_fn(causal=cfg.causal)
+    elif traffic["attention"] != "xla":
+        raise ValueError(f"unknown attention {traffic['attention']!r}")
+    return cfg, Transformer(cfg, attention_fn=attention_fn), \
+        Transformer(cfg)
+
+
+def make_loss_fn(model, traffic: dict):
+    """``loss(params, *batch)`` as the examples define it."""
+    from horovod_tpu.models.transformer import causal_lm_loss, mlm_loss
+    from horovod_tpu.ops.fused_cross_entropy import (
+        fused_causal_lm_loss, fused_linear_cross_entropy)
+
+    objective, head = traffic["objective"], traffic["loss_head"]
+    if head not in ("fused_ce", "dense"):
+        raise ValueError(f"unknown loss_head {head!r}")
+
+    def hidden_and_head(p, tok):
+        return (model.apply({"params": p}, tok, return_hidden=True),
+                p["tok_emb"]["embedding"].T)
+
+    if objective == "causal_lm":
+        def loss_fn(p, tok):
+            if head == "fused_ce":
+                return fused_causal_lm_loss(*hidden_and_head(p, tok),
+                                            tok)[0]
+            return causal_lm_loss(model.apply({"params": p}, tok),
+                                  tok)[0]
+    elif objective == "masked_lm":
+        def loss_fn(p, tok, lab, msk):
+            if head == "fused_ce":
+                return fused_linear_cross_entropy(
+                    *hidden_and_head(p, tok), lab, valid=msk)[0]
+            return mlm_loss(model.apply({"params": p}, tok), lab,
+                            msk)[0]
+    else:
+        raise ValueError(f"unknown objective {objective!r}")
+    return loss_fn
+
+
+def make_batch(model_sizes: dict, traffic: dict, n_seq: int, seed: int):
+    """The seeded batch on the host: uniform random tokens (and, for
+    masked LM, labels and a Bernoulli mask), ``n_seq`` sequences."""
+    rng = np.random.default_rng(seed)
+    shape = (n_seq, traffic["seq_len"])
+    vocab = model_sizes["vocab_size"]
+    tokens = rng.integers(0, vocab, shape, dtype=np.int32)
+    if traffic["objective"] == "causal_lm":
+        return (tokens,)
+    labels = rng.integers(0, vocab, shape, dtype=np.int32)
+    mask = rng.random(shape) < traffic["mask_fraction"]
+    return tokens, labels, mask
+
+
+def make_step(loss_fn, opt, mesh, n: int, n_batch_args: int):
+    """The jitted ``shard_map`` train step: parameters and optimizer
+    state replicated and donated, the batch split over ``hvd``."""
+    import jax
+    import optax
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def step_fn(p, s, *batch):
+        loss, g = jax.value_and_grad(loss_fn)(p, *batch)
+        upd, s = opt.update(g, s, p)
+        p = optax.apply_updates(p, upd)
+        return p, s, jax.lax.psum(loss, "hvd").reshape(1) / n
+
+    return jax.jit(
+        shard_map(
+            step_fn, mesh=mesh,
+            in_specs=(P(), P()) + (P("hvd"),) * n_batch_args,
+            out_specs=(P(), P(), P()),
+            check_vma=False,
+        ),
+        donate_argnums=(0, 1),
+    )
+
+
+STEP_MODULE_HINT = "step_fn"
+
+
+def compile_step(lowered, for_tpu: bool):
+    """The lowered step compiled with the examples' TPU option."""
+    if for_tpu:
+        return lowered.compile(compiler_options={
+            "xla_tpu_scoped_vmem_limit_kib": "65536"})
+    return lowered.compile()
+
+
+def _reference_kw(cfg, traffic: dict) -> dict:
+    return dict(objective=traffic["objective"], num_layers=cfg.num_layers,
+                causal=cfg.causal, eps=cfg.layernorm_epsilon)
+
+
+def reference_check(run, cfg, loss_fn, params, model_sizes, traffic):
+    """Loss and gradient of the system's own loss function against the
+    plain float32 reference, published width and depth, two seeded
+    sequences of the cell's length, one device."""
+    import jax
+    import optax
+
+    batch = tuple(jax.numpy.asarray(a) for a in make_batch(
+        model_sizes, traffic, 2, run.seed + 1))
+    kw = _reference_kw(cfg, traffic)
+
+    @jax.jit
+    def compare(p, *b):
+        l_sys, g_sys = jax.value_and_grad(loss_fn)(p, *b)
+        l_ref, g_ref = jax.value_and_grad(
+            lambda q: reference.mean_loss(q, b, **kw))(p)
+        diff = jax.tree_util.tree_map(
+            lambda a, r: a.astype(jax.numpy.float32) - r, g_sys, g_ref)
+        return (l_sys, l_ref,
+                optax.global_norm(diff) / optax.global_norm(g_ref))
+
+    with run.span("reference_check"):
+        l_sys, l_ref, g_err = (float(x) for x in compare(params, *batch))
+    run.log(f"reference check: loss {l_sys:.5f} vs float32 reference "
+            f"{l_ref:.5f}, gradient relative error {g_err:.3e}")
+    run.check("reference_loss",
+              math.isfinite(l_sys)
+              and abs(l_sys - l_ref) <= LOSS_RTOL * abs(l_ref),
+              f"{l_sys} vs {l_ref}")
+    run.check("reference_gradient", g_err <= GRAD_RTOL, f"{g_err}")
+
+
+def reference_global_loss(run, cfg, params, host_batch, traffic, mesh,
+                          n: int):
+    """The reference's mean loss over the whole global batch, in blocks
+    of at most REFERENCE_BLOCK_TOKENS tokens a chip (each chip takes the
+    sequences the step will give it)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    kw = _reference_kw(cfg, traffic)
+    shard = NamedSharding(mesh, P("hvd"))
+    block_fn = jax.jit(
+        lambda p, *b: reference.nll_sum(p, b, **kw),
+        in_shardings=(NamedSharding(mesh, P()),)
+        + (shard,) * len(host_batch),
+        out_shardings=NamedSharding(mesh, P()))
+    per_chip = traffic["batch_per_chip"]
+    blk = max(d for d in range(1, per_chip + 1) if per_chip % d == 0
+              and d * traffic["seq_len"] <= REFERENCE_BLOCK_TOKENS)
+    total = count = 0.0
+    with run.span("reference_global_loss"):
+        for lo in range(0, per_chip, blk):
+            rows = np.concatenate([
+                np.arange(d * per_chip + lo, d * per_chip + lo + blk)
+                for d in range(n)])
+            part = tuple(jax.device_put(a[rows], shard)
+                         for a in host_batch)
+            s, c = block_fn(params, *part)
+            total += float(s)
+            count += float(c)
+    return total / max(count, 1.0)
+
+
+def build(run, model_sizes: dict, traffic: dict, mesh=None):
+    """Model, optimizer and jitted step on ``mesh`` (default: the world
+    ``hvd.init()`` finds)."""
+    import optax
+
+    import horovod_tpu as hvd
+
+    with run.span("init"):
+        hvd.init(mesh=mesh)
+        n, mesh = hvd.size(), hvd.mesh()
+        cfg, model, plain_model = make_model(model_sizes, traffic)
+        opt = hvd.DistributedOptimizer(
+            optax.adamw(traffic["learning_rate"]))
+        loss_fn = make_loss_fn(model, traffic)
+        n_batch_args = 1 if traffic["objective"] == "causal_lm" else 3
+        step = make_step(loss_fn, opt, mesh, n, n_batch_args)
+    return dict(n=n, mesh=mesh, cfg=cfg, plain_model=plain_model,
+                opt=opt, loss_fn=loss_fn, step=step)
+
+
+def run_cell(run, model_sizes: dict, traffic: dict) -> dict:
+    """One run of the cell: set-up with the correctness checks, the
+    timed window, and with ``run.trace`` a traced window after it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+
+    built = build(run, model_sizes, traffic)
+    n, mesh, cfg = built["n"], built["mesh"], built["cfg"]
+    step, opt = built["step"], built["opt"]
+    if n != run.chips:
+        raise SystemExit(
+            f"the cell asks for {run.chips} chip(s) and hvd.init() "
+            f"found {n}")
+    seq, per_chip = traffic["seq_len"], traffic["batch_per_chip"]
+    tokens_per_step = n * per_chip * seq
+
+    with run.span("init"):
+        params = jax.jit(built["plain_model"].init)(
+            jax.random.PRNGKey(run.seed),
+            jnp.zeros((1, seq), dtype=jnp.int32))["params"]
+        jax.block_until_ready(params)
+
+    reference_check(run, cfg, built["loss_fn"], params, model_sizes,
+                    traffic)
+
+    with run.span("init"):
+        opt_state = opt.init(params)
+        params = hvd.broadcast_parameters(params, root_rank=0)
+        jax.block_until_ready(params)
+        host_batch = make_batch(model_sizes, traffic, n * per_chip,
+                                run.seed)
+        shard = NamedSharding(mesh, P("hvd"))
+        batch = tuple(jax.device_put(a, shard) for a in host_batch)
+
+    ref_loss0 = reference_global_loss(
+        run, cfg, params, host_batch, traffic, mesh, n)
+
+    on_tpu = jax.default_backend() == "tpu"
+    with run.span("lower"):
+        lowered = step.lower(params, opt_state, *batch)
+    with run.span("compile"):
+        compiled = compile_step(lowered, on_tpu)
+    run.hlo_text = compiled.as_text()
+    run.memory = compiled.memory_analysis()
+    run.step_module_hint = STEP_MODULE_HINT
+
+    losses = []  # device arrays, read after the window has closed
+
+    def run_steps(k, annotate=False):
+        nonlocal params, opt_state
+        for _ in range(k):
+            with run.span("step_call", annotate=annotate):
+                params, opt_state, loss = compiled(
+                    params, opt_state, *batch)
+            losses.append(loss)
+        with run.span("wait_loss", annotate=annotate):
+            np.asarray(losses[-1])  # host sync closes the chunk
+
+    with run.span("warmup"):
+        run_steps(WARMUP_STEPS)
+
+    chunk_s = []
+    run.begin_window()
+    t_end = time.perf_counter() + run.seconds
+    while True:
+        t0 = time.perf_counter()
+        run_steps(CHUNK_STEPS)
+        t1 = time.perf_counter()
+        chunk_s.append(t1 - t0)
+        if t1 >= t_end and len(losses) > LOSS_STEP:
+            break
+    run.end_window()
+
+    rates = [CHUNK_STEPS * tokens_per_step / s / n for s in chunk_s]
+    rate = statistics.median(rates)
+    run.log(f"window: {len(chunk_s)} chunks of {CHUNK_STEPS} steps, "
+            f"{sum(chunk_s):.2f} s; tokens/s/chip median {rate:.1f} "
+            f"min {min(rates):.1f} max {max(rates):.1f}")
+
+    if run.trace:
+        with run.profiler():
+            run_steps(TRACED_STEPS, annotate=True)
+
+    host_losses = [float(np.asarray(x)[0]) for x in losses]
+    run.log("losses: " + " ".join(f"{x:.4f}" for x in host_losses[:20]))
+    loss0, loss16 = host_losses[0], host_losses[LOSS_STEP]
+    run.log(f"step 0 loss {loss0:.5f} vs float32 reference over the "
+            f"global batch {ref_loss0:.5f}")
+    run.check("global_batch_loss",
+              abs(loss0 - ref_loss0) <= GLOBAL_LOSS_RTOL * abs(ref_loss0),
+              f"{loss0} vs {ref_loss0}")
+    failed = sum(not math.isfinite(x) for x in host_losses)
+    run.check("losses_finite", failed == 0, f"{failed} not finite")
+    run.check("loss_falls", loss16 < loss0, f"{loss0} -> {loss16}")
+    run.check("no_compile_in_window", run.window_compiles == 0,
+              f"{run.window_compiles} compilations")
+    if on_tpu:
+        from benchmarks import hlo
+        run.check("mosaic_kernels_compiled",
+                  hlo.MOSAIC_TARGET in run.hlo_text,
+                  "no tpu_custom_call in the compiled step")
+    placement_checks(run, n, batch, params, losses[-1])
+
+    mem = run.memory
+    step_bytes = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                  - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+                  + mem.generated_code_size_in_bytes)
+    run.log(f"compiled step per device: arguments "
+            f"{mem.argument_size_in_bytes} outputs "
+            f"{mem.output_size_in_bytes} aliased "
+            f"{mem.alias_size_in_bytes} temporaries "
+            f"{mem.temp_size_in_bytes} code "
+            f"{mem.generated_code_size_in_bytes} bytes")
+    run.step_bytes = step_bytes
+    run.tokens_per_s_per_chip = rate
+    run.step_seconds = tokens_per_step / n / rate
+    return {
+        "attempted": len(host_losses), "failed": failed,
+        "metrics": {
+            "tokens_per_s_per_chip": (rate, "tokens/s/chip"),
+            "step_hbm_gib": (step_bytes / harness.GIB, "GiB"),
+            "loss_step_16": (loss16, "nats"),
+        },
+        # neither a time nor a size on a device: a rehearsal may print it
+        "platform_free": {"loss_step_16"},
+    }
+
+
+def _bit_sums(params, run_mesh):
+    """[n devices, n leaves] uint32: each device's wrapping sum of the
+    bits of its own copy of every parameter."""
+    import jax
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def local(p):
+        return jnp.stack([
+            jnp.sum(jax.lax.bitcast_convert_type(
+                x.astype(jnp.float32), jnp.uint32), dtype=jnp.uint32)
+            for x in jax.tree_util.tree_leaves(p)])[None]
+
+    return jax.jit(shard_map(local, mesh=run_mesh, in_specs=(P(),),
+                             out_specs=P("hvd"), check_vma=False))(params)
+
+
+def placement_checks(run, n, batch, params, loss):
+    """chip_smoke.py's assertions about where things live."""
+    import jax
+
+    per_device = {s.device: float(np.asarray(s.data)[0])
+                  for s in loss.addressable_shards}
+    run.check("loss_on_every_device", len(per_device) == n,
+              f"{len(per_device)} of {n}")
+    run.check("loss_equal_on_every_device",
+              len(set(per_device.values())) == 1, f"{per_device}")
+    if n == 1:
+        return
+    placed = batch[0].sharding.devices_indices_map(batch[0].shape)
+    run.check("batch_split_over_devices",
+              len(placed) == n
+              and len(set(map(str, placed.values()))) == n, f"{placed}")
+    leaves = jax.tree_util.tree_leaves(params)
+    run.check("parameters_replicated",
+              all(x.sharding.is_fully_replicated
+                  and len(x.sharding.device_set) == n for x in leaves),
+              "a parameter is not on every device")
+    # bitwise equal on every device after the window: each device sums
+    # its own copy's bits and the host compares the n sums of each leaf
+    sums = np.asarray(_bit_sums(params, run_mesh=batch[0].sharding.mesh))
+    different = int(np.sum(np.any(sums != sums[:1], axis=0)))
+    run.check("parameters_bitwise_equal", different == 0,
+              f"{different} leaves differ between devices")
+    from benchmarks import hlo
+    run.check("allreduce_in_step", len(hlo.allreduces(run.hlo_text)) > 0,
+              "no all-reduce in the compiled multi-chip step")

@@ -1,0 +1,107 @@
+"""Plain reference for the ``transformer_lm`` family: GPT-2 (causal)
+and the repo's BERT-shaped encoder (bidirectional), forward pass and
+loss in straightforward ``jax.numpy``.
+
+float32 throughout under ``jax.default_matmul_precision("highest")``
+(on a TPU a float32 product otherwise runs in bfloat16 passes); no
+kernel, no flax module, nothing imported from the program. It takes the
+program's parameter tree (flax names: ``tok_emb/embedding``, ``pos_emb``,
+``block_<i>/{ln_attn,attn/{query,key,value,out},ln_mlp,mlp/{fc1,fc2}}``,
+``ln_final``) so that the same seeded weights go through both.
+
+The equations are the published pre-LayerNorm GPT-2 block (Radford et
+al. 2019; Hugging Face ``GPT2Block``): x += Attn(LN(x)); x += MLP(LN(x));
+final LN; logits through the tied token embedding. The encoder is the
+same block without the causal mask: that is how this repo's BERT
+departs from the published post-LN model, and the configuration file
+lists it. The layers run under ``lax.scan`` with ``jax.checkpoint``:
+that changes what is kept for the backward pass, not one number of it.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+
+def _ln(x, p, eps):
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+
+
+def _gelu_tanh(x):
+    return 0.5 * x * (1.0 + jnp.tanh(
+        math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
+
+
+def _block(x, p, *, causal, eps):
+    y = _ln(x, p["ln_attn"], eps)
+    a = p["attn"]
+    q = jnp.einsum("bth,hnd->btnd", y, a["query"]["kernel"]) \
+        + a["query"]["bias"]
+    k = jnp.einsum("bth,hnd->btnd", y, a["key"]["kernel"]) \
+        + a["key"]["bias"]
+    v = jnp.einsum("bth,hnd->btnd", y, a["value"]["kernel"]) \
+        + a["value"]["bias"]
+    s = jnp.einsum("bqnd,bknd->bnqk", q, k) / math.sqrt(q.shape[-1])
+    if causal:
+        t = s.shape[-1]
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bnqk,bknd->bqnd", w, v)
+    x = x + jnp.einsum("bqnd,ndh->bqh", o, a["out"]["kernel"]) \
+        + a["out"]["bias"]
+    y = _ln(x, p["ln_mlp"], eps)
+    m = p["mlp"]
+    h = _gelu_tanh(y @ m["fc1"]["kernel"] + m["fc1"]["bias"])
+    return x + h @ m["fc2"]["kernel"] + m["fc2"]["bias"]
+
+
+def logits(params, tokens, *, num_layers, causal, eps):
+    """[B, T, V] float32 logits of the parameter tree on ``tokens``."""
+    p = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), params)
+    t = tokens.shape[1]
+    x = p["tok_emb"]["embedding"][tokens] + p["pos_emb"][:t]
+    stacked = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs),
+        *[p[f"block_{i}"] for i in range(num_layers)])
+
+    @jax.checkpoint
+    def layer(x, bp):
+        return _block(x, bp, causal=causal, eps=eps), None
+
+    x, _ = jax.lax.scan(layer, x, stacked)
+    x = _ln(x, p["ln_final"], eps)
+    return x @ p["tok_emb"]["embedding"].T
+
+
+def _nll(lg, targets):
+    lse = jax.scipy.special.logsumexp(lg, axis=-1)
+    return lse - jnp.take_along_axis(lg, targets[..., None], -1)[..., 0]
+
+
+def nll_sum(params, batch, *, objective, num_layers, causal, eps):
+    """(sum of the negative log likelihoods, number of positions that
+    count) of one block of sequences. ``batch`` is ``(tokens,)`` for
+    ``causal_lm`` (each position predicts the next token) and
+    ``(tokens, labels, mask)`` for ``masked_lm`` (labels at the masked
+    positions)."""
+    with jax.default_matmul_precision("highest"):
+        lg = logits(params, batch[0], num_layers=num_layers,
+                    causal=causal, eps=eps)
+        if objective == "causal_lm":
+            nll = _nll(lg[:, :-1], batch[0][:, 1:])
+            return jnp.sum(nll), jnp.float32(nll.size)
+        if objective == "masked_lm":
+            _, labels, mask = batch
+            nll = jnp.where(mask, _nll(lg, labels), 0.0)
+            return jnp.sum(nll), jnp.sum(mask).astype(jnp.float32)
+    raise ValueError(f"unknown objective {objective!r}")
+
+
+def mean_loss(params, batch, **kw):
+    total, count = nll_sum(params, batch, **kw)
+    return total / jnp.maximum(count, 1.0)

@@ -1,0 +1,339 @@
+"""The benchmark's trace reduction, HLO counters and operation
+arithmetic, on hand-made inputs with known answers."""
+
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from benchmarks import flops, harness, hlo, trace  # noqa: E402
+
+US = 1000  # the events below are written in microseconds
+
+
+def ev(name, start_us, dur_us):
+    return (name, start_us * US, dur_us * US)
+
+
+# one 100 us step on one device: compute [0,40], an asynchronous
+# all-reduce whose start is [40,42] and done [70,80] with compute
+# [42,60] between them, a synchronous all-gather [80,85], a while
+# [85,95] with two body fusions, and nothing in [95,100]
+STEP_OPS = [
+    ev("fusion.1", 0, 40),
+    ev("all-reduce-start.1", 40, 2),
+    ev("fusion.2", 42, 18),
+    ev("all-reduce-done.1", 70, 10),
+    ev("all-gather.3", 80, 5),
+    ev("while.1", 85, 10),
+    ev("fusion.3", 85, 4),
+    ev("custom-call.7", 90, 5),
+]
+STEP_MODULES = [ev("jit_step_fn(123)", 0, 100)]
+
+
+@pytest.mark.parametrize("spans,want", [
+    ([(0, 5), (3, 8), (10, 12)], [(0, 8), (10, 12)]),
+    ([(5, 5), (1, 2)], [(1, 2)]),
+    ([], []),
+])
+def test_merge(spans, want):
+    assert trace.merge(spans) == want
+
+
+@pytest.mark.parametrize("a,b,want", [
+    ([(0, 10)], [(2, 4), (6, 12)], [(0, 2), (4, 6)]),
+    ([(0, 10), (20, 30)], [(5, 25)], [(0, 5), (25, 30)]),
+    ([(0, 10)], [], [(0, 10)]),
+    ([(0, 10)], [(0, 10)], []),
+])
+def test_subtract(a, b, want):
+    assert trace.subtract(a, b) == want
+
+
+@pytest.mark.parametrize("name,want", [
+    ("all-reduce.12", ("all-reduce", "sync")),
+    ("all-reduce-start", ("all-reduce", "start")),
+    ("all-gather-done.3", ("all-gather", "done")),
+    ("collective-permute-start.1", ("collective-permute", "start")),
+    ("fusion.3", None),
+    ("all-reduce-scatter-fusion", None),
+])
+def test_collective_kind(name, want):
+    assert trace.collective_kind(name) == want
+
+
+def test_instruction_name_of_a_device_event():
+    assert trace.instruction_name(
+        "%attn.72 = bf16[16,16,1024,64]{3,2,1,0} custom-call(%a), "
+        "custom_call_target=\"tpu_custom_call\"") == "attn.72"
+    assert trace.instruction_name("jit_step_fn(96)") == "jit_step_fn(96)"
+
+
+def test_start_done_pair_is_one_interval():
+    spans = trace.merge(trace.collective_spans(STEP_OPS))
+    # the pair is in flight from 40 to 80; the all-gather joins it
+    assert spans == [(40 * US, 85 * US)]
+
+
+def test_done_without_start_counts_from_itself():
+    assert trace.collective_spans([ev("all-reduce-done.1", 7, 3)]) == [
+        (7 * US, 10 * US)]
+
+
+def test_leaves_drop_the_parent():
+    names = [e[0] for e in trace.leaves(STEP_OPS)]
+    assert "while.1" not in names and "fusion.3" in names
+
+
+def test_self_seconds_subtract_children():
+    got = trace.self_seconds_by_name(STEP_OPS)
+    assert got["while.1"] == pytest.approx(1e-6)  # 10 - 4 - 5
+    assert got["fusion.1"] == pytest.approx(40e-6)
+
+
+@pytest.mark.parametrize("name,want", [
+    ("fusion.5839", "fusion"), ("attn.72", "attn"),
+    ("convolution_add_fusion.20.remat", "convolution_add_fusion"),
+    ("broadcast_in_dim.260.clone", "broadcast_in_dim"),
+    ("all-reduce-start.1", "all-reduce-start"), ("while", "while"),
+])
+def test_stem(name, want):
+    assert trace.stem(name) == want
+
+
+def test_one_step_one_device():
+    r = trace.reduce_device(STEP_OPS, trace.step_windows(
+        STEP_MODULES, "step_fn"), kernel_names=["custom-call.7"])
+    (s,) = r["steps"]
+    assert s["busy_ns"] == (60 + 25) * US  # [0,60] and [70,95]
+    assert s["collective_ns"] == 45 * US  # [40,85]
+    # not covered by fusion.2 [42,60]: [40,42], [60,85]
+    assert s["exposed_collective_ns"] == 27 * US
+    assert s["kernel_ns"] == {"custom-call": 5 * US}
+    assert r["gaps"] == [(60 * US, 70 * US), (95 * US, 100 * US)]
+
+
+def shifted(events, by_us):
+    return [(n, s + by_us * US, d) for n, s, d in events]
+
+
+def test_two_devices_two_steps_worst_device_and_gap():
+    # device 1 runs the same two steps but its first fusion takes 10 us
+    # less, so its busy time is lower and its idle share is higher
+    dev0_ops = STEP_OPS + shifted(STEP_OPS, 100)
+    dev1_step = [ev("fusion.1", 10, 30)] + STEP_OPS[1:]
+    dev1_ops = dev1_step + shifted(dev1_step, 100)
+    modules = STEP_MODULES + shifted(STEP_MODULES, 100)
+    host = [("bench:step_call", 0, 20 * US),
+            ("bench:wait_loss", 20 * US, 200 * US)]
+    r = trace.reduce(
+        {0: {"modules": modules, "ops": dev0_ops},
+         1: {"modules": modules, "ops": dev1_ops}},
+        host, "step_fn", kernel_names=["custom-call.7"])
+    assert r["devices"] == 2 and r["traced_steps"] == 2
+    assert r["device_busy_ms"] == pytest.approx(0.085)  # device 0
+    assert r["collective_ms"] == pytest.approx(0.045)
+    assert r["exposed_collective_ms"] == pytest.approx(0.027)
+    assert r["kernel_ms"] == pytest.approx(0.005)
+    assert r["kernel_ms_by_stem"] == {"custom-call": pytest.approx(0.005)}
+    assert r["step_period_ms"] == pytest.approx(0.1)
+    # device 0 is idle 30 of 200 us, device 1 50 of 200
+    assert r["device_idle_pct"] == pytest.approx(25.0)
+    assert r["busy_s"] == pytest.approx((170e-6 + 150e-6) / 2)
+    assert r["window_s"] == pytest.approx(200e-6)
+    # the breakdown is device 1's. Its longest gap joins the end of
+    # step one [95,100] to the late start of step two [100,110], while
+    # the host waited for the loss; [0,10] lies under the step call
+    # three fusions of two steps under one stem
+    assert r["device_ops"][0] == ["fusion x3", pytest.approx(104e-6)]
+    assert r["idle_gaps"][0] == ["bench:wait_loss", pytest.approx(15e-6)]
+    assert ["bench:step_call", pytest.approx(10e-6)] in r["idle_gaps"]
+    assert len(r["idle_gaps"]) == 5
+
+
+class FakeRun:
+    """What a reader needs of a run."""
+
+    def __init__(self, reduced_trace):
+        self.reduced_trace = reduced_trace
+        self.logged = []
+
+    def log(self, text):
+        self.logged.append(text)
+
+
+def test_attention_metric_leaves_out_another_kernel_family():
+    # a step with two attention calls and a Pallas norm beside them,
+    # all three Mosaic calls by the HLO; a fusion that shares the
+    # attention stem's name but is no Mosaic call is not a kernel
+    from benchmarks.layer_metrics import attn_kernel_ms, mosaic_kernel_ms
+    ops = [ev("fusion.1", 0, 20), ev("attn.3", 20, 30),
+           ev("layer_norm.9", 50, 8), ev("attn.4", 58, 12),
+           ev("attn_mask_fusion.2", 70, 5)]
+    r = trace.reduce(
+        {0: {"modules": [ev("jit_step_fn", 0, 80)], "ops": ops}}, [],
+        "step_fn", kernel_names=["attn.3", "attn.4", "layer_norm.9"])
+    assert r["kernel_ms_by_stem"] == {
+        "attn": pytest.approx(0.042), "layer_norm": pytest.approx(0.008)}
+    run = FakeRun(r)
+    assert attn_kernel_ms.read(run) == pytest.approx(0.042)
+    assert mosaic_kernel_ms.read(run) == pytest.approx(0.050)
+    assert "layer_norm 0.008 ms" in run.logged[0]
+    # no attention stem among the kernels: the reader has nothing to
+    # read and the harness leaves the metric out
+    r = trace.reduce(
+        {0: {"modules": [ev("jit_step_fn", 0, 80)], "ops": ops}}, [],
+        "step_fn", kernel_names=["layer_norm.9"])
+    assert attn_kernel_ms.read(FakeRun(r)) is None
+    assert mosaic_kernel_ms.read(FakeRun(r)) == pytest.approx(0.008)
+    assert attn_kernel_ms.read(FakeRun({})) is None
+    assert mosaic_kernel_ms.read(FakeRun({})) is None
+
+
+def test_no_device_plane_reduces_to_nothing():
+    assert trace.reduce({}, [], "step_fn") == {}
+
+
+def test_one_chip_step_has_zero_collective_time():
+    ops = [ev("fusion.1", 0, 40), ev("custom-call.2", 40, 10)]
+    r = trace.reduce({0: {"modules": [ev("jit_step_fn", 0, 50)],
+                          "ops": ops}}, [], "step_fn")
+    assert r["collective_ms"] == 0.0 and r["exposed_collective_ms"] == 0.0
+    assert r["device_idle_pct"] == pytest.approx(0.0)
+
+
+def test_collective_named_by_the_program_is_found_by_its_opcode():
+    # jax.lax.psum's all-reduce is named psum.<n> in the compiled step
+    ops = [ev("fusion.1", 0, 10), ev("psum.91", 10, 5),
+           ev("fusion.2", 15, 5)]
+    assert trace.collective_spans(ops) == []
+    r = trace.reduce_device(ops, [(0, 20 * US)], [],
+                            opcodes={"psum.91": "all-reduce"})
+    assert r["steps"][0]["collective_ns"] == 5 * US
+    assert r["steps"][0]["exposed_collective_ns"] == 5 * US
+
+
+@pytest.mark.parametrize("text,want", [
+    ("%psum.91 = f32[32243712]{0:T(1024)} all-reduce(f32[32243712]"
+     "{0:T(1024)} %fusion.1), channel_id=3", "all-reduce"),
+    ("%attn.72 = (bf16[16,16,1024,64]{3,2,1,0:T(8,128)(2,1)}, f32[16]"
+     "{0:T(1,128)}) custom-call(bf16[2]{0} %f)", "custom-call"),
+    ("%x = (f32[2]{0}, u32[]{:S(2)}) all-reduce-start(f32[2]{0} %y)",
+     "all-reduce-start"),
+    ("all-gather-done.3", "all-gather-done.3"),
+])
+def test_opcode_of_a_device_event(text, want):
+    assert trace.opcode_of(text) == want
+
+
+def test_recorded_chip_step():
+    """One train step of gpt2m_dp4 as the chip's trace has it (device
+    0; my chip run, PR 22): 11 gradient all-reduces and the loss's, all
+    synchronous and after the backward pass, so every nanosecond of them
+    is exposed; 72 Mosaic kernel calls."""
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "data", "gpt2m_dp4_step.json")
+    with open(path) as f:
+        rec = json.load(f)
+    ops = [tuple(o) for o in rec["ops"]]
+    modules = [tuple(m) for m in rec["modules"]]
+    assert len(trace.collective_spans(ops, rec["opcodes"])) == 12
+    r = trace.reduce({0: {"modules": modules, "ops": ops,
+                          "opcodes": rec["opcodes"]}}, [], "step_fn",
+                     kernel_names=rec["kernel_names"])
+    assert r["device_busy_ms"] == pytest.approx(432.333984)
+    assert r["collective_ms"] == pytest.approx(24.585723)
+    assert r["exposed_collective_ms"] == pytest.approx(24.585723)
+    assert r["kernel_ms"] == pytest.approx(104.297232)
+    assert r["kernel_ms_by_stem"] == {"attn": pytest.approx(104.297232)}
+    assert r["device_idle_pct"] == pytest.approx(0.0177047, rel=1e-4)
+    assert r["device_ops"][0] == ["attn x72", pytest.approx(0.104297232)]
+    # the two while loops of the fused cross entropy are parents: their
+    # time is their bodies', and is counted once
+    assert sum(s for _, s in r["device_ops"]) < 0.4324
+
+
+HLO_TEXT = """
+ENTRY %main {
+  %all-reduce-start.1 = f32[1024,256]{1,0} all-reduce-start(%fusion.3), channel_id=1
+  %all-reduce-done.1 = f32[1024,256]{1,0} all-reduce-done(%all-reduce-start.1)
+  %all-reduce.2 = (f32[8]{0}, bf16[4,4]{1,0}, f32[]) all-reduce(%a, %b, %c), to_apply=%sum
+  %custom-call.7 = bf16[16,16,1024,64]{3,2,1,0} custom-call(%q, %k, %v), custom_call_target="tpu_custom_call", backend_config={}
+  %custom-call.9 = f32[4]{0} custom-call(%x), custom_call_target="Sharding"
+  ROOT %custom-call.11 = (bf16[2]{0}) custom-call(%y), custom_call_target="tpu_custom_call"
+}
+"""
+
+
+def test_hlo_allreduces_and_mosaic_calls():
+    assert hlo.allreduces(HLO_TEXT) == [
+        1024 * 256 * 4, 8 * 4 + 16 * 2 + 4]
+    assert hlo.mosaic_call_names(HLO_TEXT) == [
+        "custom-call.7", "custom-call.11"]
+
+
+def sizes(name):
+    return harness.load_json(harness.HERE, "configs", name + ".json")[
+        "model"]
+
+
+def traffic(name):
+    return harness.load_json(harness.HERE, "traffic", name + ".json")
+
+
+def test_gpt2_medium_operations_per_token():
+    # by hand, h=1024, m=4096, 24 layers, T=1024, V=50257:
+    #   blocks    24 * 2 * (4*1024^2 + 2*1024*4096) = 603,979,776
+    #   attention 24 * 4 * 1024 * 1024 / 2          =  50,331,648
+    #   head      2 * 1024 * 50257 * 1023/1024      = 102,825,822
+    f = flops.forward_flops_per_token(
+        sizes("gpt2-medium"), traffic("lm_s1024_b16_dp1"))
+    assert f["blocks"] == 603_979_776
+    assert f["attention"] == 50_331_648
+    assert f["head"] == pytest.approx(102_825_822)
+    total = flops.train_flops_per_token(
+        sizes("gpt2-medium"), traffic("lm_s1024_b16_dp1"))
+    assert total == pytest.approx(2.2714e9, rel=1e-4)
+
+
+@pytest.mark.parametrize("mix,attention,total", [
+    # full attention 24 * 4 * T * 1024; head 0.15 * 2*1024*30522
+    ("mlm_s512_b26_dp1", 50_331_648, 1.9911e9),
+    ("mlm_s128_b104_dp1", 12_582_912, 1.8778e9),
+])
+def test_bert_large_operations_per_token(mix, attention, total):
+    f = flops.forward_flops_per_token(sizes("bert-large"), traffic(mix))
+    assert f["attention"] == attention
+    assert f["head"] == pytest.approx(0.15 * 2 * 1024 * 30522)
+    assert flops.train_flops_per_token(
+        sizes("bert-large"), traffic(mix)) == pytest.approx(
+            total, rel=1e-4)
+
+
+def test_attention_kernel_work_and_roofline():
+    # GPT-2-medium, batch 16: 24 layers * 6 products * 2*16*16*1024^2*64
+    # halved by the causal mask; 12 arrays of 16*16*1024*64 bf16 a layer
+    w = flops.attention_kernel_work(
+        sizes("gpt2-medium"), traffic("lm_s1024_b16_dp1"))
+    assert w["flops"] == 24 * 6 * 2 * 16 * 16 * 1024 * 1024 * 64 / 2
+    assert w["bytes"] == 24 * 12 * 16 * 16 * 1024 * 64 * 2
+    least, bound = flops.roofline_seconds(
+        w, harness.peak_of("TPU v5 lite"))
+    assert bound == "compute"
+    assert least == pytest.approx(w["flops"] / 197e12)
+    # BERT at T=128 moves more bytes than it computes: HBM-bound
+    w = flops.attention_kernel_work(
+        sizes("bert-large"), traffic("mlm_s128_b104_dp1"))
+    assert flops.roofline_seconds(
+        w, harness.peak_of("TPU v5 lite"))[1] == "hbm"
+
+
+def test_unknown_device_kind_is_an_error():
+    with pytest.raises(KeyError, match="no peaks"):
+        harness.peak_of("TPU v9 imaginary")
