@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -368,14 +370,15 @@ class Transformer(nn.Module):
         # MXU fast path — an f32 [B,T,H]x[H,V] here is the single
         # largest matmul in the model at a fraction of peak); the loss
         # fns upcast the logits to f32 for logsumexp stability.
-        if cfg.tie_embeddings:
-            logits = emb.attend(x)
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                param_dtype=jnp.float32, name="lm_head",
-                kernel_init=nn.initializers.normal(0.02),
-            )(x)
+        with jax.named_scope(scopes.LOSS_HEAD):
+            if cfg.tie_embeddings:
+                logits = emb.attend(x)
+            else:
+                logits = nn.Dense(
+                    cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=jnp.float32, name="lm_head",
+                    kernel_init=nn.initializers.normal(0.02),
+                )(x)
         return logits
 
 
@@ -391,6 +394,7 @@ def _gather_nll(lg, targets):
     return lse - tgt
 
 
+@jax.named_scope(scopes.LOSS_HEAD)
 def causal_lm_loss(logits, tokens, ignore_index: int = -1):
     """Next-token cross-entropy; returns (loss, n_tokens). float32."""
     targets = tokens[:, 1:]
@@ -405,6 +409,7 @@ def causal_lm_loss(logits, tokens, ignore_index: int = -1):
     return jnp.sum(nll) / n, n
 
 
+@jax.named_scope(scopes.LOSS_HEAD)
 def mlm_loss(logits, labels, mask_positions):
     """BERT masked-LM loss: `labels` at `mask_positions` (bool [B,T])."""
     lg = logits.astype(jnp.float32)

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils import scopes
+
 NEG_INF = -1e30
 
 
@@ -144,6 +146,7 @@ _fused_ce.defvjp(
 )
 
 
+@jax.named_scope(scopes.LOSS_HEAD)
 def fused_linear_cross_entropy(
     hidden, w, targets, *, valid: Optional[jnp.ndarray] = None,
     block_vocab: int = 8192, mean: bool = True,
@@ -194,9 +197,10 @@ def fused_causal_lm_loss(
     `hidden`: [B, T, h] (model __call__ with return_hidden=True);
     `w`: [h, V] head kernel (tied: params["tok_emb"]["embedding"].T).
     Returns (loss, n_tokens)."""
-    targets = tokens[:, 1:]
-    valid = targets != ignore_index
+    with jax.named_scope(scopes.LOSS_HEAD):
+        targets = tokens[:, 1:]
+        valid = targets != ignore_index
+        hidden = hidden[:, :-1]
     return fused_linear_cross_entropy(
-        hidden[:, :-1], w, targets, valid=valid,
-        block_vocab=block_vocab,
+        hidden, w, targets, valid=valid, block_vocab=block_vocab,
     )

@@ -38,6 +38,7 @@ from ..ops.adasum import adasum_allreduce
 from ..ops.collectives import ReduceOp
 from ..ops.fusion import (flatten_pytree_buckets, pack_pytree_by_plan,
                           pytree_bucket_plan)
+from ..utils import scopes
 from .compression import (Compression, NoneCompressor, WireSpec,
                           compressor_wire_spec, quantized_psum,
                           wire_sent_bytes)
@@ -249,12 +250,14 @@ def _reduce_grad_tree(
         _warn_stateless_ef_once()
 
     plan = pytree_bucket_plan(grads, threshold_bytes=fusion_threshold_bytes)
-    buckets, unflatten = pack_pytree_by_plan(grads, plan)
     res_buckets = res_unflatten = None
-    if residual is not None and int8_wire and live:
-        # residual rides the SAME bucket layout as the gradients, so a
-        # leaf's error lands back on that leaf at unflatten time
-        res_buckets, res_unflatten = pack_pytree_by_plan(residual, plan)
+    with jax.named_scope(scopes.HVD_PACK):
+        buckets, unflatten = pack_pytree_by_plan(grads, plan)
+        if residual is not None and int8_wire and live:
+            # residual rides the SAME bucket layout as the gradients,
+            # so a leaf's error lands back on that leaf at unflatten time
+            res_buckets, res_unflatten = pack_pytree_by_plan(
+                residual, plan)
     # Native eager world (top-level update, no bound mesh axis): submit
     # the WHOLE per-step bucket set through one batched enqueue round
     # (EagerRuntime.enqueue_batch via grouped_allreduce_async) instead
@@ -326,16 +329,17 @@ def _reduce_grad_tree(
     reduced = []
     new_res_buckets = []
     prev = None
-    for i, b in enumerate(buckets):
-        if ordered and prev is not None:
-            b, _ = jax.lax.optimization_barrier((b, prev))
-        r_b = res_buckets[i] if res_buckets is not None else None
-        red, prev, new_r = _reduce_bucket(
-            b, op, compression, wire, int8_wire, live, n, process_set,
-            axis_name, res_bucket=r_b)
-        if res_buckets is not None:
-            new_res_buckets.append(new_r)
-        reduced.append(red)
+    with jax.named_scope(scopes.HVD_ALLREDUCE):
+        for i, b in enumerate(buckets):
+            if ordered and prev is not None:
+                b, _ = jax.lax.optimization_barrier((b, prev))
+            r_b = res_buckets[i] if res_buckets is not None else None
+            red, prev, new_r = _reduce_bucket(
+                b, op, compression, wire, int8_wire, live, n,
+                process_set, axis_name, res_bucket=r_b)
+            if res_buckets is not None:
+                new_res_buckets.append(new_r)
+            reduced.append(red)
     pm = global_state().parameter_manager
     from ..utils import metrics as _metrics
 
@@ -374,9 +378,11 @@ def _reduce_grad_tree(
                     _metrics.record_wire_bytes, total, sent),
                 None,
             )
-    if res_unflatten is not None and residual is not None:
-        return _ret(unflatten(reduced), res_unflatten(new_res_buckets))
-    return _ret(unflatten(reduced))
+    with jax.named_scope(scopes.HVD_UNPACK):
+        if res_unflatten is not None and residual is not None:
+            return _ret(unflatten(reduced),
+                        res_unflatten(new_res_buckets))
+        return _ret(unflatten(reduced))
 
 
 class _AccumState(NamedTuple):
@@ -525,6 +531,11 @@ def DistributedOptimizer(
     ef = bool(getattr(compression, "error_feedback", False)) and op in (
         ReduceOp.SUM, ReduceOp.AVERAGE)
 
+    def inner_update(*args, **extra):
+        """The wrapped optimizer's update, under its own scope."""
+        with jax.named_scope(scopes.HVD_INNER_UPDATE):
+            return optimizer.update(*args, **extra)
+
     def reduce_fn(grads, residual=None):
         """-> reduced, or (reduced, new_residual) when residual given."""
         g = grads
@@ -532,17 +543,19 @@ def DistributedOptimizer(
             n = collectives._group_size(process_set, axis_name)
             pre = 1.0 / gradient_predivide_factor
             post = gradient_predivide_factor / n
-            g = jax.tree_util.tree_map(
-                lambda x: x * jnp.asarray(pre, x.dtype), g
-            )
+            with jax.named_scope(scopes.HVD_ALLREDUCE):
+                g = jax.tree_util.tree_map(
+                    lambda x: x * jnp.asarray(pre, x.dtype), g
+                )
             out = _reduce_grad_tree(
                 g, ReduceOp.SUM, compression, process_set, axis_name,
                 fusion_threshold_bytes, residual=residual,
             )
             g, new_res = out if residual is not None else (out, None)
-            g = jax.tree_util.tree_map(
-                lambda x: x * jnp.asarray(post, x.dtype), g
-            )
+            with jax.named_scope(scopes.HVD_ALLREDUCE):
+                g = jax.tree_util.tree_map(
+                    lambda x: x * jnp.asarray(post, x.dtype), g
+                )
             return (g, new_res) if residual is not None else g
         return _reduce_grad_tree(
             g, op, compression, process_set, axis_name,
@@ -610,12 +623,12 @@ def DistributedOptimizer(
                 # the backward-interleaved scheduler already reduced
                 # these inside the backward (ops/overlap.py)
                 return _staged_apply(staged, state, params,
-                                     optimizer.update, **extra)
+                                     inner_update, **extra)
             if isinstance(state, _EFState):
-                return _ef_update(grads, state, params, optimizer.update,
+                return _ef_update(grads, state, params, inner_update,
                                   **extra)
             reduced = reduce_fn(grads)
-            return optimizer.update(reduced, state, params, **extra)
+            return inner_update(reduced, state, params, **extra)
 
         # reduction recipe for the backward-interleaved scheduler
         # (ops/overlap.py staged_value_and_grad introspects it)
@@ -650,11 +663,11 @@ def DistributedOptimizer(
             mean = jax.tree_util.tree_map(lambda a: a / k, acc)
             if isinstance(inner, _EFState):
                 updates, new_inner = _ef_update(
-                    mean, inner, params, optimizer.update, **extra)
+                    mean, inner, params, inner_update, **extra)
                 zeros = jax.tree_util.tree_map(jnp.zeros_like, acc)
                 return updates, new_inner, zeros
             reduced = reduce_fn(mean)
-            updates, new_inner = optimizer.update(
+            updates, new_inner = inner_update(
                 reduced, inner, params, **extra
             )
             zeros = jax.tree_util.tree_map(jnp.zeros_like, acc)

@@ -473,13 +473,17 @@ def test_online_tuner_optin_dimensions_walk():
     let noise pin an arbitrary block)."""
     knobs = Knobs()
     calls = []
+    # a clock the step itself advances: with measure=1 a 2 ms sleep on
+    # the real clock was outweighed by six test workers' scheduling
+    # noise, and the ranking must not depend on the machine's load
+    now = [0.0]
 
     def int8_wins(overrides):
         calls.append(dict(overrides))
-        slow = 0.002 if knobs.compression != "int8" else 0.0
+        slow = 0.020 if knobs.compression != "int8" else 0.001
 
         def step():
-            time.sleep(slow)
+            now[0] += slow
             return jnp.zeros(())
 
         return step
@@ -489,7 +493,8 @@ def test_online_tuner_optin_dimensions_walk():
         warmup=0, measure=1, tune_ordered=False, tune_overlap=False,
         tune_fsdp_prefetch=True, prefetch_depths=[0, 1, 2],
         tune_wire=True, wire_candidates=["none", "int8"],
-        block_candidates=[128, 256], warmup_k_candidates=[3, 8])
+        block_candidates=[128, 256], warmup_k_candidates=[3, 8],
+        clock=lambda: now[0])
     cfg = t.tune(int8_wins)
     dims = {r.get("dimension") for r in t.trials}
     assert "fsdp_prefetch" in dims
@@ -509,10 +514,10 @@ def test_online_tuner_optin_dimensions_walk():
     knobs2 = Knobs()
 
     def none_wins(overrides):
-        slow = 0.002 if knobs2.compression == "int8" else 0.0
+        slow = 0.020 if knobs2.compression == "int8" else 0.001
 
         def step():
-            time.sleep(slow)
+            now[0] += slow
             return jnp.zeros(())
 
         return step
@@ -521,7 +526,8 @@ def test_online_tuner_optin_dimensions_walk():
         knobs2, thresholds=[knobs2.fusion_threshold_bytes],
         warmup=0, measure=1, tune_ordered=False, tune_overlap=False,
         tune_wire=True, wire_candidates=["none", "int8"],
-        block_candidates=[128, 256], warmup_k_candidates=[3, 8])
+        block_candidates=[128, 256], warmup_k_candidates=[3, 8],
+        clock=lambda: now[0])
     cfg2 = t2.tune(none_wins)
     assert cfg2["compression"] == "none"
     dims2 = {r.get("dimension") for r in t2.trials}

@@ -1,0 +1,174 @@
+"""The compiled train step carries the names its readers look for.
+
+``horovod_tpu/utils/scopes.py`` names the loss head and the optimizer
+wrap's parts with ``jax.named_scope``; Flax names the model's modules.
+Both end up as ``op_name`` in the compiled step's HLO, which is where
+``benchmarks/scopes.py`` reads them. Here the tiny steps of two cells
+are lowered and compiled on the test world's CPU devices and their
+``op_name``s searched: a name lost to a refactor, or a Flax that stops
+writing module names, fails here and not on the chip.
+"""
+
+import os
+import re
+import sys
+import time
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from horovod_tpu.utils import scopes  # noqa: E402
+
+# cell -> devices of its tiny step here (gpt2m_dp4: fused cross entropy,
+# buckets and all-reduces; bertl_s128: dense head, one device)
+CELLS = {"gpt2m_dp4": 4, "bertl_s128": 1}
+HVD_BUCKETS = (scopes.HVD_PACK, scopes.HVD_ALLREDUCE, scopes.HVD_UNPACK)
+# what the step function itself does outside every scope, by the
+# primitive the op_name ends in: ``apply_updates`` (add), the loss's
+# ``psum`` and its division by n, constants and their broadcasts (an
+# empty name), and the reducers of reductions and scatters, which the
+# CPU compiler names by primitive alone
+OWN_LINES = {"", "add", "div", "psum", "broadcast", "reduce_sum",
+             "reduce_max", "scatter-add"}
+# differentiated but under no scope: the job's own transpose of the
+# tied embedding in front of the fused head
+UNSCOPED_DIFFERENTIATED = {"jvp()/transpose", "transpose(jvp())/transpose"}
+
+
+def compile_tiny_step(cell: str, n: int) -> list:
+    """``op_name``s of the cell's tiny step compiled for ``n`` CPU
+    devices."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from benchmarks import harness
+    from benchmarks.jobs import dp_train
+
+    found = harness.load_cell(cell)
+    sizes = {**found["config"]["model"], **found["config"]["tiny"]}
+    traffic = {**found["traffic"], **found["traffic"]["tiny"]}
+    run = harness.Run(
+        started=time.perf_counter(), workload=cell, chips=n,
+        traffic=traffic, model_sizes=sizes, seed=0, seconds=0,
+        trace=False, rehearse=True)
+    mesh = Mesh(np.array(jax.devices()[:n]), ("hvd",))
+    hvd.shutdown()
+    built = dp_train.build(run, sizes, traffic, mesh=mesh)
+    everywhere, split = (NamedSharding(mesh, P()),
+                         NamedSharding(mesh, P("hvd")))
+
+    def described(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    params = jax.eval_shape(
+        built["plain_model"].init, jax.random.PRNGKey(0),
+        jnp.zeros((1, traffic["seq_len"]), jnp.int32))["params"]
+    opt_state = jax.eval_shape(built["opt"].init, params)
+    batch = dp_train.make_batch(
+        sizes, traffic, n * traffic["batch_per_chip"], 0)
+    text = built["step"].lower(
+        described(params, everywhere), described(opt_state, everywhere),
+        *described(batch, split)).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+_COMPILED: dict = {}
+
+
+def compiled(cell: str) -> list:
+    """Compiled once a cell and process; the world is shut down around
+    every test, so nothing of it is kept but the strings."""
+    if cell not in _COMPILED:
+        _COMPILED[cell] = compile_tiny_step(cell, CELLS[cell])
+    return _COMPILED[cell]
+
+
+@pytest.fixture
+def op_names(request):
+    return request.param, compiled(request.param)
+
+
+def parts(op_name: str) -> set:
+    return set(re.split(r"[/()]", op_name))
+
+
+def having(names, *wanted):
+    return [o for o in names if all(w in o for w in wanted)]
+
+
+@pytest.mark.parametrize("op_names", CELLS, indirect=True)
+def test_loss_head_is_named_in_both_directions(op_names):
+    _, names = op_names
+    head = [o for o in names if scopes.LOSS_HEAD in parts(o)]
+    assert having(head, "jvp("), "no forward loss_head instruction"
+    assert having(head, "transpose("), "no backward loss_head instruction"
+    forward_only = [o for o in head if "transpose(" not in o]
+    assert forward_only, "every loss_head instruction is backward"
+
+
+def test_fused_head_loops_and_dense_head_matmul_are_inside_loss_head():
+    fused, dense = compiled("gpt2m_dp4"), compiled("bertl_s128")
+    # the fused cross entropy's two scans, and nothing of it outside
+    for direction in ("jvp(loss_head)/while",
+                      "transpose(jvp(loss_head))/while"):
+        assert having(fused, direction), direction
+    assert not having(fused, "jvp()/while")
+    # the dense head's matmul with the tied embedding, and the loss
+    # function's gather
+    assert having(dense, "jvp(Transformer)", "loss_head/tok_emb.attend")
+    assert having(dense, "transpose(jvp(Transformer))",
+                  "loss_head/tok_emb.attend")
+    assert having(dense, "jvp(loss_head)", "take_along_axis")
+
+
+@pytest.mark.parametrize("op_names", CELLS, indirect=True)
+def test_optimizer_wrap_is_named_where_it_runs(op_names):
+    cell, names = op_names
+    for scope in HVD_BUCKETS:
+        found = [o for o in names if scope in parts(o)]
+        if CELLS[cell] > 1:
+            assert found, f"{scope} names nothing at n={CELLS[cell]}"
+            assert not having(found, "jvp("), found[:3]
+        else:  # the n=1 path returns the gradients untouched
+            assert not found, found[:3]
+    # AdamW runs at every n
+    assert [o for o in names if scopes.HVD_INNER_UPDATE in parts(o)]
+    if CELLS[cell] > 1:
+        assert having(names, scopes.HVD_ALLREDUCE + "/psum")
+        assert having(names, scopes.HVD_UNPACK + "/dynamic_slice")
+
+
+@pytest.mark.parametrize("op_names", CELLS, indirect=True)
+@pytest.mark.parametrize("module", [
+    "block_0/attn", "block_1/attn", "block_0/ln_attn", "block_0/ln_mlp",
+    "ln_final", "block_0/mlp/fc1", "block_0/mlp/fc2", "tok_emb"])
+def test_flax_writes_module_names(op_names, module):
+    _, names = op_names
+    assert having(names, "jvp(Transformer)", f"/{module}/"), module
+    assert having(names, "transpose(jvp(Transformer))", f"/{module}/"), \
+        module
+
+
+@pytest.mark.parametrize("op_names", CELLS, indirect=True)
+def test_little_is_left_unscoped(op_names):
+    cell, names = op_names
+    own, differentiated = set(), set()
+    for o in names:
+        if re.match(r"^(p|s|batch)(\[|$)", o):
+            continue  # an argument's name
+        path = re.sub(r"^jit\(step_fn\)/?(shard_map/?)?", "", o)
+        path = re.sub(r"\.\d+", "", path)
+        if "jvp(" in path:
+            if not {"Transformer", scopes.LOSS_HEAD} & parts(path):
+                differentiated.add(path)
+        elif not parts(path) & {*HVD_BUCKETS, scopes.HVD_INNER_UPDATE}:
+            own.add(path)
+    assert own <= OWN_LINES, own - OWN_LINES
+    assert differentiated <= UNSCOPED_DIFFERENTIATED, differentiated
