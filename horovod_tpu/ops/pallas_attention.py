@@ -6,8 +6,23 @@ its native compute is limited to fusion-buffer/scale CUDA kernels,
 TPU-first addition: the transformer family's hot op as Pallas kernels —
 blockwise online-softmax attention (Flash Attention) tiled for MXU/VMEM:
 
-* grid over (batch*heads, query blocks); K/V stream through VMEM in
-  `block_k`-sized tiles inside a `fori_loop`;
+* grid over (batch blocks, head blocks, query blocks): a program handles
+  a block of `gb x gh` consecutive (batch, head) instances side by side,
+  each exactly as a program of its own would, with K/V streaming through
+  VMEM in `block_k`-sized tiles inside a `fori_loop`. One short-sequence
+  instance is a chain of dependent steps (matrix product, row maximum,
+  exponential, row sum, matrix product) whose latencies leave the units
+  idle; independent instances in one loop body fill them.
+  `_instances_per_program` picks the block from the shapes, the dtype and
+  whether tiles are masked: the largest of 16 instances at most whose
+  work stays where more instances still pay and whose double-buffered
+  blocks fit a VMEM budget that holds under default compiler options —
+  16 heads at T=128, 4 at T=512, one instance (the kernel of one
+  instance a program) at T=1024 causal. The arrays stay [B, H, T, D]:
+  seen as [B·H, T, D] the kernels ran the same, but the compiler laid
+  the step around them out differently and lost more than they gained.
+  Trace-time gauges `hvd_flash_instances_per_program` /
+  `hvd_flash_programs_per_call` say what each kernel got;
 * causal masking on *global* positions, so sequence-parallel callers
   (ring attention) pass `query_offset`/`key_offset` and reuse the same
   kernel for off-diagonal blocks;
@@ -104,29 +119,68 @@ def _causal_kv_limit(q_base, block_q, block_k, q_offset, k_offset,
     )
 
 
+def _run_instances(gb, gh, lo, hi, start, tile, finish):
+    """The loop around one program's `gb x gh` (batch, head) instances.
+    For each instance `i = (batch, head)` of the block: `fixed, carry =
+    start(i)`; for every tile `t` in [lo, hi) `carry = tile(i, t, fixed,
+    carry)`; then `finish(i, fixed, carry)`.
+
+    The instances go through the tile loop side by side, as
+    straight-line code in one loop body: their chains of matrix product,
+    row reduction and exponential do not depend on one another, so the
+    scheduler fills one's latencies with another's work (a loop over the
+    instances does not: PERF.md section 6, PR 25). No instance's own
+    operations or their order change, so results are the same bit for
+    bit however many there are, and one is the kernel of one instance a
+    program."""
+    instances = [(b, h) for b in range(gb) for h in range(gh)]
+    fixed, carries = zip(*(start(i) for i in instances))
+
+    def body(t, carries):
+        return tuple(tile(i, t, f, carry)
+                     for i, f, carry in zip(instances, fixed, carries))
+
+    carries = lax.fori_loop(lo, hi, body, tuple(carries))
+    for i, f, carry in zip(instances, fixed, carries):
+        finish(i, f, carry)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                       causal: bool, scale: float, q_offset: int,
                       k_offset: int, kv_len: int):
-    """One (batch*head, q-block) program: stream K/V tiles, online softmax.
+    """One (gb x gh instances, q-block) program: for each instance stream
+    K/V tiles, online softmax.
 
-    q_ref: [block_q, D]; k_ref/v_ref: [Tk_padded, D]; o_ref: [block_q, D];
-    lse_ref: [block_q] f32 per-row logsumexp of the scaled logits (the
-    backward kernels rebuild P tiles from it)."""
-    block_q, d = q_ref.shape
-    # keep matmul inputs in the model dtype (bf16 → bf16 MXU path) with
-    # f32 accumulation via preferred_element_type; scale folds into q
-    q = (q_ref[:].astype(jnp.float32) * scale).astype(q_ref.dtype)
+    q_ref: [gb, gh, block_q, D]; k_ref/v_ref: [gb, gh, Tk_padded, D];
+    o_ref: [gb, gh, block_q, D]; lse_ref: [gb, gh, 1, block_q] f32 per-row
+    logsumexp of the scaled logits (the backward kernels rebuild P tiles
+    from it)."""
+    gb, gh, block_q, d = q_ref.shape
     q_base = pl.program_id(2) * block_q
-
-    num_kv_blocks = k_ref.shape[0] // block_k
+    num_kv_blocks = k_ref.shape[2] // block_k
     # static elision: the all-true mask (non-causal, no K padding — the
     # BERT/encoder fast path) costs a full VPU iota+select per tile
-    masked = causal or kv_len < k_ref.shape[0]
+    masked = causal or kv_len < k_ref.shape[2]
+    if causal:
+        limit = _causal_kv_limit(q_base, block_q, block_k, q_offset,
+                                 k_offset, num_kv_blocks)
+    else:
+        limit = num_kv_blocks
 
-    def body(kb, carry):
+    def start(i):
+        # keep matmul inputs in the model dtype (bf16 → bf16 MXU path)
+        # with f32 accumulation via preferred_element_type; scale folds
+        # into q
+        q = (q_ref[i].astype(jnp.float32) * scale).astype(q_ref.dtype)
+        acc0 = jnp.zeros((block_q, d), jnp.float32)
+        m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((block_q,), jnp.float32)
+        return q, (acc0, m0, l0)
+
+    def tile(i, kb, q, carry):
         acc, m_prev, l_prev = carry
-        k_tile = k_ref[pl.ds(kb * block_k, block_k), :]
-        v_tile = v_ref[pl.ds(kb * block_k, block_k), :]
+        k_tile = k_ref[(*i, pl.ds(kb * block_k, block_k))]
+        v_tile = v_ref[(*i, pl.ds(kb * block_k, block_k))]
         s = _dot_nt(q, k_tile)
         if masked:
             mask = _tile_mask(
@@ -150,41 +204,44 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         )
         return acc, m_new, l_new
 
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    if causal:
-        limit = _causal_kv_limit(q_base, block_q, block_k, q_offset,
-                                 k_offset, num_kv_blocks)
-    else:
-        limit = num_kv_blocks
-    acc, m, l = lax.fori_loop(0, limit, body, (acc0, m0, l0))
-    # fully-masked rows (causal + offsets) have l == 0: output zeros, and
-    # lse == NEG_INF so the backward rebuilds p == 0 for them too
-    safe_l = jnp.where(l > 0, l, 1.0)
-    o_ref[:] = (acc / safe_l[:, None]).astype(o_ref.dtype)
-    lse_ref[0, :] = jnp.where(l > 0, m + jnp.log(safe_l), NEG_INF)
+    def finish(i, q, carry):
+        acc, m, l = carry
+        # fully-masked rows (causal + offsets) have l == 0: output zeros,
+        # and lse == NEG_INF so the backward rebuilds p == 0 for them too
+        safe_l = jnp.where(l > 0, l, 1.0)
+        o_ref[i] = (acc / safe_l[:, None]).astype(o_ref.dtype)
+        lse_ref[(*i, 0)] = jnp.where(l > 0, m + jnp.log(safe_l), NEG_INF)
+
+    _run_instances(gb, gh, 0, limit, start, tile, finish)
 
 
 def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                          dq_ref, *, block_k: int, causal: bool, scale: float,
                          q_offset: int, k_offset: int, kv_len: int):
-    """dQ for one q block: stream K/V tiles, rebuild P from lse.
+    """dQ for one q block of gb x gh instances: stream K/V tiles, rebuild
+    P from lse.
 
     dS = P ∘ (dO·Vᵀ − Δ), dQ = scale · dS·K, with Δ = rowsum(dO ∘ O)
     (zero on padded rows because dO is zero-padded)."""
-    block_q, d = q_ref.shape
-    q = (q_ref[:].astype(jnp.float32) * scale).astype(q_ref.dtype)
-    do = do_ref[:]
-    lse = lse_ref[0, :]
-    delta = delta_ref[0, :]
+    gb, gh, block_q, d = q_ref.shape
     q_base = pl.program_id(2) * block_q
-    num_kv_blocks = k_ref.shape[0] // block_k
-    masked = causal or kv_len < k_ref.shape[0]
+    num_kv_blocks = k_ref.shape[2] // block_k
+    masked = causal or kv_len < k_ref.shape[2]
+    if causal:
+        limit = _causal_kv_limit(q_base, block_q, block_k, q_offset,
+                                 k_offset, num_kv_blocks)
+    else:
+        limit = num_kv_blocks
 
-    def body(kb, acc):
-        k_tile = k_ref[pl.ds(kb * block_k, block_k), :]
-        v_tile = v_ref[pl.ds(kb * block_k, block_k), :]
+    def start(i):
+        q = (q_ref[i].astype(jnp.float32) * scale).astype(q_ref.dtype)
+        fixed = (q, do_ref[i], lse_ref[(*i, 0)], delta_ref[(*i, 0)])
+        return fixed, jnp.zeros((block_q, d), jnp.float32)
+
+    def tile(i, kb, fixed, acc):
+        q, do, lse, delta = fixed
+        k_tile = k_ref[(*i, pl.ds(kb * block_k, block_k))]
+        v_tile = v_ref[(*i, pl.ds(kb * block_k, block_k))]
         s = _dot_nt(q, k_tile)
         p = jnp.exp(s - lse[:, None])
         if masked:
@@ -202,41 +259,47 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if causal:
-        limit = _causal_kv_limit(q_base, block_q, block_k, q_offset,
-                                 k_offset, num_kv_blocks)
-    else:
-        limit = num_kv_blocks
-    acc = lax.fori_loop(
-        0, limit, body, jnp.zeros((block_q, d), jnp.float32)
-    )
-    dq_ref[:] = (acc * scale).astype(dq_ref.dtype)
+    def finish(i, fixed, acc):
+        dq_ref[i] = (acc * scale).astype(dq_ref.dtype)
+
+    _run_instances(gb, gh, 0, limit, start, tile, finish)
 
 
 def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, causal: bool,
                           scale: float, q_offset: int, k_offset: int,
                           kv_len: int, total_kv: int):
-    """dK/dV for one kv block: stream Q/dO tiles.
+    """dK/dV for one kv block of gb x gh instances: stream Q/dO tiles.
 
     dV = Pᵀ·dO, dK = scale · dSᵀ·Q. Padded q rows carry dO == 0 and
     Δ == 0, so they contribute exactly nothing to either sum."""
-    block_k, d = k_ref.shape
-    k = k_ref[:]
-    v = v_ref[:]
+    gb, gh, block_k, d = k_ref.shape
     k_base = pl.program_id(2) * block_k
-    num_q_blocks = q_ref.shape[0] // block_q
+    num_q_blocks = q_ref.shape[2] // block_q
     # the K-padding mask guards this kv block's own padded rows; padded
     # q rows are harmless because their dO and Δ are zero — so the mask
     # is only needed for causal or padded-K tiles
     masked = causal or kv_len < total_kv
+    if causal:
+        # q tiles entirely above the diagonal (max(gq) < min(gk))
+        # contribute nothing to this kv block
+        first = jnp.clip(
+            (k_offset + k_base - q_offset) // block_q, 0, num_q_blocks
+        )
+    else:
+        first = 0
 
-    def body(qb, carry):
+    def start(i):
+        zeros = jnp.zeros((block_k, d), jnp.float32)
+        return (k_ref[i], v_ref[i]), (zeros, zeros)
+
+    def tile(i, qb, kv, carry):
+        k, v = kv
         dk_acc, dv_acc = carry
-        q_tile = q_ref[pl.ds(qb * block_q, block_q), :]
-        do_tile = do_ref[pl.ds(qb * block_q, block_q), :]
-        lse_tile = lse_ref[0, pl.ds(qb * block_q, block_q)]
-        delta_tile = delta_ref[0, pl.ds(qb * block_q, block_q)]
+        q_tile = q_ref[(*i, pl.ds(qb * block_q, block_q))]
+        do_tile = do_ref[(*i, pl.ds(qb * block_q, block_q))]
+        lse_tile = lse_ref[(*i, 0, pl.ds(qb * block_q, block_q))]
+        delta_tile = delta_ref[(*i, 0, pl.ds(qb * block_q, block_q))]
         qs = (q_tile.astype(jnp.float32) * scale).astype(q_tile.dtype)
         s = _dot_nt(qs, k)
         p = jnp.exp(s - lse_tile[:, None])
@@ -252,18 +315,12 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dk_acc = dk_acc + _dot_tn(ds.astype(q_tile.dtype), q_tile)
         return dk_acc, dv_acc
 
-    zeros = jnp.zeros((block_k, d), jnp.float32)
-    if causal:
-        # q tiles entirely above the diagonal (max(gq) < min(gk))
-        # contribute nothing to this kv block
-        start = jnp.clip(
-            (k_offset + k_base - q_offset) // block_q, 0, num_q_blocks
-        )
-    else:
-        start = 0
-    dk_acc, dv_acc = lax.fori_loop(start, num_q_blocks, body, (zeros, zeros))
-    dk_ref[:] = (dk_acc * scale).astype(dk_ref.dtype)
-    dv_ref[:] = dv_acc.astype(dv_ref.dtype)
+    def finish(i, kv, carry):
+        dk_acc, dv_acc = carry
+        dk_ref[i] = (dk_acc * scale).astype(dk_ref.dtype)
+        dv_ref[i] = dv_acc.astype(dv_ref.dtype)
+
+    _run_instances(gb, gh, first, num_q_blocks, start, tile, finish)
 
 
 def _pad_to(x, axis, multiple):
@@ -276,34 +333,123 @@ def _pad_to(x, axis, multiple):
     return jnp.pad(x, widths)
 
 
+# How many instances a program takes. Measured on a v5e (PERF.md section
+# 6, PR 25: kernels alone, head width 64, bf16, time of the three kernels
+# against one instance a program): what pays is instances side by side,
+# and it pays by how little one instance does, its latencies being what
+# the others fill — T=128 0.38x at 16 instances (0.43x at 8, 0.34x at
+# 32), T=256 0.58x at 16, T=512 0.87x at 4 (0.85x at 8), T=512 causal
+# 0.92x at 4; two tiles of 512 x 512 an instance, T=1024: 0.986x at 2
+# unmasked, 1.005x at 2 causal, and 4 causal do not fit VMEM. Fewer
+# programs alone, 16 instances in a loop, gave 0.83x at T=128 and 0.99x
+# at T=512.
+#
+# Work: in units of one 128 x 128 score tile over all of an instance's
+# tiles, a masked tile (iota, compare and two selects more) counted
+# twice. A program takes at most this much; an instance that is more than
+# half of it goes alone.
+_PROGRAM_TILE_UNITS = 64
+# Bodies side by side: each instance is one more body to trace and
+# compile, and at head width 64 the VMEM charge stops at 16 to 20.
+_MOST_INSTANCES = 16
+# VMEM: the pipeline keeps two copies of every block of a program, so a
+# program's blocks are charged twice, each padded to VMEM's tiles of 8
+# sublanes of 32 bits by 128 lanes. Compiled for a described v5e with no
+# compiler option (16 MiB of scoped VMEM a kernel), the smallest charge
+# Mosaic refused was 14.5 MiB and what it says it needs has run up to 1.1
+# times the charge (the kernels' own f32 tiles are on the same stack), so
+# half the 16 MiB is the budget: it holds with the options any caller
+# compiles with.
+_VMEM_BLOCK_BUDGET = 8 * 2**20
+
+# kernel -> (blocks of the program's own rows, blocks of all the rows of
+# the other side, row statistics (lse, Δ), which side those follow)
+_KERNEL_BLOCKS = {
+    "fwd": (2, 2, 1, "own"),     # q, o | k, v | lse
+    "dq": (3, 2, 2, "own"),      # q, dO, dq | k, v | lse, Δ
+    "dkv": (4, 2, 2, "other"),   # k, v, dk, dv | q, dO | lse, Δ
+}
+
+
+def _vmem_bytes(rows, cols, itemsize):
+    """Bytes a [rows, cols] block takes in VMEM: tiles of 8 sublanes of
+    32 bits (16 rows of bf16) by 128 lanes."""
+    sublanes = 8 * (4 // itemsize)
+    return (-(-rows // sublanes) * sublanes) * (-(-cols // 128) * 128) \
+        * itemsize
+
+
+def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
+                           itemsize, masked):
+    """The block `(gb, gh)` of consecutive (batch, head) instances one
+    program of `kernel` ("fwd", "dq" or "dkv") handles, side by side:
+    the largest that tiles [batch, heads] in rows (`gh` divides `heads`,
+    or is all of them with `gb` dividing `batch`), of `_MOST_INSTANCES`
+    at most, whose work stays within `_PROGRAM_TILE_UNITS` and whose
+    double-buffered blocks fit `_VMEM_BLOCK_BUDGET` — and (1, 1) when no
+    larger one does, which is the kernel of one instance a program.
+
+    `own_rows` is the program's own block (block_q; block_k for dkv),
+    `other_rows` the padded length it streams over (Tk_p; Tq_p for dkv),
+    `masked` whether its tiles are masked (causal, or padded keys). A
+    function of shapes, dtype and that flag alone: short sequences get
+    many instances a program, long ones one, with nothing to set."""
+    n_own, n_other, n_stats, stats_side = _KERNEL_BLOCKS[kernel]
+    stats_rows = own_rows if stats_side == "own" else other_rows
+    per_instance = 2 * (
+        n_own * _vmem_bytes(own_rows, d, itemsize)
+        + n_other * _vmem_bytes(other_rows, d, itemsize)
+        + n_stats * _vmem_bytes(1, stats_rows, 4)
+    )
+    units = -(-own_rows // 128) * -(-other_rows // 128) * (1 + masked)
+    most = min(_MOST_INSTANCES, _PROGRAM_TILE_UNITS // units,
+               _VMEM_BLOCK_BUDGET // per_instance)
+    blocks = [(1, gh) for gh in range(1, heads + 1) if heads % gh == 0]
+    blocks += [(gb, heads) for gb in range(2, batch + 1) if batch % gb == 0]
+    return max((blk for blk in blocks if blk[0] * blk[1] <= most),
+               key=lambda blk: blk[0] * blk[1], default=(1, 1))
+
+
+def _grid(kernel, shape, own_rows, other_rows, itemsize, masked):
+    """`(gb, gh)` and the grid of `kernel` over a padded [B, H, T, D]
+    array of `shape` whose T is the program's own side, recorded in the
+    trace-time gauges (the choice is static, so nothing runs in the
+    step): instances a program and programs a call, by kernel."""
+    from ..utils import metrics
+
+    b, h, t, d = shape
+    gb, gh = _instances_per_program(kernel, b, h, own_rows, other_rows, d,
+                                    itemsize, masked)
+    grid = (b // gb, h // gh, t // own_rows)
+    metrics.record_flash_programs(kernel, gb * gh,
+                                  grid[0] * grid[1] * grid[2])
+    return gb, gh, grid
+
 
 def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
                 key_offset, block_q, block_k):
     """Padded [B, H, Tq_p, D] x [B, H, Tk_p, D] → (out, lse); kv_len is
-    the true (unpadded) key length. Grid (B, H, q-blocks): 4-D arrays
-    tile legally because (T, D) are the minor-most dims in this layout."""
+    the true (unpadded) key length. Grid (B / gb, H / gh, q-blocks): each
+    program holds a block of gb x gh instances
+    (`_instances_per_program`); 4-D arrays tile legally because (T, D)
+    are the minor-most dims in this layout."""
     b, h, tq_p, d = qq.shape
     tk_p = kk.shape[2]
+    gb, gh, grid = _grid("fwd", qq.shape, block_q, tk_p, qq.dtype.itemsize,
+                         causal or kv_len < tk_p)
     kernel = functools.partial(
         _flash_fwd_kernel, block_k=block_k, causal=causal, scale=scale,
         q_offset=query_offset, k_offset=key_offset, kv_len=kv_len,
     )
+    rows = pl.BlockSpec((gb, gh, block_q, d), lambda b, h, j: (b, h, j, 0))
+    whole = pl.BlockSpec((gb, gh, tk_p, d), lambda b, h, j: (b, h, 0, 0))
     return pl.pallas_call(
         kernel,
-        grid=(b, h, tq_p // block_q),
-        in_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, tk_p, d),
-                         lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, tk_p, d),
-                         lambda b, h, j: (b, h, 0, 0)),
-        ],
+        grid=grid,
+        in_specs=[rows, whole, whole],
         out_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, 1, block_q),
-                         lambda b, h, j: (b, h, 0, j)),
+            rows,
+            pl.BlockSpec((gb, gh, 1, block_q), lambda b, h, j: (b, h, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tq_p, d), qq.dtype),
@@ -324,6 +470,12 @@ def _flash(q, k, v, causal, scale, query_offset, key_offset,
     return out
 
 
+# Both directions are traced once for each shape and static argument and
+# inlined where they are called (no scope of their own in `op_name`): a
+# model's layers call them with the same shapes, and tracing a kernel
+# body that holds several instances side by side costs as many times one
+# instance's.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8), inline=True)
 def _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
                block_q, block_k):
     tq, tk = q.shape[2], k.shape[2]
@@ -339,6 +491,7 @@ def _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
     return out, (q, k, v, out, lse_p[:, :, :, :tq])
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5), inline=True)
 def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
                residuals, g):
     q, k, v = residuals[:3]
@@ -357,62 +510,39 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
     kk = _pad_to(k, 2, block_k)
     vv = _pad_to(v, 2, block_k)
     tq_p, tk_p = qq.shape[2], kk.shape[2]
+    itemsize, masked = q.dtype.itemsize, causal or tk < tk_p
 
+    gb, gh, grid = _grid("dq", qq.shape, block_q, tk_p, itemsize, masked)
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, block_k=block_k, causal=causal, scale=scale,
         q_offset=query_offset, k_offset=key_offset, kv_len=tk,
     )
+    rows = pl.BlockSpec((gb, gh, block_q, d), lambda b, h, j: (b, h, j, 0))
+    stats = pl.BlockSpec((gb, gh, 1, block_q), lambda b, h, j: (b, h, 0, j))
+    whole = pl.BlockSpec((gb, gh, tk_p, d), lambda b, h, j: (b, h, 0, 0))
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(b, h, tq_p // block_q),
-        in_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, 1, block_q),
-                         lambda b, h, j: (b, h, 0, j)),
-            pl.BlockSpec((None, None, 1, block_q),
-                         lambda b, h, j: (b, h, 0, j)),
-            pl.BlockSpec((None, None, tk_p, d),
-                         lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, tk_p, d),
-                         lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, block_q, d),
-                               lambda b, h, j: (b, h, j, 0)),
+        grid=grid,
+        in_specs=[rows, rows, stats, stats, whole, whole],
+        out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((b, h, tq_p, d), q.dtype),
         interpret=interpret(),
     )(qq, do, lse_p, delta_p, kk, vv)
 
+    gb, gh, grid = _grid("dkv", kk.shape, block_k, tq_p, itemsize, masked)
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, scale=scale,
         q_offset=query_offset, k_offset=key_offset, kv_len=tk,
         total_kv=tk_p,
     )
+    rows = pl.BlockSpec((gb, gh, block_k, d), lambda b, h, j: (b, h, j, 0))
+    stats = pl.BlockSpec((gb, gh, 1, tq_p), lambda b, h, j: (b, h, 0, 0))
+    whole = pl.BlockSpec((gb, gh, tq_p, d), lambda b, h, j: (b, h, 0, 0))
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(b, h, tk_p // block_k),
-        in_specs=[
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, tq_p, d),
-                         lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, tq_p, d),
-                         lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, 1, tq_p),
-                         lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, 1, tq_p),
-                         lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda b, h, j: (b, h, j, 0)),
-        ],
+        grid=grid,
+        in_specs=[rows, rows, whole, whole, stats, stats],
+        out_specs=[rows, rows],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tk_p, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, tk_p, d), v.dtype),

@@ -994,6 +994,26 @@ def record_fused_collective(surface: str) -> None:
         labelnames=("surface",)).labels(surface=surface).inc()
 
 
+def record_flash_programs(kernel: str, instances_per_program: int,
+                          programs: int) -> None:
+    """The grid one flash-attention kernel ("fwd", "dq", "dkv") was
+    built with (ops/pallas_attention.py). Recorded at TRACE time like
+    the fused collectives' breadcrumb: the kernels choose instances a
+    program from the shapes, statically, so the gauges say what the last
+    traced call of each kernel got and nothing runs in the step."""
+    if not _enabled:
+        return
+    registry.gauge(
+        "hvd_flash_instances_per_program",
+        "(batch, head) instances one program of the flash kernel handles",
+        labelnames=("kernel",)).labels(kernel=kernel).set(
+            instances_per_program)
+    registry.gauge(
+        "hvd_flash_programs_per_call",
+        "Programs in the grid of one call of the flash kernel",
+        labelnames=("kernel",)).labels(kernel=kernel).set(programs)
+
+
 def record_overlap_window(frac: float) -> None:
     """The backward-interleaved scheduler's per-step overlap pin
     (ops/overlap.py): the fraction of backward compute the staged

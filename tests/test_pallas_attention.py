@@ -207,3 +207,159 @@ def test_fully_masked_rows_output_zero():
         block_q=8, block_k=8,
     )
     np.testing.assert_allclose(np.asarray(out), 0.0, atol=1e-7)
+
+
+# -- several (batch, head) instances a program ------------------------------
+#
+# The chooser takes the largest block of [B, H] inside its limits. These
+# shapes are a unit or two of work an instance and far inside the VMEM
+# budget, so `_MOST_INSTANCES` alone sets the most a program may take: 1
+# (one instance a program, the grid of before), 2, or all B*H.
+
+from horovod_tpu.ops import pallas_attention as pa  # noqa: E402
+from horovod_tpu.utils import metrics  # noqa: E402
+
+KERNELS = ("fwd", "dq", "dkv")
+
+# name -> (q shape, kv shape [B, T, H, D], query_offset, key_offset, block)
+INSTANCE_CASES = {
+    # 3 x 5: no block but one instance under a limit of 2
+    "b3_h5": ((3, 64, 5, 16), (3, 64, 5, 16), 0, 0, 32),
+    # T not a block multiple, Tq != Tk, ring offset
+    "unpadded_offset": ((2, 50, 2, 16), (2, 70, 2, 16), 16, 0, 32),
+    # every key after every query when causal: rows with no key at all
+    "fully_masked": ((2, 8, 2, 16), (2, 8, 2, 16), 0, 8, 8),
+    "gqa": ((1, 32, 4, 16), (1, 32, 2, 16), 0, 0, 16),
+}
+
+
+@pytest.fixture
+def flash_gauges():
+    """The registry, recording, and empty of the flash kernels' gauges;
+    the two directions forget what they traced (they are traced once a
+    shape, and the gauges are written while tracing)."""
+    pa._flash_fwd.clear_cache()
+    pa._flash_bwd.clear_cache()
+    was = metrics.enabled()
+    metrics.enable()
+    metrics.registry.clear()
+
+    def read():
+        snap = metrics.registry.snapshot()
+        per_program = snap.get("hvd_flash_instances_per_program", {})
+        per_call = snap.get("hvd_flash_programs_per_call", {})
+        return {kernel: (int(per_program[kernel]), int(per_call[kernel]))
+                for kernel in KERNELS if kernel in per_program}
+
+    yield read
+    metrics.registry.clear()
+    pa._flash_fwd.clear_cache()
+    pa._flash_bwd.clear_cache()
+    if not was:
+        metrics.disable()
+
+
+@pytest.mark.parametrize("case", INSTANCE_CASES)
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("most", [1, 2, None], ids=["g1", "g2", "gall"])
+def test_instances_per_program_match_reference(monkeypatch, flash_gauges,
+                                               most, causal, case):
+    """Forward and backward against the reference whatever the number of
+    instances a program: side by side, no instance changes."""
+    q_shape, kv_shape, q_off, k_off, block = INSTANCE_CASES[case]
+    if most is not None:
+        monkeypatch.setattr(pa, "_MOST_INSTANCES", most)
+    q, k, v, ct = (_rand(s, 60 + i) for i, s in
+                   enumerate((q_shape, kv_shape, kv_shape, q_shape)))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, query_offset=q_off,
+                               key_offset=k_off, block_q=block,
+                               block_k=block)
+
+    def ref(q, k, v):
+        out = _ref_btHD(q, k, v, causal, q_off, k_off).astype(q.dtype)
+        if causal and case == "fully_masked":
+            out = out * 0.0  # the reference averages V over no key
+        return out
+
+    out, vjp_f = jax.vjp(flash, q, k, v)
+    expected, vjp_r = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
+                               atol=2e-5)
+    for a, b in zip(vjp_f(ct), vjp_r(ct)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+    # the grid each kernel was built with: heads first, then batches
+    b, h = q_shape[0], q_shape[2]
+    instances = b * h
+    g = max(d for d in [d for d in range(1, h + 1) if h % d == 0]
+            + [d * h for d in range(1, b + 1) if b % d == 0]
+            if d <= (most or instances))
+    q_blocks, k_blocks = -(-q_shape[1] // block), -(-kv_shape[1] // block)
+    assert flash_gauges() == {
+        "fwd": (g, instances // g * q_blocks),
+        "dq": (g, instances // g * q_blocks),
+        "dkv": (g, instances // g * k_blocks)}
+
+
+# cell -> (B, H, T, block, causal) of its attention calls at head width
+# 64 in bf16, and the instances a program each kernel gets there
+CELL_SHAPES = {
+    "gpt2m_dp1": ((16, 16, 1024, 512, True), 1),
+    "gpt2m_dp4": ((16, 16, 1024, 512, True), 1),
+    "bertl_s512": ((26, 16, 512, 512, False), 4),
+    "bertl_s128": ((104, 16, 128, 128, False), 16),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_SHAPES)
+def test_chooser_divides_and_stays_inside_its_limits(cell):
+    (b, h, t, block, causal), expected = CELL_SHAPES[cell]
+    for kernel in KERNELS:
+        gb, gh = pa._instances_per_program(
+            kernel, b, h, block, t, 64, 2, causal)
+        g = gb * gh
+        assert g == expected and b % gb == 0 and h % gh == 0, (kernel, g)
+        assert gb == 1 or gh == h  # consecutive instances
+        n_own, n_other, _, _ = pa._KERNEL_BLOCKS[kernel]
+        blocks = 2 * g * (n_own * pa._vmem_bytes(block, 64, 2)
+                          + n_other * pa._vmem_bytes(t, 64, 2))
+        assert blocks <= pa._VMEM_BLOCK_BUDGET, (kernel, g, blocks)
+        units = (block // 128) * (t // 128) * (1 + causal)
+        assert g == 1 or g * units <= pa._PROGRAM_TILE_UNITS
+        assert g <= pa._MOST_INSTANCES
+
+
+@pytest.mark.parametrize("why, args", [
+    ("more heads than a program takes, and a prime number of them",
+     (64, 17, 128, 128, 64, 2, False)),
+    ("one masked instance is all the work a program should do",
+     (16, 16, 512, 1024, 64, 2, True)),
+    ("one instance fills the VMEM budget",
+     (16, 16, 128, 128, 4096, 4, False)),
+])
+def test_chooser_returns_one_where_it_must(why, args):
+    for kernel in KERNELS:
+        assert pa._instances_per_program(kernel, *args) == (1, 1), \
+            (why, kernel)
+
+
+def test_gauges_read_what_the_chooser_chose(flash_gauges):
+    """Under jit the gauges are set while tracing, once, and read the
+    chooser's own answer for the shapes of the call."""
+    b, t, h, d = 4, 256, 6, 16
+    q, k, v = (_rand((b, t, h, d), 70 + i) for i in range(3))
+    loss = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128) ** 2)))
+    loss(q, k, v)
+    want = {kernel: pa._instances_per_program(
+        kernel, b, h, 128, t, d, 4, True) for kernel in KERNELS}
+    assert flash_gauges() == {
+        kernel: (gb * gh, b * h // (gb * gh) * (t // 128))
+        for kernel, (gb, gh) in want.items()}
+    assert all(gb * gh > 1 for gb, gh in want.values()), want
+    metrics.registry.clear()
+    loss(q, k, v)  # compiled: nothing of the step writes a gauge
+    assert flash_gauges() == {}

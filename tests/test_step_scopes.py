@@ -30,16 +30,18 @@ HVD_BUCKETS = (scopes.HVD_PACK, scopes.HVD_ALLREDUCE, scopes.HVD_UNPACK)
 # primitive the op_name ends in: ``apply_updates`` (add), the loss's
 # ``psum`` and its division by n, constants and their broadcasts (an
 # empty name), and the reducers of reductions and scatters, which the
-# CPU compiler names by primitive alone
+# CPU compiler names by primitive alone (with the loop around them for
+# the interpreted flash kernels' loop over a program's instances)
 OWN_LINES = {"", "add", "div", "psum", "broadcast", "reduce_sum",
-             "reduce_max", "scatter-add"}
+             "reduce_max", "scatter-add", "while/body/reduce_sum",
+             "while/body/reduce_max"}
 # differentiated but under no scope: the job's own transpose of the
 # tied embedding in front of the fused head
 UNSCOPED_DIFFERENTIATED = {"jvp()/transpose", "transpose(jvp())/transpose"}
 
 
-def compile_tiny_step(cell: str, n: int) -> list:
-    """``op_name``s of the cell's tiny step compiled for ``n`` CPU
+def tiny_step(cell: str, n: int):
+    """The cell's tiny step and its described arguments for ``n`` CPU
     devices."""
     import jax
     import jax.numpy as jnp
@@ -73,9 +75,16 @@ def compile_tiny_step(cell: str, n: int) -> list:
     opt_state = jax.eval_shape(built["opt"].init, params)
     batch = dp_train.make_batch(
         sizes, traffic, n * traffic["batch_per_chip"], 0)
-    text = built["step"].lower(
+    return built["step"], (
         described(params, everywhere), described(opt_state, everywhere),
-        *described(batch, split)).compile().as_text()
+        *described(batch, split))
+
+
+def compile_tiny_step(cell: str, n: int) -> list:
+    """``op_name``s of the cell's tiny step compiled for ``n`` CPU
+    devices."""
+    step, args = tiny_step(cell, n)
+    text = step.lower(*args).compile().as_text()
     return re.findall(r'op_name="([^"]*)"', text)
 
 
@@ -172,3 +181,51 @@ def test_little_is_left_unscoped(op_names):
             own.add(path)
     assert own <= OWN_LINES, own - OWN_LINES
     assert differentiated <= UNSCOPED_DIFFERENTIATED, differentiated
+
+
+def pallas_calls(jaxpr, outer=""):
+    """``(name stack, pallas_call equation)`` of every kernel call in
+    the jaxpr and the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        stack = "/".join(
+            p for p in (outer, str(eqn.source_info.name_stack)) if p)
+        if eqn.primitive.name == "pallas_call":
+            yield stack, eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from pallas_calls(inner, stack)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_three_flash_kernels_a_layer_where_their_readers_look(cell):
+    """Each ``pallas_call`` of the step is one Mosaic call on the chip,
+    named by the innermost scope of its ``op_name`` (interpreted here,
+    so the step's equations are read and not the CPU's HLO). The
+    benchmark's readers find the flash kernels by that stem, ``attn``,
+    and tell them apart by phase and by how many arrays they return
+    (``benchmarks/scopes.kernel_kind``): a layer has exactly the
+    forward kernel with (out, lse) in the forward phase, dq with one
+    result and dkv with a pair in the backward phase, none of them with
+    a ``name=`` or a scope of its own."""
+    import jax
+
+    from benchmarks import scopes as readers
+
+    step, args = tiny_step(cell, CELLS[cell])
+    kinds: dict = {}
+    for stack, eqn in pallas_calls(jax.make_jaxpr(step)(*args).jaxpr):
+        assert eqn.params["name"] is None, eqn.params["name"]
+        block, stem = stack.split("/")[-2:]
+        assert stem == "attn", stack
+        phase, layer = readers.classify(stack + "/pallas_call")
+        results = len(eqn.outvars)
+        kind = readers.kernel_kind(
+            "call", phase, layer, {"call"}, {"call"} if results > 1 else ())
+        kinds.setdefault(block, []).append((kind, phase, results))
+    assert kinds == {
+        f"block_{i}": [(readers.KERNEL_FWD, "forward", 2),
+                       (readers.KERNEL_DQ, "backward", 1),
+                       (readers.KERNEL_DKV, "backward", 2)]
+        for i in range(2)}, kinds
