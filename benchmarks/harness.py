@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import contextlib
 import importlib
+import importlib.util
 import json
 import os
 import shutil
@@ -27,10 +28,14 @@ def load_json(*parts):
         return json.load(f)
 
 
-def load_cell(workload: str) -> dict:
-    """The cell's entry of BENCHMARK.json with its configuration and
-    traffic files, all found by name."""
-    bench = load_json(ROOT, "BENCHMARK.json")
+def load_cell(workload: str, root: str = ROOT) -> dict:
+    """The cell's entry of ``<root>/BENCHMARK.json`` with its
+    configuration and traffic files, all found by name under ``root``
+    (the checkout, but for the tests' fixture) and the configuration
+    held to its source (``benchmarks/published.py``)."""
+    from benchmarks import published
+
+    bench = load_json(root, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise SystemExit(f"no workload {workload!r} in BENCHMARK.json; "
@@ -38,8 +43,10 @@ def load_cell(workload: str) -> dict:
     cell = cells[workload]
     config_entry = next(c for c in bench["configs"]
                         if c["name"] == cell["config"])
-    config = load_json(ROOT, config_entry["file"])
-    traffic = load_json(HERE, "traffic", cell["traffic"] + ".json")
+    config = load_json(root, config_entry["file"])
+    published.check(config_entry, config)
+    traffic = load_json(root, "benchmarks", "traffic",
+                        cell["traffic"] + ".json")
 
     def applies(metric):
         return workload in metric.get("workloads", [workload])
@@ -53,6 +60,18 @@ def load_cell(workload: str) -> dict:
 
 def load_job(name: str):
     return importlib.import_module(f"benchmarks.jobs.{name}")
+
+
+def load_reference(family: str, root: str = ROOT):
+    """The plain reference of a configuration's ``family``:
+    ``<root>/benchmarks/reference/<family>.py``, loaded from that file
+    (its contract is written at the top of ``transformer_lm.py``)."""
+    path = os.path.join(root, "benchmarks", "reference", family + ".py")
+    spec = importlib.util.spec_from_file_location(
+        f"benchmarks_reference_{family}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def load_reader(metric: str):
@@ -80,10 +99,13 @@ class Run:
     """
 
     def __init__(self, *, started, workload, chips, traffic, model_sizes,
-                 seed, seconds, trace, rehearse):
+                 seed, seconds, trace, rehearse, config=None, root=ROOT):
         self.started = started
         self.workload = workload
         self.chips = chips
+        # the configuration file whole (a caller that only builds the
+        # step gives none), and its ``model`` group as run
+        self.config, self.root = config, root
         self.traffic, self.model_sizes = traffic, model_sizes
         self.seed, self.seconds = seed, seconds
         self.trace, self.rehearse = trace, rehearse

@@ -1,4 +1,5 @@
-"""Data-parallel training of a ``transformer_lm`` configuration.
+"""Data-parallel training of a configuration of the program's
+``Transformer``, whatever its ``family``.
 
 The step is built the way ``examples/gpt2_pretraining.py`` and
 ``examples/bert_pretraining.py`` build theirs (a copy: their loops run a
@@ -12,9 +13,12 @@ a knob: a cell runs the program's defaults.
 Everything the cell's parameters select is in its traffic file
 (``objective``, ``seq_len``, ``batch_per_chip``, ``attention``,
 ``loss_head``, ``learning_rate``); the model's sizes are in its
-configuration file and are built as written. The loop's numbers below
-define the metrics and are the same in every cell. One process drives
-every chip of the cell.
+configuration file and are built as written, and the plain reference
+the run is compared with is the file ``benchmarks/reference/<family>.py``
+that the configuration's ``family`` names (its contract is written at
+the top of ``transformer_lm.py``). The loop's numbers below define the
+metrics and are the same in every cell. One process drives every chip
+of the cell.
 """
 
 from __future__ import annotations
@@ -26,7 +30,6 @@ import time
 import numpy as np
 
 from benchmarks import harness
-from benchmarks.reference import transformer_lm as reference
 
 # Agreement of the system's loss function (bf16 activations, flash
 # attention, fused or dense cross entropy, fp32 parameters) with the
@@ -51,8 +54,14 @@ GRAD_RTOL = 3e-2
 # placement checks below see that.
 GLOBAL_LOSS_RTOL = 1e-4
 # tokens a chip takes in one reference call: [8192, V] float32 logits
-# are 1.6 GB at V=50k
+# are 1.6 GB at V=50k. A reference module that states BLOCK_TOKENS of
+# its own is given that many
 REFERENCE_BLOCK_TOKENS = 8192
+# A module of the program that adds a term to the loss (a router's
+# load-balancing term) sows it into this Flax collection; the loss a
+# job trains on is the head's loss plus every term sown, and the
+# reference's ``mean_loss`` returns the same sum
+AUX_LOSSES = "losses"
 
 # The loop. These are part of what the metrics mean, so no cell sets
 # them: ``tokens_per_s_per_chip`` is the median over chunks of
@@ -92,6 +101,8 @@ def make_model(model_sizes: dict, traffic: dict):
 
 def make_loss_fn(model, traffic: dict):
     """``loss(params, *batch)`` as the examples define it."""
+    import jax
+
     from horovod_tpu.models.transformer import causal_lm_loss, mlm_loss
     from horovod_tpu.ops.fused_cross_entropy import (
         fused_causal_lm_loss, fused_linear_cross_entropy)
@@ -100,24 +111,40 @@ def make_loss_fn(model, traffic: dict):
     if head not in ("fused_ce", "dense"):
         raise ValueError(f"unknown loss_head {head!r}")
 
+    def apply(p, tok, **kw):
+        """The model's output, and the sum of the terms its modules
+        sowed into AUX_LOSSES (None where none did)."""
+        out, sown = model.apply({"params": p}, tok, mutable=[AUX_LOSSES],
+                                **kw)
+        terms = jax.tree_util.tree_leaves(sown)
+        return out, (sum(terms) if terms else None)
+
     def hidden_and_head(p, tok):
-        return (model.apply({"params": p}, tok, return_hidden=True),
-                p["tok_emb"]["embedding"].T)
+        """Final hidden state and the head's kernel ``[h, V]``: the
+        token embedding's transpose where the head is tied."""
+        hidden, aux = apply(p, tok, return_hidden=True)
+        if model.cfg.tie_embeddings:
+            return (hidden, p["tok_emb"]["embedding"].T), aux
+        return (hidden, p["lm_head"]["kernel"]), aux
+
+    def plus(loss, aux):
+        return loss if aux is None else loss + aux
 
     if objective == "causal_lm":
         def loss_fn(p, tok):
             if head == "fused_ce":
-                return fused_causal_lm_loss(*hidden_and_head(p, tok),
-                                            tok)[0]
-            return causal_lm_loss(model.apply({"params": p}, tok),
-                                  tok)[0]
+                args, aux = hidden_and_head(p, tok)
+                return plus(fused_causal_lm_loss(*args, tok)[0], aux)
+            logits, aux = apply(p, tok)
+            return plus(causal_lm_loss(logits, tok)[0], aux)
     elif objective == "masked_lm":
         def loss_fn(p, tok, lab, msk):
             if head == "fused_ce":
-                return fused_linear_cross_entropy(
-                    *hidden_and_head(p, tok), lab, valid=msk)[0]
-            return mlm_loss(model.apply({"params": p}, tok), lab,
-                            msk)[0]
+                args, aux = hidden_and_head(p, tok)
+                return plus(fused_linear_cross_entropy(
+                    *args, lab, valid=msk)[0], aux)
+            logits, aux = apply(p, tok)
+            return plus(mlm_loss(logits, lab, msk)[0], aux)
     else:
         raise ValueError(f"unknown objective {objective!r}")
     return loss_fn
@@ -173,12 +200,8 @@ def compile_step(lowered, for_tpu: bool):
     return lowered.compile()
 
 
-def _reference_kw(cfg, traffic: dict) -> dict:
-    return dict(objective=traffic["objective"], num_layers=cfg.num_layers,
-                causal=cfg.causal, eps=cfg.layernorm_epsilon)
-
-
-def reference_check(run, cfg, loss_fn, params, model_sizes, traffic):
+def reference_check(run, reference, loss_fn, params, model_sizes,
+                    traffic):
     """Loss and gradient of the system's own loss function against the
     plain float32 reference, published width and depth, two seeded
     sequences of the cell's length, one device."""
@@ -187,7 +210,7 @@ def reference_check(run, cfg, loss_fn, params, model_sizes, traffic):
 
     batch = tuple(jax.numpy.asarray(a) for a in make_batch(
         model_sizes, traffic, 2, run.seed + 1))
-    kw = _reference_kw(cfg, traffic)
+    kw = reference.arguments(model_sizes, traffic)
 
     @jax.jit
     def compare(p, *b):
@@ -210,15 +233,17 @@ def reference_check(run, cfg, loss_fn, params, model_sizes, traffic):
     run.check("reference_gradient", g_err <= GRAD_RTOL, f"{g_err}")
 
 
-def reference_global_loss(run, cfg, params, host_batch, traffic, mesh,
-                          n: int):
+def reference_global_loss(run, reference, params, host_batch,
+                          model_sizes, traffic, mesh, n: int):
     """The reference's mean loss over the whole global batch, in blocks
     of at most REFERENCE_BLOCK_TOKENS tokens a chip (each chip takes the
     sequences the step will give it)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    kw = _reference_kw(cfg, traffic)
+    kw = reference.arguments(model_sizes, traffic)
+    block_tokens = getattr(reference, "BLOCK_TOKENS",
+                           REFERENCE_BLOCK_TOKENS)
     shard = NamedSharding(mesh, P("hvd"))
     block_fn = jax.jit(
         lambda p, *b: reference.nll_sum(p, b, **kw),
@@ -227,7 +252,7 @@ def reference_global_loss(run, cfg, params, host_batch, traffic, mesh,
         out_shardings=NamedSharding(mesh, P()))
     per_chip = traffic["batch_per_chip"]
     blk = max(d for d in range(1, per_chip + 1) if per_chip % d == 0
-              and d * traffic["seq_len"] <= REFERENCE_BLOCK_TOKENS)
+              and d * traffic["seq_len"] <= block_tokens)
     total = count = 0.0
     with run.span("reference_global_loss"):
         for lo in range(0, per_chip, blk):
@@ -272,7 +297,7 @@ def run_cell(run, model_sizes: dict, traffic: dict) -> dict:
     import horovod_tpu as hvd
 
     built = build(run, model_sizes, traffic)
-    n, mesh, cfg = built["n"], built["mesh"], built["cfg"]
+    n, mesh = built["n"], built["mesh"]
     step, opt = built["step"], built["opt"]
     if n != run.chips:
         raise SystemExit(
@@ -287,7 +312,8 @@ def run_cell(run, model_sizes: dict, traffic: dict) -> dict:
             jnp.zeros((1, seq), dtype=jnp.int32))["params"]
         jax.block_until_ready(params)
 
-    reference_check(run, cfg, built["loss_fn"], params, model_sizes,
+    reference = harness.load_reference(run.config["family"], run.root)
+    reference_check(run, reference, built["loss_fn"], params, model_sizes,
                     traffic)
 
     with run.span("init"):
@@ -300,7 +326,7 @@ def run_cell(run, model_sizes: dict, traffic: dict) -> dict:
         batch = tuple(jax.device_put(a, shard) for a in host_batch)
 
     ref_loss0 = reference_global_loss(
-        run, cfg, params, host_batch, traffic, mesh, n)
+        run, reference, params, host_batch, model_sizes, traffic, mesh, n)
 
     on_tpu = jax.default_backend() == "tpu"
     with run.span("lower"):

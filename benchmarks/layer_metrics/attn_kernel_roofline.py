@@ -1,8 +1,8 @@
 """kernels: least time the chip could take for the step's attention
 (``benchmarks/flops.py``: required operations over peak FLOP/s or least
 bytes over peak HBM bytes/s, whichever is larger) over the measured
-``attn_kernel_ms``, the attention stems' calls alone. The harness logs
-which peak bounds it."""
+``attn_kernel_ms``, the Mosaic calls of layer ``attn`` alone. The
+harness logs which peak bounds it."""
 
 from benchmarks import flops, harness
 from benchmarks.layer_metrics import attn_kernel_ms

@@ -16,6 +16,30 @@ same block without the causal mask: that is how this repo's BERT
 departs from the published post-LN model, and the configuration file
 lists it. The layers run under ``lax.scan`` with ``jax.checkpoint``:
 that changes what is kept for the backward pass, not one number of it.
+
+**What a reference module is** (this one and every
+``benchmarks/reference/<family>.py``; a job finds the file by the
+configuration's ``family`` and knows no family's name):
+
+* ``arguments(model, traffic) -> dict``: the keyword arguments the two
+  functions below take, read from the configuration's ``model`` group
+  as it is run and from the cell's traffic. A key the module needs and
+  the group lacks is a ``KeyError``: a reference assumes no default of
+  the program's;
+* ``mean_loss(params, batch, **arguments) -> scalar``: the loss the
+  program's loss function returns on ``batch``, every term of it (a
+  router's load-balancing term too), differentiable in ``params``;
+* ``nll_sum(params, batch, **arguments) -> (sum, count)`` over one
+  block of whole sequences, such that the sums over a batch's blocks,
+  divided, are the batch's loss. A term that does not add up over
+  blocks (one taken over all of a chip's tokens) needs the chip's batch
+  in one block: the module then states ``BLOCK_TOKENS``, the tokens a
+  chip takes in one call (8192 where it states none);
+* ``params`` is the program's parameter tree, as the program made it
+  from the seed, and ``batch`` the job's (``(tokens,)`` for
+  ``causal_lm``, ``(tokens, labels, mask)`` for ``masked_lm``);
+* float32 throughout under ``jax.default_matmul_precision("highest")``,
+  and nothing imported from the program.
 """
 
 from __future__ import annotations
@@ -24,6 +48,12 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+
+def arguments(model: dict, traffic: dict) -> dict:
+    return dict(objective=traffic["objective"],
+                num_layers=model["num_layers"], causal=model["causal"],
+                eps=model["layernorm_epsilon"])
 
 
 def _ln(x, p, eps):

@@ -42,7 +42,7 @@ def compile_cell(name: str, topo) -> dict:
     job = harness.load_job(traffic["job"])
     run = harness.Run(
         started=time.perf_counter(), workload=name, chips=cell["chips"],
-        traffic=traffic, model_sizes=sizes,
+        config=found["config"], traffic=traffic, model_sizes=sizes,
         seed=0, seconds=0, trace=False, rehearse=True)
     n = cell["chips"]
     mesh = Mesh(np.array(topo.devices[:n]), ("hvd",))

@@ -4,9 +4,13 @@
     python3 benchmarks/run.py --workload gpt2m_dp1 --seed 3 --seconds 10 --trace 0
 
 Finds the cell in ``BENCHMARK.json``, its configuration in
-``benchmarks/configs/``, its traffic in ``benchmarks/traffic/``, the
-job the traffic names in ``benchmarks/jobs/`` and, with ``--trace 1``,
-one reader per per-layer metric in ``benchmarks/layer_metrics/``.
+``benchmarks/configs/`` (held to its source, ``benchmarks/published.py``),
+its traffic in ``benchmarks/traffic/``, the job the traffic names in
+``benchmarks/jobs/``, the plain reference the configuration's ``family``
+names in ``benchmarks/reference/`` and, with ``--trace 1``, one reader
+per per-layer metric in ``benchmarks/layer_metrics/``. The data files
+(not the jobs or the readers) are looked for under ``--root``, which is
+the checkout unless a test points it at its fixture.
 The last line of stdout is the result: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``. With
 ``--trace 0`` the metrics are the cell's end-to-end metrics, with
@@ -43,6 +47,9 @@ def parse_args(argv=None):
     p.add_argument("--seconds", type=float, default=10.0)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     p.add_argument("--rehearse", action="store_true")
+    p.add_argument("--root", default=harness.ROOT,
+                   help="where BENCHMARK.json and the cell's data files "
+                        "are: the checkout, but for the tests' fixture")
     return p.parse_args(argv)
 
 
@@ -64,7 +71,7 @@ def enable_compile_cache() -> str:
 def reduce_trace(run) -> None:
     """Reads the traced window's file into ``run.reduced_trace``; a
     trace with no TPU plane (a rehearsal) leaves it empty."""
-    from benchmarks import hlo, trace
+    from benchmarks import hlo, scopes, trace
 
     path = trace.find_xplane(run.trace_dir)
     if path is None:
@@ -75,7 +82,8 @@ def reduce_trace(run) -> None:
             f"{sorted(devices)}, {len(host_spans)} host spans")
     kernels = hlo.mosaic_call_names(run.hlo_text)
     run.reduced_trace = trace.reduce(
-        devices, host_spans, run.step_module_hint, kernel_names=kernels)
+        devices, host_spans, run.step_module_hint, kernel_names=kernels,
+        kernel_layers=scopes.kernel_layers(run.hlo_text))
     # for reading by hand, beside the trace: the lines the file has and
     # one traced step of the first device with times from its beginning
     summary = {"lines": seen, "kernel_names": kernels,
@@ -98,7 +106,7 @@ def reduce_trace(run) -> None:
 
 def main(argv=None) -> int:
     args = parse_args(argv)
-    found = harness.load_cell(args.workload)
+    found = harness.load_cell(args.workload, args.root)
     cell, config, traffic = (found["cell"], found["config"],
                              found["traffic"])
     model_sizes = dict(config["model"])
@@ -114,9 +122,9 @@ def main(argv=None) -> int:
 
     run = harness.Run(
         started=STARTED, workload=args.workload, chips=cell["chips"],
-        traffic=traffic, model_sizes=model_sizes,
+        config=config, traffic=traffic, model_sizes=model_sizes,
         seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
-        rehearse=args.rehearse)
+        rehearse=args.rehearse, root=args.root)
     run.device_kind = dev.device_kind
     run.listen_for_compiles()
     run.log(f"cell {args.workload}: {cell['config']} x {cell['traffic']} "

@@ -34,11 +34,12 @@ The rules, all of them:
   less its body's, so the parts add up to the time the device is busy;
 * a collective (by its opcode, ``trace.collective_kind``) is in no
   phase: ``collective_ms`` has it;
-* the three flash kernels have no names of their own. A ``name=`` on
+* the three flash kernels have no names of their own (a ``name=`` on
   their ``pl.pallas_call`` becomes the innermost scope and with it the
-  instruction's stem (``flash_fwd.2`` for ``attn.6``: compiled here
-  both ways), and ``attn_kernel_ms`` finds the kernels by the stem
-  ``attn``. So a Mosaic call of layer ``attn`` is the forward kernel if
+  instruction's stem: ``flash_fwd.2`` for ``attn.6``, compiled here
+  both ways; since PR 26 ``attn_kernel_ms`` finds them by their layer,
+  :func:`kernel_layers`, so they may be given names). A Mosaic call of
+  layer ``attn`` is the forward kernel if
   its phase is ``forward``; of the backward two, dq returns one array
   and dkv a pair. (Under ``remat`` the recomputed forward kernel would
   be a pair in the backward phase too; no cell uses ``remat``.)
@@ -127,8 +128,13 @@ def classify(op_name: str) -> tuple:
     ``transpose(``, ``forward`` under a ``jvp(`` alone, else
     ``optimizer`` (whatever the step does outside the differentiated
     loss: the optimizer wrap, AdamW, ``apply_updates``). Layer: the
-    first of the program's scopes or Flax's module names found among the
-    name's parts, ``other`` when none is."""
+    first found among the name's parts of the program's five scopes,
+    then the scopes the program lists as its layers' own
+    (``LAYER_SCOPES`` in ``horovod_tpu/utils/scopes.py``, a tuple of
+    names: ``.../mlp/moe_experts/...`` is layer ``moe_experts``, and a
+    reader of it is one line over :func:`read`; a program that has no
+    such tuple has no such layers), then Flax's module names; ``other``
+    when none is."""
     if "transpose(" in op_name:
         phase = "backward"
     elif "jvp(" in op_name:
@@ -138,7 +144,8 @@ def classify(op_name: str) -> tuple:
     parts = set(_PARTS.split(op_name))
     for scope in (program.LOSS_HEAD, program.HVD_PACK,
                   program.HVD_ALLREDUCE, program.HVD_UNPACK,
-                  program.HVD_INNER_UPDATE):
+                  program.HVD_INNER_UPDATE,
+                  *getattr(program, "LAYER_SCOPES", ())):
         if scope in parts:
             return phase, scope
     if parts.intersection(NORM_MODULES):
@@ -159,6 +166,16 @@ def kernel_kind(name: str, phase: str, layer: str, mosaic: set,
     if phase == "forward":
         return KERNEL_FWD
     return KERNEL_DKV if name in tuples else KERNEL_DQ
+
+
+def kernel_layers(hlo_text: str) -> dict:
+    """Mosaic call → the layer of its ``op_name``, for the calls that
+    have one; empty for a program without scope names."""
+    if program is None:
+        return {}
+    names = op_names(hlo_text)
+    return {k: classify(names[k])[1]
+            for k in hlo.mosaic_call_names(hlo_text) if k in names}
 
 
 class Compiled(NamedTuple):

@@ -15,8 +15,9 @@ cut from that. An instruction's name does not say what it is
 (``jax.lax.psum`` leaves ``psum.<n>``, a Pallas call in ``attn`` leaves
 ``attn.<n>``), so collectives are found by opcode and kernels by the
 names the compiled HLO gives for its ``tpu_custom_call``s; a kernel's
-time is kept under its instruction's stem (``attn``), so that one
-kernel family's metric does not take in another's calls. An
+time is kept under its instruction's stem (``attn``) and under the
+layer its ``op_name`` puts it in (``benchmarks/scopes.kernel_layers``),
+so that one kernel family's metric does not take in another's calls. An
 asynchronous collective is a ``<kind>-start`` and a later
 ``<kind>-done`` with compute in between, in flight from the beginning of
 the first to the end of the second; this repo's gradient all-reduces are
@@ -183,12 +184,16 @@ def step_windows(modules: Sequence[Event], hint: str) -> list:
 
 
 def reduce_device(ops: Sequence[Event], windows: Sequence[Interval],
-                  kernel_names: Sequence[str], opcodes=None) -> dict:
+                  kernel_names: Sequence[str], opcodes=None,
+                  kernel_layers=None) -> dict:
     """Per traced step of one device, in nanoseconds: time in which any
     instruction ran, time in which a collective was in flight, the part
     of that with no other instruction running, and the summed durations
-    of the instructions named in ``kernel_names``, by their stem."""
+    of the instructions named in ``kernel_names``, by their stem and by
+    their layer (``kernel_layers``: instruction → layer; one it does
+    not name is of the layer its stem spells)."""
     opcodes = opcodes or {}
+    kernel_layers = kernel_layers or {}
     coll = merge(collective_spans(ops, opcodes))
     leaf = leaves(ops)
     compute = merge(span_of(e) for e in leaf
@@ -197,9 +202,12 @@ def reduce_device(ops: Sequence[Event], windows: Sequence[Interval],
     exposed = subtract(coll, compute)
     wanted = set(kernel_names)
     kernels: dict = {}  # stem -> intervals
+    by_layer: dict = {}  # layer -> intervals
     for e in leaf:
         if e[0] in wanted:
             kernels.setdefault(stem(e[0]), []).append(span_of(e))
+            by_layer.setdefault(kernel_layers.get(e[0], stem(e[0])),
+                                []).append(span_of(e))
     steps = []
     for w in windows:
         steps.append({
@@ -208,6 +216,8 @@ def reduce_device(ops: Sequence[Event], windows: Sequence[Interval],
             "exposed_collective_ns": total(clip(exposed, w)),
             "kernel_ns": {k: total(clip(v, w))
                           for k, v in kernels.items()},
+            "kernel_layer_ns": {k: total(clip(v, w))
+                                for k, v in by_layer.items()},
         })
     out = {"steps": steps}
     if windows:
@@ -226,7 +236,7 @@ def _median(xs):
 
 
 def reduce(devices: dict, host_spans: Sequence[Event], hint: str,
-           kernel_names: Sequence[str] = ()) -> dict:
+           kernel_names: Sequence[str] = (), kernel_layers=None) -> dict:
     """The trace's numbers for the layer metrics.
 
     ``devices`` maps a device id to ``{"modules": [...], "ops": [...],
@@ -242,7 +252,7 @@ def reduce(devices: dict, host_spans: Sequence[Event], hint: str,
         if not windows or not lines["ops"]:
             continue
         per_dev[dev] = reduce_device(lines["ops"], windows, kernel_names,
-                                     lines.get("opcodes"))
+                                     lines.get("opcodes"), kernel_layers)
         per_dev[dev]["ops"] = lines["ops"]
     if not per_dev:
         return {}
@@ -251,8 +261,11 @@ def reduce(devices: dict, host_spans: Sequence[Event], hint: str,
         return max(_median([of(s[key]) for s in d["steps"]])
                    for d in per_dev.values())
 
-    kernel_stems = sorted({k for d in per_dev.values()
-                           for s in d["steps"] for k in s["kernel_ns"]})
+    def kernel_ms_by(key):
+        found = sorted({k for d in per_dev.values() for s in d["steps"]
+                        for k in s[key]})
+        return {k: worst(key, lambda v, k=k: v.get(k, 0.0)) / 1e6
+                for k in found}
 
     spans = [d["span"][1] - d["span"][0] for d in per_dev.values()]
     idle = [1.0 - d["busy_in_span_ns"] / (d["span"][1] - d["span"][0])
@@ -264,11 +277,10 @@ def reduce(devices: dict, host_spans: Sequence[Event], hint: str,
         "device_busy_ms": worst("busy_ns") / 1e6,
         "collective_ms": worst("collective_ns") / 1e6,
         "exposed_collective_ms": worst("exposed_collective_ns") / 1e6,
-        # every Mosaic call, and each stem's own
+        # every Mosaic call, each stem's own and each layer's own
         "kernel_ms": worst("kernel_ns", lambda v: sum(v.values())) / 1e6,
-        "kernel_ms_by_stem": {
-            k: worst("kernel_ns", lambda v, k=k: v.get(k, 0.0)) / 1e6
-            for k in kernel_stems},
+        "kernel_ms_by_stem": kernel_ms_by("kernel_ns"),
+        "kernel_ms_by_layer": kernel_ms_by("kernel_layer_ns"),
         "device_idle_pct": 100.0 * max(idle),
         "step_period_ms": (_median(periods) or 0.0) / 1e6,
         "busy_s": statistics.fmean(

@@ -14,15 +14,29 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmarks import harness  # noqa: E402
+from benchmarks import harness, published  # noqa: E402
 
 BENCH = harness.load_json(ROOT, "BENCHMARK.json")
+# a BENCHMARK.json-shaped file with a configuration of a second family
+# (RMSNorm, rotary, grouped-query, SwiGLU, a head of its own), cut in
+# depth, with its reference, its traffic and one cell: what the harness
+# has to take as data. No file under benchmarks/ knows a name of it
+FIXTURE = os.path.join(ROOT, "tests", "benchmarks", "data", "fixture")
+FIXTURE_BENCH = harness.load_json(FIXTURE, "BENCHMARK.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter",
            "host_clock"}
 CELLS = [w["name"] for w in BENCH["workloads"]]
 METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+# every cell and configuration of both files, with the root it is under
+ROOTED_CELLS = [pytest.param(root, w["name"], id=w["name"])
+                for root, bench in ((ROOT, BENCH), (FIXTURE, FIXTURE_BENCH))
+                for w in bench["workloads"]]
+ROOTED_CONFIGS = [pytest.param(root, c, id=c["name"])
+                  for root, bench in ((ROOT, BENCH),
+                                      (FIXTURE, FIXTURE_BENCH))
+                  for c in bench["configs"]]
 
 
 def test_top_level_keys_and_limits():
@@ -37,14 +51,16 @@ def test_top_level_keys_and_limits():
     assert len(set(pairs)) == len(pairs)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_cell_resolves_to_files(cell):
-    found = harness.load_cell(cell)
+@pytest.mark.parametrize("root,cell", ROOTED_CELLS)
+def test_cell_resolves_to_files(root, cell):
+    found = harness.load_cell(cell, root)
     traffic, config = found["traffic"], found["config"]
+    # the job is the harness's code; the reference comes with the data
     assert os.path.exists(os.path.join(
         harness.HERE, "jobs", traffic["job"] + ".py"))
-    assert os.path.exists(os.path.join(
-        harness.HERE, "reference", config["family"] + ".py"))
+    reference = harness.load_reference(config["family"], root)
+    assert all(callable(getattr(reference, f))
+               for f in ("arguments", "mean_loss", "nll_sum"))
     assert set(config["tiny"]) <= set(config["model"])
     assert set(traffic["tiny"]) <= set(traffic)
     names = {m["name"] for m in found["end_to_end"]}
@@ -58,12 +74,12 @@ def test_cell_resolves_to_files(cell):
                 "traced_steps"} & set(traffic)
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_model_is_built_from_the_config_file_as_written(cell):
+@pytest.mark.parametrize("root,cell", ROOTED_CELLS)
+def test_model_is_built_from_the_config_file_as_written(root, cell):
     import dataclasses
 
     from benchmarks.jobs import dp_train
-    found = harness.load_cell(cell)
+    found = harness.load_cell(cell, root)
     sizes, traffic = found["config"]["model"], found["traffic"]
     cfg = dp_train.make_model(sizes, traffic)[0]
     assert {k: v for k, v in dataclasses.asdict(cfg).items()
@@ -74,25 +90,149 @@ def test_model_is_built_from_the_config_file_as_written(cell):
     with pytest.raises(ValueError, match="max_seq_len"):
         dp_train.make_model(
             sizes, {**traffic, "seq_len": cfg.max_seq_len + 1})
+    # the reference reads its arguments from the same group
+    reference = harness.load_reference(found["config"]["family"], root)
+    assert reference.arguments(sizes, traffic)["num_layers"] == \
+        cfg.num_layers
 
 
-@pytest.mark.parametrize("config", BENCH["configs"],
-                         ids=lambda c: c["name"])
-def test_config_file_holds_the_published_sizes(config):
-    body = harness.load_json(ROOT, config["file"])
+@pytest.mark.parametrize("root,config", ROOTED_CONFIGS)
+def test_config_file_holds_the_published_sizes(root, config):
+    """The model group equals the source key for key, but for the keys
+    ``reduced`` names, in the file and in the entry alike
+    (``benchmarks/published.py``)."""
+    body = harness.load_json(root, config["file"])
     assert config["file"].startswith("benchmarks/")
-    assert body["source"] == config["source"]
-    assert body["reduced"] == config["reduced"] == []
-    model, pub = body["model"], body["published"]
-    depth = pub.get("n_layer", pub.get("num_hidden_layers"))
-    width = pub.get("n_embd", pub.get("hidden_size"))
-    heads = pub.get("n_head", pub.get("num_attention_heads"))
-    inner = pub.get("intermediate_size") or pub.get("n_inner") \
-        or 4 * width
-    assert (model["num_layers"], model["hidden_size"],
-            model["num_heads"]) == (depth, width, heads)
-    assert model["hidden_size"] * model["mlp_ratio"] == inner
-    assert model["vocab_size"] == pub["vocab_size"]
+    published.check(config, body)
+    assert [c["key"] for c in body["reduced"]] == config["reduced"]
+    for cut in body["reduced"]:
+        assert cut["why"] and cut["held"] < cut["published"]
+
+
+def test_the_two_whole_configurations_pass_with_nothing_reduced():
+    for name in ("gpt2-medium", "bert-large"):
+        entry = next(c for c in BENCH["configs"] if c["name"] == name)
+        body = harness.load_json(ROOT, entry["file"])
+        assert body["reduced"] == entry["reduced"] == []
+        pub, model = body["published"], body["model"]
+        assert model["num_layers"] == pub.get(
+            "n_layer", pub.get("num_hidden_layers")) == 24
+
+
+def test_fixture_is_cut_in_depth_and_of_another_family():
+    entry = FIXTURE_BENCH["configs"][0]
+    body = harness.load_json(FIXTURE, entry["file"])
+    assert entry["reduced"] == ["num_hidden_layers"]
+    assert body["family"] != "transformer_lm"
+    assert body["model"]["tie_embeddings"] is False
+    assert (body["reduced"][0]["published"], body["reduced"][0]["held"],
+            body["model"]["num_layers"]) == (6, 2, 2)
+    # no file of the harness knows a name of the fixture
+    names = [body["family"], entry["name"],
+             FIXTURE_BENCH["workloads"][0]["name"],
+             FIXTURE_BENCH["workloads"][0]["traffic"]]
+    for base, _, files in os.walk(harness.HERE):
+        for f in files:
+            if f.endswith((".py", ".json")):
+                text = open(os.path.join(base, f)).read()
+                assert not [n for n in names if n in text], (f, names)
+
+
+def _cut(key, published_value, held):
+    return {"key": key, "published": published_value, "held": held,
+            "why": "a test"}
+
+
+def _fixture_variant(change):
+    """The fixture's entry and file after ``change(entry, body)``."""
+    entry = json.loads(json.dumps(FIXTURE_BENCH["configs"][0]))
+    body = harness.load_json(FIXTURE, entry["file"])
+    change(entry, body)
+    return entry, body
+
+
+def _depth_differs(entry, body):
+    body["model"]["num_layers"] = 1
+
+
+def _width_listed(entry, body):
+    body["hidden_size"] = body["model"]["hidden_size"] = 128
+    body["model"]["mlp_ratio"] = 4.0
+    body["reduced"].append(_cut("hidden_size", 256, 128))
+    entry["reduced"].append("hidden_size")
+
+
+def _experts_per_token_listed(entry, body):
+    body["num_experts_per_tok"] = body["model"]["experts_per_token"] = 2
+    body["reduced"].append(_cut("num_experts_per_tok", 8, 2))
+    entry["reduced"].append("num_experts_per_tok")
+
+
+def _count_without_deployment(entry, body):
+    body["vocab_size"] = body["model"]["vocab_size"] = 256
+    body["reduced"].append(_cut("vocab_size", 1024, 256))
+    entry["reduced"].append("vocab_size")
+
+
+def _entry_and_file_disagree(entry, body):
+    entry["reduced"] = []
+
+
+def _half_a_period(entry, body):
+    body["num_hidden_layers"] = body["model"]["num_layers"] = 1
+    body["reduced"][0]["held"] = 1
+
+
+def _unknown_key(entry, body):
+    body["sliding_window"] = 64
+    body["reduced"].append(_cut("sliding_window", 128, 64))
+    entry["reduced"].append("sliding_window")
+
+
+@pytest.mark.parametrize("change,key,why", [
+    (_depth_differs, "num_hidden_layers", "does not name it|model group"),
+    (_width_listed, "hidden_size", "a width is never cut"),
+    (_experts_per_token_listed, "num_experts_per_tok",
+     "a width is never cut"),
+    (_count_without_deployment, "vocab_size", "deployment"),
+    (_entry_and_file_disagree, "num_hidden_layers", "BENCHMARK.json"),
+    (_half_a_period, "num_hidden_layers", "layer_period"),
+    (_unknown_key, "sliding_window", "no row"),
+], ids=lambda x: getattr(x, "__name__", None))
+def test_a_cut_that_is_not_written_down_or_not_allowed_is_refused(
+        change, key, why):
+    with pytest.raises(ValueError, match=f"key '{key}'.*({why})"):
+        published.check(*_fixture_variant(change))
+
+
+def test_a_count_is_cut_in_a_file_that_states_its_deployment():
+    def change(entry, body):
+        _count_without_deployment(entry, body)
+        body["deployment"] = {
+            "chips": 4, "divided": "vocabulary rows split four ways"}
+    published.check(*_fixture_variant(change))
+    # and the cell is then refused where it is loaded, not only here
+    with pytest.raises(ValueError, match="vocab_size"):
+        published.check(*_fixture_variant(_count_without_deployment))
+
+
+def test_a_published_group_is_held_like_top_level_keys():
+    """The two forms a file may take: the source's keys in a
+    ``published`` group (a cut key holds the published value there) or
+    at the top level (a cut key holds what is run)."""
+    entry = json.loads(json.dumps(BENCH["configs"][0]))
+    body = harness.load_json(ROOT, entry["file"])
+    depth = "n_layer" if "n_layer" in body["published"] else \
+        "num_hidden_layers"
+    body["model"]["num_layers"] = 12
+    with pytest.raises(ValueError, match=f"key '{depth}'"):
+        published.check(entry, body)
+    body["reduced"] = [_cut(depth, 24, 12)]
+    entry["reduced"] = [depth]
+    published.check(entry, body)
+    body["reduced"] = [_cut(depth, 36, 12)]
+    with pytest.raises(ValueError, match=f"key '{depth}'"):
+        published.check(entry, body)
 
 
 @pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
@@ -145,16 +285,55 @@ def run_py(*args, devices=1, program=None):
         capture_output=True, text=True, timeout=600)
 
 
-REHEARSALS = [("gpt2m_dp1", 1, 0), ("bertl_s128", 1, 1),
-              ("gpt2m_dp4", 4, 1)]
+def kind_of(root, cell):
+    """What a rehearsal stands for: the job, the family and whether
+    there is more than one chip."""
+    found = harness.load_cell(cell, root)
+    return (found["traffic"]["job"], found["config"]["family"],
+            found["cell"]["chips"] > 1)
+
+
+def rehearsals():
+    """The three cells rehearsed since PR 22, and one traced rehearsal
+    of the first cell of every further kind (job, family, more than one
+    chip) in either file: a cell of a new job or family is rehearsed
+    without an edit here."""
+    fixed = [(ROOT, "gpt2m_dp1", 1, 0), (ROOT, "bertl_s128", 1, 1),
+             (ROOT, "gpt2m_dp4", 4, 1)]
+    seen = {kind_of(root, cell) for root, cell, _, _ in fixed}
+    found = []
+    for root, bench in ((ROOT, BENCH), (FIXTURE, FIXTURE_BENCH)):
+        for w in bench["workloads"]:
+            kind = kind_of(root, w["name"])
+            if kind not in seen:
+                seen.add(kind)
+                found.append((root, w["name"], w["chips"], 1))
+    return fixed + found
+
+
+REHEARSALS = rehearsals()
 SEED = "5"
 SOUND_LOSS = {}  # cell -> loss_step_16 of its untraced rehearsal
 
 
-@pytest.mark.parametrize("cell,devices,traced", REHEARSALS)
-def test_rehearsal_runs_end_to_end(cell, devices, traced):
+def test_every_kind_of_cell_is_rehearsed_and_one_is_traced():
+    kinds = {kind_of(root, cell) for root, cell, _, _ in REHEARSALS}
+    for root, bench in ((ROOT, BENCH), (FIXTURE, FIXTURE_BENCH)):
+        assert {kind_of(root, w["name"])
+                for w in bench["workloads"]} <= kinds
+    assert ("dp_train", "rope_swiglu_lm", False) in kinds
+    assert any(traced for _, _, _, traced in REHEARSALS)
+    assert {cell for _, cell, _, _ in REHEARSALS} >= {
+        "gpt2m_dp1", "bertl_s128", "gpt2m_dp4", "fixture_dp1"}
+
+
+@pytest.mark.parametrize("root,cell,devices,traced", REHEARSALS,
+                         ids=[r[1] for r in REHEARSALS])
+def test_rehearsal_runs_end_to_end(root, cell, devices, traced):
+    bench = harness.load_json(root, "BENCHMARK.json")
     done = run_py("--workload", cell, "--seed", SEED, "--seconds", "1",
-                  "--trace", str(traced), "--rehearse", devices=devices)
+                  "--trace", str(traced), "--rehearse", "--root", root,
+                  devices=devices)
     assert done.returncode == 0, done.stderr[-4000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert set(line) == {"correct", "attempted", "failed", "metrics",
@@ -164,7 +343,7 @@ def test_rehearsal_runs_end_to_end(cell, devices, traced):
     assert line["device"] == {"platform": "cpu", "kind": "cpu",
                               "count": devices}
     declared = {m["name"]: m["unit"] for m in (
-        BENCH["per_layer"] if traced else BENCH["end_to_end"])}
+        bench["per_layer"] if traced else bench["end_to_end"])}
     for name, m in line["metrics"].items():
         assert set(m) == {"value", "unit"} and m["unit"] == declared[name]
     # a rehearsal prints no time, rate or size of a device
@@ -179,6 +358,9 @@ def test_rehearsal_runs_end_to_end(cell, devices, traced):
         assert set(line["metrics"]) == {"loss_step_16"}
         SOUND_LOSS[cell] = line["metrics"]["loss_step_16"]["value"]
     assert "check no_compile_in_window: ok" in done.stdout
+    for check in ("reference_loss", "reference_gradient",
+                  "global_batch_loss"):
+        assert f"check {check}: ok" in done.stdout
 
 
 def test_without_a_tpu_the_benchmark_refuses():

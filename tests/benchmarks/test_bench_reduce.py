@@ -194,6 +194,32 @@ def test_attention_metric_leaves_out_another_kernel_family():
     assert mosaic_kernel_ms.read(FakeRun(r)) == pytest.approx(0.008)
     assert attn_kernel_ms.read(FakeRun({})) is None
     assert mosaic_kernel_ms.read(FakeRun({})) is None
+    # a kernel that was given a name is called by it (``flash_fwd.2``,
+    # PERF.md) and is found by the layer of its op_name all the same;
+    # a kernel of another layer whose stem says ``attn`` is not
+    from benchmarks import scopes
+    hlo_text = """
+ENTRY %main {
+  %flash_fwd.2 = bf16[2]{0} custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/jvp(Transformer)/block_0/attn/flash_fwd"}
+  %attn.3 = bf16[2]{0} custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/transpose(jvp(Transformer))/block_0/attn/pallas_call"}
+  %attn.7 = bf16[2]{0} custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_fn)/jvp(Transformer)/block_0/ln_attn/pallas_call"}
+  %layer_norm.9 = bf16[2]{0} custom-call(%x), custom_call_target="tpu_custom_call"
+}
+"""
+    layers = scopes.kernel_layers(hlo_text)
+    assert layers == {"flash_fwd.2": "attn", "attn.3": "attn",
+                      "attn.7": "norm"}
+    ops = [ev("flash_fwd.2", 0, 20), ev("attn.3", 20, 30),
+           ev("layer_norm.9", 50, 8), ev("attn.7", 58, 12)]
+    r = trace.reduce(
+        {0: {"modules": [ev("jit_step_fn", 0, 80)], "ops": ops}}, [],
+        "step_fn", kernel_names=hlo.mosaic_call_names(hlo_text),
+        kernel_layers=layers)
+    assert r["kernel_ms_by_layer"] == {
+        "attn": pytest.approx(0.050), "norm": pytest.approx(0.012),
+        "layer_norm": pytest.approx(0.008)}
+    assert attn_kernel_ms.read(FakeRun(r)) == pytest.approx(0.050)
+    assert mosaic_kernel_ms.read(FakeRun(r)) == pytest.approx(0.070)
 
 
 def test_no_device_plane_reduces_to_nothing():
@@ -252,6 +278,7 @@ def test_recorded_chip_step():
     assert r["exposed_collective_ms"] == pytest.approx(24.585723)
     assert r["kernel_ms"] == pytest.approx(104.297232)
     assert r["kernel_ms_by_stem"] == {"attn": pytest.approx(104.297232)}
+    assert r["kernel_ms_by_layer"] == r["kernel_ms_by_stem"]
     assert r["device_idle_pct"] == pytest.approx(0.0177047, rel=1e-4)
     assert r["device_ops"][0] == ["attn x72", pytest.approx(0.104297232)]
     # the two while loops of the fused cross entropy are parents: their
@@ -314,6 +341,79 @@ def test_bert_large_operations_per_token(mix, attention, total):
     assert flops.train_flops_per_token(
         sizes("bert-large"), traffic(mix)) == pytest.approx(
             total, rel=1e-4)
+
+
+# the shapes to come, as dictionaries of the keys flops.py reads: one
+# OLMoE-1B-7B layer (64 experts of width 1024, 8 a token, SwiGLU, 16
+# heads of 128, V=50,304 untied, seq 4096) and a grouped-query block
+OLMOE_LAYER = {
+    "hidden_size": 2048, "num_heads": 16, "mlp_ratio": 0.5,
+    "activation": "swiglu", "num_experts": 64, "experts_per_token": 8,
+    "vocab_size": 50304, "causal": True, "num_layers": 1}
+GQA_LAYER = {
+    "hidden_size": 4096, "num_heads": 32, "num_kv_heads": 8,
+    "mlp_ratio": 3.5, "activation": "swiglu", "vocab_size": 32000,
+    "causal": True, "num_layers": 1}
+SEQ_4096 = {"objective": "causal_lm", "seq_len": 4096,
+            "batch_per_chip": 4}
+
+
+@pytest.mark.parametrize("model,blocks,attention,head", [
+    # projections 4 * 2048^2 = 16,777,216; eight experts of three
+    # 2048 x 1024 matrices 50,331,648; router 2048 * 64 = 131,072;
+    # twice their sum. Attention 4 * 4096 * 2048 / 2. Head
+    # 2 * 2048 * 50304 * 4095/4096
+    (OLMOE_LAYER, 134_479_872, 16_777_216, 205_994_880),
+    # q and out 2 * 4096^2, k and v 2 * 4096 * 8 * 128 (a quarter);
+    # three 4096 x 14336 matrices; head 2 * 4096 * 32000 * 4095/4096
+    (GQA_LAYER, 2 * (33_554_432 + 8_388_608 + 176_160_768), 33_554_432,
+     262_080_000),
+    # a dense MLP where experts_per_token is 0, and a head width that
+    # is stated and is not hidden / heads
+    ({**OLMOE_LAYER, "num_experts": 0, "experts_per_token": 0},
+     2 * (16_777_216 + 6_291_456), 16_777_216, 205_994_880),
+    ({**GQA_LAYER, "head_dim": 64},
+     2 * (16_777_216 + 4_194_304 + 176_160_768), 16_777_216,
+     262_080_000),
+], ids=["olmoe_layer", "gqa_layer", "olmoe_dense", "stated_head_dim"])
+def test_operations_per_token_of_the_shapes_to_come(model, blocks,
+                                                    attention, head):
+    f = flops.forward_flops_per_token(model, SEQ_4096)
+    assert (f["blocks"], f["attention"], f["head"]) == (
+        blocks, attention, head)
+    assert flops.train_flops_per_token(model, SEQ_4096) == 3 * (
+        blocks + attention + head)
+
+
+def test_olmoe_layer_counts_the_active_experts_alone():
+    assert flops.train_flops_per_token(OLMOE_LAYER, SEQ_4096) == \
+        1_071_755_904
+    assert sum(flops.forward_flops_per_token(
+        OLMOE_LAYER, SEQ_4096).values()) == 357_251_968
+    # the count before PR 26 (two MLP matrices, one MLP a token, no
+    # router) said 264,715,136: 26% under
+    old = 2 * (4 * 2048 * 2048 + 2 * 2048 * 1024) + 16_777_216 \
+        + 205_994_880
+    assert old == 264_715_136 and 0.25 < 1 - old / 357_251_968 < 0.27
+    # and the experts the layer holds but does not send a token to are
+    # not required work
+    held = flops.forward_flops_per_token(
+        {**OLMOE_LAYER, "experts_per_token": 64}, SEQ_4096)["blocks"]
+    assert held == 2 * (16_777_216 + 64 * 6_291_456 + 131_072)
+
+
+def test_attention_kernel_work_of_grouped_query_heads():
+    # 32 query heads of 128, 8 key and value heads, batch 4, T=4096:
+    # operations by the query heads; of the twelve arrays q, o, do, dq
+    # and forward's q, o are 32 heads wide, k, v, dk, dv and forward's
+    # k, v are 8
+    w = flops.attention_kernel_work(GQA_LAYER, SEQ_4096)
+    assert w["flops"] == 6 * 2 * 4 * 32 * 4096 * 4096 * 128 / 2
+    assert w["bytes"] == 6 * (32 + 8) * 4 * 4096 * 128 * 2
+    # heads of 128 at hidden 2048: the stated head width is what counts
+    w = flops.attention_kernel_work(OLMOE_LAYER, SEQ_4096)
+    assert w["flops"] == 6 * 2 * 4 * 16 * 4096 * 4096 * 128 / 2
+    assert w["bytes"] == 12 * 4 * 16 * 4096 * 128 * 2
 
 
 def test_attention_kernel_work_and_roofline():

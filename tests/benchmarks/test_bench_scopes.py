@@ -92,6 +92,77 @@ def test_classify(op_name, want):
     assert scopes.classify(op_name) == want
 
 
+def old_classify(op_name):
+    """``scopes.classify`` as it stood before it took a layer's scope
+    from the program (PR 24's rule, word for word)."""
+    import re
+    if "transpose(" in op_name:
+        phase = "backward"
+    elif "jvp(" in op_name:
+        phase = "forward"
+    else:
+        phase = "optimizer"
+    parts = set(re.split(r"[/()]", op_name))
+    for scope in ("loss_head", "hvd_pack", "hvd_allreduce", "hvd_unpack",
+                  "hvd_inner_update"):
+        if scope in parts:
+            return phase, scope
+    if parts.intersection(("ln_attn", "ln_mlp", "ln_final")):
+        return phase, "norm"
+    for layer in ("attn", "mlp"):
+        if layer in parts:
+            return phase, layer
+    if any(p.startswith("tok_emb") for p in parts):
+        return phase, "embed"
+    return phase, "other"
+
+
+@pytest.fixture
+def layer_scopes(monkeypatch):
+    """Puts names into the program's ``LAYER_SCOPES`` for one test."""
+    def put(*names):
+        monkeypatch.setattr(program, "LAYER_SCOPES", names, raising=False)
+        scopes.classify.cache_clear()
+    yield put
+    monkeypatch.undo()
+    scopes.classify.cache_clear()
+
+
+def test_a_layers_scope_comes_from_the_program(layer_scopes):
+    under_mlp = ("jit(step_fn)/shard_map/jvp(Transformer)/block_0/mlp/"
+                 "moe_experts/dot_general")
+    router = ("jit(step_fn)/transpose(jvp(Transformer))/block_0/mlp/"
+              "moe_router/reduce_sum")
+    # a program that lists no layer scopes: Flax's module names, as ever
+    assert not getattr(program, "LAYER_SCOPES", ())
+    assert scopes.classify(under_mlp) == ("forward", "mlp")
+    layer_scopes("moe_router", "moe_experts")
+    assert scopes.classify(under_mlp) == ("forward", "moe_experts")
+    assert scopes.classify(router) == ("backward", "moe_router")
+    # after the five scopes there are: the loss head keeps what is its
+    assert scopes.classify(
+        "jit(step_fn)/jvp(loss_head)/moe_experts/dot_general") == (
+        "forward", "loss_head")
+    # and a reader of the layer is one line over scopes.read
+    found = scopes.tables({0: device()}, "step_fn", HLO.replace(
+        "block_0/ln_attn/mul", "block_0/mlp/moe_experts/mul"))
+    assert us(found, lambda p, l, k: l == "moe_experts") == 10
+    assert us(found, lambda p, l, k: l == "norm") == 0
+
+
+def test_classify_answers_as_before_for_every_recorded_op_name(
+        layer_scopes):
+    d = harness.load_json(ROOT, "tests", "benchmarks", "data",
+                          "gpt2m_dp1_scoped_step.json")
+    recorded = {d["paths"][enc[0]] + "/" + enc[1]
+                for enc in d["op_names"] if enc is not None}
+    assert len(recorded) > 300
+    for names in ((), ("moe_router", "moe_experts")):
+        layer_scopes(*names)
+        assert {n: scopes.classify(n) for n in recorded} == {
+            n: old_classify(n) for n in recorded}
+
+
 def test_scope_names_are_the_programs():
     assert (program.LOSS_HEAD, program.HVD_PACK, program.HVD_ALLREDUCE,
             program.HVD_UNPACK, program.HVD_INNER_UPDATE) == (
