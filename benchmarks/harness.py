@@ -111,6 +111,7 @@ class Run:
         self.trace, self.rehearse = trace, rehearse
         self.spans: list = []
         self.checks: dict = {}
+        self.compared: dict = {}  # check -> (number compared, its limit)
         self.window = None  # (start, end)
         self.window_compiles = 0
         self.compiles = self.cache_misses = 0
@@ -147,8 +148,13 @@ class Run:
         finally:
             self.spans.append((name, t0, time.perf_counter()))
 
-    def check(self, name: str, ok: bool, detail: str = "") -> None:
+    def check(self, name: str, ok: bool, detail: str = "", *,
+              value=None, limit=None) -> None:
+        """One part of ``correct``; where it compares a number with a
+        limit, both go into the result's line."""
         self.checks[name] = bool(ok)
+        if value is not None:
+            self.compared[name] = (float(value), float(limit))
         if not ok:
             self.log(f"CHECK FAILED {name}: {detail}")
 

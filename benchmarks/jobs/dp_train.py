@@ -62,6 +62,29 @@ REFERENCE_BLOCK_TOKENS = 8192
 # job trains on is the head's loss plus every term sown, and the
 # reference's ``mean_loss`` returns the same sum
 AUX_LOSSES = "losses"
+# A module of the program that makes a discrete choice (a router's k
+# experts of e for a token) sows it into this Flax collection: integer
+# arrays ``[..., k]`` whose last axis holds one token's k choices. The
+# step makes the collection mutable nowhere, so there the sowing is
+# nothing; for a reference that ``TAKES_CHOICES``
+# (``reference/transformer_lm.py``) the reference check makes it
+# mutable in the pass whose gradient it compares
+CHOICES = "choices"
+# Such a model is compared at its own choices, and how many of them the
+# float32 reference would have made itself is held to a floor that the
+# job derives and no module states: one, less the share of tokens at
+# which the reference's own scores all but tie, the gap between its
+# k-th and its (k+1)-th score under NEAR_TIE of the spread between its
+# best and its worst. bf16 keeps 8 bits: a score read from bf16
+# activations is off by 2^-9 of its size at the least and, by the sum
+# over layers that puts 1% into the gradient (above), by up to about
+# 2^-7; a token's scores spread about as wide as they are large, and
+# 2^-6 of the spread is twice that. A token outside that band that the
+# system routes otherwise is a router at fault (another k, a correction
+# left out), not rounding. Arithmetic, NOT a measurement: no program
+# sows choices yet, and the first cell whose program does has to read
+# both shares on the chip beside a router at fault (PERF.md section 7)
+NEAR_TIE = 2.0 ** -6
 
 # The loop. These are part of what the metrics mean, so no cell sets
 # them: ``tokens_per_s_per_chip`` is the median over chunks of
@@ -99,8 +122,24 @@ def make_model(model_sizes: dict, traffic: dict):
         Transformer(cfg)
 
 
-def make_loss_fn(model, traffic: dict):
-    """``loss(params, *batch)`` as the examples define it."""
+def named_choices(sown) -> dict:
+    """What a pass's modules sowed into CHOICES, as ``{path: integer
+    array}`` with the path's names joined by ``/``: the modules' names,
+    the name sown under, and the position in the tuple Flax's ``sow``
+    keeps (``block_1/mlp/router/experts/0``)."""
+    from flax import traverse_util
+
+    by_name = traverse_util.flatten_dict(
+        dict(sown).get(CHOICES, {}), sep="/")
+    return {f"{name}/{i}": array for name, kept in by_name.items()
+            for i, array in enumerate(kept)}
+
+
+def make_loss_fn(model, traffic: dict, with_choices: bool = False):
+    """``loss(params, *batch)`` as the examples define it. With
+    ``with_choices`` (the reference check's, never the step's) the same
+    pass also makes CHOICES mutable and the function returns ``(loss,
+    named_choices)``, for ``jax.value_and_grad(..., has_aux=True)``."""
     import jax
 
     from horovod_tpu.models.transformer import causal_lm_loss, mlm_loss
@@ -110,44 +149,71 @@ def make_loss_fn(model, traffic: dict):
     objective, head = traffic["objective"], traffic["loss_head"]
     if head not in ("fused_ce", "dense"):
         raise ValueError(f"unknown loss_head {head!r}")
+    mutable = [AUX_LOSSES, CHOICES] if with_choices else [AUX_LOSSES]
 
     def apply(p, tok, **kw):
-        """The model's output, and the sum of the terms its modules
-        sowed into AUX_LOSSES (None where none did)."""
-        out, sown = model.apply({"params": p}, tok, mutable=[AUX_LOSSES],
+        """The model's output, and what its modules sowed: the sum of
+        the terms in AUX_LOSSES (None where none did), and the
+        choices."""
+        out, sown = model.apply({"params": p}, tok, mutable=mutable,
                                 **kw)
-        terms = jax.tree_util.tree_leaves(sown)
-        return out, (sum(terms) if terms else None)
+        terms = jax.tree_util.tree_leaves(dict(sown).get(AUX_LOSSES, {}))
+        return out, ((sum(terms) if terms else None),
+                     named_choices(sown))
 
     def hidden_and_head(p, tok):
         """Final hidden state and the head's kernel ``[h, V]``: the
         token embedding's transpose where the head is tied."""
-        hidden, aux = apply(p, tok, return_hidden=True)
+        hidden, sown = apply(p, tok, return_hidden=True)
         if model.cfg.tie_embeddings:
-            return (hidden, p["tok_emb"]["embedding"].T), aux
-        return (hidden, p["lm_head"]["kernel"]), aux
+            return (hidden, p["tok_emb"]["embedding"].T), sown
+        return (hidden, p["lm_head"]["kernel"]), sown
 
-    def plus(loss, aux):
-        return loss if aux is None else loss + aux
+    def result(loss, sown):
+        aux, choices = sown
+        loss = loss if aux is None else loss + aux
+        return (loss, choices) if with_choices else loss
 
     if objective == "causal_lm":
         def loss_fn(p, tok):
             if head == "fused_ce":
-                args, aux = hidden_and_head(p, tok)
-                return plus(fused_causal_lm_loss(*args, tok)[0], aux)
-            logits, aux = apply(p, tok)
-            return plus(causal_lm_loss(logits, tok)[0], aux)
+                args, sown = hidden_and_head(p, tok)
+                return result(fused_causal_lm_loss(*args, tok)[0], sown)
+            logits, sown = apply(p, tok)
+            return result(causal_lm_loss(logits, tok)[0], sown)
     elif objective == "masked_lm":
         def loss_fn(p, tok, lab, msk):
             if head == "fused_ce":
-                args, aux = hidden_and_head(p, tok)
-                return plus(fused_linear_cross_entropy(
-                    *args, lab, valid=msk)[0], aux)
-            logits, aux = apply(p, tok)
-            return plus(mlm_loss(logits, lab, msk)[0], aux)
+                args, sown = hidden_and_head(p, tok)
+                return result(fused_linear_cross_entropy(
+                    *args, lab, valid=msk)[0], sown)
+            logits, sown = apply(p, tok)
+            return result(mlm_loss(logits, lab, msk)[0], sown)
     else:
         raise ValueError(f"unknown objective {objective!r}")
     return loss_fn
+
+
+def choices_agreement(scores: dict, system: dict):
+    """Over every array of the system's choices ``[..., k]`` and the
+    reference's scores ``[..., e]`` under the same name: the number of
+    tokens at which the top k of the scores is the system's set (the
+    order inside a token's k does not count), the number at which the
+    scores all but tie (NEAR_TIE), and the number of tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    agree = near = count = 0
+    for name, chosen in system.items():
+        s = scores[name].astype(jnp.float32)
+        k = chosen.shape[-1]
+        ranked, own = jax.lax.top_k(s, k + 1)
+        agree += jnp.sum(jnp.all(
+            jnp.sort(own[..., :k], -1) == jnp.sort(chosen, -1), -1))
+        near += jnp.sum(ranked[..., k - 1] - ranked[..., k]
+                        < NEAR_TIE * (ranked[..., 0] - jnp.min(s, -1)))
+        count += s[..., 0].size
+    return agree, near, count
 
 
 def make_batch(model_sizes: dict, traffic: dict, n_seq: int, seed: int):
@@ -204,33 +270,74 @@ def reference_check(run, reference, loss_fn, params, model_sizes,
                     traffic):
     """Loss and gradient of the system's own loss function against the
     plain float32 reference, published width and depth, two seeded
-    sequences of the cell's length, one device."""
+    sequences of the cell's length, one device.
+
+    A reference that ``TAKES_CHOICES`` is compared at the system's
+    choices, and ``loss_fn`` is then the one that returns them beside
+    the loss (``make_loss_fn(..., with_choices=True)``), so that they
+    are those of the very pass whose gradient is compared. Where a
+    router's k-th and (k+1)-th score are closer than bf16 rounds, the
+    float32 reference picks another expert and the two gradients differ
+    by an expert's whole contribution, which says nothing of the
+    arithmetic; how many of the system's choices the reference would
+    have made itself is held to the floor NEAR_TIE gives."""
     import jax
     import optax
 
     batch = tuple(jax.numpy.asarray(a) for a in make_batch(
         model_sizes, traffic, 2, run.seed + 1))
     kw = reference.arguments(model_sizes, traffic)
+    takes_choices = getattr(reference, "TAKES_CHOICES", False)
+    names = {}  # what each side names its choices, noted when traced
 
     @jax.jit
     def compare(p, *b):
-        l_sys, g_sys = jax.value_and_grad(loss_fn)(p, *b)
+        out, g_sys = jax.value_and_grad(
+            loss_fn, has_aux=takes_choices)(p, *b)
+        l_sys, system = out if takes_choices else (out, {})
+        scores = reference.choice_scores(p, b, **kw) \
+            if takes_choices else {}
+        names.update(system=sorted(system), reference=sorted(scores))
+        # other names, or none: nothing to give the reference, which is
+        # then compared freely, and `reference_choices` fails below
+        matched = bool(system) and set(system) == set(scores)
+        imposed = {"choices": system} if matched else {}
         l_ref, g_ref = jax.value_and_grad(
-            lambda q: reference.mean_loss(q, b, **kw))(p)
+            lambda q: reference.mean_loss(q, b, **kw, **imposed))(p)
         diff = jax.tree_util.tree_map(
             lambda a, r: a.astype(jax.numpy.float32) - r, g_sys, g_ref)
         return (l_sys, l_ref,
-                optax.global_norm(diff) / optax.global_norm(g_ref))
+                optax.global_norm(diff) / optax.global_norm(g_ref),
+                choices_agreement(scores, system) if matched else None)
 
     with run.span("reference_check"):
-        l_sys, l_ref, g_err = (float(x) for x in compare(params, *batch))
+        *numbers, counts = compare(params, *batch)
+        l_sys, l_ref, g_err = (float(x) for x in numbers)
     run.log(f"reference check: loss {l_sys:.5f} vs float32 reference "
             f"{l_ref:.5f}, gradient relative error {g_err:.3e}")
     run.check("reference_loss",
               math.isfinite(l_sys)
               and abs(l_sys - l_ref) <= LOSS_RTOL * abs(l_ref),
-              f"{l_sys} vs {l_ref}")
-    run.check("reference_gradient", g_err <= GRAD_RTOL, f"{g_err}")
+              f"{l_sys} vs {l_ref}",
+              value=abs(l_sys - l_ref) / abs(l_ref), limit=LOSS_RTOL)
+    run.check("reference_gradient", g_err <= GRAD_RTOL, f"{g_err}",
+              value=g_err, limit=GRAD_RTOL)
+    if not takes_choices:
+        return
+    if counts is None:
+        run.check("reference_choices", False,
+                  f"the program sowed {names['system']} into "
+                  f"{CHOICES!r} and the reference scores "
+                  f"{names['reference']}")
+        return
+    agree, near, count = (int(x) for x in counts)
+    share, floor = agree / count, (count - near) / count
+    run.log(f"reference check: {len(names['system'])} arrays of "
+            f"choices, the float32 reference makes {100 * share:.3f}% "
+            f"of the system's itself; its scores all but tie at "
+            f"{100 - 100 * floor:.3f}% of the tokens")
+    run.check("reference_choices", agree >= count - near,
+              f"{share} < {floor}", value=share, limit=floor)
 
 
 def reference_global_loss(run, reference, params, host_batch,
@@ -283,8 +390,9 @@ def build(run, model_sizes: dict, traffic: dict, mesh=None):
         loss_fn = make_loss_fn(model, traffic)
         n_batch_args = 1 if traffic["objective"] == "causal_lm" else 3
         step = make_step(loss_fn, opt, mesh, n, n_batch_args)
-    return dict(n=n, mesh=mesh, cfg=cfg, plain_model=plain_model,
-                opt=opt, loss_fn=loss_fn, step=step)
+    return dict(n=n, mesh=mesh, cfg=cfg, model=model,
+                plain_model=plain_model, opt=opt, loss_fn=loss_fn,
+                step=step)
 
 
 def run_cell(run, model_sizes: dict, traffic: dict) -> dict:
@@ -313,8 +421,10 @@ def run_cell(run, model_sizes: dict, traffic: dict) -> dict:
         jax.block_until_ready(params)
 
     reference = harness.load_reference(run.config["family"], run.root)
-    reference_check(run, reference, built["loss_fn"], params, model_sizes,
-                    traffic)
+    loss_fn = built["loss_fn"]
+    if getattr(reference, "TAKES_CHOICES", False):
+        loss_fn = make_loss_fn(built["model"], traffic, with_choices=True)
+    reference_check(run, reference, loss_fn, params, model_sizes, traffic)
 
     with run.span("init"):
         opt_state = opt.init(params)
@@ -381,12 +491,17 @@ def run_cell(run, model_sizes: dict, traffic: dict) -> dict:
             f"global batch {ref_loss0:.5f}")
     run.check("global_batch_loss",
               abs(loss0 - ref_loss0) <= GLOBAL_LOSS_RTOL * abs(ref_loss0),
-              f"{loss0} vs {ref_loss0}")
+              f"{loss0} vs {ref_loss0}",
+              value=abs(loss0 - ref_loss0) / abs(ref_loss0),
+              limit=GLOBAL_LOSS_RTOL)
     failed = sum(not math.isfinite(x) for x in host_losses)
-    run.check("losses_finite", failed == 0, f"{failed} not finite")
-    run.check("loss_falls", loss16 < loss0, f"{loss0} -> {loss16}")
+    run.check("losses_finite", failed == 0, f"{failed} not finite",
+              value=failed, limit=0)
+    run.check("loss_falls", loss16 < loss0, f"{loss0} -> {loss16}",
+              value=loss16, limit=loss0)
     run.check("no_compile_in_window", run.window_compiles == 0,
-              f"{run.window_compiles} compilations")
+              f"{run.window_compiles} compilations",
+              value=run.window_compiles, limit=0)
     if on_tpu:
         from benchmarks import hlo
         run.check("mosaic_kernels_compiled",
@@ -463,7 +578,8 @@ def placement_checks(run, n, batch, params, loss):
     sums = np.asarray(_bit_sums(params, run_mesh=batch[0].sharding.mesh))
     different = int(np.sum(np.any(sums != sums[:1], axis=0)))
     run.check("parameters_bitwise_equal", different == 0,
-              f"{different} leaves differ between devices")
+              f"{different} leaves differ between devices",
+              value=different, limit=0)
     from benchmarks import hlo
     run.check("allreduce_in_step", len(hlo.allreduces(run.hlo_text)) > 0,
               "no all-reduce in the compiled multi-chip step")

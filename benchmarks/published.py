@@ -16,50 +16,136 @@ the key:
   key as the source spells it; ``published`` is the source's value,
   ``held`` the ``model`` group's, and the ``BENCHMARK.json`` entry's
   ``reduced`` lists the same keys;
-* depth may be cut freely, to whole periods of the layer pattern (the
-  file's ``layer_period``, 1 where it states none);
+* depth may be cut freely, to the leading dense layers (the ``model``
+  group's ``dense_layers``, which count once) and whole periods of the
+  layer pattern after them (the file's ``layer_period``, 1 where it
+  states none);
 * a count (experts, heads, rows of the vocabulary or of the position
   table) may be cut only in a file that states the ``deployment`` it is
-  one chip's share of: ``{"chips": n, "divided": "how"}``;
-* a width (hidden, head, MLP or expert width, experts per token) never;
-* a key no row knows is refused too: a ``benchmark`` PR adds the row.
+  one chip's share of: ``{"chips": n, "divided": "how"}``. Where the
+  experts are cut the ``model`` group's ``experts_held`` is what is
+  held, and its ``num_experts``, the router's width, stays as
+  published;
+* a cut keeps to the floors of the ``model-configs`` guide: at least
+  four layers after the leading dense ones in a model that has experts,
+  at least 8 experts held, at least an eighth of the vocabulary;
+* a width (hidden, head, latent, MLP or expert width, experts per
+  token) never, and neither what makes a mechanism what it is (how
+  many shared experts, leading dense layers and further prediction
+  heads, how the chosen experts' weights are scaled);
+* a key of the source that no row knows is refused, unless the file's
+  ``not_held`` lists it with a word on why it says nothing of the
+  shape: ``{"rope_theta": "a constant of the position code"}``. In the
+  top-level form every key of the file that FILE_KEYS does not name is
+  a key of the source. A ``benchmark`` PR adds a row.
 """
 
 from __future__ import annotations
 
+from typing import Callable, NamedTuple
+
 from benchmarks import flops
 
+# WIDTH is every key that is never cut: a width, and what makes a
+# mechanism what it is
 DEPTH, COUNT, WIDTH = "depth", "count", "width"
-
-# (the source's spellings, kind, the ``model`` group's value)
-ROWS = (
-    (("n_layer", "num_hidden_layers"), DEPTH,
-     lambda m: m["num_layers"]),
-    (("n_embd", "hidden_size"), WIDTH, lambda m: m["hidden_size"]),
-    (("n_head", "num_attention_heads"), COUNT, lambda m: m["num_heads"]),
-    (("num_key_value_heads",), COUNT, flops.kv_heads),
-    (("head_dim",), WIDTH, flops.head_dim),
-    # the width of one MLP, or of one expert where there are experts
-    (("n_inner", "intermediate_size"), WIDTH,
-     lambda m: m["hidden_size"] * m["mlp_ratio"]),
-    (("num_experts", "n_routed_experts", "num_local_experts"), COUNT,
-     lambda m: m.get("num_experts", 0)),
-    (("num_experts_per_tok",), WIDTH,
-     lambda m: m.get("experts_per_token", 0)),
-    (("vocab_size",), COUNT, lambda m: m["vocab_size"]),
-    (("n_positions", "max_position_embeddings"), COUNT,
-     lambda m: m["max_seq_len"]),
-)
-KNOWN = {key for keys, _, _ in ROWS for key in keys}
+NEVER_CUT = (
+    "is never cut: a width (hidden, head, latent, MLP or expert width, "
+    "experts per token) or what makes a mechanism what it is (shared "
+    "experts, leading dense layers, further prediction heads, the "
+    "scaling of the chosen experts' weights, a tied head)")
+# what a configuration file has of its own beside the source's keys
+FILE_KEYS = {"name", "source", "family", "note", "published", "model",
+             "tiny", "layer_period", "deployment", "reduced", "assumed",
+             "not_held", "departures"}
 REDUCED_KEYS = {"key", "published", "held", "why"}
+
+
+class Row(NamedTuple):
+    """One line of the mapping: the source's spellings, the kind, what
+    the ``model`` group holds of it; what of the ``model`` group equals
+    the published value even where the key is cut; and the least a cut
+    may hold, with the floor's name, given the published value and the
+    ``model`` group."""
+    keys: tuple
+    kind: str
+    held: Callable
+    as_published: Callable | None = None
+    floor: Callable | None = None
+
+
+ROWS = (
+    Row(("n_layer", "num_hidden_layers"), DEPTH,
+        lambda m: m["num_layers"],
+        floor=lambda pub, m: (
+            m.get("dense_layers", 0) + 4 if m.get("num_experts") else 1,
+            "four layers after the leading dense ones where there are "
+            "experts")),
+    Row(("n_embd", "hidden_size"), WIDTH, lambda m: m["hidden_size"]),
+    Row(("n_head", "num_attention_heads"), COUNT,
+        lambda m: m["num_heads"]),
+    Row(("num_key_value_heads",), COUNT, flops.kv_heads),
+    Row(("head_dim",), WIDTH, flops.head_dim),
+    # the width of a plain MLP, and of one expert where the source
+    # gives the experts no width of their own
+    Row(("n_inner", "intermediate_size"), WIDTH,
+        lambda m: m["hidden_size"] * m["mlp_ratio"]),
+    Row(("moe_intermediate_size",), WIDTH,
+        lambda m: m.get("expert_mlp_dim")),
+    # the experts held here; the router keeps the published width
+    Row(("num_experts", "n_routed_experts", "num_local_experts"), COUNT,
+        lambda m: m.get("experts_held", m.get("num_experts", 0)),
+        as_published=lambda m: m.get("num_experts", 0),
+        floor=lambda pub, m: (8, "8 experts held")),
+    Row(("num_experts_per_tok",), WIDTH,
+        lambda m: m.get("experts_per_token", 0)),
+    Row(("n_shared_experts",), WIDTH,
+        lambda m: m.get("shared_experts", 0)),
+    Row(("first_k_dense_replace",), WIDTH,
+        lambda m: m.get("dense_layers", 0)),
+    Row(("num_nextn_predict_layers",), WIDTH,
+        lambda m: m.get("mtp_layers", 0)),
+    Row(("routed_scaling_factor",), WIDTH,
+        lambda m: m.get("routed_scaling_factor")),
+    Row(("norm_topk_prob",), WIDTH, lambda m: m.get("norm_topk_prob")),
+    Row(("q_lora_rank",), WIDTH, lambda m: m.get("q_lora_rank") or 0),
+    Row(("kv_lora_rank",), WIDTH, lambda m: m.get("kv_lora_rank")),
+    Row(("qk_nope_head_dim",), WIDTH,
+        lambda m: m.get("qk_nope_head_dim")),
+    Row(("qk_rope_head_dim",), WIDTH,
+        lambda m: m.get("qk_rope_head_dim")),
+    Row(("v_head_dim",), WIDTH, lambda m: m.get("v_head_dim")),
+    Row(("vocab_size",), COUNT, lambda m: m["vocab_size"],
+        floor=lambda pub, m: (-(-pub // 8),
+                              "an eighth of the vocabulary")),
+    Row(("n_positions", "n_ctx", "max_position_embeddings"), COUNT,
+        lambda m: m["max_seq_len"]),
+    # an untied head is a second [V, h] matrix
+    Row(("tie_word_embeddings",), WIDTH, lambda m: m["tie_embeddings"]),
+)
+KNOWN = {key for row in ROWS for key in row.keys}
+# what a source means by null, where it means something
+NULL_MEANS = {
+    # GPT-2's config.json: four times the width
+    "n_inner": lambda source: 4 * source["n_embd"],
+    # a latent-attention config.json: the query is full rank
+    "q_lora_rank": lambda source: 0,
+}
+
+
+def source_of(body: dict) -> dict:
+    """What a file says was published: its ``published`` group, or its
+    top-level keys but for the file's own."""
+    if "published" in body:
+        return body["published"]
+    return {k: v for k, v in body.items() if k not in FILE_KEYS}
 
 
 def _source_value(key: str, source: dict):
     """What the source says of ``key``; None where it says nothing."""
     value = source.get(key)
-    if value is None and key == "n_inner" and "n_inner" in source:
-        # GPT-2's config.json: null means four times the width
-        return 4 * source["n_embd"]
+    if value is None and key in source and key in NULL_MEANS:
+        return NULL_MEANS[key](source)
     return value
 
 
@@ -85,25 +171,30 @@ def check(entry: dict, body: dict) -> None:
                f"BENCHMARK.json's {sorted(entry['reduced'])}")
 
     grouped = "published" in body
-    source = body["published"] if grouped else body
-    model = body["model"]
-    for keys, kind, held_by in ROWS:
-        for key in keys:
+    source, model = source_of(body), body["model"]
+    for row in ROWS:
+        for key in row.keys:
             value = _source_value(key, source)
             if value is None:
                 continue
-            held = held_by(model)
+            held = row.held(model)
             cut = cuts.get(key)
+            published = value if cut is None or grouped \
+                else cut["published"]
+            if row.as_published and row.as_published(model) != published:
+                refuse(key, f"published {published!r} and never cut "
+                       f"itself (the router keeps its width where the "
+                       f"experts held are cut); the model group holds "
+                       f"{row.as_published(model)!r}")
             if cut is None:
                 if held != value:
                     refuse(key, f"published {value!r}, the model group "
                            f"holds {held!r}, and `reduced` does not "
                            f"name it")
                 continue
-            if kind == WIDTH:
-                refuse(key, "a width is never cut (hidden, head, MLP "
-                       "or expert width, experts per token)")
-            if kind == COUNT and not _deployment(body):
+            if row.kind == WIDTH:
+                refuse(key, NEVER_CUT)
+            if row.kind == COUNT and not _deployment(body):
                 refuse(key, "a count (experts, heads, rows) is cut only "
                        "in a file that states its `deployment`: "
                        '{"chips": n, "divided": "how"}')
@@ -117,14 +208,32 @@ def check(entry: dict, body: dict) -> None:
                 refuse(key, f"held {cut['held']!r} is no cut of "
                        f"{cut['published']!r}")
             period = body.get("layer_period", 1)
-            if kind == DEPTH and cut["held"] % period:
-                refuse(key, f"depth {cut['held']} is not whole periods "
-                       f"of the layer pattern (`layer_period` {period})")
+            after = cut["held"] - model.get("dense_layers", 0)
+            if row.kind == DEPTH and (after < period or after % period):
+                refuse(key, f"depth {cut['held']} is not the "
+                       f"{model.get('dense_layers', 0)} leading dense "
+                       f"layer(s) and whole periods of the layer pattern "
+                       f"(`layer_period` {period})")
+            least, floor = row.floor(cut["published"], model) \
+                if row.floor else (0, "")
+            if cut["held"] < least:
+                refuse(key, f"held {cut['held']} is under the floor of "
+                       f"a cut, {least}: {floor}")
     for key in sorted(set(cuts) - KNOWN):
         refuse(key, "`reduced` names a key that no row of "
                "benchmarks/published.py maps to the model group")
     for key in sorted(set(cuts) - set(source)):
         refuse(key, "`reduced` names a key the source does not have")
+    not_held = body.get("not_held", {})
+    for key in sorted(set(source) - KNOWN - set(not_held)):
+        refuse(key, "no row of benchmarks/published.py maps this key "
+               "of the source to the model group: list it in the "
+               "file's `not_held` with why it says nothing of the "
+               "shape, or a `benchmark` PR adds the row")
+    for key in sorted(not_held):
+        if key in KNOWN or key not in source or not not_held[key]:
+            refuse(key, "`not_held` lists, each with its reason, keys "
+                   "the source has and no row knows")
 
 
 def _deployment(body: dict) -> bool:

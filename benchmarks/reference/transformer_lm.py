@@ -39,7 +39,33 @@ configuration's ``family`` and knows no family's name):
   from the seed, and ``batch`` the job's (``(tokens,)`` for
   ``causal_lm``, ``(tokens, labels, mask)`` for ``masked_lm``);
 * float32 throughout under ``jax.default_matmul_precision("highest")``,
-  and nothing imported from the program.
+  and nothing imported from the program;
+* a model that makes discrete choices (a router's k experts of e for a
+  token) cannot be compared through them: where two scores are closer
+  than bfloat16 rounds, float32 picks another expert, and the gradients
+  then differ by a whole expert's contribution, which says nothing of
+  the arithmetic. Its modules sow each choice into the Flax collection
+  ``choices`` (integer arrays ``[..., k]``, the last axis one token's k
+  choices), and its reference module states ``TAKES_CHOICES = True``
+  and has besides: ``choice_scores(params, batch, **arguments)``, the
+  float32 scores ``[..., e]`` (finite, k < e) whose top k the
+  reference would choose itself, as ``{path: array}`` under the names
+  the job gives the program's (``dp_train.named_choices``: the modules'
+  names, the name sown under and ``0``, joined by ``/``); and
+  ``mean_loss(..., choices=the system's, by those names)``, which takes
+  the choices as given and computes everything else, the chosen
+  experts' weights too, itself. The reference check reads the system's
+  choices from the pass whose gradient it compares, and compares loss
+  and gradient at them under the limits of every family. The share of
+  tokens at which the top k of ``choice_scores`` is the system's set is
+  held to a floor that the job derives from those scores
+  (``dp_train.NEAR_TIE``) and the module does not state. A module
+  without the flag is called as before, and ``nll_sum`` is always
+  called without choices;
+* the limits on the loss and the gradient are the job's, for every
+  family: a module states none, and one that a family's readings on the
+  chip show to be wrong is changed by a ``benchmark`` PR, with those
+  readings in ``PERF.md``.
 """
 
 from __future__ import annotations

@@ -12,9 +12,11 @@ per per-layer metric in ``benchmarks/layer_metrics/``. The data files
 (not the jobs or the readers) are looked for under ``--root``, which is
 the checkout unless a test points it at its fixture.
 The last line of stdout is the result: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``. With
-``--trace 0`` the metrics are the cell's end-to-end metrics, with
-``--trace 1`` its per-layer metrics. Without a TPU it exits non-zero.
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+``compared``: each number ``correct`` compared, beside its limit (the
+last lines of stderr say the same). With ``--trace 0`` the metrics are
+the cell's end-to-end metrics, with ``--trace 1`` its per-layer
+metrics. Without a TPU it exits non-zero.
 
 ``--rehearse`` runs the job at the ``tiny`` presets of the
 configuration and the traffic on whatever platform JAX finds (Pallas
@@ -31,6 +33,7 @@ STARTED = time.perf_counter()  # set-up counts from here
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -197,6 +200,15 @@ def main(argv=None) -> int:
     }
     if breakdown is not None:
         line["breakdown"] = breakdown
+    # a number that is not finite would not be JSON: it goes as text
+    line["compared"] = {
+        name: {"value": value if math.isfinite(value) else repr(value),
+               "limit": limit, "ok": run.checks[name]}
+        for name, (value, limit) in run.compared.items()}
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {c['value']!r} limit {c['limit']!r} "
+              f"{'ok' if c['ok'] else 'FAILED'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

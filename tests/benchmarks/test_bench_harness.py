@@ -23,6 +23,11 @@ BENCH = harness.load_json(ROOT, "BENCHMARK.json")
 # has to take as data. No file under benchmarks/ knows a name of it
 FIXTURE = os.path.join(ROOT, "tests", "benchmarks", "data", "fixture")
 FIXTURE_BENCH = harness.load_json(FIXTURE, "BENCHMARK.json")
+# an entry and a file's body (no cell): the public keys of a
+# latent-attention, shared-expert model with a leading dense layer and
+# a second prediction head, cut to one chip's share of eight
+SHARE = harness.load_json(ROOT, "tests", "benchmarks", "data",
+                          "latent_shared_expert_share.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter",
@@ -151,6 +156,14 @@ def _fixture_variant(change):
     return entry, body
 
 
+def _share_variant(change):
+    """The share's entry and body after ``change(entry, body)``."""
+    entry, body = (json.loads(json.dumps(SHARE[k]))
+                   for k in ("entry", "body"))
+    change(entry, body)
+    return entry, body
+
+
 def _depth_differs(entry, body):
     body["model"]["num_layers"] = 1
 
@@ -189,20 +202,154 @@ def _unknown_key(entry, body):
     entry["reduced"].append("sliding_window")
 
 
-@pytest.mark.parametrize("change,key,why", [
+def _recut(body, key, held):
+    """``key`` of the share's body and its `reduced` entry hold
+    ``held``."""
+    body[key] = held
+    next(c for c in body["reduced"] if c["key"] == key)["held"] = held
+
+
+def _latent_width_changed(entry, body):
+    body["model"]["kv_lora_rank"] = 256
+
+
+def _head_width_listed(entry, body):
+    body["v_head_dim"] = body["model"]["v_head_dim"] = 128
+    body["reduced"].append(_cut("v_head_dim", 256, 128))
+    entry["reduced"].append("v_head_dim")
+
+
+def _expert_width_changed(entry, body):
+    body["model"]["expert_mlp_dim"] = 768
+
+
+def _router_cut_with_the_experts(entry, body):
+    body["model"]["num_experts"] = 8
+
+
+def _seven_experts(entry, body):
+    _recut(body, "n_routed_experts", 7)
+    body["model"]["experts_held"] = 7
+
+
+def _vocabulary_under_an_eighth(entry, body):
+    _recut(body, "vocab_size", 19359)
+    body["model"]["vocab_size"] = 19359
+
+
+def _three_layers_after_the_dense_one(entry, body):
+    _recut(body, "num_hidden_layers", 4)
+    body["model"]["num_layers"] = 4
+
+
+def _depth_splits_a_period(entry, body):
+    body["layer_period"] = 3  # 1 dense + 4 is one period and a third
+
+
+def _dense_layer_left_out(entry, body):
+    body["model"]["dense_layers"] = 0
+
+
+def _shared_expert_listed(entry, body):
+    body["n_shared_experts"] = body["model"]["shared_experts"] = 0
+    body["reduced"].append(_cut("n_shared_experts", 1, 0))
+    entry["reduced"].append("n_shared_experts")
+
+
+def _second_head_left_out(entry, body):
+    del body["model"]["mtp_layers"]
+
+
+def _unknown_key_not_listed(entry, body):
+    body["sliding_window"] = 4096
+
+
+def _known_key_listed_as_not_held(entry, body):
+    body["not_held"]["kv_lora_rank"] = "not a size"
+
+
+def _not_held_without_a_reason(entry, body):
+    body["not_held"]["rope_theta"] = ""
+
+
+NEVER = "is never cut"
+REFUSALS = [(_fixture_variant, *case) for case in [
     (_depth_differs, "num_hidden_layers", "does not name it|model group"),
-    (_width_listed, "hidden_size", "a width is never cut"),
-    (_experts_per_token_listed, "num_experts_per_tok",
-     "a width is never cut"),
+    (_width_listed, "hidden_size", NEVER),
+    (_experts_per_token_listed, "num_experts_per_tok", NEVER),
     (_count_without_deployment, "vocab_size", "deployment"),
     (_entry_and_file_disagree, "num_hidden_layers", "BENCHMARK.json"),
     (_half_a_period, "num_hidden_layers", "layer_period"),
     (_unknown_key, "sliding_window", "no row"),
-], ids=lambda x: getattr(x, "__name__", None))
+]] + [(_share_variant, *case) for case in [
+    (_latent_width_changed, "kv_lora_rank", "published 512"),
+    (_head_width_listed, "v_head_dim", NEVER),
+    (_expert_width_changed, "moe_intermediate_size", "published 1536"),
+    (_router_cut_with_the_experts, "n_routed_experts",
+     "published 64.*router"),
+    (_seven_experts, "n_routed_experts", "floor.*8 experts"),
+    (_vocabulary_under_an_eighth, "vocab_size", "floor.*19360"),
+    (_three_layers_after_the_dense_one, "num_hidden_layers",
+     "floor.*four layers"),
+    (_depth_splits_a_period, "num_hidden_layers", "layer_period"),
+    (_dense_layer_left_out, "first_k_dense_replace", "published 1"),
+    (_shared_expert_listed, "n_shared_experts", NEVER),
+    (_second_head_left_out, "num_nextn_predict_layers", "published 1"),
+    (_unknown_key_not_listed, "sliding_window", "not_held"),
+    (_known_key_listed_as_not_held, "kv_lora_rank", "no row knows"),
+    (_not_held_without_a_reason, "rope_theta", "reason"),
+]]
+
+
+@pytest.mark.parametrize(
+    "variant,change,key,why", REFUSALS,
+    ids=[change.__name__ for _, change, _, _ in REFUSALS])
 def test_a_cut_that_is_not_written_down_or_not_allowed_is_refused(
-        change, key, why):
+        variant, change, key, why):
     with pytest.raises(ValueError, match=f"key '{key}'.*({why})"):
-        published.check(*_fixture_variant(change))
+        published.check(*variant(change))
+
+
+def test_one_chips_share_of_a_latent_shared_expert_model_is_held():
+    """The share's body passes as it is: depth cut to the dense layer
+    and four expert layers, 8 of 64 experts held under a router of 64,
+    an eighth of the vocabulary, every width and every mechanism's key
+    as published, every other key of the source under ``not_held``.
+    It is a body and an entry, no cell: no BENCHMARK.json names it."""
+    entry, body = _share_variant(lambda entry, body: None)
+    published.check(entry, body)
+    model = body["model"]
+    assert (model["num_experts"], model["experts_held"]) == (64, 8)
+    assert [c["key"] for c in body["reduced"]] == entry["reduced"] == [
+        "num_hidden_layers", "n_routed_experts", "vocab_size"]
+    source = set(published.source_of(body))
+    assert source == (source & published.KNOWN) | set(body["not_held"])
+    for bench in (BENCH, FIXTURE_BENCH):
+        assert entry["name"] not in json.dumps(bench)
+    # the same file in its other form, a `published` group, where a cut
+    # key holds the published value: held the same way
+    grouped = {k: v for k, v in body.items() if k not in source}
+    grouped["published"] = {
+        **{k: body[k] for k in source},
+        **{c["key"]: c["published"] for c in body["reduced"]}}
+    published.check(entry, grouped)
+    grouped["model"]["num_experts"] = 8
+    with pytest.raises(ValueError, match="key 'n_routed_experts'"):
+        published.check(entry, grouped)
+
+
+@pytest.mark.parametrize("root,config", ROOTED_CONFIGS)
+def test_every_key_of_a_source_is_held_or_listed(root, config):
+    """No key of a source is passed in silence: a row of
+    ``published.ROWS`` holds it, or the file's ``not_held`` says why it
+    says nothing of the shape."""
+    body = harness.load_json(root, config["file"])
+    source = published.source_of(body)
+    assert set(source) - published.KNOWN == set(body["not_held"])
+    assert all(body["not_held"].values())
+    del body["not_held"]
+    with pytest.raises(ValueError, match="not_held"):
+        published.check(config, body)
 
 
 def test_a_count_is_cut_in_a_file_that_states_its_deployment():
@@ -337,7 +484,19 @@ def test_rehearsal_runs_end_to_end(root, cell, devices, traced):
     assert done.returncode == 0, done.stderr[-4000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+                         "device", "compared"}
+    # each number `correct` compared beside its limit, last in the
+    # line and as the last lines of stderr
+    assert list(line)[-1] == "compared"
+    compared = line["compared"]
+    assert {"reference_loss", "reference_gradient", "global_batch_loss",
+            "loss_falls", "no_compile_in_window"} <= set(compared)
+    assert compared["reference_gradient"]["limit"] == 3e-2
+    assert all(set(c) == {"value", "limit", "ok"} and c["ok"]
+               for c in compared.values())
+    said = done.stderr.strip().splitlines()[-len(compared):]
+    assert [s.split(":")[0] for s in said] == [
+        f"compared {name}" for name in compared]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 17
     assert line["device"] == {"platform": "cpu", "kind": "cpu",

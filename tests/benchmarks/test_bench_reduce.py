@@ -3,6 +3,7 @@ arithmetic, on hand-made inputs with known answers."""
 
 import json
 import os
+import re
 import sys
 
 import pytest
@@ -343,10 +344,10 @@ def test_bert_large_operations_per_token(mix, attention, total):
             total, rel=1e-4)
 
 
-# the shapes to come, as dictionaries of the keys flops.py reads: one
-# OLMoE-1B-7B layer (64 experts of width 1024, 8 a token, SwiGLU, 16
-# heads of 128, V=50,304 untied, seq 4096) and a grouped-query block
-OLMOE_LAYER = {
+# shapes as dictionaries of the keys flops.py reads: one whole expert
+# layer (all 64 experts of width 1024 on this chip, 8 a token, SwiGLU,
+# 16 heads of 128, V=50,304 untied, seq 4096) and a grouped-query block
+EXPERT_LAYER = {
     "hidden_size": 2048, "num_heads": 16, "mlp_ratio": 0.5,
     "activation": "swiglu", "num_experts": 64, "experts_per_token": 8,
     "vocab_size": 50304, "causal": True, "num_layers": 1}
@@ -363,19 +364,19 @@ SEQ_4096 = {"objective": "causal_lm", "seq_len": 4096,
     # 2048 x 1024 matrices 50,331,648; router 2048 * 64 = 131,072;
     # twice their sum. Attention 4 * 4096 * 2048 / 2. Head
     # 2 * 2048 * 50304 * 4095/4096
-    (OLMOE_LAYER, 134_479_872, 16_777_216, 205_994_880),
+    (EXPERT_LAYER, 134_479_872, 16_777_216, 205_994_880),
     # q and out 2 * 4096^2, k and v 2 * 4096 * 8 * 128 (a quarter);
     # three 4096 x 14336 matrices; head 2 * 4096 * 32000 * 4095/4096
     (GQA_LAYER, 2 * (33_554_432 + 8_388_608 + 176_160_768), 33_554_432,
      262_080_000),
     # a dense MLP where experts_per_token is 0, and a head width that
     # is stated and is not hidden / heads
-    ({**OLMOE_LAYER, "num_experts": 0, "experts_per_token": 0},
+    ({**EXPERT_LAYER, "num_experts": 0, "experts_per_token": 0},
      2 * (16_777_216 + 6_291_456), 16_777_216, 205_994_880),
     ({**GQA_LAYER, "head_dim": 64},
      2 * (16_777_216 + 4_194_304 + 176_160_768), 16_777_216,
      262_080_000),
-], ids=["olmoe_layer", "gqa_layer", "olmoe_dense", "stated_head_dim"])
+], ids=["expert_layer", "gqa_layer", "expert_layer_made_dense", "stated_head_dim"])
 def test_operations_per_token_of_the_shapes_to_come(model, blocks,
                                                     attention, head):
     f = flops.forward_flops_per_token(model, SEQ_4096)
@@ -385,11 +386,11 @@ def test_operations_per_token_of_the_shapes_to_come(model, blocks,
         blocks + attention + head)
 
 
-def test_olmoe_layer_counts_the_active_experts_alone():
-    assert flops.train_flops_per_token(OLMOE_LAYER, SEQ_4096) == \
+def test_expert_layer_counts_the_active_experts_alone():
+    assert flops.train_flops_per_token(EXPERT_LAYER, SEQ_4096) == \
         1_071_755_904
     assert sum(flops.forward_flops_per_token(
-        OLMOE_LAYER, SEQ_4096).values()) == 357_251_968
+        EXPERT_LAYER, SEQ_4096).values()) == 357_251_968
     # the count before PR 26 (two MLP matrices, one MLP a token, no
     # router) said 264,715,136: 26% under
     old = 2 * (4 * 2048 * 2048 + 2 * 2048 * 1024) + 16_777_216 \
@@ -398,8 +399,152 @@ def test_olmoe_layer_counts_the_active_experts_alone():
     # and the experts the layer holds but does not send a token to are
     # not required work
     held = flops.forward_flops_per_token(
-        {**OLMOE_LAYER, "experts_per_token": 64}, SEQ_4096)["blocks"]
+        {**EXPERT_LAYER, "experts_per_token": 64}, SEQ_4096)["blocks"]
     assert held == 2 * (16_777_216 + 64 * 6_291_456 + 131_072)
+
+
+# one chip's share of eight of a latent-attention, shared-expert model
+# (the body the harness's tests hold to its source), and the same keys
+# uncut: all 47 layers, all 64 experts, the whole vocabulary
+SHARE = harness.load_json(
+    harness.ROOT, "tests", "benchmarks", "data",
+    "latent_shared_expert_share.json")["body"]["model"]
+WHOLE = {**{k: v for k, v in SHARE.items() if k != "experts_held"},
+         "num_layers": 47, "vocab_size": 154880}
+
+
+def test_operations_per_token_of_one_chips_share_by_part():
+    # by hand, h=2048, 20 heads, T=4096, multiply-adds a token:
+    #   latent projections a layer
+    #     query        2048*768 + 768*20*(192+64) = 1,572,864 + 3,932,160
+    #     latent + rope key   2048*(512+64)       = 1,179,648
+    #     keys' and values' expansion 512*20*(192+256) = 4,587,520
+    #     out          20*256*2048                = 10,485,760
+    #                                        sum  = 21,757,952
+    #   dense MLP, three matrices 3*2048*10240    = 62,914,560
+    #   expert layer: the shared expert 3*2048*1536 = 9,437,184, half a
+    #     routed expert (4 a token * 8 held / 64) 4,718,592, the router
+    #     at its 64 outputs 131,072               = 14,286,848
+    #   blocks 2 * (5*21,757,952 + 62,914,560 + 4*14,286,848)
+    #   attention a layer: scores over 256, values over 256, halved by
+    #     the mask: 2*4096*20*(256+256)/2 = 41,943,040; five layers
+    #   head 2*2048*19360 = 79,298,560, at 4095 of 4096 positions
+    #   the second head: one more expert layer's block 2*(21,757,952 +
+    #     14,286,848) = 72,089,600, the 4096 -> 2048 product
+    #     2*4096*2048 = 16,777,216, its attention 41,943,040, and the
+    #     head at 4094 of 4096 positions 79,298,560 - 38,720
+    f = flops.forward_flops_per_token(SHARE, SEQ_4096)
+    assert f == {"blocks": 457_703_424, "attention": 209_715_200,
+                 "head": 79_279_200, "mtp": 210_069_696}
+    assert sum(f.values()) == 956_767_520
+    assert flops.train_flops_per_token(SHARE, SEQ_4096) == 2_870_302_560
+    # what the count before this PR said of the same share, on the only
+    # model group published.py then let the file have: four experts a
+    # token at the dense layer's width, all on this chip, four full
+    # 2048 x 5120 projections, no shared expert, no dense layer, no
+    # second head: 3.37 times the work
+    old = (2 * 5 * (4 * 2048 * 5120 + 4 * 3 * 2048 * 10240 + 2048 * 8)
+           + 5 * 4 * 4096 * 20 * 256 / 2 + 79_279_200)
+    assert old == 3_225_171_040 and 3.37 < old / 956_767_520 < 3.38
+
+
+def test_operations_per_token_of_the_same_model_uncut():
+    # by hand, as above but for the layers and what they hold:
+    #   an expert layer with all 64 held: shared 9,437,184 + four
+    #     routed 37,748,736 + router 131,072      = 47,316,992
+    #   blocks 2 * (47*21,757,952 + 62,914,560 + 46*47,316,992)
+    #        = 2 * (1,022,623,744 + 62,914,560 + 2,176,581,632)
+    #   attention 47 * 41,943,040
+    #   head 2*2048*154880 = 634,388,480, less 154,880 (1 of 4096)
+    #   second head 2*(21,757,952 + 47,316,992) = 138,149,888, plus
+    #     16,777,216, plus 41,943,040, plus 634,388,480 - 309,760
+    f = flops.forward_flops_per_token(WHOLE, SEQ_4096)
+    assert f == {"blocks": 6_524_239_872, "attention": 1_971_322_880,
+                 "head": 634_233_600, "mtp": 830_948_864}
+    assert sum(f.values()) == 9_960_745_216
+    # the share holds an eighth of the experts and of the vocabulary
+    # and 5 of 47 layers, yet 9.6% of the operations: attention, the
+    # shared expert and the dense layer are not divided
+    assert 0.096 < 956_767_520 / sum(f.values()) < 0.0961
+
+
+def test_a_full_rank_query_and_plain_heads_of_two_widths():
+    # a latent-attention model whose query is full rank (q_lora_rank
+    # null): 2048*20*256 in place of the two latent products
+    full = flops.projection_macs({**SHARE, "q_lora_rank": None})
+    assert full == 21_757_952 - 5_505_024 + 2048 * 20 * 256
+    # plain projections at unequal widths: q and k at 192, v and out at
+    # 128, 8 key and value heads for 32
+    plain = {"hidden_size": 4096, "num_heads": 32, "num_kv_heads": 8,
+             "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+             "v_head_dim": 128}
+    assert flops.projection_macs(plain) == 4096 * (
+        32 * 192 + 8 * 192 + 8 * 128 + 32 * 128)
+    # no head width stated and none to be had: refused, not rounded
+    with pytest.raises(ValueError, match="no whole head width"):
+        flops.head_dim({"hidden_size": 2048, "num_heads": 20})
+
+
+class Recording(dict):
+    """A model group that notes which of its keys are asked for."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.asked = set()
+
+    def __getitem__(self, key):
+        self.asked.add(key)
+        return super().__getitem__(key)
+
+    def get(self, key, default=None):
+        self.asked.add(key)
+        return super().get(key, default)
+
+    def __contains__(self, key):
+        self.asked.add(key)
+        return super().__contains__(key)
+
+
+def test_flops_docstring_lists_every_key_it_reads():
+    """The next key is not read in silence either: what the module's
+    docstring lists is what its functions ask a model group for, over
+    the kinds of model there are."""
+    listed = set(re.findall(
+        r"``(\w+)``", flops.__doc__.split("absence means")[1]))
+    listed -= {"swiglu"}  # a value of ``activation``, not a key
+    asked = set()
+    for model in (SHARE, {**SHARE, "q_lora_rank": None}, EXPERT_LAYER,
+                  GQA_LAYER, {**GQA_LAYER, "head_dim": 64},
+                  sizes("gpt2-medium"), sizes("bert-large")):
+        model = Recording(model)
+        flops.train_flops_per_token(model, SEQ_4096)
+        flops.attention_kernel_work(model, SEQ_4096)
+        asked |= model.asked
+    assert asked == listed, (asked - listed, listed - asked)
+    # and the share's file states every one of them but the plain head
+    # width its latent attention has no use for
+    assert listed - set(SHARE) == {"head_dim"}
+
+
+def test_attention_kernel_work_of_two_head_widths_and_a_second_head():
+    # the share at batch 4, T=4096: six layers' attention (five and the
+    # second head's), three products over the query-and-key width 256
+    # and three over the value width 256, halved by the mask; of the
+    # twelve arrays q, dq, k, dk at 20 heads of 256 and o, do, v, dv at
+    # 20 heads of 256
+    w = flops.attention_kernel_work(SHARE, SEQ_4096)
+    assert w["flops"] == 6 * 3 * 2 * 4 * 20 * 4096 * 4096 * (256 + 256) / 2
+    assert w["bytes"] == 6 * 3 * (20 + 20) * 4 * 4096 * (256 + 256) * 2
+    # the two widths apart: 192 + 64 for q and k, 128 for v and o
+    narrow = {**SHARE, "v_head_dim": 128, "mtp_layers": 0}
+    w = flops.attention_kernel_work(narrow, SEQ_4096)
+    assert w["flops"] == 5 * 2 * 4 * 20 * 4096 * 4096 * (
+        3 * 256 + 3 * 128) / 2
+    assert w["bytes"] == 5 * 4 * 4096 * 2 * (
+        3 * 20 * 256 + 3 * 20 * 256 + 3 * 20 * 128 + 3 * 20 * 128)
+    f = flops.forward_flops_per_token(narrow, SEQ_4096)
+    assert f["attention"] == 5 * 2 * 4096 * 20 * (256 + 128) / 2
+    assert "mtp" not in f
 
 
 def test_attention_kernel_work_of_grouped_query_heads():
@@ -411,7 +556,7 @@ def test_attention_kernel_work_of_grouped_query_heads():
     assert w["flops"] == 6 * 2 * 4 * 32 * 4096 * 4096 * 128 / 2
     assert w["bytes"] == 6 * (32 + 8) * 4 * 4096 * 128 * 2
     # heads of 128 at hidden 2048: the stated head width is what counts
-    w = flops.attention_kernel_work(OLMOE_LAYER, SEQ_4096)
+    w = flops.attention_kernel_work(EXPERT_LAYER, SEQ_4096)
     assert w["flops"] == 6 * 2 * 4 * 16 * 4096 * 4096 * 128 / 2
     assert w["bytes"] == 12 * 4 * 16 * 4096 * 128 * 2
 

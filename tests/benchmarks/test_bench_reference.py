@@ -106,6 +106,8 @@ def test_a_term_a_module_sows_is_part_of_the_loss_the_job_trains_on():
             for weight in (0.25, 0.5):
                 self.sow(dp_train.AUX_LOSSES, f"term_{weight}",
                          weight * jnp.mean(jnp.square(hidden)))
+            self.sow(dp_train.CHOICES, "experts",
+                     jnp.argsort(hidden[..., :4], -1)[..., :2])
             return inner(tokens, return_hidden=return_hidden)
 
     for head in ("dense", "fused_ce"):
@@ -139,6 +141,13 @@ def test_a_term_a_module_sows_is_part_of_the_loss_the_job_trains_on():
         for a, b in zip(jax.tree_util.tree_leaves(got_g),
                         jax.tree_util.tree_leaves(want_g)):
             assert jnp.allclose(a, b, rtol=1e-4, atol=1e-7)
+        # the reference check's variant: the same loss, and beside it
+        # what that pass sowed into CHOICES, which the step's never sees
+        (loss, chosen), _ = jax.value_and_grad(dp_train.make_loss_fn(
+            Inner, traffic, with_choices=True), has_aux=True)(params, tok)
+        assert float(loss) == float(got)
+        assert list(chosen) == ["experts/0"]
+        assert chosen["experts/0"].shape == (*tok.shape, 2)
 
 
 def test_reference_takes_the_global_batch_in_blocks_of_its_own_size():
@@ -209,3 +218,238 @@ def test_reference_causal_mask_hides_the_future():
                 for t in (tok, other))
         assert np.array_equal(np.asarray(a[0, :-1]),
                               np.asarray(b[0, :-1])) is same
+
+
+# -- a model that chooses, without the program ------------------------------
+#
+# A stub system and a stub reference: embedding, one layer in which a
+# gate picks the two of four expert matrices a token goes through, a
+# head. The system computes in float32 but for the gate's scores, which
+# it rounds to bfloat16 as a bf16 model's router sees them.
+
+STUB_V, STUB_H, STUB_T, STUB_E, STUB_K = 32, 64, 64, 4, 2
+STUB_SIZES = {"vocab_size": STUB_V}
+STUB_TRAFFIC = {"objective": "causal_lm", "seq_len": STUB_T}
+RIGGED_IDS = (0, 1, 2)  # tokens whose second and third score all but tie
+# the rigged tokens' scores: expert 2 leads, and expert 1 is ahead of
+# expert 0 by less than bfloat16 keeps at 1.0, so the rounded scores tie
+# and ``top_k`` gives the lower index; ``apart`` puts them clearly apart
+TIED_SCORES = (1.0, 1.0 + 2.0 ** -10, 2.0, 0.0)
+APART_SCORES = (1.0, 1.25, 2.0, 0.0)
+
+
+def stub_model(expert_dtype=None, router_fault=False):
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    class Gated(nn.Module):
+        @nn.compact
+        def __call__(self, tokens):
+            x = nn.Embed(STUB_V, STUB_H, name="tok_emb")(tokens)
+            router = self.param(
+                "router", nn.initializers.normal(1.0), (STUB_H, STUB_E))
+            experts = self.param(
+                "experts", nn.initializers.normal(STUB_H ** -0.5),
+                (STUB_E, STUB_H, STUB_H))
+            scores = (x @ router).astype(jnp.bfloat16)
+            if router_fault:  # a router at fault: the rigged ids' second
+                # and third expert change places whatever their scores
+                scores = jnp.where((tokens < len(RIGGED_IDS))[..., None],
+                                   scores[..., jnp.array([1, 0, 2, 3])],
+                                   scores)
+            pick = jax.lax.top_k(scores, STUB_K)[1]
+            self.sow(dp_train.CHOICES, "experts", pick)
+            w = experts[pick]
+            if expert_dtype is not None:  # the product's inputs, lower
+                x_in, w = (a.astype(expert_dtype).astype(jnp.float32)
+                           for a in (x, w))
+            else:
+                x_in = x
+            x = x + jnp.tanh(jnp.einsum("bth,btehk->btk", x_in, w))
+            return nn.Dense(STUB_V, name="lm_head")(x)
+
+    return Gated()
+
+
+def stub_nll(logits, tokens):
+    import jax
+    import jax.numpy as jnp
+    lse = jax.scipy.special.logsumexp(logits[:, :-1], -1)
+    return jnp.mean(lse - jnp.take_along_axis(
+        logits[:, :-1], tokens[:, 1:, None], -1)[..., 0])
+
+
+class StubReference:
+    """The stub's plain reference, all float32. ``calls`` notes how the
+    job called it."""
+
+    __name__ = "stub_reference"
+
+    def __init__(self, takes_choices, scores_name="experts/0"):
+        self.calls = []
+        self.scores_name = scores_name
+        if takes_choices:
+            self.TAKES_CHOICES = True
+            self.choice_scores = self._choice_scores
+
+    @staticmethod
+    def arguments(model, traffic):
+        return {"objective": traffic["objective"]}
+
+    @staticmethod
+    def _forward(p, tokens, choices=None):
+        import jax
+        import jax.numpy as jnp
+        x = p["tok_emb"]["embedding"][tokens]
+        scores = jnp.dot(x, p["router"], precision="highest")
+        pick = jax.lax.top_k(scores, STUB_K)[1]
+        if choices is not None:
+            pick = choices["experts/0"]
+        x = x + jnp.tanh(jnp.einsum(
+            "bth,btehk->btk", x, p["experts"][pick], precision="highest"))
+        logits = jnp.dot(x, p["lm_head"]["kernel"], precision="highest")
+        return logits + p["lm_head"]["bias"], scores
+
+    def _choice_scores(self, p, batch, **kw):
+        self.calls.append(("choice_scores", sorted(kw)))
+        return {self.scores_name: self._forward(p, batch[0])[1]}
+
+    def mean_loss(self, p, batch, **kw):
+        self.calls.append(("mean_loss", sorted(kw)))
+        return stub_nll(self._forward(p, batch[0], kw.get("choices"))[0],
+                        batch[0])
+
+
+def stub_check(reference, *, rigged=TIED_SCORES, seed=11, **model_kw):
+    """``dp_train.reference_check`` of the stub system against
+    ``reference``: the checks' outcomes, and what was compared."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    model = stub_model(**model_kw)
+    tokens = jnp.asarray(dp_train.make_batch(
+        STUB_SIZES, STUB_TRAFFIC, 2, seed + 1)[0])
+    params = model.init(jax.random.PRNGKey(seed), tokens)["params"]
+    # rig a few token ids: the embedding of least norm whose scores are
+    # the ones wanted
+    emb = params["tok_emb"]["embedding"]
+    wanted = jnp.linalg.pinv(params["router"].T) @ jnp.asarray(rigged)
+    for i in RIGGED_IDS:
+        emb = emb.at[i].set(wanted)
+    params = {**params, "tok_emb": {"embedding": emb}}
+    assert set(RIGGED_IDS) <= set(map(int, tokens.ravel()))
+
+    def loss_fn(p, tok):
+        logits, sown = model.apply(
+            {"params": p}, tok, mutable=[dp_train.CHOICES])
+        return stub_nll(logits, tok), dp_train.named_choices(sown)
+
+    takes_choices = getattr(reference, "TAKES_CHOICES", False)
+    run = harness.Run(
+        started=time.perf_counter(), workload="stub", chips=1,
+        traffic=STUB_TRAFFIC, model_sizes=STUB_SIZES, seed=seed,
+        seconds=0, trace=False, rehearse=True)
+    dp_train.reference_check(
+        run, reference,
+        loss_fn if takes_choices else lambda p, t: loss_fn(p, t)[0],
+        params, STUB_SIZES, STUB_TRAFFIC)
+    return run.checks, run.compared
+
+
+def test_a_flipped_choice_fails_the_free_comparison_and_not_the_imposed():
+    """Where the system's rounded scores tie and float32's do not, a
+    token goes through another expert: compared freely, as a reference
+    without the flag is, the gradient is a whole expert's contribution
+    off and the old limit fails. Given the system's choices the same
+    arithmetic passes, and the share of choices the reference would
+    have made itself is held to the floor its own near ties give."""
+    free = StubReference(takes_choices=False)
+    checks, _ = stub_check(free)
+    # (one expert of two, at a tenth of the tokens, moves the loss by
+    # 3e-4 and the gradient by 0.13)
+    assert set(checks) == {"reference_loss", "reference_gradient"}
+    assert checks["reference_gradient"] is False
+    # a module without the flag is called exactly as before: once, with
+    # its own arguments and nothing else
+    assert free.calls == [("mean_loss", ["objective"])]
+
+    imposed = StubReference(takes_choices=True)
+    checks, compared = stub_check(imposed)
+    assert checks == {"reference_choices": True, "reference_loss": True,
+                      "reference_gradient": True}
+    assert imposed.calls == [("choice_scores", ["objective"]),
+                             ("mean_loss", ["choices", "objective"])]
+    # the rigged ids flipped and the rest agree; every flip is a near
+    # tie by the job's rule, so the floor is at or under the share
+    share, floor = compared["reference_choices"]
+    assert 0.8 < share < 0.95 and 0.8 < floor <= share
+    # the limits are the job's whatever the module states
+    imposed.LIMITS = {"loss_rtol": 1.0, "grad_rtol": 1.0, "why": "wider"}
+    _, compared = stub_check(imposed, expert_dtype="float8_e4m3fn")
+    assert compared["reference_gradient"][1] == dp_train.GRAD_RTOL == 3e-2
+    assert compared["reference_loss"][1] == dp_train.LOSS_RTOL == 5e-4
+
+
+def test_a_router_at_fault_is_not_passed_by_being_given_its_choices():
+    """The imposed comparison cannot see a router that chooses wrongly:
+    the arithmetic at its choices agrees. The share does: tokens whose
+    scores lie clearly apart and that the system routes otherwise pull
+    it under the floor, which no near tie lowers for them."""
+    reference = StubReference(takes_choices=True)
+    checks, compared = stub_check(
+        reference, rigged=APART_SCORES, router_fault=True)
+    assert checks == {"reference_choices": False, "reference_loss": True,
+                      "reference_gradient": True}
+    share, floor = compared["reference_choices"]
+    assert share < 0.95 < floor
+    # the same scores through a sound router: every choice agrees
+    checks, compared = stub_check(reference, rigged=APART_SCORES)
+    assert checks["reference_choices"] is True
+    assert compared["reference_choices"][0] > 0.95
+
+
+def test_the_imposed_comparison_still_sees_a_lower_precision():
+    """Imposing the choices does not blind the comparison: with the
+    expert product's inputs in an 8-bit float, the step below the
+    bfloat16 the limits were measured on (``dp_train``'s comment), the
+    gradient is far past GRAD_RTOL at the system's own choices; with
+    them in bfloat16, which the limits were set to pass, it is inside.
+    A product accumulated in bfloat16 over this stub's 64 terms reads
+    5e-3, inside too: it takes the sums over thousands of tokens of a
+    real step to be seen, so the 8-bit float is the control here."""
+    import jax.numpy as jnp
+
+    low = StubReference(takes_choices=True)
+    checks, _ = stub_check(low, expert_dtype=jnp.float8_e4m3fn)
+    assert checks["reference_choices"] is True
+    assert checks["reference_gradient"] is False
+    checks, _ = stub_check(StubReference(takes_choices=True),
+                           expert_dtype=jnp.bfloat16)
+    assert checks == {"reference_choices": True, "reference_loss": True,
+                      "reference_gradient": True}
+
+
+def test_the_program_has_to_sow_the_choices_the_reference_names():
+    """A reference that takes choices and a program that sows them
+    under other names (or sows none): not `correct`, and the reference
+    is compared freely, as there is nothing to give it."""
+    reference = StubReference(takes_choices=True, scores_name="router/0")
+    checks, _ = stub_check(reference)
+    assert checks["reference_choices"] is False
+    assert checks["reference_gradient"] is False
+    assert reference.calls[-1] == ("mean_loss", ["objective"])
+
+
+def test_no_reference_module_states_limits_or_takes_choices_yet():
+    """``transformer_lm`` and the fixture's reference state no limits
+    (the job's hold for every family, and nothing reads a module's) and
+    make no choices."""
+    for root, family in ((ROOT, "transformer_lm"),
+                         (FIXTURE, "rope_swiglu_lm")):
+        module = harness.load_reference(family, root)
+        assert not hasattr(module, "LIMITS")
+        assert not hasattr(module, "TAKES_CHOICES")
+    assert (dp_train.LOSS_RTOL, dp_train.GRAD_RTOL) == (5e-4, 3e-2)
