@@ -9,23 +9,32 @@ blockwise online-softmax attention (Flash Attention) tiled for MXU/VMEM:
 * grid over (batch blocks, head blocks, query blocks): a program handles
   a block of `gb x gh` consecutive (batch, head) instances side by side,
   each exactly as a program of its own would, with K/V streaming through
-  VMEM in `block_k`-sized tiles inside a `fori_loop`. One short-sequence
-  instance is a chain of dependent steps (matrix product, row maximum,
-  exponential, row sum, matrix product) whose latencies leave the units
-  idle; independent instances in one loop body fill them.
-  `_instances_per_program` picks the block from the shapes, the dtype and
-  whether tiles are masked: the largest of 16 instances at most whose
-  work stays where more instances still pay and whose double-buffered
-  blocks fit a VMEM budget that holds under default compiler options —
-  16 heads at T=128, 4 at T=512, one instance (the kernel of one
-  instance a program) at T=1024 causal. The arrays stay [B, H, T, D]:
-  seen as [B·H, T, D] the kernels ran the same, but the compiler laid
-  the step around them out differently and lost more than they gained.
-  Trace-time gauges `hvd_flash_instances_per_program` /
-  `hvd_flash_programs_per_call` say what each kernel got;
+  VMEM in `block_k`-sized tiles. One short-sequence instance is a chain
+  of dependent steps (matrix product, row maximum, exponential, row sum,
+  matrix product) whose latencies leave the units idle; independent
+  instances in one loop body fill them. `_instances_per_program` picks
+  the block from the shapes and the dtype: the largest of 16 instances
+  at most whose work stays where more instances still pay and whose
+  double-buffered blocks fit a VMEM budget that holds under default
+  compiler options — 16 heads at T=128, 4 at T=512, 2 at T=1024. The
+  arrays stay [B, H, T, D]: seen as [B·H, T, D] the kernels ran the
+  same, but the compiler laid the step around them out differently and
+  lost more than they gained. Trace-time gauges
+  `hvd_flash_instances_per_program` / `hvd_flash_programs_per_call` say
+  what each kernel got;
 * causal masking on *global* positions, so sequence-parallel callers
   (ring attention) pass `query_offset`/`key_offset` and reuse the same
-  kernel for off-diagonal blocks;
+  kernel for off-diagonal blocks. A tile pays for the mask only where
+  the mask can be false in it: `_tile_ranges` classes each program's
+  tiles from the static arguments and `program_id` — wholly above the
+  diagonal: not run; wholly at or under it with no padded key: the
+  unmasked body a non-causal call runs; crossed by the diagonal or
+  holding the padded tail (the last kv tile): masked, by one compare
+  and one select (the forward a second only where a row can have seen
+  no key yet). A class that is one tile in every program, as the
+  diagonal tile of square blocks is, runs without a loop. Gauges
+  `hvd_flash_tiles_per_call` / `hvd_flash_boundary_tiles_per_call`
+  count a call's tiles and the masked ones;
 * f32 accumulators over bf16 inputs (MXU-native mixed precision);
 * the forward emits per-row logsumexp; the backward is two more flash
   kernels (dq over K/V tiles, dk/dv over Q tiles) that rebuild each
@@ -73,21 +82,26 @@ def _reference_attention(q, k, v, causal, scale, query_offset, key_offset):
 
 
 def _tile_mask(block_q, block_k, q_base, k_base, *, causal, q_offset,
-               k_offset, kv_len):
-    """Validity mask for one [block_q, block_k] logits tile.
+               k_offset, kv_len, padded):
+    """Validity mask for one [block_q, block_k] logits tile that
+    `_tile_ranges` calls masked: the diagonal crosses it (`causal`) or it
+    holds padded keys (`padded`, static: kv_len is less than the padded
+    length), so at least one of the two is set.
 
     `q_base`/`k_base` are the tile's local starting rows/cols; global
-    positions add the caller's sequence offsets (ring attention)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    positions add the caller's sequence offsets (ring attention). Row r
+    sees column c iff q_offset + q_base + r >= k_offset + k_base + c: one
+    compare of the iota difference r - c, the same in every tile, against
+    a scalar."""
     cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    k_local = k_base + cols
-    mask = k_local < kv_len  # K padding
+    mask = None
     if causal:
-        mask = jnp.logical_and(
-            mask, (q_offset + q_base + rows) >= (k_offset + k_local)
-        )
+        rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        mask = (rows - cols) >= (k_offset + k_base) - (q_offset + q_base)
+    if padded:
+        real = cols < kv_len - k_base
+        mask = real if mask is None else jnp.logical_and(mask, real)
     return mask
-
 
 
 def _dot_nt(a, b):
@@ -107,65 +121,158 @@ def _dot_tn(a, b):
     )
 
 
-def _causal_kv_limit(q_base, block_q, block_k, q_offset, k_offset,
-                     num_kv_blocks):
-    """Number of leading kv blocks that can contribute under the causal
-    mask for the q block starting at local row `q_base`: the last kb with
-    min(gk) ≤ max(gq). Shared by the forward and dq kernels so their tile
-    coverage can never diverge."""
-    return jnp.clip(
-        (q_offset + q_base + block_q - 1 - k_offset) // block_k + 1,
-        0, num_kv_blocks,
-    )
+def _clip(x, lo, hi):
+    """`jnp.clip`, and plain `min`/`max` where nothing is traced: the
+    tiles are counted at trace time with the arithmetic the kernels
+    run."""
+    if any(isinstance(a, jax.Array) for a in (x, lo, hi)):
+        return jnp.clip(x, lo, hi)
+    return min(max(x, lo), hi)
 
 
-def _run_instances(gb, gh, lo, hi, start, tile, finish):
-    """The loop around one program's `gb x gh` (batch, head) instances.
+def _tile_ranges(over, base, block_q, block_k, num_tiles, *, causal,
+                 q_offset, k_offset, kv_len, padded):
+    """The tiles one program runs, in the order it runs them, as
+    `(lo, hi, masked)` ranges of tile indices. `over == "kv"`: the
+    program owns the q block at local row `base` and streams the kv tiles
+    (forward, dq); `over == "q"`: it owns the kv block at local column
+    `base` and streams the q tiles (dkv). `base` is traced (from
+    `program_id`) or a Python int, `num_tiles` the padded streamed length
+    in tiles, `padded` whether kv_len is less than the padded key length.
+
+    On *global* positions the tile of rows [q0, q0 + block_q) and columns
+    [k0, k0 + block_k)
+      * runs      iff its last row sees its first key, or nothing is
+                  causal: q_offset + q0 + block_q - 1 >= k_offset + k0;
+      * is masked iff its first row does not see its last key:
+                  q_offset + q0 < k_offset + k0 + block_k - 1,
+                  or it holds a padded key: k0 + block_k > kv_len.
+    In an unmasked tile the mask would be all true, so it runs the body a
+    non-causal, unpadded call runs. All three kernels take their ranges
+    from here, so forward and backward can never cover different tiles,
+    and the gauges count them from here. Always two ranges, either of
+    which may be empty."""
+    shift = q_offset - k_offset
+    if over == "kv":
+        whole = kv_len // block_k  # leading kv tiles with no padded key
+        if causal:
+            limit = _clip((shift + base + block_q - 1) // block_k + 1,
+                          0, num_tiles)
+            unmasked = _clip((shift + base + 1) // block_k, 0, whole)
+            ranges = [(0, unmasked, False), (unmasked, limit, True)]
+        else:
+            ranges = [(0, whole, False), (whole, num_tiles, True)]
+    else:
+        first, unmasked = 0, 0
+        if causal:
+            first = _clip((base - shift) // block_q, 0, num_tiles)
+            # ceil((k0 + block_k - 1 - shift) / block_q)
+            unmasked = _clip(-((shift - base - block_k + 1) // block_q),
+                             first, num_tiles)
+        if padded:  # the kv block with the padded keys: every tile masked
+            unmasked = _clip(unmasked, num_tiles * (base + block_k > kv_len),
+                             num_tiles)
+        ranges = [(first, unmasked, True), (unmasked, num_tiles, False)]
+    return ranges
+
+
+def _every_program(over, own_blocks, block_q, block_k, num_tiles,
+                   **geometry):
+    """`_tile_ranges` of each of the `own_blocks` programs of one (batch,
+    head) instance, on Python ints: what is static about them."""
+    own_rows = block_q if over == "kv" else block_k
+    return [_tile_ranges(over, j * own_rows, block_q, block_k, num_tiles,
+                         **geometry) for j in range(own_blocks)]
+
+
+def _trips(every):
+    """For each of the two ranges, the set of lengths it has over an
+    instance's programs (`_every_program`): `_run_instances` leaves out a
+    range that is empty in every program and does not loop over one that
+    is one tile in every program."""
+    return tuple(frozenset(r[n][1] - r[n][0] for r in every)
+                 for n in range(2))
+
+
+def _count_tiles(every):
+    """(tiles, masked tiles) one (batch, head) instance runs."""
+    tiles = sum(hi - lo for r in every for lo, hi, _ in r)
+    return tiles, sum(hi - lo for r in every for lo, hi, masked in r
+                      if masked)
+
+
+def _rows_may_see_no_key(*, causal, q_offset, k_offset, **_):
+    """Whether a row can come to a tile with every key it has met so far
+    masked, that tile's included. Only where queries start before the
+    keys: otherwise every row sees key 0 (never a padded one) in tile 0,
+    the first it runs, and from then on its running maximum is a score:
+    a masked lane's `exp(NEG_INF - m)` is exactly 0 without a select."""
+    return causal and q_offset < k_offset
+
+
+def _run_instances(gb, gh, ranges, trips, start, tile, finish, mask):
+    """The loops around one program's `gb x gh` (batch, head) instances.
     For each instance `i = (batch, head)` of the block: `fixed, carry =
-    start(i)`; for every tile `t` in [lo, hi) `carry = tile(i, t, fixed,
-    carry)`; then `finish(i, fixed, carry)`.
+    start(i)`; for every range `(lo, hi, masked)` of `ranges`
+    (`_tile_ranges`), in order, and every tile `t` in [lo, hi) `carry =
+    tile(i, t, fixed, carry, m)`; then `finish(i, fixed, carry)`. `m` is
+    `mask(t)`, built once a tile for all the instances, in a masked range
+    and None in an unmasked one: the ranges share the carries, so only
+    the tiles the mask can be false in pay for it.
 
-    The instances go through the tile loop side by side, as
-    straight-line code in one loop body: their chains of matrix product,
-    row reduction and exponential do not depend on one another, so the
-    scheduler fills one's latencies with another's work (a loop over the
-    instances does not: PERF.md section 6, PR 25). No instance's own
-    operations or their order change, so results are the same bit for
-    bit however many there are, and one is the kernel of one instance a
-    program."""
+    By `trips` (`_trips`) a range that is empty in every program is left
+    out, and one that is one tile in every program (the diagonal tile of
+    square blocks, the only tile of a sequence of one block) runs
+    as straight-line code between `start` and the next loop or `finish`:
+    a loop whose trip count the program computes costs 0.2 to 0.6 us
+    each time it is entered, more with more carries (PERF.md section 6,
+    PR 28), which is more than the mask it would save.
+
+    The instances go through a tile side by side, as straight-line code
+    in one loop body: their chains of matrix product, row reduction and
+    exponential do not depend on one another, so the scheduler fills
+    one's latencies with another's work (a loop over the instances does
+    not: PERF.md section 6, PR 25). No instance's own operations or
+    their order change, so results are the same bit for bit however
+    many there are, and one is the kernel of one instance a program."""
     instances = [(b, h) for b in range(gb) for h in range(gh)]
     fixed, carries = zip(*(start(i) for i in instances))
 
-    def body(t, carries):
-        return tuple(tile(i, t, f, carry)
-                     for i, f, carry in zip(instances, fixed, carries))
+    for (lo, hi, masked), lengths in zip(ranges, trips):
+        def body(t, carries, masked=masked):
+            m = mask(t) if masked else None
+            return tuple(tile(i, t, f, carry, m)
+                         for i, f, carry in zip(instances, fixed, carries))
 
-    carries = lax.fori_loop(lo, hi, body, tuple(carries))
+        if lengths == {1}:
+            carries = body(lo, carries)
+        elif lengths != {0}:
+            carries = lax.fori_loop(lo, hi, body, tuple(carries))
     for i, f, carry in zip(instances, fixed, carries):
         finish(i, f, carry)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                      causal: bool, scale: float, q_offset: int,
-                      k_offset: int, kv_len: int):
+                      scale: float, geometry: dict, trips: tuple):
     """One (gb x gh instances, q-block) program: for each instance stream
     K/V tiles, online softmax.
 
     q_ref: [gb, gh, block_q, D]; k_ref/v_ref: [gb, gh, Tk_padded, D];
     o_ref: [gb, gh, block_q, D]; lse_ref: [gb, gh, 1, block_q] f32 per-row
     logsumexp of the scaled logits (the backward kernels rebuild P tiles
-    from it)."""
+    from it). `geometry` is what `_tile_ranges` takes, `trips` what
+    `_trips` says of its ranges."""
     gb, gh, block_q, d = q_ref.shape
     q_base = pl.program_id(2) * block_q
-    num_kv_blocks = k_ref.shape[2] // block_k
-    # static elision: the all-true mask (non-causal, no K padding — the
-    # BERT/encoder fast path) costs a full VPU iota+select per tile
-    masked = causal or kv_len < k_ref.shape[2]
-    if causal:
-        limit = _causal_kv_limit(q_base, block_q, block_k, q_offset,
-                                 k_offset, num_kv_blocks)
-    else:
-        limit = num_kv_blocks
+    # a tile pays for the mask (compare + select on the VPU) only where
+    # the mask can be false in it; non-causal and unpadded (the
+    # BERT/encoder path) none does
+    ranges = _tile_ranges("kv", q_base, block_q, block_k,
+                          k_ref.shape[2] // block_k, **geometry)
+    empty_rows = _rows_may_see_no_key(**geometry)
+
+    def mask(kb):
+        return _tile_mask(block_q, block_k, q_base, kb * block_k, **geometry)
 
     def start(i):
         # keep matmul inputs in the model dtype (bf16 → bf16 MXU path)
@@ -177,25 +284,22 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         l0 = jnp.zeros((block_q,), jnp.float32)
         return q, (acc0, m0, l0)
 
-    def tile(i, kb, q, carry):
+    def tile(i, kb, q, carry, mask):
         acc, m_prev, l_prev = carry
         k_tile = k_ref[(*i, pl.ds(kb * block_k, block_k))]
         v_tile = v_ref[(*i, pl.ds(kb * block_k, block_k))]
         s = _dot_nt(q, k_tile)
-        if masked:
-            mask = _tile_mask(
-                block_q, block_k, q_base, kb * block_k, causal=causal,
-                q_offset=q_offset, k_offset=k_offset, kv_len=kv_len,
-            )
+        if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
         m_cur = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
-        # explicit mask on p: for a fully-masked row m_new == NEG_INF and
-        # exp(s - m_new) would be exp(0) == 1, silently averaging V — the
-        # masked entries must contribute exactly zero
         p = jnp.exp(s - m_new[:, None])
-        if masked:
+        # explicit mask on p: for a row with no key yet m_new == NEG_INF
+        # and exp(s - m_new) would be exp(0) == 1, silently averaging V —
+        # the masked entries must contribute exactly zero. Where no row
+        # can be such a row, exp(NEG_INF - m_new) is that zero already
+        if mask is not None and empty_rows:
             p = jnp.where(mask, p, 0.0)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1)
         acc = acc * alpha[:, None] + jnp.dot(
@@ -212,12 +316,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         o_ref[i] = (acc / safe_l[:, None]).astype(o_ref.dtype)
         lse_ref[(*i, 0)] = jnp.where(l > 0, m + jnp.log(safe_l), NEG_INF)
 
-    _run_instances(gb, gh, 0, limit, start, tile, finish)
+    _run_instances(gb, gh, ranges, trips, start, tile, finish, mask)
 
 
 def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                         dq_ref, *, block_k: int, causal: bool, scale: float,
-                         q_offset: int, k_offset: int, kv_len: int):
+                         dq_ref, *, block_k: int, scale: float,
+                         geometry: dict, trips: tuple):
     """dQ for one q block of gb x gh instances: stream K/V tiles, rebuild
     P from lse.
 
@@ -225,32 +329,27 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     (zero on padded rows because dO is zero-padded)."""
     gb, gh, block_q, d = q_ref.shape
     q_base = pl.program_id(2) * block_q
-    num_kv_blocks = k_ref.shape[2] // block_k
-    masked = causal or kv_len < k_ref.shape[2]
-    if causal:
-        limit = _causal_kv_limit(q_base, block_q, block_k, q_offset,
-                                 k_offset, num_kv_blocks)
-    else:
-        limit = num_kv_blocks
+    ranges = _tile_ranges("kv", q_base, block_q, block_k,
+                          k_ref.shape[2] // block_k, **geometry)
+
+    def mask(kb):
+        return _tile_mask(block_q, block_k, q_base, kb * block_k, **geometry)
 
     def start(i):
         q = (q_ref[i].astype(jnp.float32) * scale).astype(q_ref.dtype)
         fixed = (q, do_ref[i], lse_ref[(*i, 0)], delta_ref[(*i, 0)])
         return fixed, jnp.zeros((block_q, d), jnp.float32)
 
-    def tile(i, kb, fixed, acc):
+    def tile(i, kb, fixed, acc, mask):
         q, do, lse, delta = fixed
         k_tile = k_ref[(*i, pl.ds(kb * block_k, block_k))]
         v_tile = v_ref[(*i, pl.ds(kb * block_k, block_k))]
         s = _dot_nt(q, k_tile)
         p = jnp.exp(s - lse[:, None])
-        if masked:
-            mask = _tile_mask(
-                block_q, block_k, q_base, kb * block_k, causal=causal,
-                q_offset=q_offset, k_offset=k_offset, kv_len=kv_len,
-            )
-            # masked lanes: exp may overflow to +inf (lse == NEG_INF
-            # rows); the where() selects 0 before anything multiplies it
+        if mask is not None:
+            # masked lanes: exp(s - lse) is not 0, and may overflow to
+            # +inf (lse == NEG_INF rows, which no unmasked tile holds);
+            # the where() selects 0 before anything multiplies it
             p = jnp.where(mask, p, 0.0)
         dp = _dot_nt(do, v_tile)
         ds = p * (dp - delta[:, None])
@@ -262,38 +361,34 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     def finish(i, fixed, acc):
         dq_ref[i] = (acc * scale).astype(dq_ref.dtype)
 
-    _run_instances(gb, gh, 0, limit, start, tile, finish)
+    _run_instances(gb, gh, ranges, trips, start, tile, finish, mask)
 
 
 def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, causal: bool,
-                          scale: float, q_offset: int, k_offset: int,
-                          kv_len: int, total_kv: int):
+                          dk_ref, dv_ref, *, block_q: int, scale: float,
+                          geometry: dict, trips: tuple):
     """dK/dV for one kv block of gb x gh instances: stream Q/dO tiles.
 
     dV = Pᵀ·dO, dK = scale · dSᵀ·Q. Padded q rows carry dO == 0 and
     Δ == 0, so they contribute exactly nothing to either sum."""
     gb, gh, block_k, d = k_ref.shape
     k_base = pl.program_id(2) * block_k
-    num_q_blocks = q_ref.shape[2] // block_q
     # the K-padding mask guards this kv block's own padded rows; padded
     # q rows are harmless because their dO and Δ are zero — so the mask
-    # is only needed for causal or padded-K tiles
-    masked = causal or kv_len < total_kv
-    if causal:
-        # q tiles entirely above the diagonal (max(gq) < min(gk))
-        # contribute nothing to this kv block
-        first = jnp.clip(
-            (k_offset + k_base - q_offset) // block_q, 0, num_q_blocks
-        )
-    else:
-        first = 0
+    # is only needed for causal or padded-K tiles. q tiles entirely
+    # above the diagonal (max(gq) < min(gk)) contribute nothing to this
+    # kv block and do not run
+    ranges = _tile_ranges("q", k_base, block_q, block_k,
+                          q_ref.shape[2] // block_q, **geometry)
+
+    def mask(qb):
+        return _tile_mask(block_q, block_k, qb * block_q, k_base, **geometry)
 
     def start(i):
         zeros = jnp.zeros((block_k, d), jnp.float32)
         return (k_ref[i], v_ref[i]), (zeros, zeros)
 
-    def tile(i, qb, kv, carry):
+    def tile(i, qb, kv, carry, mask):
         k, v = kv
         dk_acc, dv_acc = carry
         q_tile = q_ref[(*i, pl.ds(qb * block_q, block_q))]
@@ -303,11 +398,7 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         qs = (q_tile.astype(jnp.float32) * scale).astype(q_tile.dtype)
         s = _dot_nt(qs, k)
         p = jnp.exp(s - lse_tile[:, None])
-        if masked:
-            mask = _tile_mask(
-                block_q, block_k, qb * block_q, k_base, causal=causal,
-                q_offset=q_offset, k_offset=k_offset, kv_len=kv_len,
-            )
+        if mask is not None:
             p = jnp.where(mask, p, 0.0)
         dv_acc = dv_acc + _dot_tn(p.astype(do_tile.dtype), do_tile)
         dp = _dot_nt(do_tile, v)
@@ -320,7 +411,7 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dk_ref[i] = (dk_acc * scale).astype(dk_ref.dtype)
         dv_ref[i] = dv_acc.astype(dv_ref.dtype)
 
-    _run_instances(gb, gh, first, num_q_blocks, start, tile, finish)
+    _run_instances(gb, gh, ranges, trips, start, tile, finish, mask)
 
 
 def _pad_to(x, axis, multiple):
@@ -334,20 +425,21 @@ def _pad_to(x, axis, multiple):
 
 
 # How many instances a program takes. Measured on a v5e (PERF.md section
-# 6, PR 25: kernels alone, head width 64, bf16, time of the three kernels
-# against one instance a program): what pays is instances side by side,
-# and it pays by how little one instance does, its latencies being what
-# the others fill — T=128 0.38x at 16 instances (0.43x at 8, 0.34x at
-# 32), T=256 0.58x at 16, T=512 0.87x at 4 (0.85x at 8), T=512 causal
-# 0.92x at 4; two tiles of 512 x 512 an instance, T=1024: 0.986x at 2
-# unmasked, 1.005x at 2 causal, and 4 causal do not fit VMEM. Fewer
-# programs alone, 16 instances in a loop, gave 0.83x at T=128 and 0.99x
-# at T=512.
+# 6, PR 25 and PR 28: kernels alone, head width 64, bf16, time of the
+# three kernels against one instance a program): what pays is instances
+# side by side, and it pays by how little one instance does, its
+# latencies being what the others fill — T=128 0.38x at 16 instances
+# (0.43x at 8, 0.34x at 32), T=256 0.58x at 16, T=512 0.87x at 4 (0.85x
+# at 8), T=512 causal 0.88x at 4; two tiles of 512 x 512 for a q block,
+# T=1024: 0.986x at 2, unmasked or causal, and 4 causal do not fit VMEM.
+# Fewer programs alone, 16 instances in a loop, gave 0.83x at T=128 and
+# 0.99x at T=512.
 #
-# Work: in units of one 128 x 128 score tile over all of an instance's
-# tiles, a masked tile (iota, compare and two selects more) counted
-# twice. A program takes at most this much; an instance that is more than
-# half of it goes alone.
+# Work: in units of one 128 x 128 score tile over all of a block's tiles,
+# causal or not: a causal program runs half of them on average, and a
+# masked tile is only one the diagonal crosses or one that holds padded
+# keys, a compare and a select more. A program takes at most this much;
+# an instance that is more than half of it goes alone.
 _PROGRAM_TILE_UNITS = 64
 # Bodies side by side: each instance is one more body to trace and
 # compile, and at head width 64 the VMEM charge stops at 16 to 20.
@@ -380,7 +472,7 @@ def _vmem_bytes(rows, cols, itemsize):
 
 
 def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
-                           itemsize, masked):
+                           itemsize):
     """The block `(gb, gh)` of consecutive (batch, head) instances one
     program of `kernel` ("fwd", "dq" or "dkv") handles, side by side:
     the largest that tiles [batch, heads] in rows (`gh` divides `heads`,
@@ -390,10 +482,9 @@ def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
     larger one does, which is the kernel of one instance a program.
 
     `own_rows` is the program's own block (block_q; block_k for dkv),
-    `other_rows` the padded length it streams over (Tk_p; Tq_p for dkv),
-    `masked` whether its tiles are masked (causal, or padded keys). A
-    function of shapes, dtype and that flag alone: short sequences get
-    many instances a program, long ones one, with nothing to set."""
+    `other_rows` the padded length it streams over (Tk_p; Tq_p for dkv).
+    A function of shapes and dtype alone: short sequences get many
+    instances a program, long ones one, with nothing to set."""
     n_own, n_other, n_stats, stats_side = _KERNEL_BLOCKS[kernel]
     stats_rows = own_rows if stats_side == "own" else other_rows
     per_instance = 2 * (
@@ -401,7 +492,7 @@ def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
         + n_other * _vmem_bytes(other_rows, d, itemsize)
         + n_stats * _vmem_bytes(1, stats_rows, 4)
     )
-    units = -(-own_rows // 128) * -(-other_rows // 128) * (1 + masked)
+    units = -(-own_rows // 128) * -(-other_rows // 128)
     most = min(_MOST_INSTANCES, _PROGRAM_TILE_UNITS // units,
                _VMEM_BLOCK_BUDGET // per_instance)
     blocks = [(1, gh) for gh in range(1, heads + 1) if heads % gh == 0]
@@ -410,20 +501,30 @@ def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
                key=lambda blk: blk[0] * blk[1], default=(1, 1))
 
 
-def _grid(kernel, shape, own_rows, other_rows, itemsize, masked):
-    """`(gb, gh)` and the grid of `kernel` over a padded [B, H, T, D]
-    array of `shape` whose T is the program's own side, recorded in the
-    trace-time gauges (the choice is static, so nothing runs in the
-    step): instances a program and programs a call, by kernel."""
+def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry):
+    """`(gb, gh)`, the grid and the `trips` (`_trips`) of `kernel` over a
+    padded [B, H, T, D] array of `shape` whose T is the program's own
+    side, `other_rows` the padded length it streams over and `geometry`
+    what `_tile_ranges` takes. Recorded in the trace-time gauges (all of
+    it is static, so nothing runs in the step), by kernel: instances a
+    program, programs a call, and the tiles a call runs with those of
+    them that are masked."""
     from ..utils import metrics
 
     b, h, t, d = shape
+    over = "q" if kernel == "dkv" else "kv"
+    own_rows, other_block = \
+        (block_k, block_q) if over == "q" else (block_q, block_k)
     gb, gh = _instances_per_program(kernel, b, h, own_rows, other_rows, d,
-                                    itemsize, masked)
+                                    itemsize)
     grid = (b // gb, h // gh, t // own_rows)
-    metrics.record_flash_programs(kernel, gb * gh,
-                                  grid[0] * grid[1] * grid[2])
-    return gb, gh, grid
+    every = _every_program(over, t // own_rows, block_q, block_k,
+                           other_rows // other_block, **geometry)
+    tiles, masked_tiles = _count_tiles(every)
+    metrics.record_flash_programs(
+        kernel, gb * gh, grid[0] * grid[1] * grid[2], b * h * tiles,
+        b * h * masked_tiles)
+    return gb, gh, grid, _trips(every)
 
 
 def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
@@ -435,12 +536,12 @@ def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
     are the minor-most dims in this layout."""
     b, h, tq_p, d = qq.shape
     tk_p = kk.shape[2]
-    gb, gh, grid = _grid("fwd", qq.shape, block_q, tk_p, qq.dtype.itemsize,
-                         causal or kv_len < tk_p)
-    kernel = functools.partial(
-        _flash_fwd_kernel, block_k=block_k, causal=causal, scale=scale,
-        q_offset=query_offset, k_offset=key_offset, kv_len=kv_len,
-    )
+    geometry = dict(causal=causal, q_offset=query_offset,
+                    k_offset=key_offset, kv_len=kv_len, padded=kv_len < tk_p)
+    gb, gh, grid, trips = _grid("fwd", qq.shape, block_q, block_k, tk_p,
+                                qq.dtype.itemsize, geometry)
+    kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
+                               scale=scale, geometry=geometry, trips=trips)
     rows = pl.BlockSpec((gb, gh, block_q, d), lambda b, h, j: (b, h, j, 0))
     whole = pl.BlockSpec((gb, gh, tk_p, d), lambda b, h, j: (b, h, 0, 0))
     return pl.pallas_call(
@@ -510,13 +611,14 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
     kk = _pad_to(k, 2, block_k)
     vv = _pad_to(v, 2, block_k)
     tq_p, tk_p = qq.shape[2], kk.shape[2]
-    itemsize, masked = q.dtype.itemsize, causal or tk < tk_p
+    itemsize = q.dtype.itemsize
+    geometry = dict(causal=causal, q_offset=query_offset,
+                    k_offset=key_offset, kv_len=tk, padded=tk < tk_p)
 
-    gb, gh, grid = _grid("dq", qq.shape, block_q, tk_p, itemsize, masked)
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, block_k=block_k, causal=causal, scale=scale,
-        q_offset=query_offset, k_offset=key_offset, kv_len=tk,
-    )
+    gb, gh, grid, trips = _grid("dq", qq.shape, block_q, block_k, tk_p,
+                                itemsize, geometry)
+    dq_kernel = functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
+                                  scale=scale, geometry=geometry, trips=trips)
     rows = pl.BlockSpec((gb, gh, block_q, d), lambda b, h, j: (b, h, j, 0))
     stats = pl.BlockSpec((gb, gh, 1, block_q), lambda b, h, j: (b, h, 0, j))
     whole = pl.BlockSpec((gb, gh, tk_p, d), lambda b, h, j: (b, h, 0, 0))
@@ -529,12 +631,11 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
         interpret=interpret(),
     )(qq, do, lse_p, delta_p, kk, vv)
 
-    gb, gh, grid = _grid("dkv", kk.shape, block_k, tq_p, itemsize, masked)
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, block_q=block_q, causal=causal, scale=scale,
-        q_offset=query_offset, k_offset=key_offset, kv_len=tk,
-        total_kv=tk_p,
-    )
+    gb, gh, grid, trips = _grid("dkv", kk.shape, block_q, block_k, tq_p,
+                                itemsize, geometry)
+    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
+                                   scale=scale, geometry=geometry,
+                                   trips=trips)
     rows = pl.BlockSpec((gb, gh, block_k, d), lambda b, h, j: (b, h, j, 0))
     stats = pl.BlockSpec((gb, gh, 1, tq_p), lambda b, h, j: (b, h, 0, 0))
     whole = pl.BlockSpec((gb, gh, tq_p, d), lambda b, h, j: (b, h, 0, 0))

@@ -995,12 +995,16 @@ def record_fused_collective(surface: str) -> None:
 
 
 def record_flash_programs(kernel: str, instances_per_program: int,
-                          programs: int) -> None:
+                          programs: int, tiles: int,
+                          boundary_tiles: int) -> None:
     """The grid one flash-attention kernel ("fwd", "dq", "dkv") was
-    built with (ops/pallas_attention.py). Recorded at TRACE time like
-    the fused collectives' breadcrumb: the kernels choose instances a
-    program from the shapes, statically, so the gauges say what the last
-    traced call of each kernel got and nothing runs in the step."""
+    built with (ops/pallas_attention.py), the score tiles one call of it
+    runs and those of them that pay for the mask: boundary tiles, which
+    the causal diagonal crosses or which hold padded keys. Recorded at
+    TRACE time like the fused collectives' breadcrumb: the kernels
+    choose instances a program and class their tiles from the shapes,
+    statically, so the gauges say what the last traced call of each
+    kernel got and nothing runs in the step."""
     if not _enabled:
         return
     registry.gauge(
@@ -1012,6 +1016,14 @@ def record_flash_programs(kernel: str, instances_per_program: int,
         "hvd_flash_programs_per_call",
         "Programs in the grid of one call of the flash kernel",
         labelnames=("kernel",)).labels(kernel=kernel).set(programs)
+    registry.gauge(
+        "hvd_flash_tiles_per_call",
+        "Score tiles one call of the flash kernel runs",
+        labelnames=("kernel",)).labels(kernel=kernel).set(tiles)
+    registry.gauge(
+        "hvd_flash_boundary_tiles_per_call",
+        "Tiles of one call of the flash kernel that are masked",
+        labelnames=("kernel",)).labels(kernel=kernel).set(boundary_tiles)
 
 
 def record_overlap_window(frac: float) -> None:

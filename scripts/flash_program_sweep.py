@@ -1,12 +1,15 @@
 #!/usr/bin/env python3
 """Time the three flash-attention kernels alone on the chip, over the
-number of (batch, head) instances a program handles.
+number of (batch, head) instances a program handles or over which tiles
+pay for the mask.
 
-    python scripts/flash_program_sweep.py [--shapes s128,s512,gpt2]
-        [--parent .parent] [--out chiprun_out/flash_program_sweep.jsonl]
+    python scripts/flash_program_sweep.py [--sweep instances|tiles]
+        [--shapes s128,s512,gpt2] [--parent .parent]
+        [--out chiprun_out/flash_program_sweep.jsonl]
 
-For each shape (the benchmark cells' attention calls and a few more:
-head width 64, bf16) and each number of instances `g`, the chooser of
+`--sweep instances` (the default): for each shape (the benchmark
+cells' attention calls and a few more: head width 64, bf16) and each
+number of instances `g`, the chooser of
 `ops/pallas_attention.py` is replaced by `g`, the forward, dq and dkv
 calls are compiled apart (a backward whose other result is unused is
 dropped by the compiler) with no compiler option, and each is traced
@@ -17,8 +20,21 @@ bit for bit with `g = 1`'s, and with `--parent` (a checkout of another
 commit) with that commit's, whose kernels are timed the same way. A `g`
 the compiler refuses (VMEM) is printed as refused: that is where the
 budget of `_instances_per_program` has to stay under. The line marked
-`chosen` is what the chooser itself picks. Exits non-zero without a
-TPU: a time from anywhere else is not a kernel time.
+`chosen` is what the chooser itself picks.
+
+`--sweep tiles`: for each shape, at the instances the chooser picks,
+the kernels are timed once for each of `TILES`, the kernels' module
+patched: `every_tile_masked` (every tile that runs is masked, in one
+loop, and the forward selects twice: the kernels of before PR 28 but
+for how the mask is built), `one_select` (the same without the
+forward's second select where no row can be empty), `classes_in_loops`
+(`_tile_ranges` classes the tiles, each class in a loop of its own) and
+`classes` (the module as it is: a class that is one tile in every
+program runs without a loop). Results are compared bit for bit with
+the first row's and with `--parent`'s.
+
+Exits non-zero without a TPU: a time from anywhere else is not a kernel
+time.
 """
 
 from __future__ import annotations
@@ -45,6 +61,47 @@ SHAPES = {
     "c2048": (8, 16, 2048, True, (1, 2)),
 }
 HEAD, BLOCK = 64, 512
+
+
+def patched(pa, **values):
+    """Set attributes of module `pa`; returns the undo."""
+    was = {name: getattr(pa, name) for name in values}
+    for name, value in values.items():
+        setattr(pa, name, value)
+    return lambda: [setattr(pa, name, value) for name, value in was.items()]
+
+
+def in_loops(pa):
+    """Every range of tiles in a loop, also one that is one tile in
+    every program (the loops of before PR 28)."""
+    trips = pa._trips
+    return patched(pa, _trips=lambda every: tuple(
+        lengths if lengths == {0} else None for lengths in trips(every)))
+
+
+def one_masked_loop(pa, second_select=False):
+    """Every tile that runs masked, in one loop; `second_select`: the
+    forward selects twice in every tile (with it, the kernels of before
+    PR 28 but for how the mask is built)."""
+    ranges = pa._tile_ranges
+
+    def all_masked(*a, **kw):
+        (lo, _, _), (_, hi, _) = ranges(*a, **kw)
+        return [(lo, hi, True), (hi, hi, False)]
+
+    undo = [in_loops(pa), patched(pa, _tile_ranges=all_masked)]
+    if second_select:
+        undo.append(patched(pa, _rows_may_see_no_key=lambda **geometry: True))
+    return lambda: [u() for u in reversed(undo)]
+
+
+# name -> patch of the kernels' module that returns its undo
+TILES = {
+    "every_tile_masked": lambda pa: one_masked_loop(pa, second_select=True),
+    "one_select": one_masked_loop,
+    "classes_in_loops": in_loops,
+    "classes": lambda pa: lambda: None,
+}
 
 
 def load_parent(checkout):
@@ -127,6 +184,8 @@ def same_bits(a, b):
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--sweep", choices=("instances", "tiles"),
+                    default="instances")
     ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--parent", default=None)
@@ -142,6 +201,11 @@ def main(argv=None):
     if device.platform != "tpu":
         print(f"no TPU here (platform {device.platform}): nothing measured")
         return 1
+    from horovod_tpu.utils import metrics
+
+    # a parent's kernels call this commit's recorder, with its own
+    # arguments; nothing reads the gauges here
+    metrics.record_flash_programs = lambda *a, **kw: None
     parent = load_parent(args.parent) if args.parent else None
     chooser = pa._instances_per_program
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -156,13 +220,40 @@ def main(argv=None):
             shape = SHAPES[name]
             b, h, t, causal, sweep = shape
             block = min(t, BLOCK)
-            chosen = {k: chooser(k, b, h, block, t, HEAD, 2, causal)
+            chosen = {k: chooser(k, b, h, block, t, HEAD, 2)
                       for k in ("fwd", "dq", "dkv")}
             before = measure(parent, shape, args.calls) \
                 if parent else {}
             for k, (ms, whole, _) in before.items():
                 emit(shape=name, kernel=k, commit="parent", kernel_ms=ms,
                      whole_ms=whole)
+            if args.sweep == "tiles":
+                first = None
+                for tiles, patch in TILES.items():
+                    undo = patch(pa)
+                    pa._flash_fwd.clear_cache()  # traced once a shape
+                    pa._flash_bwd.clear_cache()
+                    try:
+                        found = measure(pa, shape, args.calls)
+                    except Exception as e:  # Mosaic's refusal, printed
+                        emit(shape=name, tiles=tiles, refused=str(e)[-220:])
+                        continue
+                    finally:
+                        undo()
+                    first = first or found
+                    for k, (ms, whole, bits) in found.items():
+                        emit(shape=name, kernel=k, tiles=tiles,
+                             g=chosen[k][0] * chosen[k][1], kernel_ms=ms,
+                             whole_ms=whole,
+                             same_bits_as_first=same_bits(bits, first[k][2]),
+                             **({"same_bits_as_parent":
+                                 same_bits(bits, before[k][2])}
+                                if before else {}))
+                    emit(shape=name, kernel="all", tiles=tiles,
+                         kernel_ms=sum(ms for ms, _, _ in found.values()))
+                pa._flash_fwd.clear_cache()
+                pa._flash_bwd.clear_cache()
+                continue
             one = None
             for g in sweep:
                 pa._instances_per_program = \

@@ -244,12 +244,12 @@ def flash_gauges():
     metrics.enable()
     metrics.registry.clear()
 
-    def read():
+    def read(*names):
+        names = names or ("hvd_flash_instances_per_program",
+                          "hvd_flash_programs_per_call")
         snap = metrics.registry.snapshot()
-        per_program = snap.get("hvd_flash_instances_per_program", {})
-        per_call = snap.get("hvd_flash_programs_per_call", {})
-        return {kernel: (int(per_program[kernel]), int(per_call[kernel]))
-                for kernel in KERNELS if kernel in per_program}
+        return {kernel: tuple(int(snap[name][kernel]) for name in names)
+                for kernel in KERNELS if kernel in snap.get(names[0], {})}
 
     yield read
     metrics.registry.clear()
@@ -307,8 +307,8 @@ def test_instances_per_program_match_reference(monkeypatch, flash_gauges,
 # cell -> (B, H, T, block, causal) of its attention calls at head width
 # 64 in bf16, and the instances a program each kernel gets there
 CELL_SHAPES = {
-    "gpt2m_dp1": ((16, 16, 1024, 512, True), 1),
-    "gpt2m_dp4": ((16, 16, 1024, 512, True), 1),
+    "gpt2m_dp1": ((16, 16, 1024, 512, True), 2),
+    "gpt2m_dp4": ((16, 16, 1024, 512, True), 2),
     "bertl_s512": ((26, 16, 512, 512, False), 4),
     "bertl_s128": ((104, 16, 128, 128, False), 16),
 }
@@ -318,8 +318,7 @@ CELL_SHAPES = {
 def test_chooser_divides_and_stays_inside_its_limits(cell):
     (b, h, t, block, causal), expected = CELL_SHAPES[cell]
     for kernel in KERNELS:
-        gb, gh = pa._instances_per_program(
-            kernel, b, h, block, t, 64, 2, causal)
+        gb, gh = pa._instances_per_program(kernel, b, h, block, t, 64, 2)
         g = gb * gh
         assert g == expected and b % gb == 0 and h % gh == 0, (kernel, g)
         assert gb == 1 or gh == h  # consecutive instances
@@ -327,18 +326,18 @@ def test_chooser_divides_and_stays_inside_its_limits(cell):
         blocks = 2 * g * (n_own * pa._vmem_bytes(block, 64, 2)
                           + n_other * pa._vmem_bytes(t, 64, 2))
         assert blocks <= pa._VMEM_BLOCK_BUDGET, (kernel, g, blocks)
-        units = (block // 128) * (t // 128) * (1 + causal)
+        units = (block // 128) * (t // 128)
         assert g == 1 or g * units <= pa._PROGRAM_TILE_UNITS
         assert g <= pa._MOST_INSTANCES
 
 
 @pytest.mark.parametrize("why, args", [
     ("more heads than a program takes, and a prime number of them",
-     (64, 17, 128, 128, 64, 2, False)),
-    ("one masked instance is all the work a program should do",
-     (16, 16, 512, 1024, 64, 2, True)),
+     (64, 17, 128, 128, 64, 2)),
+    ("one instance is all the work a program should do",
+     (16, 16, 512, 2048, 64, 2)),
     ("one instance fills the VMEM budget",
-     (16, 16, 128, 128, 4096, 4, False)),
+     (16, 16, 128, 128, 4096, 4)),
 ])
 def test_chooser_returns_one_where_it_must(why, args):
     for kernel in KERNELS:
@@ -355,7 +354,7 @@ def test_gauges_read_what_the_chooser_chose(flash_gauges):
         q, k, v, causal=True, block_q=128, block_k=128) ** 2)))
     loss(q, k, v)
     want = {kernel: pa._instances_per_program(
-        kernel, b, h, 128, t, d, 4, True) for kernel in KERNELS}
+        kernel, b, h, 128, t, d, 4) for kernel in KERNELS}
     assert flash_gauges() == {
         kernel: (gb * gh, b * h // (gb * gh) * (t // 128))
         for kernel, (gb, gh) in want.items()}
@@ -363,3 +362,121 @@ def test_gauges_read_what_the_chooser_chose(flash_gauges):
     metrics.registry.clear()
     loss(q, k, v)  # compiled: nothing of the step writes a gauge
     assert flash_gauges() == {}
+
+
+# -- a tile pays for the mask only where the mask can be false in it --------
+#
+# `_tile_ranges` classes each program's tiles: unmasked (wholly at or
+# under the diagonal, no padded key), masked (the diagonal crosses it, or
+# it holds padded keys), or not run (wholly above the diagonal).
+
+# name -> (Tq, Tk, causal, query_offset, key_offset, block_q, block_k)
+TILE_CASES = {
+    # 1, 2 and 3 blocks a side: masked only; + an unmasked and a skipped
+    # tile; + a program with two unmasked tiles
+    "causal_1_block": (128, 128, True, 0, 0, 128, 128),
+    "causal_2_blocks": (256, 256, True, 0, 0, 128, 128),
+    "causal_3_blocks": (384, 384, True, 0, 0, 128, 128),
+    # the diagonal crosses two kv tiles of a q block / two q tiles of a kv
+    # block
+    "wide_q_block": (256, 256, True, 0, 0, 128, 64),
+    "wide_k_block": (256, 256, True, 0, 0, 64, 128),
+    # ring attention's blocks: wholly under the diagonal (no masked tile),
+    # straddling it off the tiles' corners, wholly above it (nothing runs)
+    "ring_under": (128, 256, True, 256, 0, 64, 64),
+    "ring_straddling": (192, 192, True, 96, 0, 64, 64),
+    "ring_above": (128, 128, True, 0, 128, 64, 64),
+    # padded keys: only the last kv tile is masked
+    "padded_keys": (200, 200, False, 0, 0, 128, 128),
+    "causal_padded_keys": (200, 200, True, 0, 0, 128, 128),
+    # queries that start before the keys: rows that see no key in their
+    # first tiles, or in none (the forward keeps its second select)
+    "rows_without_keys": (128, 128, True, 0, 96, 64, 64),
+}
+
+
+def _tile_case(case):
+    tq, tk, causal, q_off, k_off, block_q, block_k = TILE_CASES[case]
+    q, ct = _rand((2, tq, 2, 16), 80), _rand((2, tq, 2, 16), 83)
+    k, v = _rand((2, tk, 2, 16), 81), _rand((2, tk, 2, 16), 82)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, query_offset=q_off,
+                               key_offset=k_off, block_q=block_q,
+                               block_k=block_k)
+
+    def ref(q, k, v):
+        out = _ref_btHD(q, k, v, causal, q_off, k_off)
+        if causal:  # the reference averages V over no key
+            sees = (q_off + jnp.arange(tq) >= k_off).astype(out.dtype)
+            out = out * sees[None, :, None, None]
+        return out
+
+    return flash, ref, (q, k, v), ct
+
+
+@pytest.mark.parametrize("case", TILE_CASES)
+def test_tile_classes_match_reference(flash_gauges, case):
+    """Forward and all three gradients against the reference, whatever
+    mix of unmasked, masked and skipped tiles a program runs."""
+    flash, ref, args, ct = _tile_case(case)
+    out, vjp_f = jax.vjp(flash, *args)
+    expected, vjp_r = jax.vjp(ref, *args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
+                               atol=2e-5)
+    for a, b in zip(vjp_f(ct), vjp_r(ct)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+
+@pytest.mark.parametrize("case", TILE_CASES)
+def test_tile_classes_same_bits_as_every_tile_masked(monkeypatch,
+                                                     flash_gauges, case):
+    """What the classes leave out is work whose result is known: with
+    every tile that runs masked and the forward's second select kept
+    everywhere (the kernels of before) the results are the same bits."""
+    flash, _, args, ct = _tile_case(case)
+    out, vjp = jax.vjp(flash, *args)
+    grads = vjp(ct)
+
+    ranges = pa._tile_ranges
+
+    def every_tile_masked(*a, **kw):
+        (lo, _, _), (_, hi, _) = ranges(*a, **kw)
+        return [(lo, hi, True), (hi, hi, False)]
+
+    monkeypatch.setattr(pa, "_tile_ranges", every_tile_masked)
+    monkeypatch.setattr(pa, "_rows_may_see_no_key",
+                        lambda **geometry: True)
+    pa._flash_fwd.clear_cache()
+    pa._flash_bwd.clear_cache()
+    out_masked, vjp_masked = jax.vjp(flash, *args)
+    for a, b in zip((out, *grads), (out_masked, *vjp_masked(ct))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# name -> ((B, H, T, block, causal, query_offset), (tiles, masked tiles) a
+# call of each kernel) at head width 64 in bf16
+TILE_COUNTS = {
+    # 16 x 16 instances x 3 tiles, 2 of them on the diagonal
+    "gpt2m": ((16, 16, 1024, 512, True, 0), (768, 512)),
+    "bertl_s512": ((26, 16, 512, 512, False, 0), (416, 0)),
+    "bertl_s128": ((104, 16, 128, 128, False, 0), (1664, 0)),
+    # a ring block wholly under the diagonal: all 4 tiles, none masked
+    "ring_under": ((16, 16, 1024, 512, True, 1024), (1024, 0)),
+    # ... and one wholly above it: nothing runs
+    "ring_above": ((16, 16, 1024, 512, True, -1024), (0, 0)),
+}
+
+
+@pytest.mark.parametrize("shape", TILE_COUNTS)
+def test_gauges_count_tiles_and_boundary_tiles(flash_gauges, shape):
+    """Traced, not run: the gauges are arithmetic on the call's shapes."""
+    (b, h, t, block, causal, q_off), want = TILE_COUNTS[shape]
+    x = jax.ShapeDtypeStruct((b, t, h, 64), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=causal, query_offset=q_off, block_q=block,
+        block_k=block).astype(jnp.float32)), argnums=(0, 1, 2)), x, x, x)
+    assert flash_gauges("hvd_flash_tiles_per_call",
+                        "hvd_flash_boundary_tiles_per_call") == \
+        {kernel: want for kernel in KERNELS}
