@@ -62,9 +62,6 @@ def main(argv=None, stats=None):
     p.add_argument("--fused-ce", action="store_true",
                    help="vocab-blocked fused LM-head cross-entropy "
                         "(logits never materialize in HBM)")
-    p.add_argument("--fused-ln", action="store_true",
-                   help="pallas single-pass LayerNorm kernels "
-                        "(ops/pallas_layernorm.py)")
     p.add_argument("--zero", action="store_true",
                    help="ZeRO-1 sharded optimizer states "
                         "(hvd.ShardedOptimizer): Adam m/v split 1/N "
@@ -89,9 +86,7 @@ def main(argv=None, stats=None):
             cfg, hidden_size=args.hidden, num_heads=heads
         )
     cfg = dataclasses.replace(
-        cfg, max_seq_len=args.seq_len, remat=args.remat,
-        fused_norm=args.fused_ln,
-    )
+        cfg, max_seq_len=args.seq_len, remat=args.remat)
     attention_fn = None
     if args.flash:
         from horovod_tpu.ops.pallas_attention import make_flash_attention_fn

@@ -63,12 +63,6 @@ def main(argv=None, stats=None):
                    help="space-to-depth stem (2x2 unshuffle + 4x4/s1 "
                         "conv; the TPU MLPerf transform of the 7x7/s2 "
                         "3-channel stem). resnet family only")
-    p.add_argument("--fused-bn", action="store_true",
-                   help="pallas fused BN+relu(+residual) kernels "
-                        "(ops/pallas_batchnorm.py). resnet family only")
-    p.add_argument("--one-by-one", choices=["conv", "dot"], default="conv",
-                   help="lower 1x1 convs as convolution or channel "
-                        "matmul. resnet family only")
     p.add_argument("--bf16-allreduce", action="store_true",
                    help="bfloat16 wire compression for gradients "
                         "(the reference's --fp16-allreduce)")
@@ -87,14 +81,6 @@ def main(argv=None, stats=None):
         if not args.model.startswith("resnet"):
             raise SystemExit("--s2d-stem applies to the resnet family")
         model_kw["stem"] = "space_to_depth"
-    if args.fused_bn:
-        if not args.model.startswith("resnet"):
-            raise SystemExit("--fused-bn applies to the resnet family")
-        model_kw["fused_bn"] = True
-    if args.one_by_one != "conv":
-        if not args.model.startswith("resnet"):
-            raise SystemExit("--one-by-one applies to the resnet family")
-        model_kw["one_by_one"] = args.one_by_one
     model = model_cls(num_classes=args.num_classes, dtype=jnp.bfloat16,
                       **model_kw)
     rng = jax.random.PRNGKey(0)

@@ -192,14 +192,6 @@ class Knobs:
     # longest for backward), capping host-link traffic per step the
     # way the replicator's duty cycle caps host CPU (docs/fsdp.md).
     fsdp_offload_duty: float = 1.0
-    # Fused computation-collective Pallas backend
-    # (ops/pallas_collectives.py): quantize-in-collective int8 wire,
-    # producer pack/matmul epilogues into the reduce-scatter first hop,
-    # and the fused decode KV-append+attention kernel. Off by default:
-    # the knob-off lowering of every call site is unchanged, and values
-    # are bitwise-identical either way (docs/fused_collectives.md), so
-    # the autotuner can flip it as a pure-performance dimension.
-    fused_collectives: bool = False
 
     # --- hierarchy (operations.cc:551-565) ---
     # On TPU: "hierarchical" = reduce-scatter over ICI within a slice, then
@@ -473,7 +465,6 @@ class Knobs:
             fsdp_regather=_env_bool("FSDP_REGATHER", True),
             fsdp_offload=_env_bool("FSDP_OFFLOAD", False),
             fsdp_offload_duty=_env_float("FSDP_OFFLOAD_DUTY", 1.0),
-            fused_collectives=_env_bool("FUSED_COLLECTIVES", False),
             hierarchical_allreduce=_env_bool("HIERARCHICAL_ALLREDUCE", False),
             hierarchical_allgather=_env_bool("HIERARCHICAL_ALLGATHER", False),
             hierarchical_local_size=_env_int("HIERARCHICAL_LOCAL_SIZE", 0),

@@ -17,38 +17,9 @@ import functools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
-
-
-class ChannelDot(nn.Module):
-    """1x1 convolution expressed as a channel matmul (`dot_general` over
-    the trailing axis). Numerically identical to nn.Conv(k=(1,1)); on
-    TPU it lowers to the dot path whose prologue/epilogue fusions
-    pipeline differently from conv_general_dilated — selectable via
-    ResNet(one_by_one="dot") to pick whichever benches faster."""
-
-    features: int
-    strides: Tuple[int, int] = (1, 1)
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param(
-            "kernel",
-            nn.initializers.lecun_normal(batch_axis=(), in_axis=-2,
-                                         out_axis=-1),
-            (1, 1, x.shape[-1], self.features), jnp.float32)
-        if self.strides != (1, 1):
-            x = x[:, ::self.strides[0], ::self.strides[1], :]
-        y = jax.lax.dot_general(
-            x.astype(self.dtype),
-            kernel.reshape(x.shape[-1], self.features).astype(self.dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
-        )
-        return y
 
 
 class BottleneckBlock(nn.Module):
@@ -57,46 +28,21 @@ class BottleneckBlock(nn.Module):
     conv: ModuleDef
     norm: ModuleDef
     act: Callable
-    # 1x1 convolutions factory: (features, strides) -> module; defaults
-    # to `conv` with a (1, 1) kernel (see ResNet.one_by_one)
-    conv1x1: Optional[ModuleDef] = None
-    # fused BN+relu(+residual) epilogues (pallas kernels); when set,
-    # `norm` must be a FusedBatchNorm factory and `act` is folded in
-    fused_bn: bool = False
-
-    def _c1(self, features, strides=(1, 1), name=None):
-        if self.conv1x1 is not None:
-            return self.conv1x1(features, strides, name=name)
-        return self.conv(features, (1, 1), strides, name=name)
 
     @nn.compact
     def __call__(self, x):
         residual = x
-        if self.fused_bn:
-            y = self._c1(self.filters)(x)
-            y = self.norm(activation="relu")(y)
-            y = self.conv(self.filters, (3, 3), self.strides)(y)
-            y = self.norm(activation="relu")(y)
-            y = self._c1(self.filters * 4)(y)
-            if residual.shape[-1] != self.filters * 4 or self.strides != (
-                    1, 1):
-                residual = self._c1(
-                    self.filters * 4, self.strides,
-                    name="conv_proj")(residual)
-                residual = self.norm(name="norm_proj")(residual)
-            return self.norm(scale_init=nn.initializers.zeros,
-                             activation="relu")(y, residual=residual)
-        y = self._c1(self.filters)(x)
+        y = self.conv(self.filters, (1, 1))(x)
         y = self.norm()(y)
         y = self.act(y)
         y = self.conv(self.filters, (3, 3), self.strides)(y)
         y = self.norm()(y)
         y = self.act(y)
-        y = self._c1(self.filters * 4)(y)
+        y = self.conv(self.filters * 4, (1, 1))(y)
         y = self.norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
-            residual = self._c1(
-                self.filters * 4, self.strides, name="conv_proj"
+            residual = self.conv(
+                self.filters * 4, (1, 1), self.strides, name="conv_proj"
             )(residual)
             residual = self.norm(name="norm_proj")(residual)
         return self.act(residual + y)
@@ -129,37 +75,14 @@ class ResNet(nn.Module):
     # channels instead of 3 (a 3-channel conv leaves >95% of the lanes
     # idle)
     stem: str = "conv"
-    # pallas fused BN+relu(+residual) epilogues instead of
-    # flax.linen.BatchNorm (ops/pallas_batchnorm.py) — the BN statistics
-    # passes are the measured CNN bottleneck (docs/benchmarks.md)
-    fused_bn: bool = False
-    # "conv" lowers 1x1 convs via conv_general_dilated; "dot" via a
-    # channel matmul (ChannelDot) whose TPU fusion pipeline differs
-    one_by_one: str = "conv"
 
     @nn.compact
     def __call__(self, x, train: bool = True):
         conv = functools.partial(
             nn.Conv, use_bias=False, dtype=self.dtype, padding="SAME"
         )
-        if self.fused_bn and self.norm_cls is not None:
-            raise ValueError(
-                "fused_bn=True conflicts with norm_cls: the fused pallas "
-                "epilogues replace the norm layer entirely")
-        fused = self.fused_bn
         if self.norm_cls is not None:
             norm = functools.partial(self.norm_cls, use_running_average=not train)
-        elif fused:
-            from ..ops.pallas_batchnorm import FusedBatchNorm
-
-            norm = functools.partial(
-                FusedBatchNorm,
-                use_running_average=not train,
-                momentum=0.9,
-                epsilon=1e-5,
-                dtype=self.dtype,
-                param_dtype=jnp.float32,
-            )
         else:
             norm = functools.partial(
                 nn.BatchNorm,
@@ -180,20 +103,9 @@ class ResNet(nn.Module):
                 f"unknown stem {self.stem!r}: expected 'conv' or "
                 "'space_to_depth'"
             )
-        if fused:
-            x = norm(name="bn_init", activation="relu")(x)
-        else:
-            x = norm(name="bn_init")(x)
-            x = nn.relu(x)
+        x = norm(name="bn_init")(x)
+        x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
-        if self.one_by_one == "dot":
-            conv1x1 = functools.partial(ChannelDot, dtype=self.dtype)
-        elif self.one_by_one == "conv":
-            conv1x1 = None
-        else:
-            raise ValueError(
-                f"unknown one_by_one {self.one_by_one!r}: expected "
-                "'conv' or 'dot'")
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
@@ -203,8 +115,6 @@ class ResNet(nn.Module):
                     conv=conv,
                     norm=norm,
                     act=nn.relu,
-                    conv1x1=conv1x1,
-                    fused_bn=fused,
                 )(x)
         x = jnp.mean(x, axis=(1, 2))
         x = nn.Dense(self.num_classes, dtype=jnp.float32,

@@ -51,10 +51,6 @@ class TransformerConfig:
     remat: bool = False
     rope_theta: float = 10000.0
     layernorm_epsilon: float = 1e-5
-    # pallas single-pass norm kernels (ops/pallas_layernorm.py); XLA's
-    # standalone layernorm fusions measured ~9x off the HBM floor on the
-    # BERT-L bench (docs/benchmarks.md)
-    fused_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -120,12 +116,6 @@ class RMSNorm(nn.Module):
 
 
 def _norm(cfg: TransformerConfig, name: str):
-    if cfg.fused_norm:
-        from ..ops.pallas_layernorm import FusedLayerNorm
-
-        return FusedLayerNorm(
-            epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
-            param_dtype=jnp.float32, kind=cfg.norm, name=name)
     if cfg.norm == "rmsnorm":
         return RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
                        name=name)
@@ -236,16 +226,9 @@ class Attention(nn.Module):
                     "kv_cache decoding derives its own validity mask "
                     "from positions; an explicit padding mask is not "
                     "composable with it")
-            appender = getattr(kv_cache, "append_attend", None)
-            if appender is not None:
-                # fused append+attend (serving/decode.py): one kernel
-                # per batch row under the fused-collectives knob, the
-                # exact update + cached_attention lowering otherwise
-                out = appender(layer, q, k, v, positions)
-            else:
-                k_full, v_full, valid = kv_cache.update(
-                    layer, k, v, positions)
-                out = cached_attention(q, k_full, v_full, valid)
+            k_full, v_full, valid = kv_cache.update(
+                layer, k, v, positions)
+            out = cached_attention(q, k_full, v_full, valid)
         elif self.attention_fn is None:
             attn = functools.partial(
                 dot_product_attention, causal=cfg.causal)

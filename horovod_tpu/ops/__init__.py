@@ -46,14 +46,6 @@ from .pallas_attention import (  # noqa: F401
     flash_attention_bhtd,
     make_flash_attention_fn,
 )
-from .pallas_batchnorm import FusedBatchNorm, fused_batch_norm  # noqa: F401
-from .pallas_collectives import (  # noqa: F401
-    decode_append_attend,
-    fused_enabled,
-    matmul_reduce_scatter,
-    maybe_pack_rows,
-)
-from .pallas_layernorm import FusedLayerNorm, fused_layer_norm  # noqa: F401
 from .fused_cross_entropy import (  # noqa: F401
     fused_causal_lm_loss,
     fused_linear_cross_entropy,

@@ -53,7 +53,7 @@ _CANDIDATE_THRESHOLDS = [
 #: bump when the tunable-knob vocabulary changes meaning or shape: a
 #: cached winner from another schema generation must re-tune loudly,
 #: never be silently reused (docs/autotune.md, staleness contract)
-KNOB_SCHEMA_VERSION = 2
+KNOB_SCHEMA_VERSION = 3
 
 #: every knob any OnlineTuner dimension may pin — the schema the cache
 #: staleness check validates entries against
@@ -64,7 +64,6 @@ TUNABLE_KNOBS = (
     "hierarchical_allreduce",
     "hierarchical_local_size",
     "fsdp_prefetch",
-    "fused_collectives",
     "compression",
     "compression_block",
     "eager_fast_path_warmup",
@@ -631,13 +630,7 @@ class OnlineTuner(SPMDStepTuner):
        ``hierarchical_allreduce`` × ``hierarchical_local_size``;
     5. ``fsdp_prefetch`` (``tune_fsdp_prefetch=True``) — forward
        all-gather look-ahead depth (docs/fsdp.md);
-    6. ``fused_collectives`` (``tune_fused_collectives=True``) — the
-       fused Pallas computation-collective backend
-       (ops/pallas_collectives.py, docs/fused_collectives.md). NOT in
-       the numerics group: the fused path is bitwise-identical, so the
-       flip is pure performance — incumbent-seeded like every
-       dimension, it only pins where measured never-worse;
-    7. opt-in, NUMERICS-CHANGING (``tune_wire=True`` /
+    6. opt-in, NUMERICS-CHANGING (``tune_wire=True`` /
        ``HOROVOD_AUTOTUNE_WIRE``): wire dtype (``compression``),
        quantization block (``compression_block``), and eager fast-path
        warmup K (``eager_fast_path_warmup``). The factory must rebuild
@@ -668,7 +661,6 @@ class OnlineTuner(SPMDStepTuner):
         hier_blocks: Optional[List[int]] = None,
         tune_fsdp_prefetch: bool = False,
         prefetch_depths: Optional[List[int]] = None,
-        tune_fused_collectives: bool = False,
         tune_wire: Optional[bool] = None,
         wire_candidates: Optional[List[str]] = None,
         block_candidates: Optional[List[int]] = None,
@@ -697,7 +689,6 @@ class OnlineTuner(SPMDStepTuner):
         self._tune_fsdp = tune_fsdp_prefetch
         self._prefetch_depths = (list(prefetch_depths) if prefetch_depths
                                  else [0, 1, 2])
-        self._tune_fused = tune_fused_collectives
         self._block_candidates = (list(block_candidates)
                                   if block_candidates else [128, 256, 512])
         self._warmup_ks = (list(warmup_k_candidates)
@@ -723,8 +714,6 @@ class OnlineTuner(SPMDStepTuner):
             keys += ["hierarchical_allreduce", "hierarchical_local_size"]
         if self._tune_fsdp:
             keys.append("fsdp_prefetch")
-        if self._tune_fused:
-            keys.append("fused_collectives")
         if self._tune_wire:
             keys += ["compression", "compression_block",
                      "eager_fast_path_warmup"]
@@ -752,11 +741,6 @@ class OnlineTuner(SPMDStepTuner):
             yield ("fsdp_prefetch",
                    [{"fsdp_prefetch": d} for d in self._prefetch_depths
                     if d != best["fsdp_prefetch"]])
-        if self._tune_fused:
-            # bitwise-equal backends, so the single flip candidate is a
-            # pure latency race against the incumbent
-            yield ("fused_collectives",
-                   [{"fused_collectives": not best["fused_collectives"]}])
         if self._tune_wire:
             yield ("compression",
                    [{"compression": w} for w in self._wire_candidates

@@ -114,30 +114,6 @@ def _outer_wire_sum(rs, outer_ax, groups, n_outer: int, wire, residual):
     if residual is not None:
         flat = flat + residual.astype(jnp.float32).reshape(-1)[:L]
     padded = _comp._pad_flat(flat, wire.block)
-    from . import pallas_collectives as _pc
-
-    if _pc.fused_enabled():
-        # fused DCN leg (docs/fused_collectives.md): quantize/EF and
-        # the local dequant-accumulate run as Pallas kernels around the
-        # same gathers — bitwise-identical sum and residual
-        m = padded.shape[0]
-        row = padded.reshape(1, m)
-        if residual is None:
-            q2, s2 = _pc._quantize_rows(row, wire.block)
-            err2 = None
-        else:
-            q2, s2, err2 = _pc._quantize_ef_rows(row, wire.block)
-        qg = lax.all_gather(q2.reshape(-1), outer_ax,
-                            axis_index_groups=groups)
-        sg = lax.all_gather(s2.reshape(-1), outer_ax,
-                            axis_index_groups=groups)
-        acc = _pc._accum_rows(qg.reshape(n_outer, m),
-                              sg.reshape(n_outer, m // wire.block),
-                              wire.block)
-        y = acc[:L].reshape(rs.shape).astype(rs.dtype)
-        if residual is None:
-            return y
-        return y, err2.reshape(-1)[:L].reshape(rs.shape)
     q, s = _comp.quantize_blocks(padded, wire.block)
     # the DCN leg: quantized shards + scales, gathered (not reduced) —
     # each rank dequant-accumulates the n_outer contributions locally

@@ -494,12 +494,7 @@ def _run_staged(stages: Sequence[Stage], params, info: dict, mode: str,
                     res_bucket=r_b)
                 new_res_buckets[bi] = new_r
             else:
-                # pack epilogue: fused Pallas layout kernel when the
-                # fused-collectives knob is on (ops/pallas_collectives),
-                # zero._pad_rows (unchanged lowering) when off
-                from . import pallas_collectives as _pc
-
-                rows = _pc.maybe_pack_rows(bucket, n)
+                rows = zero_mod._pad_rows(bucket, n)
                 red = zero_mod._scatter_bucket(rows, ax, n, wire)
                 token = red
             reduced[bi] = red
@@ -596,7 +591,7 @@ def fsdp_staged_value_and_grad(stages_fn: Callable, opt,
     — no vjp residual captures gathered weights — and the backward
     re-issues each bucket's all-gather at its backward-first-use
     boundary (fusion.bucket_regather_schedule), pinned behind the
-    incoming cotangent, then runs the IDENTICAL pack → maybe_pack_rows
+    incoming cotangent, then runs the IDENTICAL pack → zero._pad_rows
     → zero._scatter_bucket chain, so values stay bitwise the
     saved-gather mode's on plain and int8+error-feedback wires while
     within-step peak param liveness drops to sharded + the bucket
@@ -779,9 +774,7 @@ def _run_fsdp_staged(stages: Sequence[Stage], layout, rows, info: dict,
                 bool(jnp.issubdtype(bucket.dtype, jnp.floating)))
             if ordered and chain is not None:
                 bucket = _barrier_pair(bucket, chain)
-            from . import pallas_collectives as _pc
-
-            rows_b = _pc.maybe_pack_rows(bucket, n)
+            rows_b = zero_mod._pad_rows(bucket, n)
             if ef:
                 red, nr = zero_mod._scatter_bucket(
                     rows_b, ax, n, wire, residual=res_mats[bi])
@@ -863,7 +856,7 @@ def _run_fsdp_regather(stages: Sequence[Stage], layout, rows,
     (fusion.bucket_regather_schedule; pinned behind the incoming
     cotangent so no scheduler may hoist it into forward), rebuilding
     that segment's vjp against the freshly gathered rows, and feeding
-    the resulting bucket through the IDENTICAL pack → maybe_pack_rows
+    the resulting bucket through the IDENTICAL pack → zero._pad_rows
     → zero._scatter_bucket chain as the saved-gather path. The LAST
     stage is the forward/backward boundary itself: its vjp is built
     once at backward step 0 and its primal output is the returned loss
@@ -1094,9 +1087,7 @@ def _run_fsdp_regather(stages: Sequence[Stage], layout, rows,
                 bool(jnp.issubdtype(bucket.dtype, jnp.floating)))
             if ordered and chain is not None:
                 bucket = _barrier_pair(bucket, chain)
-            from . import pallas_collectives as _pc
-
-            rows_b = _pc.maybe_pack_rows(bucket, n)
+            rows_b = zero_mod._pad_rows(bucket, n)
             if ef:
                 red, nr = zero_mod._scatter_bucket(
                     rows_b, ax, n, wire, residual=res_mats[bi])

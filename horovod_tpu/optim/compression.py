@@ -131,57 +131,31 @@ def _check_block(block, length: int, what: str) -> int:
     return block
 
 
-# -- shape-polymorphic block math -------------------------------------------
-#
-# The single source of truth for the int8 wire format: these helpers
-# take an array whose LAST axis is the quantization block and work for
-# any leading shape, so the XLA collectives below and the Pallas kernel
-# bodies (ops/pallas_collectives.py) run literally the same expressions
-# — which is what makes fused-vs-unfused parity bitwise rather than
-# approximate.
-
-def block_scales(blocks):
-    """Per-block symmetric scales for a ``(..., block)`` f32 array:
-    ``amax/127``, with all-zero blocks pinned to 1 so the divide is
-    always defined. Returns shape ``(...,)``.
-
-    Written as a multiply by the reciprocal constant, NOT ``amax /
-    127.0``: XLA rewrites constant-divisor division to a reciprocal
-    multiply inside compiled (Pallas) programs but not in the op-by-op
-    path, so the division form would put the XLA and kernel paths one
-    ulp apart on ~4% of blocks and break fused-vs-unfused bitwise
-    parity. The multiply is correctly rounded and identical everywhere.
-    """
-    amax = jnp.max(jnp.abs(blocks), axis=-1)
-    return jnp.where(amax > 0, amax * (1.0 / 127.0), 1.0)
-
-
-def block_quantize(blocks) -> Tuple:
-    """Quantize a ``(..., block)`` f32 array to ``(q int8 (..., block),
-    scales f32 (...))`` with ``x ≈ q * scale`` per block."""
-    scale = block_scales(blocks)
-    q = jnp.clip(jnp.round(blocks / scale[..., None]),
-                 -127, 127).astype(jnp.int8)
-    return q, scale
-
-
-def block_dequantize(q, scales):
-    """Inverse of :func:`block_quantize` (f32, same shape as ``q``)."""
-    return q.astype(jnp.float32) * scales.astype(jnp.float32)[..., None]
-
-
 def quantize_blocks(flat, block: int) -> Tuple:
     """Per-block symmetric int8 quantization of a 1-D float array whose
     length is a multiple of `block`. Returns ``(q int8 [m], scales f32 [m/block])``
     with ``x ≈ q * scale`` per block; all-zero blocks get scale 1 so the
-    divide is always defined."""
-    q, scale = block_quantize(flat.astype(jnp.float32).reshape(-1, block))
+    divide is always defined.
+
+    The scale is ``amax * (1.0 / 127.0)``, NOT ``amax / 127.0``: XLA
+    rewrites constant-divisor division to a reciprocal multiply inside
+    compiled programs but not in the op-by-op path, so the division
+    form would put a jitted and an eager quantization one ulp apart on
+    ~4% of blocks. The multiply is correctly rounded and identical
+    everywhere.
+    """
+    blocks = flat.astype(jnp.float32).reshape(-1, block)
+    amax = jnp.max(jnp.abs(blocks), axis=-1)
+    scale = jnp.where(amax > 0, amax * (1.0 / 127.0), 1.0)
+    q = jnp.clip(jnp.round(blocks / scale[..., None]),
+                 -127, 127).astype(jnp.int8)
     return q.reshape(-1), scale
 
 
 def dequantize_blocks(q, scales, block: int):
     """Inverse of :func:`quantize_blocks` (float32 output)."""
-    return block_dequantize(q.reshape(-1, block), scales).reshape(-1)
+    blocks = q.reshape(-1, block).astype(jnp.float32)
+    return (blocks * scales.astype(jnp.float32)[..., None]).reshape(-1)
 
 
 def quantize_dequantize(x, block: int = DEFAULT_BLOCK):
@@ -393,15 +367,6 @@ def quantized_psum(x, axis: str, n: int, block: int = DEFAULT_BLOCK,
     padded = _pad_flat(flat, n * int(block))
     m = padded.shape[0]
     block = _check_block(block, m, "quantized_psum")
-    from ..ops import pallas_collectives as _pc
-
-    if _pc.fused_enabled():
-        # compiled backend: the quantize/EF, dequant-accumulate and
-        # final dequant stages run as Pallas kernels around the same
-        # lax exchanges — same block math (the shared helpers above),
-        # bitwise-identical values (docs/fused_collectives.md)
-        return _pc.fused_quantized_psum(x, axis, n, block,
-                                        residual=residual)
     q, s = quantize_blocks(padded, block)
     # tiled all_to_all on the flat payload: chunk j of ours goes to rank
     # j; we receive every rank's chunk `rank` back-to-back. Scales ride
@@ -459,15 +424,6 @@ def quantized_reduce_scatter_rows(rows, axis: str,
     rows_f = rows.astype(jnp.float32)
     if residual is not None:
         rows_f = rows_f + residual.astype(jnp.float32)
-    from ..ops import pallas_collectives as _pc
-
-    if _pc.fused_enabled():
-        # compiled backend (docs/fused_collectives.md): quantize+EF and
-        # dequant-accumulate run as Pallas kernels around the same
-        # tiled all_to_all — bitwise-identical shard and residual
-        return _pc.fused_quantized_reduce_scatter_rows(
-            rows_f, axis, n, k, k2, block,
-            with_residual=residual is not None)
     q, s = quantize_blocks(rows_f.reshape(-1), block)
     # row-major layout: row r occupies [r*k2, (r+1)*k2) and block
     # divides k2, so blocks never straddle rows and the tiled all_to_all

@@ -520,11 +520,9 @@ def fsdp_value_and_grad(stages_fn, opt, layout: FsdpLayout,
                 "state; pass opt_state= so the residual rides the "
                 "quantized reduce-scatters (docs/fsdp.md)")
         ordered = (global_state().knobs.ordered_buckets and len(gb) > 1)
-        from ..ops import pallas_collectives as _pc
-
         reduced, new_res, prev = [], [], None
         for bi, b in enumerate(gb):
-            rws = _pc.maybe_pack_rows(b, n)
+            rws = zero_mod._pad_rows(b, n)
             if ordered and prev is not None:
                 rws, _ = jax.lax.optimization_barrier((rws, prev))
             if ef:

@@ -251,13 +251,9 @@ def ShardedOptimizer(optimizer, axis_name=None,
                     else compression)
             wire = compressor_wire_spec(comp)
 
-            from ..ops import pallas_collectives as _pc
-
             g_shards, prev = [], None
             for b in gb:
-                # gradient pack epilogue: fused Pallas layout kernel
-                # under the fused-collectives knob, _pad_rows otherwise
-                rows = _pc.maybe_pack_rows(b, n)
+                rows = _pad_rows(b, n)
                 if ordered and prev is not None:
                     rows, _ = jax.lax.optimization_barrier((rows, prev))
                 s = _scatter_bucket(rows, ax, n, wire)

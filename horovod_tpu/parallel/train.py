@@ -172,16 +172,6 @@ def tune_lm_train_step(
             jnp.ones((1, cfg.max_seq_len), jnp.int32))["params"])
     fingerprint = model_fingerprint(abs_params)
     if tuner is None:
-        if "tune_fused_collectives" not in tuner_kwargs:
-            # a run that enables the fused Pallas collective backend
-            # (HOROVOD_FUSED_COLLECTIVES) gets the tuner's
-            # fused_collectives dimension automatically: the backends
-            # are bitwise-equal, so the incumbent-seeded flip can only
-            # back the fused path out where it measures slower
-            from ..core.state import global_state
-
-            if getattr(global_state().knobs, "fused_collectives", False):
-                tuner_kwargs["tune_fused_collectives"] = True
         tuner = autotune_mod.OnlineTuner(**tuner_kwargs)
 
     def build_step(overrides):

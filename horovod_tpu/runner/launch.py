@@ -162,16 +162,6 @@ def parse_args(argv: Optional[List[str]] = None):
                         "earliest stages first — bound the host PCIe "
                         "duty cycle when full offload would not hide "
                         "under compute")
-    p.add_argument("--fused-collectives", dest="fused_collectives",
-                   choices=["0", "1"],
-                   help="fused computation-collective Pallas backend "
-                        "(HOROVOD_FUSED_COLLECTIVES, "
-                        "docs/fused_collectives.md): 1 runs the int8 "
-                        "wire's quantize/error-feedback/accumulate, "
-                        "the bucket pack epilogue and the decode "
-                        "KV-append+attention as Pallas kernels — "
-                        "bitwise-identical values, fewer programs "
-                        "around each collective; default 0")
     p.add_argument("--compression-wire-dtype",
                    dest="compression_wire_dtype",
                    choices=["bfloat16", "float16"])

@@ -38,7 +38,6 @@ ARG_TO_ENV = {
     "fsdp_regather": "HOROVOD_FSDP_REGATHER",
     "fsdp_offload": "HOROVOD_FSDP_OFFLOAD",
     "fsdp_offload_duty": "HOROVOD_FSDP_OFFLOAD_DUTY",
-    "fused_collectives": "HOROVOD_FUSED_COLLECTIVES",
     "hierarchical_allreduce": "HOROVOD_HIERARCHICAL_ALLREDUCE",
     "hierarchical_allgather": "HOROVOD_HIERARCHICAL_ALLGATHER",
     "hierarchical_local_size": "HOROVOD_HIERARCHICAL_LOCAL_SIZE",

@@ -268,21 +268,6 @@ class SlottedKVCache:
         valid = m_idx[None, None, :] <= positions[:, :, None]  # [B,T,M]
         return outs[0], outs[1], valid
 
-    def append_attend(self, layer: int, q, k_new, v_new, positions):
-        """Fused append + attention (the model's kv_cache fast path,
-        models/transformer.Attention): merge the new K/V rows into
-        ``layer`` and return the attention output ``[B, T, H, D]`` in
-        one step. With HOROVOD_FUSED_COLLECTIVES this runs the Pallas
-        append+attend kernel (ops/pallas_collectives.py) — int8
-        quantize-on-write, merge, dequantize and attention in one
-        kernel per batch row; otherwise it is exactly
-        :meth:`update` + ``cached_attention`` (unchanged lowering).
-        Either way the buffers are rebound like :meth:`update`."""
-        from ..ops import pallas_collectives as _pc
-
-        return _pc.decode_append_attend(self, layer, q, k_new, v_new,
-                                        positions)
-
 
 # ---------------------------------------------------------------------------
 # checkpoint metadata <-> TransformerConfig

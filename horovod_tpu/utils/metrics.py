@@ -976,24 +976,6 @@ def record_wire_bytes(logical: int, sent: int) -> None:
     step_stats.add_wire(int(logical), int(sent))
 
 
-def record_fused_collective(surface: str) -> None:
-    """One fused Pallas computation-collective lowering
-    (ops/pallas_collectives.py). Recorded at TRACE time — a breadcrumb
-    of which fused surfaces this process compiled, not a per-step
-    counter: the per-step wire/step accounting is unchanged by fusion
-    (the fused path moves the same bytes), so those gauges keep
-    reporting through the existing hvd_step_* / hvd_wire_* families."""
-    if not _enabled:
-        return
-    registry.gauge(
-        "hvd_fused_collectives_enabled",
-        "1 when the fused Pallas collective backend is selected").set(1)
-    registry.counter(
-        "hvd_fused_collective_lowerings_total",
-        "Fused computation-collective kernels lowered, by surface",
-        labelnames=("surface",)).labels(surface=surface).inc()
-
-
 def record_flash_programs(kernel: str, instances_per_program: int,
                           programs: int, tiles: int,
                           boundary_tiles: int) -> None:

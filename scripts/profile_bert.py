@@ -23,7 +23,7 @@ def main(argv=None):
                         "(bert_pretraining | gpt2_pretraining)")
     p.add_argument("--extra", default="--flash",
                    help="comma-separated flags forwarded to "
-                        "bert_pretraining, e.g. --extra=--flash,--fused-ln")
+                        "bert_pretraining, e.g. --extra=--flash,--fused-ce")
     args = p.parse_args(argv)
 
     bert = load_example(args.example)

@@ -43,7 +43,6 @@ def main(argv=None):
     p.add_argument("--logdir", default="/tmp/xplane_cnn")
     p.add_argument("--steps", type=int, default=3)
     p.add_argument("--s2d-stem", action="store_true")
-    p.add_argument("--fused-bn", action="store_true")
     args = p.parse_args(argv)
 
     hvd.init()
@@ -51,12 +50,9 @@ def main(argv=None):
     n = hvd.size()
 
     model_cls, size = _MODELS[args.model]
-    if (args.s2d_stem or args.fused_bn) and not args.model.startswith(
-            "resnet"):
-        raise SystemExit("--s2d-stem/--fused-bn apply to the resnet family")
+    if args.s2d_stem and not args.model.startswith("resnet"):
+        raise SystemExit("--s2d-stem applies to the resnet family")
     kw = {"stem": "space_to_depth"} if args.s2d_stem else {}
-    if args.fused_bn:
-        kw["fused_bn"] = True
     model = model_cls(num_classes=1000, dtype=jnp.bfloat16, **kw)
     rng = jax.random.PRNGKey(0)
     xb = np.random.rand(args.batch_size * n, size, size, 3).astype(np.float32)

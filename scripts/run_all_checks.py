@@ -61,14 +61,6 @@ locally before the full pytest tier:
   ``hvd_alert_active`` gauge fires then clears on the aggregated
   scrape, the incident JSONL carries the fire/clear pair, and the
   anomaly-triggered flight dump lands on the sink);
-* ``fused`` — ``scripts/fused_check.py --check`` (the fused
-  computation-collective backend, ops/pallas_collectives.py: fp32
-  fused reduce-scatter bitwise vs unfused, int8+EF reduce-scatter and
-  psum carry identical residual trajectories, fused decode
-  append+attend bitwise on fp32 and int8 KV, the
-  HOROVOD_FUSED_COLLECTIVES knob inert-off by lowering hash, and the
-  loopback exposed-wire A/B + autotune never-worse selection written
-  to ``FUSED_AB_r09.json``);
 * ``perf`` — ``scripts/perf_baseline.py --check`` (the perf-regression
   gate: structural invariants — fast-path engaged, zero steady
   negotiated bytes, profiler sampled + attributed inside its duty
@@ -231,7 +223,7 @@ def check_overlap():
 
 
 def check_fsdp():
-    """The fully-sharded-parameter gate (10th): parity vs the gathered
+    """The fully-sharded-parameter gate: parity vs the gathered
     reference AND regather-vs-saved, pin structure both directions,
     memory bound, peak-liveness proof, offload smoke, knob hashes."""
     env = _env()
@@ -247,7 +239,7 @@ def check_fsdp():
 
 
 def check_autotune():
-    """The closed-loop autotuner gate (11th): agreement, never-worse,
+    """The closed-loop autotuner gate: agreement, never-worse,
     warm start, pin-then-rebuild determinism, decision trail."""
     env = _env()
     if "xla_force_host_platform_device_count" not in env.get(
@@ -262,7 +254,7 @@ def check_autotune():
 
 
 def check_decode():
-    """The continuous-batching decode gate (12th): parity, int8 KV
+    """The continuous-batching decode gate: parity, int8 KV
     tolerance, >= 2x over static batching, autoscale grow/drain."""
     env = _env()
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
@@ -273,7 +265,7 @@ def check_decode():
 
 
 def check_multipod():
-    """The multi-pod federation gate (13th): relay fan-in reduction,
+    """The multi-pod federation gate: relay fan-in reduction,
     localK convergence envelope, K=1 bitwise parity, root failover
     with relays attached."""
     env = _env()
@@ -289,29 +281,12 @@ def check_multipod():
 
 
 def check_health():
-    """The fleet-health monitor gate (14th): live straggler naming,
+    """The fleet-health monitor gate: live straggler naming,
     alert fire/clear, incident records, anomaly-triggered capture."""
     return _run([
         sys.executable, os.path.join(_SCRIPTS, "health_check.py"),
         "--check",
     ])
-
-
-def check_fused():
-    """The fused computation-collective gate (15th): interpret-mode
-    bitwise parity on every fused surface, knob-off lowering inertness,
-    and the loopback exposed-wire A/B artifact FUSED_AB_r09.json."""
-    env = _env()
-    if "xla_force_host_platform_device_count" not in env.get(
-            "XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count=8"
-                            ).strip()
-    env.pop("HOROVOD_FUSED_COLLECTIVES", None)
-    return _run([
-        sys.executable, os.path.join(_SCRIPTS, "fused_check.py"),
-        "--check",
-    ], env=env)
 
 
 def check_perf():
@@ -344,7 +319,6 @@ GATES = [
     ("decode", check_decode),
     ("multipod", check_multipod),
     ("health", check_health),
-    ("fused", check_fused),
     ("perf", check_perf),
 ]
 

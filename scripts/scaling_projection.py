@@ -237,11 +237,6 @@ def main(argv=None):
                          "replaces the unscheduled one in a second "
                          "projection (default: newest in repo root)")
     ap.add_argument("--out", default="SCALING_PROJECTION_r08.json")
-    ap.add_argument("--fused-artifact", default="",
-                    help="FUSED_AB_*.json from fused_check.py: its "
-                         "loopback exposed-wire delta scales the "
-                         "256-chip exposed time in a fused-wire row "
-                         "(default: newest in repo root)")
     ap.add_argument("--multipod-out", default="",
                     help="also write the N-pod DCN-tier projection "
                          "(MULTIPOD_PROJECTION_r01.json): sync vs "
@@ -398,48 +393,6 @@ def main(argv=None):
     step_s = MODELS["bert-large"]["batch_tokens_per_chip"] / rate
     out["models"]["bert-large"] = _model_block(
         step_s, MODELS["bert-large"]["params"] * 4)
-
-    # fused computation-collective backend (docs/fused_collectives.md):
-    # fold the measured loopback exposed-wire delta into the 256-chip
-    # rows — the Pallas fused kernels shrink the exposed wire around
-    # each collective (FUSED_AB exposed_wire_frac_proxy, unfused vs
-    # fused), scaling the projected exposed time by the same factor
-    fused_path = args.fused_artifact
-    if not fused_path:
-        cands = sorted(f for f in os.listdir(root)
-                       if f.startswith("FUSED_AB_")
-                       and f.endswith(".json"))
-        fused_path = os.path.join(root, cands[-1]) if cands else ""
-    if fused_path and os.path.exists(fused_path):
-        with open(fused_path) as f:
-            fab = json.load(f)
-        runs = fab.get("runs", [])
-        off_r = next((r for r in runs if not r.get("fused")), None)
-        on_r = next((r for r in runs if r.get("fused")), None)
-        if off_r and on_r and off_r.get("exposed_wire_frac_proxy"):
-            scale = (on_r["exposed_wire_frac_proxy"]
-                     / off_r["exposed_wire_frac_proxy"])
-            for block in out["models"].values():
-                step_ms = block["step_ms_per_chip"]
-                for key in ("projection", "projection_scheduled"):
-                    r256 = next((r for r in block.get(key) or []
-                                 if r["chips"] == 256), None)
-                    if r256 is None:
-                        continue
-                    t_exp = r256["t_exposed_ms"] * scale
-                    r256["fused_wire"] = {
-                        "t_exposed_ms": round(t_exp, 3),
-                        "efficiency": round(
-                            step_ms / (step_ms + t_exp), 4),
-                    }
-            out["inputs"]["fused_wire_source"] = (
-                f"{os.path.basename(fused_path)}: loopback "
-                f"exposed_wire_frac_proxy "
-                f"{off_r['exposed_wire_frac_proxy']} unfused -> "
-                f"{on_r['exposed_wire_frac_proxy']} fused (x"
-                f"{round(scale, 4)} on the projected 256-chip exposed "
-                f"wire; CPU loopback proxy — TPU-hardware validation "
-                f"still pending)")
 
     txt = json.dumps(out, indent=1)
     print(txt)

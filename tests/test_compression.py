@@ -135,6 +135,124 @@ def test_quantized_psum_close_to_psum(hvd8):
     assert not np.array_equal(q, exact)  # it really quantized
 
 
+# An independent model of the int8 exchange, NumPy float32 throughout:
+# per-block symmetric quantization with float32 scales, the exchange as
+# plain indexing over the ranks, dequantize-accumulate in rank order.
+_F32 = np.float32
+
+
+def _np_quantize(flat, block):
+    blocks = flat.reshape(-1, block)
+    amax = np.abs(blocks).max(axis=1)
+    scale = np.where(amax > 0, amax * _F32(1.0 / 127.0),
+                     _F32(1.0)).astype(_F32)
+    q = np.clip(np.rint(blocks / scale[:, None]), -127, 127)
+    return q.astype(np.int8).reshape(-1), scale
+
+
+def _np_dequantize(q, scale, block):
+    return (q.reshape(-1, block).astype(_F32)
+            * scale[:, None]).reshape(-1)
+
+
+def _np_accumulate(parts):
+    acc = np.zeros_like(parts[0])
+    for part in parts:
+        acc = acc + part
+    return acc
+
+
+def _np_quantized_psum(xs, block, residual):
+    """xs, residual: (ranks, L). Returns (y (L,), new residual)."""
+    n, L = xs.shape
+    m = -(-L // (n * block)) * n * block
+    padded = np.zeros((n, m), _F32)
+    padded[:, :L] = xs if residual is None else xs + residual
+    sent = [_np_quantize(padded[r], block) for r in range(n)]
+    deq = np.stack([_np_dequantize(q, s, block) for q, s in sent])
+    chunk = m // n
+    gathered = []
+    for j in range(n):  # rank j reduces chunk j of every rank
+        shard = _np_accumulate(
+            [deq[r, j * chunk:(j + 1) * chunk] for r in range(n)])
+        gathered.append(_np_dequantize(*_np_quantize(shard, block),
+                                       block))
+    return np.concatenate(gathered)[:L], (padded - deq)[:, :L]
+
+
+def _np_quantized_reduce_scatter_rows(rows, block, residual):
+    """rows: (ranks, n, k); residual: (ranks, n, k2). Returns the
+    (ranks, k) shards, rank r holding the sum of every rank's row r,
+    and the new residual."""
+    n, _, k = rows.shape
+    k2 = -(-k // block) * block
+    padded = np.zeros((n, n, k2), _F32)
+    padded[:, :, :k] = rows
+    if residual is not None:
+        padded = padded + residual
+    deq = np.stack([
+        _np_dequantize(*_np_quantize(padded[p].reshape(-1), block),
+                       block).reshape(n, k2) for p in range(n)])
+    shards = np.stack([
+        _np_accumulate([deq[p, r] for p in range(n)])
+        for r in range(n)])
+    return shards[:, :k], padded - deq
+
+
+@pytest.mark.parametrize("block", [64, 256])
+@pytest.mark.parametrize("with_residual", [False, True])
+@pytest.mark.parametrize("exchange", ["psum", "reduce_scatter_rows"])
+def test_int8_exchange_matches_numpy_model(hvd8, exchange,
+                                           with_residual, block):
+    """Three steps of each int8 collective on the 8-device world against
+    the NumPy model above, the residual (when carried) fed from one
+    step into the next on both sides. A code that differs by one is an
+    error of amax/127 ~ 0.1 here; float32 rounding is ~1e-6."""
+    mesh = hvd.mesh()
+    rng = np.random.RandomState(20)
+    if exchange == "psum":
+        shape, res_shape = (8, 1000), (8, 1000)
+
+        def body(x, r):
+            out = comp.quantized_psum(
+                x[0], "hvd", 8, block,
+                residual=r[0] if with_residual else None)
+            y, nr = out if with_residual else (out, r[0])
+            return y, nr[None]
+
+        out_specs = (P(), P("hvd"))
+        model = _np_quantized_psum
+    else:
+        k2 = -(-300 // block) * block
+        shape, res_shape = (8, 8, 300), (8, 8, k2)
+
+        def body(x, r):
+            out = comp.quantized_reduce_scatter_rows(
+                x[0], "hvd", block,
+                residual=r[0] if with_residual else None)
+            y, nr = out if with_residual else (out, r[0])
+            return y[None], nr[None]
+
+        out_specs = (P("hvd"), P("hvd"))
+        model = _np_quantized_reduce_scatter_rows
+    step = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=(P("hvd"), P("hvd")),
+        out_specs=out_specs, check_vma=False))
+    res = np.zeros(res_shape, _F32)
+    want_res = res.copy() if with_residual else None
+    for _ in range(3):
+        x = rng.uniform(-2, 2, shape).astype(_F32)
+        y, res = step(x, res)
+        want_y, new_res = model(x, block, want_res)
+        np.testing.assert_allclose(np.asarray(y), want_y, rtol=0,
+                                   atol=1e-4)
+        if with_residual:
+            want_res = new_res
+            np.testing.assert_allclose(np.asarray(res), want_res,
+                                       rtol=0, atol=1e-5)
+            assert np.abs(want_res).max() > 1e-4  # something is carried
+
+
 def test_hierarchical_outer_int8_close_to_flat(hvd8):
     """Outer-hop-only compression: ICI legs full precision, DCN leg
     quantized — the result stays within one quantization stage of the

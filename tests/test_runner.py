@@ -1,6 +1,7 @@
 """Launcher-layer tests (tier-1; reference test/single/test_run.py pattern:
 command/env construction with injected exec, no real ssh)."""
 
+import functools
 import os
 import threading
 import time
@@ -239,6 +240,59 @@ def test_config_file(tmp_path):
     assert args.cycle_time_ms == 2.5
     assert args.autotune is True
     assert args.log_level == "DEBUG"  # CLI beats config file
+
+
+@functools.lru_cache(maxsize=None)
+def _sources_reading_env():
+    """Every module of the package but the two that only declare the
+    launcher's options (read once for all the cases below)."""
+    import pathlib
+
+    root = pathlib.Path(launch.__file__).resolve().parents[1]
+    skip = {root / "runner" / "launch.py",
+            root / "runner" / "util" / "config_parser.py"}
+    return {str(f.relative_to(root)): f.read_text()
+            for f in sorted(root.rglob("*.py")) if f not in skip}
+
+
+@pytest.mark.parametrize("dest", sorted(config_parser.ARG_TO_ENV))
+def test_launcher_option_is_declared_and_read(dest):
+    """A knob lives in four files (core/knobs.py field and from_env,
+    config_parser.ARG_TO_ENV, launch.py): one removed from some of them
+    and not the others fails here by name. The environment name must be
+    read by the package (knobs._env takes it without its prefix) and
+    launch.py's parser must have the option."""
+    import re
+
+    var = config_parser.ARG_TO_ENV[dest]
+    assert var.startswith("HOROVOD_")
+    names = "|".join(re.escape(n) for n in (var, var[len("HOROVOD_"):]))
+    quoted = re.compile(r"""["'](?:%s)["']""" % names)
+    readers = [f for f, text in _sources_reading_env().items()
+               if quoted.search(text)]
+    assert readers, f"{var} ({dest}) is set by the launcher, read by nothing"
+    args = launch.parse_args(["-np", "1", "python", "t.py"])
+    assert hasattr(args, dest), f"launch.py has no option for {dest}"
+
+
+@pytest.mark.parametrize("example, flag", [
+    (None, "--fused-collectives=1"),
+    ("bert_pretraining", "--fused-ln"),
+    ("resnet50_synthetic", "--fused-bn"),
+    ("resnet50_synthetic", "--one-by-one=dot"),
+])
+def test_removed_flag_is_unknown(example, flag, capsys):
+    """The Pallas families PR 29 deleted left no option behind: their
+    flags are refused by argparse, before anything is initialised."""
+    from horovod_tpu.utils.script_loader import load_example
+
+    with pytest.raises(SystemExit) as e:
+        if example is None:
+            launch.parse_args([flag, "-np", "1", "python", "t.py"])
+        else:
+            load_example(example).main([flag])
+    assert e.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err
 
 
 # ---------------------------------------------------------------- launch
