@@ -7,14 +7,21 @@ dtype/device, fused size ≤ HOROVOD_FUSION_THRESHOLD), and the batched D2D
 scatter/gather CUDA kernels (cuda/cuda_kernels.cu:48-260).
 
 TPU-native shape: fusion is *compile-time packing*, not a runtime buffer.
-Tensors are flattened, grouped by dtype, concatenated into buckets bounded
-by the fusion threshold, one XLA collective runs per bucket, and the
-results are sliced back out. XLA fuses the pack/unpack copies into the
-collective's prologue/epilogue (the role of batched_memcpy_k) and its own
-all-reduce combiner can further merge buckets; keeping the bucket structure
-anyway (a) bounds collective latency for overlap, (b) gives the autotuner
-a knob (ops/autotune.py), exactly the role HOROVOD_FUSION_THRESHOLD plays
-in the reference.
+Tensors are grouped by dtype into buckets bounded by the fusion threshold
+and one XLA collective runs per bucket. What is packed depends on who
+reduces the bucket. A reduce-scatter, a quantised wire or Adasum needs one
+contiguous array: leaves are flattened, concatenated and sliced back out
+(pack_pytree_by_plan). The packing is not free: on the chip a matrix and
+a 1-D array of its elements are tiled differently, so every flatten and
+every slice is a relayout copy, and GPT-2-medium's 1.4 GB of gradients
+spent 21.8 ms a step in them (PERF.md section 5). The plain all-reduce
+therefore takes a bucket as a GROUP of arrays (pack_groups_by_plan): a
+large leaf rides it in its own shape, as the reference sends a tensor over
+the threshold alone and uncopied, and only the small leaves, the ones
+fusion exists for, share a packed operand. The bucket structure (a) keeps
+collectives separate and ordered, (b) gives the autotuner a knob
+(ops/autotune.py), exactly the role HOROVOD_FUSION_THRESHOLD plays in the
+reference.
 """
 
 from __future__ import annotations
@@ -85,7 +92,8 @@ def _record_fusion(n_tensors: int, n_buckets: int, threshold: int,
                    bucket_bytes: Sequence[int] = ()) -> None:
     """Timeline instant marking a (compile-time) fusion plan — the analog
     of the reference's MEMCPY_IN/OUT_FUSION_BUFFER runtime phases, which
-    XLA absorbs into the collective's prologue/epilogue here. Also feeds
+    here are copies inside the compiled step (`hvd_pack`, `hvd_unpack`).
+    Also feeds
     the live telemetry (utils/metrics.py): plan/bucket counters + the
     fill-ratio histogram from per-bucket byte totals."""
     from ..utils import metrics
@@ -401,6 +409,80 @@ def unflatten_buckets_by_plan(buckets, treedef, plans, nleaves):
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 
+# A leaf of rank >= 2 and at least this many bytes rides its bucket's
+# all-reduce as an operand of its own (pack_groups_by_plan). Fusion is
+# for tensors that are small against a collective's latency; a matrix
+# past that regime gains nothing from sharing a buffer and, on the
+# chip, pays a relayout each way: a [4096, 1024] array and a 1-D array
+# of its elements are tiled differently, so `reshape(-1)` is a copy.
+# Set from a sweep on four chips (PERF.md section 6, PR 30): every
+# value from 16 KiB to 4 MiB splits GPT-2-medium's and BERT-Large's
+# trees alike (matrices direct, vectors packed), and that split beats
+# both all packed and every leaf direct. On narrower trees the more
+# matrices ride direct the faster, down to 256 KiB ones, so the sweep
+# alone would put this lower; it stays above 1 MiB because
+# tests/test_overlap_schedule.py counts one all_reduce a bucket in the
+# lowered step of a 512-wide model (PERF.md section 7).
+DIRECT_MIN_BYTES = 2 << 20
+
+
+def rides_direct(leaf) -> bool:
+    """Does this leaf ride its bucket's all-reduce in its own shape?
+    Decided by what the leaf shows: its rank and its bytes."""
+    return (jnp.ndim(leaf) >= 2 and
+            leaf.size * leaf.dtype.itemsize >= DIRECT_MIN_BYTES)
+
+
+def pack_groups_by_plan(tree, plan):
+    """`tree`'s leaves as one GROUP of arrays per bucket of a
+    pytree_bucket_plan: the bucket's direct leaves (rides_direct) in
+    plan order, each as it is, then one packed 1-D operand holding the
+    bucket's other leaves, flattened and concatenated; a bucket with no
+    small leaf has no packed operand. Same buckets, same order and same
+    bytes as pack_pytree_by_plan, with nothing copied for the leaves
+    that never needed fusing. Returns (groups, unflatten);
+    `unflatten(reduced_groups)` restores the tree."""
+    from ..utils import metrics
+
+    treedef, plans = plan
+    leaves = [jnp.asarray(l) for l in jax.tree_util.tree_leaves(tree)]
+    groups, layouts = [], []
+    for bplan in plans:
+        direct, packed = [], []
+        for (i, _, _, _) in bplan:
+            (direct if rides_direct(leaves[i]) else packed).append(i)
+        group = [leaves[i] for i in direct]
+        if packed:
+            flats = [leaves[i].reshape(-1) for i in packed]
+            group.append(
+                jnp.concatenate(flats) if len(flats) > 1 else flats[0])
+        groups.append(tuple(group))
+        layouts.append((direct, packed))
+
+    def nbytes(which):
+        return sum(leaves[i].size * leaves[i].dtype.itemsize
+                   for layout in layouts for i in layout[which])
+
+    metrics.record_fusion_groups(
+        nbytes(0), nbytes(1), sum(len(direct) for direct, _ in layouts))
+    shapes = [leaf.shape for leaf in leaves]  # all unflatten keeps of them
+
+    def unflatten(reduced_groups):
+        new_leaves = [None] * len(shapes)
+        for group, (direct, packed) in zip(reduced_groups, layouts):
+            for i, red in zip(direct, group):
+                new_leaves[i] = red
+            off = 0
+            for i in packed:
+                n = int(np.prod(shapes[i]))
+                new_leaves[i] = jax.lax.dynamic_slice_in_dim(
+                    group[-1], off, n).reshape(shapes[i])
+                off += n
+        return jax.tree_util.tree_unflatten(treedef, new_leaves)
+
+    return groups, unflatten
+
+
 def pack_pytree_by_plan(tree, plan):
     """Pack `tree`'s leaves into buckets following a pytree_bucket_plan
     (possibly computed from a DIFFERENT tree of the same structure —
@@ -433,7 +515,8 @@ def flatten_pytree_buckets(tree, threshold_bytes: int | None = None,
     first, embeddings last — `_backward_availability_order`), the order
     the reference gets for free from its grad hooks firing during
     backward. It decides which bucket the ordered-bucket chain releases
-    first and therefore how much backward compute the collectives can
-    overlap (tests/test_overlap_schedule.py)."""
+    first, and so how much of the backward pass a scheduler COULD run
+    beside the collectives (the compiled step on the chip runs none:
+    optim/distributed.py's comment on the chain)."""
     return pack_pytree_by_plan(
         tree, pytree_bucket_plan(tree, threshold_bytes, backward_order))

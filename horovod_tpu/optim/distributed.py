@@ -36,8 +36,8 @@ from ..core.state import global_state
 from ..ops import collectives
 from ..ops.adasum import adasum_allreduce
 from ..ops.collectives import ReduceOp
-from ..ops.fusion import (flatten_pytree_buckets, pack_pytree_by_plan,
-                          pytree_bucket_plan)
+from ..ops.fusion import (flatten_pytree_buckets, pack_groups_by_plan,
+                          pack_pytree_by_plan, pytree_bucket_plan)
 from ..utils import scopes
 from .compression import (Compression, NoneCompressor, WireSpec,
                           compressor_wire_spec, quantized_psum,
@@ -67,10 +67,15 @@ def _int8_bucket_allreduce(bucket, live, wire: WireSpec, residual):
 def _reduce_bucket(b, op, compression, wire: Optional[WireSpec],
                    int8_wire: bool, live, n, process_set, axis_name,
                    res_bucket=None):
-    """Reduce ONE fused 1-D bucket on the configured wire — the shared
+    """Reduce ONE bucket on the configured wire — the shared
     per-bucket data plane of the monolithic chain (_reduce_grad_tree)
     and the backward-interleaved scheduler (ops/overlap.py), extracted
-    verbatim so both trace identical collectives.
+    verbatim so both trace identical collectives. `b` is the bucket as
+    one fused 1-D array, or as a group (a tuple: its direct leaves in
+    their own shapes, then its packed small operand —
+    ops/fusion.pack_groups_by_plan); a group's operands are cast,
+    reduced and scaled one by one, the same elementwise arithmetic and
+    the same sums as over the flat array, and come back as a tuple.
 
     Returns ``(reduced, chain_token, new_residual)``: `reduced` is the
     decompressed result, `chain_token` the value the ordered-bucket
@@ -78,6 +83,21 @@ def _reduce_bucket(b, op, compression, wire: Optional[WireSpec],
     exact HLO the chain emitted before the extraction), `new_residual`
     the updated error-feedback bucket (or `res_bucket` unchanged on
     paths that don't consume it)."""
+    def allreduce_payload(x):
+        """The plain all-reduce of a wire payload, array or tuple."""
+        return collectives.allreduce(
+            x,
+            op=ReduceOp.SUM if op == ReduceOp.AVERAGE else op,
+            process_set=process_set,
+            axis_name=axis_name,
+            postscale_factor=(1.0 / n) if op == ReduceOp.AVERAGE else 1.0,
+        )
+
+    if isinstance(b, tuple):
+        wires, ctxs = zip(*map(compression.compress, b))
+        red = allreduce_payload(wires)
+        return (tuple(map(compression.decompress, red, ctxs)), red,
+                res_bucket)
     b_float = jnp.issubdtype(b.dtype, jnp.floating)
     if int8_wire and b_float and live:
         # quantized SUM over the live axes (flat EQuARX form or
@@ -107,13 +127,7 @@ def _reduce_bucket(b, op, compression, wire: Optional[WireSpec],
             red = adasum_allreduce(wire_b, live[0],
                                    process_set=process_set)
     else:
-        red = collectives.allreduce(
-            wire_b,
-            op=ReduceOp.SUM if op == ReduceOp.AVERAGE else op,
-            process_set=process_set,
-            axis_name=axis_name,
-            postscale_factor=(1.0 / n) if op == ReduceOp.AVERAGE else 1.0,
-        )
+        red = allreduce_payload(wire_b)
     return compression.decompress(red, ctx), red, res_bucket
 
 
@@ -250,9 +264,23 @@ def _reduce_grad_tree(
         _warn_stateless_ef_once()
 
     plan = pytree_bucket_plan(grads, threshold_bytes=fusion_threshold_bytes)
+    # A bucket is a GROUP of arrays wherever its reduction is elementwise
+    # over a bound mesh axis: a large leaf rides the bucket's all-reduce
+    # in its own shape and only the small leaves are packed. What needs
+    # one contiguous array keeps the flat bucket: the int8 wire (blocks
+    # and residual are laid out over it), Adasum (dot products over it),
+    # the two-level all-reduce (a reduce-scatter cuts it in n) and the
+    # native eager runtime (negotiation per tensor is the latency
+    # fusion is for).
+    from ..ops import hierarchical
+
+    grouped = bool(live) and not int8_wire and op in (
+        ReduceOp.SUM, ReduceOp.AVERAGE, ReduceOp.MIN, ReduceOp.MAX
+    ) and not hierarchical.hierarchy_enabled_for("allreduce", process_set)
     res_buckets = res_unflatten = None
     with jax.named_scope(scopes.HVD_PACK):
-        buckets, unflatten = pack_pytree_by_plan(grads, plan)
+        buckets, unflatten = (pack_groups_by_plan if grouped
+                              else pack_pytree_by_plan)(grads, plan)
         if residual is not None and int8_wire and live:
             # residual rides the SAME bucket layout as the gradients,
             # so a leaf's error lands back on that leaf at unflatten time
@@ -317,14 +345,20 @@ def _reduce_grad_tree(
         return _ret(unflatten(reduced))
     # Ordered buckets (reference semantics: fused responses execute in
     # controller order, operations.cc PerformOperation): chain bucket k
-    # on bucket k-1's result through an optimization_barrier. Without
-    # this XLA's all-reduce combiner merges every bucket into ONE
-    # variadic all-reduce that can only run after ALL gradients exist —
-    # destroying comm/compute overlap. With it, bucket k's collective
-    # stays a separate op whose only inputs are its own gradients (plus
-    # the ordering edge), so the scheduler issues it while backward for
-    # earlier layers is still computing (tests/test_overlap_schedule.py
-    # asserts this on the compiled schedule).
+    # on bucket k-1's result through an optimization_barrier. What the
+    # chain does: it keeps the buckets separate and in plan order.
+    # Without it XLA's all-reduce combiner merges every bucket into ONE
+    # variadic all-reduce over all gradients; with it, bucket k's
+    # collective stays an instruction of its own whose only inputs are
+    # its own gradients plus the ordering edge (a group's operands pass
+    # the barrier together and may be combined with each other, not
+    # with another bucket's). What it does not do: make the collectives
+    # overlap the backward pass. On the chip they compile to synchronous
+    # all-reduces after the backward pass and none of their time is
+    # hidden (PERF.md section 5, every ledger line since PR 22);
+    # tests/test_overlap_schedule.py's tier-1 test holds the structure
+    # in the lowered module, and its compiled-schedule test is in the
+    # slow tier, compiled for a described chip and never run on one.
     ordered = global_state().knobs.ordered_buckets and len(buckets) > 1
     reduced = []
     new_res_buckets = []
@@ -351,7 +385,9 @@ def _reduce_grad_tree(
         # structure; the tuned threshold applies to eager ops and
         # subsequent compilations — and a step compiled with metrics OFF
         # stays uninstrumented until recompiled.
-        total = sum(int(b.size) * b.dtype.itemsize for b in buckets)
+        # a grouped bucket's operands count one by one (same bytes)
+        arrays = jax.tree_util.tree_leaves(buckets)
+        total = sum(int(b.size) * b.dtype.itemsize for b in arrays)
         from jax.experimental import io_callback
 
         if pm is not None:
@@ -371,7 +407,7 @@ def _reduce_grad_tree(
                     wire if (wire is not None
                              and jnp.issubdtype(b.dtype, jnp.floating))
                     else None)
-                for b in buckets
+                for b in arrays
             )
             io_callback(
                 functools.partial(
@@ -545,7 +581,7 @@ def DistributedOptimizer(
             post = gradient_predivide_factor / n
             with jax.named_scope(scopes.HVD_ALLREDUCE):
                 g = jax.tree_util.tree_map(
-                    lambda x: x * jnp.asarray(pre, x.dtype), g
+                    lambda x: x * jnp.asarray(pre, jnp.result_type(x)), g
                 )
             out = _reduce_grad_tree(
                 g, ReduceOp.SUM, compression, process_set, axis_name,
@@ -554,7 +590,7 @@ def DistributedOptimizer(
             g, new_res = out if residual is not None else (out, None)
             with jax.named_scope(scopes.HVD_ALLREDUCE):
                 g = jax.tree_util.tree_map(
-                    lambda x: x * jnp.asarray(post, x.dtype), g
+                    lambda x: x * jnp.asarray(post, jnp.result_type(x)), g
                 )
             return (g, new_res) if residual is not None else g
         return _reduce_grad_tree(

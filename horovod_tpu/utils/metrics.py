@@ -945,6 +945,31 @@ def record_fusion_plan(n_tensors: int, n_buckets: int, threshold: int,
     step_stats.add_fusion(n_buckets, fill_sum)
 
 
+def record_fusion_groups(direct_bytes: int, packed_bytes: int,
+                         direct_leaves: int) -> None:
+    """How one gradient tree split when its buckets were built as
+    groups of arrays (ops/fusion.pack_groups_by_plan): the bytes and
+    the count of the leaves that ride their bucket's all-reduce in
+    their own shape, and the bytes still flattened and concatenated.
+    Recorded at TRACE time, like the flash kernels' gauges below:
+    arithmetic on the tree's shapes, the last traced tree's, nothing
+    inside the step."""
+    if not _enabled:
+        return
+    registry.gauge(
+        "hvd_fusion_direct_bytes",
+        "Gradient bytes that ride their bucket's all-reduce unpacked"
+    ).set(direct_bytes)
+    registry.gauge(
+        "hvd_fusion_packed_bytes",
+        "Gradient bytes flattened and concatenated into packed operands"
+    ).set(packed_bytes)
+    registry.gauge(
+        "hvd_fusion_direct_leaves",
+        "Gradient leaves that ride their bucket's all-reduce unpacked"
+    ).set(direct_leaves)
+
+
 def record_grad_reduction(nbytes: int, n_buckets: int) -> None:
     """One executed gradient reduction (io_callback from the compiled
     step — fires per real step, not per trace)."""

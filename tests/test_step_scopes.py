@@ -80,23 +80,39 @@ def tiny_step(cell: str, n: int):
         *described(batch, split))
 
 
-def compile_tiny_step(cell: str, n: int) -> list:
-    """``op_name``s of the cell's tiny step compiled for ``n`` CPU
-    devices."""
+# The tiny preset's matrices are 64 to 256 KiB and its vectors at most
+# 2 KiB: under this constant the tiny step splits as the real one does
+# under the module's (matrices ride direct, vectors are packed).
+TINY_DIRECT_MIN_BYTES = 64 << 10
+
+
+def compile_tiny_step(cell: str, n: int) -> str:
+    """The cell's tiny step compiled for ``n`` CPU devices, as text."""
+    from horovod_tpu.ops import fusion
+
     step, args = tiny_step(cell, n)
-    text = step.lower(*args).compile().as_text()
-    return re.findall(r'op_name="([^"]*)"', text)
+    real, fusion.DIRECT_MIN_BYTES = (fusion.DIRECT_MIN_BYTES,
+                                     TINY_DIRECT_MIN_BYTES)
+    try:
+        return step.lower(*args).compile().as_text()
+    finally:
+        fusion.DIRECT_MIN_BYTES = real
 
 
 _COMPILED: dict = {}
 
 
-def compiled(cell: str) -> list:
+def compiled_text(cell: str) -> str:
     """Compiled once a cell and process; the world is shut down around
-    every test, so nothing of it is kept but the strings."""
+    every test, so nothing of it is kept but the string."""
     if cell not in _COMPILED:
         _COMPILED[cell] = compile_tiny_step(cell, CELLS[cell])
     return _COMPILED[cell]
+
+
+def compiled(cell: str) -> list:
+    """``op_name``s of the cell's compiled tiny step."""
+    return re.findall(r'op_name="([^"]*)"', compiled_text(cell))
 
 
 @pytest.fixture
@@ -152,6 +168,16 @@ def test_optimizer_wrap_is_named_where_it_runs(op_names):
     if CELLS[cell] > 1:
         assert having(names, scopes.HVD_ALLREDUCE + "/psum")
         assert having(names, scopes.HVD_UNPACK + "/dynamic_slice")
+        # the small leaves pass through pack and unpack; a direct leaf
+        # is cut out of nothing, so no slice under hvd_unpack is as
+        # large as one
+        sliced = [
+            int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(
+                r"= \w+\[([\d,]*)\][^\n]*op_name=\"[^\"]*"
+                + scopes.HVD_UNPACK + r"/dynamic_slice",
+                compiled_text(cell))]
+        assert sliced and max(sliced) * 4 < TINY_DIRECT_MIN_BYTES, sliced
 
 
 @pytest.mark.parametrize("op_names", CELLS, indirect=True)
