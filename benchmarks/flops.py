@@ -6,6 +6,14 @@ what the forward and backward passes need if nothing is recomputed: a
 matrix multiplication costs 2·m·n·k forward and twice that backward, so
 training is three times the forward count.
 
+Of the traffic it reads ``objective``, ``seq_len`` (the **data tokens**
+of a sequence, which ``tokens_per_s_per_chip`` counts: what a user pays
+for), ``batch_per_chip`` and what the objective needs
+(``mask_fraction``; ``t_min``). Under ``block_diffusion`` a sequence of
+T data tokens is noised block by block and run beside its clean copy:
+every layer runs 2T positions, the mask shows T^2 + T*b of their pairs,
+and the head is required at the masked positions of the noisy half.
+
 A step is counted from the configuration's ``model`` group as it is
 run, which may be one chip's share of a deployment, by kind of layer.
 The keys this file reads, and what a key's absence means (a test holds
@@ -15,6 +23,8 @@ this list to the keys the functions touch):
   ``vocab_size``, ``causal``: required. ``num_layers`` counts the
   leading dense layers and the layers after them, not the MTP layers;
   ``vocab_size`` is the rows of the head that are held here.
+* ``diffusion_block``: the block length b of a block-diffusion mask,
+  which then stands in place of ``causal``; absent, no such mask.
 * ``num_kv_heads``: absent, as many as heads.
 * ``head_dim``: absent, ``hidden_size / num_heads``, which has to be
   whole.
@@ -49,15 +59,42 @@ BF16_BYTES = 2
 
 
 def head_positions_per_token(traffic: dict, ahead: int = 1) -> float:
-    """Share of positions at which a vocabulary head is required: the
-    first head predicts the next token (``ahead`` 1), a further
-    prediction head the one after it (``ahead`` 2)."""
+    """Share of a sequence's data tokens at which a vocabulary head is
+    required: the first head predicts the next token (``ahead`` 1), a
+    further prediction head the one after it (``ahead`` 2)."""
     if traffic["objective"] == "causal_lm":
         t = traffic["seq_len"]
         return (t - ahead) / t  # the last positions predict nothing
     if traffic["objective"] == "masked_lm":
         return float(traffic["mask_fraction"])
+    if traffic["objective"] == "block_diffusion":
+        # a block's tokens are masked with probability t ~ U(t_min, 1]
+        return (1.0 + traffic["t_min"]) / 2
     raise ValueError(f"unknown objective {traffic['objective']!r}")
+
+
+def positions_per_token(traffic: dict) -> int:
+    """Positions every layer runs for one data token: a block-diffusion
+    step runs the noisy copy of a sequence beside the clean one."""
+    return 2 if traffic["objective"] == "block_diffusion" else 1
+
+
+def visible_pairs(model: dict, traffic: dict) -> float:
+    """(query, key) pairs of one sequence that the mask shows: what the
+    scores and the weighted values are required over.
+
+    Full: T^2. Causal: counted as half of that, as since PR 22 (the
+    diagonal's T/2 are left out). Block diffusion, block length b, over
+    the 2T positions ``[noisy ; clean]`` with blk(i) = (i mod T) // b: a
+    noisy query sees the noisy keys of its own block (T*b pairs) and the
+    clean keys of earlier blocks (T^2/2 - T*b/2), a clean query the
+    clean keys of its own and earlier blocks (T^2/2 + T*b/2):
+    T^2 + T*b."""
+    t = traffic["seq_len"]
+    block = model.get("diffusion_block")
+    if block:
+        return t * t + t * block
+    return t * t * (0.5 if model["causal"] else 1.0)
 
 
 def head_dim(model: dict) -> int:
@@ -134,39 +171,45 @@ def mlp_macs(model: dict) -> tuple:
 
 def attention_flops_per_layer(model: dict, traffic: dict) -> float:
     """Scores at the query-and-key width and weighted values at the
-    value width: 2·t·heads·width each per token, a causal mask counted
-    as half."""
-    return (2 * traffic["seq_len"] * model["num_heads"]
-            * (qk_head_dim(model) + v_head_dim(model))
-            * (0.5 if model["causal"] else 1.0))
+    value width, a data token: 2·heads·width each for every pair the
+    mask shows (``visible_pairs``) of a sequence, over its T data
+    tokens: 2·T·heads·widths halved under a causal mask,
+    2·(T + b)·heads·widths under a block-diffusion one."""
+    return (2 * (visible_pairs(model, traffic) / traffic["seq_len"])
+            * model["num_heads"]
+            * (qk_head_dim(model) + v_head_dim(model)))
 
 
 def forward_flops_per_token(model: dict, traffic: dict) -> dict:
-    """Forward operations per token, by part: ``blocks``, the matrix
-    multiplications of the ``num_layers`` layers (``dense_layers`` of
-    them with a plain MLP); ``attention``, their scores and weighted
-    values at the cell's sequence length; ``head``, the vocabulary head
-    at the positions that have a target; and, where there are
-    ``mtp_layers``, ``mtp``: for each one block of the last kind, the
-    product that takes the hidden state beside the next token's
-    embedding from 2h to h, its attention, and the head once more at
-    the positions that have a token two ahead. The keys read are listed
-    at the top of this file."""
+    """Forward operations per data token, by part: ``blocks``, the
+    matrix multiplications of the ``num_layers`` layers
+    (``dense_layers`` of them with a plain MLP) at every position a
+    data token runs (``positions_per_token``); ``attention``, their
+    scores and weighted values over the pairs the mask shows; ``head``,
+    the vocabulary head at the positions that have a target; and, where
+    there are ``mtp_layers``, ``mtp``: for each one block of the last
+    kind, the product that takes the hidden state beside the next
+    token's embedding from 2h to h, its attention, and the head once
+    more at the positions that have a token two ahead. The keys read
+    are listed at the top of this file."""
     h = model["hidden_size"]
     layers = model["num_layers"]
     dense_layers = model.get("dense_layers", 0) \
         if model.get("num_experts", 0) else layers
     projections = projection_macs(model)
     dense, last = mlp_macs(model)
-    blocks = 2 * (layers * projections + dense_layers * dense
-                  + (layers - dense_layers) * last)
+    positions = positions_per_token(traffic)
+    blocks = positions * 2 * (
+        layers * projections + dense_layers * dense
+        + (layers - dense_layers) * last)
     attention = attention_flops_per_layer(model, traffic)
     head = 2 * h * model["vocab_size"]
     parts = {"blocks": blocks, "attention": layers * attention,
              "head": head * head_positions_per_token(traffic)}
     if model.get("mtp_layers", 0):
         parts["mtp"] = model["mtp_layers"] * (
-            2 * (projections + last) + 2 * 2 * h * h + attention
+            positions * (2 * (projections + last) + 2 * 2 * h * h)
+            + attention
             + head * head_positions_per_token(traffic, ahead=2))
     return parts
 
@@ -179,25 +222,26 @@ def attention_kernel_work(model: dict, traffic: dict) -> dict:
     """Required operations and least HBM bytes of one training step's
     attention on one chip, over ``num_layers`` and ``mtp_layers``.
 
-    Operations: forward QK^T and PV, backward dV, dP, dQ, dK: six T×T
-    products per head, QK^T, dQ and dK over the query-and-key width,
+    Operations: forward QK^T and PV, backward dV, dP, dQ, dK: six
+    products per head over the pairs the mask shows
+    (``visible_pairs``), QK^T, dQ and dK over the query-and-key width,
     PV, dV and dP over the value width (the flash backward's recomputed
     scores are not required work). Bytes: forward reads q, k, v and
     writes o; backward reads q, k, v, o, do and writes dq, dk, dv:
-    twelve bf16 arrays, each moved once. q and dq are (B, heads, T,
-    query-and-key width), o and do (B, heads, T, value width), k and dk
-    (B, kv_heads, T, query-and-key width), v and dv (B, kv_heads, T,
+    twelve bf16 arrays, each moved once, of P positions a sequence (T,
+    or 2T where a data token runs two). q and dq are (B, heads, P,
+    query-and-key width), o and do (B, heads, P, value width), k and dk
+    (B, kv_heads, P, query-and-key width), v and dv (B, kv_heads, P,
     value width)."""
     b = traffic["batch_per_chip"]
-    t = traffic["seq_len"]
+    positions = positions_per_token(traffic) * traffic["seq_len"]
     heads = model["num_heads"]
     widths = qk_head_dim(model) + v_head_dim(model)
     layers = model["num_layers"] + model.get("mtp_layers", 0)
-    flops = layers * 3 * 2 * b * heads * t * t * widths
-    if model["causal"]:
-        flops *= 0.5
-    nbytes = (layers * 3 * (heads + kv_heads(model)) * b * t * widths
-              * BF16_BYTES)
+    flops = (layers * 3 * 2 * b * heads * visible_pairs(model, traffic)
+             * widths)
+    nbytes = (layers * 3 * (heads + kv_heads(model)) * b * positions
+              * widths * BF16_BYTES)
     return {"flops": float(flops), "bytes": float(nbytes)}
 
 

@@ -11,8 +11,10 @@ called as an executable. Nothing here sets a ``HOROVOD_*`` variable or
 a knob: a cell runs the program's defaults.
 
 Everything the cell's parameters select is in its traffic file
-(``objective``, ``seq_len``, ``batch_per_chip``, ``attention``,
-``loss_head``, ``learning_rate``); the model's sizes are in its
+(``objective``, one of the rows of OBJECTIVES below, and what that
+row reads; ``seq_len``, the data tokens of a sequence;
+``batch_per_chip``, ``attention``, ``loss_head``,
+``learning_rate``); the model's sizes are in its
 configuration file and are built as written, and the plain reference
 the run is compared with is the file ``benchmarks/reference/<family>.py``
 that the configuration's ``family`` names (its contract is written at
@@ -26,6 +28,7 @@ from __future__ import annotations
 import math
 import statistics
 import time
+from typing import Callable, NamedTuple
 
 import numpy as np
 
@@ -38,7 +41,9 @@ from benchmarks import harness
 # to 1.2e-5 (GPT-2-medium) and 1.2e-4 (BERT-Large) relative, the
 # gradient's global norm to 1.23e-2 .. 1.30e-2. bf16 keeps 8 bits, so
 # each activation is off by up to 2^-9 = 0.2% and 24 layers of them add
-# to about 1% in the gradient. The limits are four times the loss error
+# to about 1% in the gradient (a six-layer model of 644 M parameters at
+# 8,192 positions read 1.17e-2 too, PR 32: between 6 and 24 layers the
+# error does not grow with depth). The limits are four times the loss error
 # and a little over twice the gradient error seen: activations in an
 # 8-bit float (2^-4 a value) or gradients accumulated in bf16 are many
 # times past them, and float32 activations would pass far inside.
@@ -83,7 +88,10 @@ CHOICES = "choices"
 # system routes otherwise is a router at fault (another k, a correction
 # left out), not rounding. Arithmetic, NOT a measurement: no program
 # sows choices yet, and the first cell whose program does has to read
-# both shares on the chip beside a router at fault (PERF.md section 7)
+# both shares on the chip beside a router at fault (PERF.md section 7).
+# The band grows with the number of scores: of seeded normal scores it
+# holds 3% of tokens at 4 top 2 and 72% at 128 top 8, where the floor
+# holds almost nothing; it has to be set from the rounding then
 NEAR_TIE = 2.0 ** -6
 
 # The loop. These are part of what the metrics mean, so no cell sets
@@ -115,7 +123,12 @@ def make_model(model_sizes: dict, traffic: dict):
     if traffic["attention"] == "flash":
         from horovod_tpu.ops.pallas_attention import (
             make_flash_attention_fn)
-        attention_fn = make_flash_attention_fn(causal=cfg.causal)
+        # a model group that states a block-diffusion mask gets the
+        # kernels' (the default attention reads it from ``cfg``)
+        block = model_sizes.get("diffusion_block")
+        attention_fn = make_flash_attention_fn(
+            causal=cfg.causal,
+            **({"diffusion_block": block} if block else {}))
     elif traffic["attention"] != "xla":
         raise ValueError(f"unknown attention {traffic['attention']!r}")
     return cfg, Transformer(cfg, attention_fn=attention_fn), \
@@ -135,36 +148,169 @@ def named_choices(sown) -> dict:
             for i, array in enumerate(kept)}
 
 
+class Objective(NamedTuple):
+    """One row of OBJECTIVES: what a training objective is to this job.
+    ``batch(rng, shape, model_sizes, traffic)`` draws the host arrays
+    of ``shape = (sequences, seq_len)`` that follow the parameters in a
+    call of the loss; ``loss(heads, cfg, traffic)`` builds
+    ``loss_fn(params, *batch)`` from the program's entry points, with
+    ``heads`` (``make_loss_fn``) for the model's output and what its
+    modules sowed; ``n_batch_args`` is how many arrays a batch has."""
+    batch: Callable
+    loss: Callable
+    n_batch_args: int
+
+
+def _causal_lm_batch(rng, shape, model_sizes, traffic):
+    return (rng.integers(0, model_sizes["vocab_size"], shape,
+                         dtype=np.int32),)
+
+
+def _causal_lm_loss(heads, cfg, traffic):
+    from horovod_tpu.models.transformer import causal_lm_loss
+    from horovod_tpu.ops.fused_cross_entropy import fused_causal_lm_loss
+
+    def loss_fn(p, tok):
+        if heads.fused:
+            args, sown = heads.hidden_and_head(p, tok)
+            return heads.result(fused_causal_lm_loss(*args, tok)[0], sown)
+        logits, sown = heads.apply(p, tok)
+        return heads.result(causal_lm_loss(logits, tok)[0], sown)
+    return loss_fn
+
+
+def _masked_lm_batch(rng, shape, model_sizes, traffic):
+    vocab = model_sizes["vocab_size"]
+    tokens = rng.integers(0, vocab, shape, dtype=np.int32)
+    labels = rng.integers(0, vocab, shape, dtype=np.int32)
+    mask = rng.random(shape) < traffic["mask_fraction"]
+    return tokens, labels, mask
+
+
+def _masked_lm_loss(heads, cfg, traffic):
+    from horovod_tpu.models.transformer import mlm_loss
+    from horovod_tpu.ops.fused_cross_entropy import (
+        fused_linear_cross_entropy)
+
+    def loss_fn(p, tok, lab, msk):
+        if heads.fused:
+            args, sown = heads.hidden_and_head(p, tok)
+            return heads.result(fused_linear_cross_entropy(
+                *args, lab, valid=msk)[0], sown)
+        logits, sown = heads.apply(p, tok)
+        return heads.result(mlm_loss(logits, lab, msk)[0], sown)
+    return loss_fn
+
+
+def _block_diffusion_batch(rng, shape, model_sizes, traffic):
+    """``(x0, m, w)``: the clean tokens, drawn from the rows held less
+    the last, which is the mask token; for each sequence and block of
+    ``diffusion_block`` tokens one noise level t ~ U(``t_min``, 1];
+    ``m``, which of a block's tokens are masked, Bernoulli(t); ``w`` =
+    1/t, a masked token's weight in the loss."""
+    block, t_min = model_sizes.get("diffusion_block"), traffic["t_min"]
+    if not block or shape[1] % block:
+        raise ValueError(
+            f"objective 'block_diffusion': the traffic's seq_len "
+            f"{shape[1]} is no multiple of the model group's "
+            f"diffusion_block {block!r}")
+    if not 0.0 <= t_min < 1.0:
+        raise ValueError(f"t_min {t_min!r} is not in [0, 1)")
+    x0 = rng.integers(0, model_sizes["vocab_size"] - 1, shape,
+                      dtype=np.int32)
+    level = 1.0 - (1.0 - t_min) * rng.random((shape[0], shape[1] // block))
+    level = np.repeat(level, block, axis=1)
+    return x0, rng.random(shape) < level, (1.0 / level).astype(np.float32)
+
+
+def _block_diffusion_loss(heads, cfg, traffic):
+    """The step's input is ``[x_t ; x0]``, 2T positions numbered
+    ``[0..T-1 ; 0..T-1]``: the noisy copy (the mask token where ``m``)
+    beside the clean one, under the model's block-diffusion mask. The
+    loss is taken at the noisy half alone, no shift: the sum over its
+    masked positions of ``w`` times the negative log likelihood of
+    ``x0``, over all B·T data tokens."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.fused_cross_entropy import (
+        fused_linear_cross_entropy)
+
+    if not heads.fused:
+        raise ValueError(
+            "objective 'block_diffusion' takes loss_head 'fused_ce': "
+            "the head runs at the noisy half's positions alone")
+    mask_token = cfg.vocab_size - 1
+
+    def loss_fn(p, x0, m, w):
+        n, t = x0.shape
+        tokens = jnp.concatenate(
+            [jnp.where(m, mask_token, x0), x0], axis=1)
+        positions = jnp.broadcast_to(
+            jnp.tile(jnp.arange(t), 2)[None], (n, 2 * t))
+        (hidden, kernel), sown = heads.hidden_and_head(
+            p, tokens, positions=positions)
+        total = fused_linear_cross_entropy(
+            hidden[:, :t], kernel, x0, valid=m, weight=w, mean=False)[0]
+        return heads.result(total / x0.size, sown)
+    return loss_fn
+
+
+# A fourth objective is one more row. What a row asks of the program
+# that the program does not have yet is written in PERF.md section 4
+# under the name the row calls it by
+OBJECTIVES = {
+    "causal_lm": Objective(_causal_lm_batch, _causal_lm_loss, 1),
+    "masked_lm": Objective(_masked_lm_batch, _masked_lm_loss, 3),
+    "block_diffusion": Objective(
+        _block_diffusion_batch, _block_diffusion_loss, 3),
+}
+
+
+def objective_of(traffic: dict) -> Objective:
+    if traffic["objective"] not in OBJECTIVES:
+        raise ValueError(
+            f"unknown objective {traffic['objective']!r}: "
+            f"benchmarks/jobs/dp_train.py has the rows "
+            f"{sorted(OBJECTIVES)}")
+    return OBJECTIVES[traffic["objective"]]
+
+
+class Heads(NamedTuple):
+    """What an objective's loss builds on: ``apply(p, tokens, **kw)``,
+    the model's output and what its modules sowed;
+    ``hidden_and_head(p, tokens, **kw)``, the final hidden state and
+    the head's kernel ``[h, V]`` (the token embedding's transpose where
+    the head is tied) and what was sown; ``result(loss, sown)``, the
+    loss with every term sown into AUX_LOSSES added; ``fused``, whether
+    the cell's ``loss_head`` is the fused cross entropy."""
+    apply: Callable
+    hidden_and_head: Callable
+    result: Callable
+    fused: bool
+
+
 def make_loss_fn(model, traffic: dict, with_choices: bool = False):
-    """``loss(params, *batch)`` as the examples define it. With
-    ``with_choices`` (the reference check's, never the step's) the same
-    pass also makes CHOICES mutable and the function returns ``(loss,
-    named_choices)``, for ``jax.value_and_grad(..., has_aux=True)``."""
+    """``loss(params, *batch)`` as the examples define it, by the
+    traffic's row of OBJECTIVES. With ``with_choices`` (the reference
+    check's, never the step's) the same pass also makes CHOICES mutable
+    and the function returns ``(loss, named_choices)``, for
+    ``jax.value_and_grad(..., has_aux=True)``."""
     import jax
 
-    from horovod_tpu.models.transformer import causal_lm_loss, mlm_loss
-    from horovod_tpu.ops.fused_cross_entropy import (
-        fused_causal_lm_loss, fused_linear_cross_entropy)
-
-    objective, head = traffic["objective"], traffic["loss_head"]
+    objective, head = objective_of(traffic), traffic["loss_head"]
     if head not in ("fused_ce", "dense"):
         raise ValueError(f"unknown loss_head {head!r}")
     mutable = [AUX_LOSSES, CHOICES] if with_choices else [AUX_LOSSES]
 
     def apply(p, tok, **kw):
-        """The model's output, and what its modules sowed: the sum of
-        the terms in AUX_LOSSES (None where none did), and the
-        choices."""
         out, sown = model.apply({"params": p}, tok, mutable=mutable,
                                 **kw)
         terms = jax.tree_util.tree_leaves(dict(sown).get(AUX_LOSSES, {}))
         return out, ((sum(terms) if terms else None),
                      named_choices(sown))
 
-    def hidden_and_head(p, tok):
-        """Final hidden state and the head's kernel ``[h, V]``: the
-        token embedding's transpose where the head is tied."""
-        hidden, sown = apply(p, tok, return_hidden=True)
+    def hidden_and_head(p, tok, **kw):
+        hidden, sown = apply(p, tok, return_hidden=True, **kw)
         if model.cfg.tie_embeddings:
             return (hidden, p["tok_emb"]["embedding"].T), sown
         return (hidden, p["lm_head"]["kernel"]), sown
@@ -174,24 +320,9 @@ def make_loss_fn(model, traffic: dict, with_choices: bool = False):
         loss = loss if aux is None else loss + aux
         return (loss, choices) if with_choices else loss
 
-    if objective == "causal_lm":
-        def loss_fn(p, tok):
-            if head == "fused_ce":
-                args, sown = hidden_and_head(p, tok)
-                return result(fused_causal_lm_loss(*args, tok)[0], sown)
-            logits, sown = apply(p, tok)
-            return result(causal_lm_loss(logits, tok)[0], sown)
-    elif objective == "masked_lm":
-        def loss_fn(p, tok, lab, msk):
-            if head == "fused_ce":
-                args, sown = hidden_and_head(p, tok)
-                return result(fused_linear_cross_entropy(
-                    *args, lab, valid=msk)[0], sown)
-            logits, sown = apply(p, tok)
-            return result(mlm_loss(logits, lab, msk)[0], sown)
-    else:
-        raise ValueError(f"unknown objective {objective!r}")
-    return loss_fn
+    return objective.loss(
+        Heads(apply, hidden_and_head, result, head == "fused_ce"),
+        model.cfg, traffic)
 
 
 def choices_agreement(scores: dict, system: dict):
@@ -217,17 +348,13 @@ def choices_agreement(scores: dict, system: dict):
 
 
 def make_batch(model_sizes: dict, traffic: dict, n_seq: int, seed: int):
-    """The seeded batch on the host: uniform random tokens (and, for
-    masked LM, labels and a Bernoulli mask), ``n_seq`` sequences."""
-    rng = np.random.default_rng(seed)
-    shape = (n_seq, traffic["seq_len"])
-    vocab = model_sizes["vocab_size"]
-    tokens = rng.integers(0, vocab, shape, dtype=np.int32)
-    if traffic["objective"] == "causal_lm":
-        return (tokens,)
-    labels = rng.integers(0, vocab, shape, dtype=np.int32)
-    mask = rng.random(shape) < traffic["mask_fraction"]
-    return tokens, labels, mask
+    """The seeded batch on the host, ``n_seq`` sequences: what the
+    traffic's row of OBJECTIVES draws (uniform random tokens; for
+    masked LM labels and a Bernoulli mask too; for block diffusion the
+    clean tokens, which of them are masked, and their weights)."""
+    return objective_of(traffic).batch(
+        np.random.default_rng(seed), (n_seq, traffic["seq_len"]),
+        model_sizes, traffic)
 
 
 def make_step(loss_fn, opt, mesh, n: int, n_batch_args: int):
@@ -266,6 +393,50 @@ def compile_step(lowered, for_tpu: bool):
     return lowered.compile()
 
 
+def make_compare(reference, loss_fn, model_sizes: dict, traffic: dict):
+    """The jitted program of the reference check,
+    ``compare(params, *batch)``: the system's loss, the reference's,
+    the norm of the two gradients' difference over the norm of the
+    reference's, and for a reference that ``TAKES_CHOICES`` the counts
+    of ``choices_agreement`` (None where the two sides name their
+    choices otherwise). Also returned: a dictionary that holds, once
+    the program is traced, what each side names its choices."""
+    import jax
+    import optax
+
+    kw = reference.arguments(model_sizes, traffic)
+    takes_choices = getattr(reference, "TAKES_CHOICES", False)
+    names = {}
+
+    @jax.jit
+    def compare(p, *b):
+        out, g_sys = jax.value_and_grad(
+            loss_fn, has_aux=takes_choices)(p, *b)
+        l_sys, system = out if takes_choices else (out, {})
+        scores = reference.choice_scores(p, b, **kw) \
+            if takes_choices else {}
+        names.update(system=sorted(system), reference=sorted(scores))
+        # other names, or none: nothing to give the reference, which is
+        # then compared freely, and `reference_choices` fails below
+        matched = bool(system) and set(system) == set(scores)
+        imposed = {"choices": system} if matched else {}
+        # the reference's pass starts once the system's gradient is
+        # whole: left to itself the compiler runs the two side by side
+        # and keeps both passes' activations at once (at a share's 644 M
+        # parameters 13.3 GiB of temporaries against 10.3 with the
+        # barrier, compiled for a described v5e; PERF.md section 4)
+        p_ref, g_sys = jax.lax.optimization_barrier((p, g_sys))
+        l_ref, g_ref = jax.value_and_grad(
+            lambda q: reference.mean_loss(q, b, **kw, **imposed))(p_ref)
+        diff = jax.tree_util.tree_map(
+            lambda a, r: a.astype(jax.numpy.float32) - r, g_sys, g_ref)
+        return (l_sys, l_ref,
+                optax.global_norm(diff) / optax.global_norm(g_ref),
+                choices_agreement(scores, system) if matched else None)
+
+    return compare, names
+
+
 def reference_check(run, reference, loss_fn, params, model_sizes,
                     traffic):
     """Loss and gradient of the system's own loss function against the
@@ -282,34 +453,12 @@ def reference_check(run, reference, loss_fn, params, model_sizes,
     arithmetic; how many of the system's choices the reference would
     have made itself is held to the floor NEAR_TIE gives."""
     import jax
-    import optax
 
     batch = tuple(jax.numpy.asarray(a) for a in make_batch(
         model_sizes, traffic, 2, run.seed + 1))
-    kw = reference.arguments(model_sizes, traffic)
     takes_choices = getattr(reference, "TAKES_CHOICES", False)
-    names = {}  # what each side names its choices, noted when traced
-
-    @jax.jit
-    def compare(p, *b):
-        out, g_sys = jax.value_and_grad(
-            loss_fn, has_aux=takes_choices)(p, *b)
-        l_sys, system = out if takes_choices else (out, {})
-        scores = reference.choice_scores(p, b, **kw) \
-            if takes_choices else {}
-        names.update(system=sorted(system), reference=sorted(scores))
-        # other names, or none: nothing to give the reference, which is
-        # then compared freely, and `reference_choices` fails below
-        matched = bool(system) and set(system) == set(scores)
-        imposed = {"choices": system} if matched else {}
-        l_ref, g_ref = jax.value_and_grad(
-            lambda q: reference.mean_loss(q, b, **kw, **imposed))(p)
-        diff = jax.tree_util.tree_map(
-            lambda a, r: a.astype(jax.numpy.float32) - r, g_sys, g_ref)
-        return (l_sys, l_ref,
-                optax.global_norm(diff) / optax.global_norm(g_ref),
-                choices_agreement(scores, system) if matched else None)
-
+    compare, names = make_compare(reference, loss_fn, model_sizes,
+                                  traffic)
     with run.span("reference_check"):
         *numbers, counts = compare(params, *batch)
         l_sys, l_ref, g_err = (float(x) for x in numbers)
@@ -340,26 +489,50 @@ def reference_check(run, reference, loss_fn, params, model_sizes,
               f"{share} < {floor}", value=share, limit=floor)
 
 
-def reference_global_loss(run, reference, params, host_batch,
-                          model_sizes, traffic, mesh, n: int):
-    """The reference's mean loss over the whole global batch, in blocks
-    of at most REFERENCE_BLOCK_TOKENS tokens a chip (each chip takes the
-    sequences the step will give it)."""
+def reference_block(reference, model_sizes: dict, traffic: dict, mesh):
+    """The jitted program of one block of the reference's loss over the
+    global batch, ``block(params, *part) -> (sum, count)``, and how
+    many of a chip's sequences a block takes: the most that divide its
+    batch and hold at most the reference's BLOCK_TOKENS data tokens
+    (REFERENCE_BLOCK_TOKENS where it states none)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     kw = reference.arguments(model_sizes, traffic)
     block_tokens = getattr(reference, "BLOCK_TOKENS",
                            REFERENCE_BLOCK_TOKENS)
+    seq, per_chip = traffic["seq_len"], traffic["batch_per_chip"]
+    if seq > block_tokens:
+        name = getattr(reference, "__name__", reference)
+        raise ValueError(
+            f"one sequence of the traffic, seq_len {seq} data tokens, "
+            f"is longer than the {block_tokens} tokens a chip takes in "
+            f"one call of the reference {name!r} (its BLOCK_TOKENS, or "
+            f"dp_train.REFERENCE_BLOCK_TOKENS): the reference takes "
+            f"whole sequences")
     shard = NamedSharding(mesh, P("hvd"))
     block_fn = jax.jit(
         lambda p, *b: reference.nll_sum(p, b, **kw),
         in_shardings=(NamedSharding(mesh, P()),)
-        + (shard,) * len(host_batch),
+        + (shard,) * objective_of(traffic).n_batch_args,
         out_shardings=NamedSharding(mesh, P()))
+    return block_fn, max(
+        d for d in range(1, per_chip + 1)
+        if per_chip % d == 0 and d * seq <= block_tokens)
+
+
+def reference_global_loss(run, reference, params, host_batch,
+                          model_sizes, traffic, mesh, n: int):
+    """The reference's mean loss over the whole global batch, in blocks
+    of at most REFERENCE_BLOCK_TOKENS data tokens a chip (each chip
+    takes the sequences the step will give it)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    block_fn, blk = reference_block(reference, model_sizes, traffic,
+                                    mesh)
+    shard = NamedSharding(mesh, P("hvd"))
     per_chip = traffic["batch_per_chip"]
-    blk = max(d for d in range(1, per_chip + 1) if per_chip % d == 0
-              and d * traffic["seq_len"] <= block_tokens)
     total = count = 0.0
     with run.span("reference_global_loss"):
         for lo in range(0, per_chip, blk):
@@ -381,6 +554,7 @@ def build(run, model_sizes: dict, traffic: dict, mesh=None):
 
     import horovod_tpu as hvd
 
+    objective = objective_of(traffic)  # refused before any device work
     with run.span("init"):
         hvd.init(mesh=mesh)
         n, mesh = hvd.size(), hvd.mesh()
@@ -388,8 +562,7 @@ def build(run, model_sizes: dict, traffic: dict, mesh=None):
         opt = hvd.DistributedOptimizer(
             optax.adamw(traffic["learning_rate"]))
         loss_fn = make_loss_fn(model, traffic)
-        n_batch_args = 1 if traffic["objective"] == "causal_lm" else 3
-        step = make_step(loss_fn, opt, mesh, n, n_batch_args)
+        step = make_step(loss_fn, opt, mesh, n, objective.n_batch_args)
     return dict(n=n, mesh=mesh, cfg=cfg, model=model,
                 plain_model=plain_model, opt=opt, loss_fn=loss_fn,
                 step=step)

@@ -36,10 +36,34 @@ configuration's ``family`` and knows no family's name):
   in one block: the module then states ``BLOCK_TOKENS``, the tokens a
   chip takes in one call (8192 where it states none);
 * ``params`` is the program's parameter tree, as the program made it
-  from the seed, and ``batch`` the job's (``(tokens,)`` for
-  ``causal_lm``, ``(tokens, labels, mask)`` for ``masked_lm``);
+  from the seed, and ``batch`` the job's, by the traffic's objective
+  (``dp_train.OBJECTIVES``): ``(tokens,)`` for ``causal_lm``,
+  ``(tokens, labels, mask)`` for ``masked_lm``, ``(x0, m, w)`` for
+  ``block_diffusion`` (clean tokens, which are masked, their weights
+  1/t; the module builds the step's input ``[x_t ; x0]``, its
+  positions and its mask itself, and returns the sum over the noisy
+  half of m · w · nll and the count B·T);
+* ``BLOCK_TOKENS``, like the traffic's ``seq_len``, counts **data
+  tokens**, whatever positions a data token runs (two under
+  ``block_diffusion``); one sequence longer than the block is refused
+  by name (``dp_train.reference_block``): the reference takes whole
+  sequences;
 * float32 throughout under ``jax.default_matmul_precision("highest")``,
   and nothing imported from the program;
+* what a reference may do to fit a chip and still be plain: it may
+  change what is **kept** for the backward pass, never what is
+  **computed**. ``jax.checkpoint`` around a layer or a mapped function,
+  and ``jax.lax.map`` over heads, over blocks of queries or over blocks
+  of rows, are that: every number is the same sum of the same float32
+  terms. (This module's ``lax.scan`` over stacked layers is the same
+  kind; at a share's size the stacked copy of the weights is a tree too
+  many, and the layers are looped.) A blockwise softmax, a lower
+  precision, a fused kernel or anything of the program's is not. A
+  mapped function that closes over a weight keeps more than it saves:
+  read at 644 M parameters, an MLP mapped over blocks of rows kept 7
+  GiB more than the MLP whole (``tests/benchmarks/data/share_fixture``
+  has a reference written to this, and ``benchmarks/compare_size.py``
+  reads what a comparison needs of a chip before a chip is asked);
 * a model that makes discrete choices (a router's k experts of e for a
   token) cannot be compared through them: where two scores are closer
   than bfloat16 rounds, float32 picks another expert, and the gradients

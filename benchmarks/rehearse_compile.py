@@ -15,6 +15,7 @@ length of the compile).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import sys
@@ -27,6 +28,27 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 from benchmarks import harness, hlo  # noqa: E402
 
 
+@contextlib.contextmanager
+def mosaic_kernels():
+    """Inside, the program's Pallas kernels compile with Mosaic where
+    the CPU is the default backend: the rule that says whether to
+    interpret them is replaced, with every copy of it that a kernel
+    module imported by name."""
+    from horovod_tpu.ops import _pallas
+
+    real_rule = _pallas.interpret
+    holders = [m for m in list(sys.modules.values())
+               if getattr(m, "__name__", "").startswith("horovod_tpu")
+               and getattr(m, "interpret", None) is real_rule]
+    for m in holders:
+        m.interpret = lambda: False
+    try:
+        yield
+    finally:
+        for m in holders:
+            m.interpret = real_rule
+
+
 def compile_cell(name: str, topo) -> dict:
     import jax
     import jax.numpy as jnp
@@ -34,7 +56,6 @@ def compile_cell(name: str, topo) -> dict:
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     import horovod_tpu as hvd
-    from horovod_tpu.ops import _pallas
 
     found = harness.load_cell(name)
     cell, traffic = found["cell"], found["traffic"]
@@ -62,29 +83,17 @@ def compile_cell(name: str, topo) -> dict:
         built["plain_model"].init, jax.random.PRNGKey(0),
         jnp.zeros((1, seq), jnp.int32))["params"]
     opt_state = jax.eval_shape(built["opt"].init, params)
-    batch = job.make_batch(sizes, {**traffic, "seq_len": 1}, 1, 0)
     batch = tuple(jax.ShapeDtypeStruct(
         (n * traffic["batch_per_chip"], seq), a.dtype, sharding=split)
-        for a in batch)
+        for a in job.make_batch(sizes, traffic, 1, 0))
 
-    # compile the kernels with Mosaic: the rule, and every copy of it
-    # that a kernel module imported by name
-    real_rule = _pallas.interpret
-    holders = [m for m in list(sys.modules.values())
-               if getattr(m, "__name__", "").startswith("horovod_tpu")
-               and getattr(m, "interpret", None) is real_rule]
-    for m in holders:
-        m.interpret = lambda: False
-    try:
+    with mosaic_kernels():
         t0 = time.perf_counter()
         lowered = built["step"].lower(
             described(params, rep), described(opt_state, rep), *batch)
         t1 = time.perf_counter()
         compiled = job.compile_step(lowered, for_tpu=True)
         t2 = time.perf_counter()
-    finally:
-        for m in holders:
-            m.interpret = real_rule
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     reduces = hlo.allreduces(text)

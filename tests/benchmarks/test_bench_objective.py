@@ -1,0 +1,517 @@
+"""An objective and a mask come in as data: the rows of
+``dp_train.OBJECTIVES``, ``flops.py``'s count by what a mask shows and
+what a data token runs, and the comparison with the reference at a
+share's size, proven on a body the harness's tests keep
+(``data/block_diffusion_share.json``: an entry, a configuration's body
+and a traffic body, no cell) and on a fixture root of their own
+(``data/share_fixture``) with stubs where the program owes an entry
+point. Nothing here is a device number."""
+
+import copy
+import json
+import os
+import sys
+import time
+import types
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from benchmarks import flops, harness, published  # noqa: E402
+from benchmarks.jobs import dp_train  # noqa: E402
+
+DATA = os.path.join(ROOT, "tests", "benchmarks", "data")
+FIXTURE = os.path.join(DATA, "fixture")
+SHARE_FIXTURE = os.path.join(DATA, "share_fixture")
+SHARE = harness.load_json(DATA, "block_diffusion_share.json")
+MODEL, TRAFFIC = SHARE["body"]["model"], SHARE["traffic"]
+BENCH = harness.load_json(ROOT, "BENCHMARK.json")
+
+
+# -- the share's body is held to its source ---------------------------------
+
+def _recut(body, key, held):
+    body[key] = held
+    next(c for c in body["reduced"] if c["key"] == key)["held"] = held
+
+
+def _width_cut(entry, body):
+    body["moe_intermediate_size"] = 384
+    body["model"]["expert_mlp_dim"] = 384
+    body["reduced"].append({"key": "moe_intermediate_size",
+                            "published": 768, "held": 384, "why": "-"})
+    entry["reduced"].append("moe_intermediate_size")
+
+
+def _head_width_changed(entry, body):
+    body["model"]["head_dim"] = 64
+
+
+def _seven_experts(entry, body):
+    _recut(body, "num_experts", 7)
+    body["model"]["experts_held"] = 7
+
+
+def _three_layers(entry, body):
+    _recut(body, "num_hidden_layers", 3)
+    body["model"]["num_layers"] = 3
+
+
+def _router_narrower_than_published(entry, body):
+    body["model"]["num_experts"] = 16
+
+
+def _unknown_key(entry, body):
+    body["block_length"] = 4
+
+
+@pytest.mark.parametrize("change,key,why", [
+    (None, None, None),
+    (_width_cut, "moe_intermediate_size", "never cut"),
+    (_head_width_changed, "head_dim", "published 128"),
+    (_seven_experts, "num_experts", "8 experts held"),
+    (_three_layers, "num_hidden_layers", "four layers"),
+    (_router_narrower_than_published, "num_experts",
+     "router keeps its width"),
+    (_unknown_key, "block_length", "not_held"),
+], ids=lambda x: getattr(x, "__name__", None))
+def test_the_share_is_held_to_its_source_and_refused_by_the_keys_name(
+        change, key, why):
+    entry, body = copy.deepcopy((SHARE["entry"], SHARE["body"]))
+    if change is None:
+        published.check(entry, body)
+        source = published.source_of(body)
+        assert len(source) == 24 and len(body["not_held"]) == 11
+        assert [c["key"] for c in body["reduced"]] == entry["reduced"]
+        model = body["model"]
+        assert (model["num_experts"], model["experts_held"],
+                model["diffusion_block"]) == (128, 16, 4)
+        # a body, an entry and a traffic body: no file of the benchmark
+        # names it, and none under benchmarks/ knows its family
+        for root in (ROOT, FIXTURE, SHARE_FIXTURE):
+            assert entry["name"] not in json.dumps(
+                harness.load_json(root, "BENCHMARK.json"))
+        return
+    change(entry, body)
+    with pytest.raises(ValueError, match=f"key '{key}'.*({why})"):
+        published.check(entry, body)
+
+
+# -- counted by the pairs the mask shows ------------------------------------
+
+def test_operations_per_data_token_of_a_block_diffusion_share():
+    # by hand, h=2048, 32 heads of 128 over 4 kv heads, T=4096, b=4:
+    #   projections a layer q 2048*4096 + k, v 2*2048*512 + o 4096*2048
+    #                                             = 18,874,368
+    #   expert layer: 8 * 16/128 = one routed expert 3*2048*768
+    #     = 4,718,592, the router at its 128 outputs 262,144
+    #   six layers, 2 operations a multiply-add: 286,261,248 a
+    #     position, and a data token runs two: the noisy and the clean
+    #   attention a layer 2 * (4096 + 4) * 32 * (128 + 128): T^2 + T*b
+    #     pairs a sequence over T data tokens
+    #   head 2*2048*18992 = 77,791,232 at the masked positions of the
+    #     noisy half: (1 + t_min) / 2 of the data tokens
+    f = flops.forward_flops_per_token(MODEL, TRAFFIC)
+    assert f == {"blocks": 572_522_496, "attention": 403_046_400,
+                 "head": 38_895_616}
+    assert f["blocks"] == 2 * 286_261_248
+    assert f["attention"] == 6 * 2 * (4096 + 4) * 32 * 256
+    assert sum(f.values()) == 1_014_464_512
+    assert flops.train_flops_per_token(MODEL, TRAFFIC) == 3_043_393_536
+    assert flops.head_positions_per_token(
+        {**TRAFFIC, "t_min": 0.2}) == pytest.approx(0.6)
+    # the same model group under a causal objective would run one
+    # position a token; the mask is the model's and is counted alike
+    assert flops.positions_per_token(TRAFFIC) == 2
+    assert flops.positions_per_token({"objective": "causal_lm"}) == 1
+
+
+def test_attention_kernel_work_of_a_block_diffusion_share():
+    # two sequences: six layers, six products a head over T^2 + T*b
+    # pairs a sequence, three at each of the two widths; twelve bf16
+    # arrays of 2T = 8192 positions, q, o, do, dq of 32 heads and k, v,
+    # dk, dv of 4
+    w = flops.attention_kernel_work(MODEL, TRAFFIC)
+    assert w == {"flops": 9_905_268_326_400, "bytes": 5_435_817_984}
+    assert w["flops"] == 6 * 3 * 2 * 2 * 32 * (4096 ** 2 + 4096 * 4) * 256
+    assert w["bytes"] == 6 * 3 * (32 + 4) * 2 * 8192 * 256 * 2
+    # stated as 2T positions under the two masks the harness knew, the
+    # same kernels would have been counted 4.0 and 2.0 times too high
+    as_positions = {**TRAFFIC, "objective": "causal_lm", "seq_len": 8192}
+    plain = {k: v for k, v in MODEL.items() if k != "diffusion_block"}
+    for causal, times in ((False, 4.0), (True, 2.0)):
+        over = flops.attention_kernel_work(
+            {**plain, "causal": causal}, as_positions)["flops"]
+        assert over / w["flops"] == pytest.approx(times, rel=2e-3)
+    least, bound = flops.roofline_seconds(
+        w, harness.peak_of("TPU v5 lite"))
+    assert bound == "compute" and least == pytest.approx(0.05028, rel=1e-3)
+
+
+def block_diffusion_mask(t, b):
+    """[2T, 2T] bool from the equation: with half(i) noisy for i < T
+    and blk(i) = (i mod T) // b, query q sees key k iff both are noisy
+    and blk(q) = blk(k), or q is noisy, k clean and blk(k) < blk(q), or
+    both are clean and blk(k) <= blk(q)."""
+    seen = np.zeros((2 * t, 2 * t), bool)
+    for q in range(2 * t):
+        for k in range(2 * t):
+            q_noisy, k_noisy = q < t, k < t
+            q_blk, k_blk = (q % t) // b, (k % t) // b
+            seen[q, k] = (
+                (q_noisy and k_noisy and q_blk == k_blk)
+                or (q_noisy and not k_noisy and k_blk < q_blk)
+                or (not q_noisy and not k_noisy and k_blk <= q_blk))
+    return seen
+
+
+@pytest.mark.parametrize("t,b", [(8, 8), (8, 1), (16, 4), (12, 3),
+                                 (32, 2), (64, 4), (64, 32)])
+def test_visible_pairs_are_a_brute_force_count_of_the_mask(t, b):
+    mask = block_diffusion_mask(t, b)
+    traffic = {"objective": "block_diffusion", "seq_len": t}
+    assert flops.visible_pairs(
+        {"diffusion_block": b, "causal": False}, traffic) \
+        == int(mask.sum()) == t * t + t * b
+    # no query is blind, and the noisy half is never a key of the clean
+    assert mask.any(axis=1).all() and not mask[t:, :t].any()
+    # the fixture's reference builds the same mask from indices
+    reference = harness.load_reference("rope_swiglu_mapped", SHARE_FIXTURE)
+    index = np.arange(2 * t)
+    assert np.array_equal(np.asarray(
+        reference.visible(index, index, t=t, block=b)), mask)
+    # the two masks there were: full is every pair; causal is counted
+    # as half, which leaves out the diagonal's T/2
+    one = {"objective": "causal_lm", "seq_len": t}
+    assert flops.visible_pairs({"causal": False}, one) == t * t
+    assert flops.visible_pairs({"causal": True}, one) == t * t / 2 \
+        == np.tril(np.ones((t, t), bool)).sum() - t / 2
+
+
+def test_every_per_layer_reader_takes_a_block_diffusion_cell():
+    """``mfu_pct`` has no ``workloads`` list, so it is read in every
+    cell: a traced run of a block-diffusion cell goes through every
+    reader the benchmark has, and the two that count operations count
+    the share's."""
+    run = harness.Run(
+        started=time.perf_counter(), workload="share", chips=1,
+        traffic=TRAFFIC, model_sizes=MODEL, seed=0, seconds=10,
+        trace=True, rehearse=False)
+    run.device_kind = "TPU v5 lite"
+    run.tokens_per_s_per_chip = 20_000.0
+    run.window = (0.0, 1.0)
+    run.log = lambda text: None
+    run.reduced_trace = {"kernel_ms_by_layer": {"attn": 200.0}}
+    read = {m["name"]: harness.load_reader(m["name"])(run)
+            for m in BENCH["per_layer"]}
+    assert read["mfu_pct"] == pytest.approx(
+        100 * 3 * 1_014_464_512 * 20_000 / 197e12)
+    assert read["attn_kernel_roofline"] == pytest.approx(
+        100 * (9_905_268_326_400 / 197e12) / 0.2)
+    assert read["attn_kernel_ms"] == 200.0
+    # an objective no row knows dies in the first of them, by name
+    run.traffic = {**TRAFFIC, "objective": "span_corruption"}
+    with pytest.raises(ValueError, match="unknown objective "
+                                         "'span_corruption'"):
+        harness.load_reader("mfu_pct")(run)
+
+
+# -- the objective's row ----------------------------------------------------
+
+def bd_batch(n=64, t=256, b=4, t_min=0.2, vocab=1024, seed=3):
+    return dp_train.make_batch(
+        {"vocab_size": vocab, "diffusion_block": b},
+        {"objective": "block_diffusion", "seq_len": t, "t_min": t_min},
+        n, seed)
+
+
+def test_block_diffusion_batch_from_the_seed():
+    x0, m, w = bd_batch()
+    assert (x0.dtype, m.dtype, w.dtype) == (np.int32, bool, np.float32)
+    assert x0.shape == m.shape == w.shape == (64, 256)
+    for a, b in zip((x0, m, w), bd_batch()):
+        assert np.array_equal(a, b)
+    assert not np.array_equal(x0, bd_batch(seed=4)[0])
+    # no clean token is the mask token, the last row held
+    assert x0.min() == 0 and x0.max() == 1022
+    # one noise level a sequence and block: constant inside a block,
+    # another in the next, inside (t_min, 1], and w is its reciprocal
+    level = 1.0 / w.astype(np.float64)
+    blocks = level.reshape(64, 64, 4)
+    assert np.all(blocks == blocks[..., :1])
+    assert len(np.unique(blocks[..., 0])) > 0.99 * 64 * 64
+    assert 0.2 < level.min() < 0.21 and 0.99 < level.max() <= 1.0
+    # masked with probability t: over 4096 blocks the masked share is
+    # the mean level, (1 + t_min) / 2 = 0.6, within sampling error
+    # (16,384 tokens at a variance under 1/4: three sigma is 0.012)
+    assert abs(m.mean() - 0.6) < 0.012
+    assert abs(m.mean() - level.mean()) < 0.012
+    assert abs(bd_batch(t_min=0.0)[1].mean() - 0.5) < 0.012
+    # and inside one block a token is masked independently of the next
+    heavy = blocks[..., 0] > 0.9
+    assert m.reshape(64, 64, 4)[heavy].mean() > 0.9
+    assert flops.head_positions_per_token(
+        {"objective": "block_diffusion", "t_min": 0.2}) \
+        == pytest.approx(0.6)
+
+
+def test_block_diffusion_batch_refuses_what_it_cannot_noise():
+    with pytest.raises(ValueError, match="seq_len 254 is no multiple of "
+                                         ".*diffusion_block 4"):
+        bd_batch(t=254)
+    with pytest.raises(ValueError, match="diffusion_block None"):
+        dp_train.make_batch(
+            {"vocab_size": 8}, {"objective": "block_diffusion",
+                                "seq_len": 8, "t_min": 0.0}, 1, 0)
+    with pytest.raises(ValueError, match="t_min 1.0"):
+        bd_batch(t_min=1.0)
+
+
+def test_an_objective_no_row_knows_is_refused_by_name_before_any_device_work(
+        monkeypatch):
+    import horovod_tpu as hvd
+
+    def no_device_work(*args, **kw):
+        raise AssertionError("hvd.init() ran before the refusal")
+
+    monkeypatch.setattr(hvd, "init", no_device_work)
+    traffic = {**TRAFFIC, "objective": "span_corruption"}
+    with pytest.raises(ValueError, match=(
+            "unknown objective 'span_corruption'.*block_diffusion.*"
+            "causal_lm.*masked_lm")):
+        dp_train.build(None, MODEL, traffic)
+    with pytest.raises(ValueError, match="unknown objective"):
+        dp_train.make_batch(MODEL, traffic, 1, 0)
+    # a row is (batch, loss, how many arrays a batch has), and the
+    # three there are need nothing else of the job
+    assert {name: row.n_batch_args
+            for name, row in dp_train.OBJECTIVES.items()} == {
+        "causal_lm": 1, "masked_lm": 3, "block_diffusion": 3}
+
+
+# What the program owes the row, as stubs (PERF.md section 4 names each
+# under the name the job calls): a ``diffusion_block`` field of
+# ``TransformerConfig``, which the model group's key becomes; the mask
+# in ``make_flash_attention_fn(causal=, diffusion_block=)``; a per-row
+# ``weight=`` in ``fused_linear_cross_entropy``. Plain ``jax.numpy``
+# stands in for the kernels; everything else is the program's.
+STUBS = """
+import dataclasses, sys
+sys.path.insert(0, {root!r})
+import jax, jax.numpy as jnp
+from horovod_tpu.models import transformer
+from horovod_tpu.ops import fused_cross_entropy, pallas_attention
+
+
+@dataclasses.dataclass(frozen=True)
+class Config(transformer.TransformerConfig):
+    diffusion_block: int = 0
+
+
+transformer.TransformerConfig = Config
+real_flash = pallas_attention.make_flash_attention_fn
+real_ce = fused_cross_entropy.fused_linear_cross_entropy
+
+
+def make_flash_attention_fn(causal=True, diffusion_block=0, **kw):
+    if not diffusion_block:
+        return real_flash(causal=causal, **kw)
+
+    def attend(q, k, v):  # [B, 2T, heads, d], the noisy half first
+        t = q.shape[1] // 2
+        i = jnp.arange(2 * t)
+        noisy, blk = i < t, (i % t) // diffusion_block
+        seen = ((noisy[:, None] & noisy[None] & (blk[:, None] == blk[None]))
+                | (noisy[:, None] & ~noisy[None] & (blk[None] < blk[:, None]))
+                | (~noisy[:, None] & ~noisy[None]
+                   & (blk[None] <= blk[:, None])))
+        k, v = (jnp.repeat(a, q.shape[2] // a.shape[2], axis=2)
+                for a in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+        s = jnp.where(seen[None, None], s / q.shape[-1] ** 0.5, -1e30)
+        return jnp.einsum("bhqk,bkhd->bqhd",
+                          jax.nn.softmax(s, -1).astype(q.dtype), v)
+    return attend
+
+
+def fused_linear_cross_entropy(hidden, w, targets, *, valid=None,
+                               weight=None, mean=True, **kw):
+    if weight is None:
+        return real_ce(hidden, w, targets, valid=valid, mean=mean, **kw)
+    logits = jnp.einsum("...h,hv->...v", hidden, w.astype(hidden.dtype)
+                        ).astype(jnp.float32)
+    nll = jax.scipy.special.logsumexp(logits, -1) - jnp.take_along_axis(
+        logits, targets[..., None], -1)[..., 0]
+    total = jnp.sum(jnp.where(valid, weight * nll, 0.0))
+    n = jnp.sum(valid)
+    return (total / jnp.maximum(n, 1) if mean else total), n
+
+
+pallas_attention.make_flash_attention_fn = make_flash_attention_fn
+fused_cross_entropy.fused_linear_cross_entropy = fused_linear_cross_entropy
+FAULTS = {{
+    "sound": lambda m: m,
+    # a loss that forgets the weights 1/t, as a masked-LM loss would
+    "unweighted": lambda m: setattr(
+        m, "fused_linear_cross_entropy",
+        lambda *a, weight=None, **kw: fused_linear_cross_entropy(
+            *a, weight=jnp.ones_like(weight), **kw)),
+}}
+FAULTS[sys.argv.pop(1)](fused_cross_entropy)
+from benchmarks import run
+sys.exit(run.main(sys.argv[1:]))
+""".format(root=ROOT)
+
+
+def rehearse_with_stubs(fault, trace):
+    from test_bench_harness import run_py
+
+    done = run_py(fault, "--workload", "bd_dp1", "--seed", "5",
+                  "--seconds", "1", "--trace", str(trace), "--rehearse",
+                  "--root", SHARE_FIXTURE, program=STUBS)
+    assert done.returncode == 0, done.stderr[-4000:]
+    return done, json.loads(done.stdout.strip().splitlines()[-1])
+
+
+def test_a_block_diffusion_cell_is_rehearsed_end_to_end_with_stubs():
+    """The traffic body goes through ``make_batch``, ``make_loss_fn``,
+    ``make_step``, the reference check, the reference's loss over the
+    global batch in blocks of data tokens and every per-layer reader of
+    the fixture's file, with no file under ``benchmarks/`` edited: the
+    stub's loss agrees with the plain reference (which builds the
+    input, the mask and the weighted loss itself) and falls over the 17
+    steps."""
+    done, line = rehearse_with_stubs("sound", 1)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 17
+    compared = line["compared"]
+    assert {"reference_loss", "reference_gradient", "global_batch_loss",
+            "loss_falls"} <= set(compared)
+    assert all(c["ok"] for c in compared.values())
+    assert compared["reference_gradient"]["limit"] == 3e-2
+    assert compared["loss_falls"]["value"] < compared["loss_falls"]["limit"]
+    # traced: the readers ran (a rehearsal prints only the counts)
+    assert set(line["metrics"]) == {"allreduce_ops_per_step",
+                                    "allreduce_mib_per_step"}
+    assert "REHEARSAL" in done.stdout
+
+
+def test_a_loss_that_forgets_the_weights_is_not_correct():
+    _, line = rehearse_with_stubs("unweighted", 0)
+    assert line["correct"] is False
+    assert line["compared"]["reference_loss"]["ok"] is False
+    assert line["compared"]["global_batch_loss"]["ok"] is False
+
+
+# -- the comparison at a share's size ---------------------------------------
+
+def test_a_reference_that_maps_and_recomputes_computes_the_same():
+    """What the contract allows a reference, to fit (``jax.lax.map``
+    over heads, blocks of queries and blocks of rows, ``jax.checkpoint``
+    around a layer and a mapped function), changes what is kept and not
+    what is computed: the share fixture's reference, with blocks small
+    enough to loop at this size, against the fixture's, which has none
+    of it, on the same weights and tokens."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    plain_ref = harness.load_reference("rope_swiglu_lm", FIXTURE)
+    mapped = harness.load_reference("rope_swiglu_mapped", SHARE_FIXTURE)
+    mapped.QUERY_BLOCK, mapped.ROW_BLOCK = 16, 32
+    cfg_file = harness.load_json(FIXTURE, "benchmarks", "configs",
+                                 "fixture-lm.json")
+    sizes = {**cfg_file["model"], **cfg_file["tiny"],
+             "dtype": jnp.float32}
+    traffic = {"objective": "causal_lm", "seq_len": 64,
+               "attention": "xla", "loss_head": "dense"}
+    _, _, plain = dp_train.make_model(sizes, traffic)
+    batch = tuple(jnp.asarray(a) for a in dp_train.make_batch(
+        sizes, traffic, 3, seed=7))
+    params = plain.init(jax.random.PRNGKey(7), batch[0][:1])["params"]
+    want, want_g = jax.value_and_grad(lambda p: plain_ref.mean_loss(
+        p, batch, **plain_ref.arguments(sizes, traffic)))(params)
+    got, got_g = jax.value_and_grad(lambda p: mapped.mean_loss(
+        p, batch, **mapped.arguments(sizes, traffic)))(params)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    diff = jax.tree_util.tree_map(lambda a, b: a - b, got_g, want_g)
+    assert float(optax.global_norm(diff)
+                 / optax.global_norm(want_g)) < 1e-5
+    # and it takes no objective or mask it was not written for
+    with pytest.raises(ValueError, match="masked_lm"):
+        mapped.arguments(sizes, {"objective": "masked_lm"})
+    with pytest.raises(ValueError, match="diffusion_block 0"):
+        mapped.arguments(sizes, {"objective": "block_diffusion"})
+
+
+def test_a_sequence_longer_than_the_references_block_is_refused_by_name():
+    import jax
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("hvd",))
+    module = types.SimpleNamespace(
+        __name__="a_reference", arguments=lambda model, traffic: {},
+        nll_sum=None)
+    traffic = {"objective": "causal_lm", "seq_len": 8193,
+               "batch_per_chip": 2}
+    with pytest.raises(ValueError, match=(
+            "seq_len 8193 data tokens, is longer than the 8192 tokens.*"
+            "'a_reference'")):
+        dp_train.reference_block(module, {}, traffic, mesh)
+    module.BLOCK_TOKENS = 4096  # data tokens, whatever positions they run
+    with pytest.raises(ValueError, match="longer than the 4096 tokens"):
+        dp_train.reference_block(
+            module, {}, {**traffic, "seq_len": 4100}, mesh)
+    _, sequences = dp_train.reference_block(
+        module, {}, {"objective": "block_diffusion", "seq_len": 2048,
+                     "batch_per_chip": 6}, mesh)
+    assert sequences == 2
+
+
+def test_the_stand_in_is_of_the_shares_size():
+    """The configuration ``benchmarks/compare_size.py`` reads on the
+    chip has the share's hidden width, depth and rows and as many
+    parameters within 0.3%, from what the program builds today."""
+    found = harness.load_cell("standin_s8192", SHARE_FIXTURE)
+    model, traffic = found["config"]["model"], found["traffic"]
+    h, layers = model["hidden_size"], model["num_layers"]
+    kv = model["num_kv_heads"] * h // model["num_heads"]
+    layer = 2 * h * h + 2 * h * kv + 3 * h * int(h * model["mlp_ratio"]) \
+        + 2 * h
+    standin = layers * layer + 2 * model["vocab_size"] * h + h
+    share = 6 * (18_874_368 + 16 * 4_718_592 + 262_144) + 77_791_232
+    assert standin == 644_048_896 and share == 645_595_136
+    assert abs(standin / share - 1) < 0.003
+    assert (h, layers, model["vocab_size"]) == (
+        MODEL["hidden_size"], MODEL["num_layers"], MODEL["vocab_size"])
+    # as many positions a sequence and a chip as the share's step runs
+    assert traffic["seq_len"] == 2 * TRAFFIC["seq_len"]
+    assert traffic["batch_per_chip"] == TRAFFIC["batch_per_chip"]
+
+
+# -- NEAR_TIE at many experts -----------------------------------------------
+
+@pytest.mark.parametrize("experts,k,share", [
+    (4, 2, 0.03), (64, 4, 0.43), (128, 8, 0.72)])
+def test_the_near_tie_band_holds_most_tokens_at_many_experts(
+        experts, k, share, capsys):
+    """Arithmetic on seeded normal scores, no reading of any program:
+    the share of tokens whose k-th and (k+1)-th score lie closer than
+    ``NEAR_TIE`` of the spread between the best and the worst, which is
+    what ``reference_choices``' floor gives away. At the stub's 4
+    scores, top 2, it is 3%; at 128, top 8, 72%: the floor there is 28%
+    and holds almost nothing (PERF.md section 7). No limit changes."""
+    assert dp_train.NEAR_TIE == 2.0 ** -6
+    scores = -np.sort(-np.random.default_rng(0).standard_normal(
+        (200_000, experts)), axis=-1)
+    near = np.mean(scores[:, k - 1] - scores[:, k]
+                   < dp_train.NEAR_TIE * (scores[:, 0] - scores[:, -1]))
+    with capsys.disabled():
+        print(f"\nNEAR_TIE 2^-6 of the spread, {experts} scores top {k}: "
+              f"{100 * near:.1f}% of tokens all but tie, floor "
+              f"{100 - 100 * near:.1f}%")
+    assert near == pytest.approx(share, abs=0.01)

@@ -522,8 +522,8 @@ def test_flops_docstring_lists_every_key_it_reads():
         asked |= model.asked
     assert asked == listed, (asked - listed, listed - asked)
     # and the share's file states every one of them but the plain head
-    # width its latent attention has no use for
-    assert listed - set(SHARE) == {"head_dim"}
+    # width its latent attention has no use for, and a mask it has not
+    assert listed - set(SHARE) == {"head_dim", "diffusion_block"}
 
 
 def test_attention_kernel_work_of_two_head_widths_and_a_second_head():
