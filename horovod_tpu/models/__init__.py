@@ -1,5 +1,5 @@
 from .mlp import MLP, MnistNet  # noqa: F401
-from .moe import MoeMlp  # noqa: F401
+from .moe import RoutedMlp  # noqa: F401
 from .resnet import ResNet, ResNet50, ResNet101, ResNet152  # noqa: F401
 from .inception import InceptionV3  # noqa: F401
 from .vgg import VGG16  # noqa: F401

@@ -1,158 +1,238 @@
-"""Mixture-of-Experts layer with expert parallelism over the mesh.
+"""The routed MLP: a layer that is told which of the router's experts
+it holds.
 
-The reference provides the EP *primitive* — alltoall with uneven splits
-(/root/reference/horovod/common/operations.cc:1858, SURVEY.md §2.5 row
-"Alltoall (EP building block)") — but no MoE layer; users were expected to
-build one on top. Here it is first-class, TPU-first:
+The reference provides the expert-parallel *primitive*, alltoall with
+uneven splits (/root/reference/horovod/common/operations.cc:1858,
+SURVEY.md §2.5 row "Alltoall (EP building block)"), and no layer on top
+of it. Here the layer is the model's own (`models/transformer.Block`
+builds it where `num_experts > 0`), in the form expert parallelism asks
+of it anyway:
 
-* top-k token routing with an auxiliary load-balancing loss (the standard
-  switch/mixtral recipe);
-* **dense path** (no `ep` axis bound): every device computes all experts —
-  correct at any scale, optimal single-chip;
-* **expert-parallel path** (`ep` axis bound inside shard_map): experts are
-  sharded over the ep axis and tokens reach their experts via
-  `lax.all_to_all` over ICI — the XLA-native form of the reference's
-  alltoall-based EP. Capacity-factor dropping keeps shapes static for XLA.
+* the router keeps its published width: scores `softmax(W_r x)` in
+  float32 over all `num_experts`, the top `experts_per_token` of them,
+  their weights renormalised over the chosen where `norm_topk_prob`;
+* a share (`experts_held < num_experts`) has no exchange, and two things
+  follow from that. **Its router is not trained**: the gradient through
+  a token's weights needs the result of every expert the token chose,
+  and the absent ones' never arrive; what one chip can compute of it
+  alone (the held experts' terms) is not a part of the deployment's
+  update but a pull towards the experts held here, which by twenty
+  steps at 1e-4 sends this chip five of a token's eight choices
+  (PERF.md section 6, PR 33). So the scores are constants of the
+  backward pass there; a layer that holds every expert trains its
+  router as usual. **Its seeded router is even over the chips**
+  (`share_router_init`): the `experts_held` seeded columns, repeated for
+  every chip of the deployment, so that a token's scores repeat chip by
+  chip and its top `experts_per_token` are that many a chip, whatever
+  the token: the rows routed here are the even share on every seed,
+  which is what a trained router gives on average and seeded normal
+  columns do not (a layer's positions share most of their residual
+  stream at seeded weights and choose alike: 0 to 3 times the even
+  share by the seed). Weights that are loaded are taken as they are;
+* the layer holds `experts_held` SwiGLU experts, the router's experts
+  `first_expert .. first_expert + experts_held - 1`, and returns for
+  every token the sum, over its choices **that live here**, of weight x
+  `W_down(silu(W_gate x) * W_up x)`. A token with no choice here gets
+  zero; what the absent experts would add is left out, and nothing
+  stands in for the chips that hold them or for their exchange;
+* it is exact for any routing: no capacity, no token dropped;
+* its cost follows the rows routed here, not tokens x choices. The
+  (token, choice) pairs are ordered held-expert-major by one sort of
+  integers; the rows of the first `rows_static` of them are gathered
+  and go through three `jax.lax.ragged_dot`s whose groups are the
+  experts, and each row's result is added to its token's, weighted.
+  `rows_static` is the rows even routing sends here (all of them where
+  every expert is held); rows short of it ride the last group with
+  weight zero. Pairs beyond `rows_static` are taken by further products
+  of the same size, each under `lax.cond` and rematerialised, which run
+  only when routing sends that many here: a layer sent twice the even
+  share runs two products, one sent a tenth of it runs one;
+* each token's choices are sown into the Flax collection `choices`
+  (int32 `[..., experts_per_token]`); no auxiliary term is sown.
+
+Scopes (`utils/scopes.LAYER_SCOPES`): `moe_dispatch` names the router,
+the choice, the ordering, the gather and the weighted combine;
+`moe_experts` the three expert products. Trace-time gauges
+(`utils/metrics.record_moe_rows`): `hvd_moe_experts_held`,
+`hvd_moe_router_width`, `hvd_moe_rows_expected`, `hvd_moe_rows_static`.
 """
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+import math
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core import basics
+from ..utils import metrics, scopes
+
+# rows_static is a multiple of this (the MXU's rows, and a tile of
+# every dtype)
+ROWS_MULTIPLE = 512
 
 
-class MoeMlp(nn.Module):
-    """Top-k routed expert MLP (SwiGLU experts).
+def rows_static(tokens: int, experts_per_token: int, experts_held: int,
+                num_experts: int) -> tuple:
+    """(rows even routing sends here, rows one expert product is sized
+    for, the most that any routing sends here). A product is sized for
+    the even share: what routing sends beyond it is the further
+    products', whose cost a layer pays only when it is sent that much.
+    Routing need not be even (PERF.md section 6, PR 33: seeded normal
+    router columns sent a layer 900 to 35,054 rows of an even 16,384 on
+    the chip), and a constant above one here would make every layer pay
+    for the unluckiest."""
+    most = tokens * min(experts_per_token, experts_held)
+    expected = tokens * experts_per_token * experts_held / num_experts
+    if experts_held == num_experts:
+        return expected, most, most  # every pair is routed here
+    static = math.ceil(expected / ROWS_MULTIPLE) * ROWS_MULTIPLE
+    return expected, min(static, most), most
 
-    Args mirror TransformerConfig naming; `ep_axis` names the mesh axis
-    experts shard over when bound (num_experts must divide by its size).
-    """
 
-    hidden_size: int
+def share_router_init(stddev: float, experts_held: int):
+    """A share's seeded router `[hidden, num_experts]`: `experts_held`
+    normal columns, repeated once for every chip of the deployment. A
+    token's scores then repeat with the chips, so where a token's
+    choices are a multiple of the chips its top choices are as many on
+    every chip and the rows routed here are exactly the even share.
+    Nothing else of the forward pass knows: the product, the softmax and
+    the top k run at the router's published width."""
+    normal = nn.initializers.normal(stddev)
+
+    def init(key, shape, dtype=jnp.float32):
+        hidden, experts = shape
+        if experts % experts_held:
+            return normal(key, shape, dtype)
+        return jnp.tile(normal(key, (hidden, experts_held), dtype),
+                        (1, experts // experts_held))
+    return init
+
+
+def expert_product(rows, weights, groups):
+    """`[rows, a] x [experts, a, c] -> [rows, c]`: each row through the
+    matrix of the expert whose group it lies in (`groups` are the
+    experts' row counts, in order). Both sides in the rows' dtype,
+    accumulated in float32 on the MXU, returned in the rows' dtype. One
+    function, so that a control can stand a lower precision in its
+    place (`scripts/routed_readings.py`)."""
+    return lax.ragged_dot(rows, weights.astype(rows.dtype), groups,
+                          preferred_element_type=rows.dtype)
+
+
+class RoutedMlp(nn.Module):
+    """[..., hidden] -> [..., hidden]: this chip's part of a routed
+    SwiGLU MLP. `num_experts` is the router's width, `experts_held` how
+    many of its experts live here, from `first_expert`."""
+
+    num_experts: int
+    experts_held: int
+    experts_per_token: int
     mlp_dim: int
-    num_experts: int = 8
-    top_k: int = 2
-    capacity_factor: float = 1.25
-    ep_axis: str = "ep"
+    norm_topk_prob: bool = True
+    first_expert: int = 0
     dtype: Any = jnp.bfloat16
-    router_aux_weight: float = 0.01
 
     @nn.compact
-    def __call__(self, x) -> Tuple[jax.Array, jax.Array]:
-        """[tokens, hidden] -> ([tokens, hidden], aux_loss)."""
-        t, h = x.shape
-        e, k = self.num_experts, self.top_k
+    def __call__(self, x):
+        *lead, h = x.shape
+        e, held, k = self.num_experts, self.experts_held, \
+            self.experts_per_token
+        if not 0 < k <= e or not 0 < held <= e - self.first_expert:
+            raise ValueError(
+                f"experts_per_token {k} and experts_held {held} from "
+                f"first_expert {self.first_expert} of num_experts {e}")
+        tokens = x.reshape(-1, h)
+        t = tokens.shape[0]
+        expected, static, most = rows_static(t, k, held, e)
+        metrics.record_moe_rows(held, e, expected, static)
 
-        router = nn.Dense(e, dtype=jnp.float32, name="router")
-        logits = router(x.astype(jnp.float32))           # [t, e]
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = lax.top_k(probs, k)        # [t, k]
-        gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
+        # every expert a xavier-uniform matrix of its own
+        init = nn.initializers.xavier_uniform(
+            in_axis=1, out_axis=2, batch_axis=(0,))
+        w_gate = self.param("gate", init, (held, h, self.mlp_dim),
+                            jnp.float32)
+        w_up = self.param("up", init, (held, h, self.mlp_dim), jnp.float32)
+        w_down = self.param("down", init, (held, self.mlp_dim, h),
+                            jnp.float32)
 
-        # load-balancing aux loss (Switch Transformer eq. 4)
-        me = jnp.mean(probs, axis=0)                     # [e]
-        ce = jnp.mean(
-            jax.nn.one_hot(gate_idx[:, 0], e, dtype=jnp.float32), axis=0
-        )
-        aux = self.router_aux_weight * e * jnp.sum(me * ce)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            # bf16 activations are exact in float32, and at the highest
+            # precision so are their products with the float32 kernel:
+            # the scores differ from a float32 model's by the
+            # activations' rounding alone (2 x t x h x e operations)
+            logits = nn.Dense(
+                e, use_bias=False, dtype=jnp.float32,
+                param_dtype=jnp.float32, precision=lax.Precision.HIGHEST,
+                kernel_init=nn.initializers.normal(0.02) if held == e
+                else share_router_init(0.02, held), name="router",
+            )(tokens.astype(jnp.float32))
+            if held < e:
+                # no exchange, no gradient through the scores (above)
+                logits = lax.stop_gradient(logits)
+            weights, chosen = lax.top_k(jax.nn.softmax(logits, -1), k)
+            if self.norm_topk_prob:
+                weights = weights / jnp.sum(weights, -1, keepdims=True)
+            self.sow("choices", "experts", chosen.reshape(*lead, k))
+            # the (token, choice) pairs, held-expert-major: a pair's key
+            # is its expert's place here, `held` where it lives elsewhere
+            local = chosen.reshape(-1) - self.first_expert
+            key = jnp.where((local >= 0) & (local < held), local, held)
+            order = jnp.argsort(key).astype(jnp.int32)
+            sizes = jnp.sum(key[:, None] == jnp.arange(held)[None],
+                            axis=0, dtype=jnp.int32)
+            ends = jnp.cumsum(sizes)
+            routed = ends[-1]
+            chunks = -(-most // static)
+            order = jnp.pad(order, (0, max(0, chunks * static - t * k)))
+            pair_weight = weights.reshape(-1)
 
-        w_in = self.param(
-            "w_in", nn.initializers.lecun_normal(),
-            (e, h, 2 * self.mlp_dim), jnp.float32,
-        ).astype(self.dtype)
-        w_out = self.param(
-            "w_out", nn.initializers.lecun_normal(),
-            (e, self.mlp_dim, h), jnp.float32,
-        ).astype(self.dtype)
+        def chunk(c):
+            """The pairs `[c * static, (c + 1) * static)` of the order:
+            their tokens, and their experts' weighted results
+            `[static, h]` in float32."""
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                lo = c * static
+                pairs = lax.dynamic_slice_in_dim(order, lo, static)
+                real = lo + jnp.arange(static) < routed
+                token = pairs // k
+                rows = tokens[token].astype(self.dtype)
+                scale = jnp.where(real, pair_weight[pairs], 0.0)
+                # each expert's rows inside the chunk; the rows past the
+                # routed ones ride the last group at weight zero
+                inside = jnp.clip(ends - lo, 0, static)
+                groups = jnp.diff(inside, prepend=0)
+                groups = groups.at[-1].add(static - inside[-1])
+            with jax.named_scope(scopes.MOE_EXPERTS):
+                hidden = nn.silu(expert_product(rows, w_gate, groups)) \
+                    * expert_product(rows, w_up, groups)
+                out = expert_product(hidden, w_down, groups)
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                return token, out.astype(jnp.float32) * scale[:, None]
 
-        ep = self._ep_size()
-        if ep > 1:
-            y = self._expert_parallel(x, gate_idx, gate_vals, w_in, w_out, ep)
-        else:
-            y = self._dense(x, gate_idx, gate_vals, w_in, w_out)
-        return y.astype(x.dtype), aux
+        def combine(total, token, out):
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                return total.at[token].add(out)
 
-    # ---------------------------------------------------------------- dense
+        y = combine(jnp.zeros((t, h), jnp.float32), *chunk(0))
+        if chunks > 1:
+            # Pairs past the first product's, where routing sends so many
+            # here: one product of the same size for each `static` of
+            # them, added into the same sum. Both conditions hand the sum
+            # on untouched where they do not hold, and a further product
+            # keeps nothing for the backward pass but its number and its
+            # rows' tokens (a scan would stack what each of them kept)
+            def further(total, c):
+                return lax.cond(
+                    c * static < routed,
+                    lambda total: combine(total, *jax.checkpoint(chunk)(c)),
+                    lambda total: total, total), None
 
-    def _dense(self, x, gate_idx, gate_vals, w_in, w_out):
-        """All experts on every device: one einsum over the expert dim."""
-        xc = x.astype(self.dtype)
-        up = jnp.einsum("th,ehm->tem", xc, w_in)          # [t, e, 2m]
-        g, u = jnp.split(up, 2, axis=-1)
-        act = jax.nn.silu(g) * u
-        per_expert = jnp.einsum("tem,emh->teh", act, w_out)  # [t, e, h]
-        mask = jax.nn.one_hot(
-            gate_idx, self.num_experts, dtype=self.dtype
-        )                                                  # [t, k, e]
-        weights = jnp.einsum(
-            "tke,tk->te", mask, gate_vals.astype(self.dtype)
-        )
-        return jnp.einsum("teh,te->th", per_expert, weights)
-
-    # ------------------------------------------------------ expert parallel
-
-    def _expert_parallel(self, x, gate_idx, gate_vals, w_in, w_out, ep):
-        """Capacity-bucketed dispatch via all_to_all over the ep axis.
-
-        Each device holds num_experts/ep experts (its shard of w_in/w_out
-        is selected by ep rank). Token shards are dispatched: every device
-        builds [e, capacity, h] buckets, all_to_all rotates the expert dim
-        so device j receives the buckets for its experts from every peer,
-        computes, and the reverse all_to_all returns results.
-        """
-        t, h = x.shape
-        e, k = self.num_experts, self.top_k
-        local_e = e // ep
-        capacity = int(self.capacity_factor * k * t / e) + 1
-
-        # position of each (token, k) within its expert's bucket
-        flat_idx = gate_idx.reshape(-1)                    # [t*k]
-        onehot = jax.nn.one_hot(flat_idx, e, dtype=jnp.int32)
-        pos_in_expert = jnp.cumsum(onehot, axis=0) * onehot  # 1-based
-        pos = jnp.sum(pos_in_expert, axis=-1) - 1            # [t*k]
-        keep = pos < capacity                                 # drop overflow
-
-        xc = x.astype(self.dtype)
-        tok = jnp.repeat(jnp.arange(t), k)
-        buckets = jnp.zeros((e, capacity, h), self.dtype)
-        buckets = buckets.at[
-            jnp.where(keep, flat_idx, 0),
-            jnp.where(keep, pos, 0),
-        ].add(jnp.where(keep[:, None], xc[tok], 0))
-
-        # [e, c, h] -> [ep, local_e, c, h]; all_to_all over ep axis swaps
-        # the leading ep dim with the device dim (ICI all-to-all)
-        buckets = buckets.reshape(ep, local_e, capacity, h)
-        recv = lax.all_to_all(
-            buckets, self.ep_axis, split_axis=0, concat_axis=0, tiled=False
-        )                                  # [ep(src), local_e, c, h]
-
-        my = lax.axis_index(self.ep_axis)
-        w_in_l = lax.dynamic_slice_in_dim(w_in, my * local_e, local_e, 0)
-        w_out_l = lax.dynamic_slice_in_dim(w_out, my * local_e, local_e, 0)
-        up = jnp.einsum("slch,lhm->slcm", recv, w_in_l)
-        g, u = jnp.split(up, 2, axis=-1)
-        act = jax.nn.silu(g) * u
-        out = jnp.einsum("slcm,lmh->slch", act, w_out_l)
-
-        back = lax.all_to_all(
-            out, self.ep_axis, split_axis=0, concat_axis=0, tiled=False
-        )                                  # [ep, local_e, c, h] expert-major
-        back = back.reshape(e, capacity, h)
-
-        gathered = back[
-            jnp.where(keep, flat_idx, 0), jnp.where(keep, pos, 0)
-        ]                                  # [t*k, h]
-        gathered = jnp.where(keep[:, None], gathered, 0)
-        weighted = gathered * gate_vals.reshape(-1, 1).astype(self.dtype)
-        return jnp.zeros((t, h), self.dtype).at[tok].add(weighted)
-
-    def _ep_size(self) -> int:
-        sizes = basics.bound_axis_sizes()
-        return sizes.get(self.ep_axis, 1)
+            y = lax.cond(
+                routed > static,
+                lambda y: lax.scan(further, y, jnp.arange(1, chunks))[0],
+                lambda y: y, y)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            return y.astype(x.dtype).reshape(*lead, h)

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import scopes
+from .moe import RoutedMlp
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,10 +52,30 @@ class TransformerConfig:
     remat: bool = False
     rope_theta: float = 10000.0
     layernorm_epsilon: float = 1e-5
+    # one head's width where the model states it apart from
+    # hidden_size // num_heads (the projections are then
+    # hidden_size x num_heads * head_dim); None = that quotient
+    head_dim: Optional[int] = None
+    # RMS norm of every head's q and k (one scale of the head's width
+    # shared by the heads), before rope
+    qk_norm: bool = False
+    # block-diffusion training: the input is [noisy ; clean], 2T
+    # positions, and b = diffusion_block the block length of the mask
+    # (`diffusion_mask`) that stands in place of `causal`; 0 = no such mask
+    diffusion_block: int = 0
+    # routed MLP (models/moe.py) in every layer where num_experts > 0:
+    # the router's width, how many of its experts this chip holds (None
+    # = all), the experts a token is sent to, one expert's width (None
+    # = mlp_dim) and whether a token's chosen weights are renormalised
+    num_experts: int = 0
+    experts_held: Optional[int] = None
+    experts_per_token: int = 0
+    expert_mlp_dim: Optional[int] = None
+    norm_topk_prob: bool = False
 
     @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+    def head_width(self) -> int:
+        return self.head_dim or self.hidden_size // self.num_heads
 
     @property
     def kv_heads(self) -> int:
@@ -168,11 +189,30 @@ def cached_attention(q, k, v, valid):
     return jnp.einsum("bhtm,bhmd->bthd", probs, v)
 
 
-def dot_product_attention(q, k, v, *, causal: bool, mask=None):
+def diffusion_mask(positions: int, block: int):
+    """[P, P] bool, which keys a query sees under block diffusion: the
+    P = 2T positions are [noisy ; clean] and position i lies in block
+    (i mod T) // block. A noisy query sees the noisy keys of its own
+    block and the clean keys of earlier blocks; a clean query sees the
+    clean keys of its own and earlier blocks and no noisy key."""
+    t = positions // 2
+    i = jnp.arange(positions)
+    noisy, blk = i < t, (i % t) // block
+    q_noisy, k_noisy = noisy[:, None], noisy[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return ((q_noisy & k_noisy & (q_blk == k_blk))
+            | (q_noisy & ~k_noisy & (k_blk < q_blk))
+            | (~q_noisy & ~k_noisy & (k_blk <= q_blk)))
+
+
+def dot_product_attention(q, k, v, *, causal: bool, mask=None,
+                          diffusion_block: int = 0):
     """Default attention: q,k,v [B, T, H, D] -> [B, T, H, D].
 
     float32 softmax accumulation on bf16 inputs (TPU-stable). Swappable via
-    `attention_fn` for ring/Ulysses sequence parallelism.
+    `attention_fn` for ring/Ulysses sequence parallelism. With
+    `diffusion_block` the mask is `diffusion_mask` and `causal` is not
+    read.
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -183,7 +223,10 @@ def dot_product_attention(q, k, v, *, causal: bool, mask=None):
         v = jnp.repeat(v, rep, axis=2)
     scale = 1.0 / np.sqrt(D)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    if causal:
+    if diffusion_block:
+        logits = jnp.where(diffusion_mask(Tq, diffusion_block)[None, None],
+                           logits, -1e30)
+    elif causal:
         cm = jnp.tril(jnp.ones((Tq, Tk), dtype=bool))
         logits = jnp.where(cm[None, None], logits, -1e30)
     if mask is not None:
@@ -200,7 +243,7 @@ class Attention(nn.Module):
     def __call__(self, x, positions, mask=None, kv_cache=None, layer=0):
         cfg = self.cfg
         B, T, _ = x.shape
-        H, KH, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+        H, KH, D = cfg.num_heads, cfg.kv_heads, cfg.head_width
         dense = functools.partial(
             nn.DenseGeneral, dtype=cfg.dtype, param_dtype=jnp.float32,
             use_bias=cfg.norm == "layernorm",
@@ -211,6 +254,11 @@ class Attention(nn.Module):
                   kernel_init=nn.initializers.xavier_uniform())(x)
         v = dense(features=(KH, D), name="value",
                   kernel_init=nn.initializers.xavier_uniform())(x)
+        if cfg.qk_norm:
+            q = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
+                        name="q_norm")(q)
+            k = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
+                        name="k_norm")(k)
         if cfg.position == "rope":
             cos, sin = rope_frequencies(D, cfg.max_seq_len, cfg.rope_theta)
             q = apply_rope(q, cos, sin, positions)
@@ -231,7 +279,8 @@ class Attention(nn.Module):
             out = cached_attention(q, k_full, v_full, valid)
         elif self.attention_fn is None:
             attn = functools.partial(
-                dot_product_attention, causal=cfg.causal)
+                dot_product_attention, causal=cfg.causal,
+                diffusion_block=cfg.diffusion_block)
             out = attn(q, k, v, mask=mask)
         else:
             attn = self.attention_fn
@@ -287,8 +336,17 @@ class Block(nn.Module):
                           name="attn")(y, positions, mask,
                                        kv_cache=kv_cache, layer=layer)
         y = _norm(cfg, "ln_mlp")(x)
-        x = x + Mlp(cfg, name="mlp")(y)
-        return x
+        if cfg.num_experts:
+            mlp = RoutedMlp(
+                num_experts=cfg.num_experts,
+                experts_held=cfg.experts_held or cfg.num_experts,
+                experts_per_token=cfg.experts_per_token,
+                mlp_dim=cfg.expert_mlp_dim or cfg.mlp_dim,
+                norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype,
+                name="mlp")
+        else:
+            mlp = Mlp(cfg, name="mlp")
+        return x + mlp(y)
 
 
 class Transformer(nn.Module):

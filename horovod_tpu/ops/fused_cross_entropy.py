@@ -41,13 +41,14 @@ def _pad_w(w, block: int):
     return w, v
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _fused_ce(x, w, targets, valid, gscale, block_vocab):
-    loss, _ = _fused_ce_fwd(x, w, targets, valid, gscale, block_vocab)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _fused_ce(x, w, targets, valid, gscale, weight, block_vocab):
+    loss, _ = _fused_ce_fwd(x, w, targets, valid, gscale, weight,
+                            block_vocab)
     return loss
 
 
-def _fused_ce_fwd(x, w, targets, valid, gscale, block_vocab):
+def _fused_ce_fwd(x, w, targets, valid, gscale, weight, block_vocab):
     n, h = x.shape
     wp, v = _pad_w(w, block_vocab)
     nb = wp.shape[1] // block_vocab
@@ -85,21 +86,26 @@ def _fused_ce_fwd(x, w, targets, valid, gscale, block_vocab):
     )
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
     nll = jnp.where(valid, lse - tgt, 0.0)
+    if weight is not None:  # a row's own weight, [N] float32
+        nll = nll * weight
     nll_sum = jnp.sum(nll)
     loss = nll_sum * gscale
-    return loss, (x, w, targets, valid, lse, gscale, nll_sum)
+    return loss, (x, w, targets, valid, lse, gscale, weight, nll_sum)
 
 
 def _fused_ce_bwd(block_vocab, residuals, g):
-    x, w, targets, valid, lse, gscale, nll_sum = residuals
+    x, w, targets, valid, lse, gscale, weight, nll_sum = residuals
     n, h = x.shape
     wp, v = _pad_w(w, block_vocab)
     nb = wp.shape[1] // block_vocab
     # d loss / d logit_ib = gscale · (softmax_ib − onehot_ib) per valid
-    # row, times the incoming cotangent
+    # row, times the incoming cotangent (and the row's own weight, which
+    # so reaches its row of both products below)
     row = (
         g * gscale * jnp.where(valid, 1.0, 0.0)
     ).astype(jnp.float32)
+    if weight is not None:
+        row = row * weight
 
     def step(carry, base):
         dx, dwp = carry
@@ -137,18 +143,18 @@ def _fused_ce_bwd(block_vocab, residuals, g):
         # gscale is differentiable (a caller may thread dynamic loss
         # scaling through it): d loss / d gscale = Σ nll, saved forward
         g * nll_sum,
+        # the rows' weights are data (a noise level's 1/t), not trained
+        None if weight is None else jnp.zeros_like(weight),
     )
 
 
-_fused_ce.defvjp(
-    lambda x, w, t, va, gs, bv: _fused_ce_fwd(x, w, t, va, gs, bv),
-    _fused_ce_bwd,
-)
+_fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
 @jax.named_scope(scopes.LOSS_HEAD)
 def fused_linear_cross_entropy(
     hidden, w, targets, *, valid: Optional[jnp.ndarray] = None,
+    weight: Optional[jnp.ndarray] = None,
     block_vocab: int = 8192, mean: bool = True,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Cross-entropy of `hidden @ w` against `targets` without ever
@@ -161,8 +167,12 @@ def fused_linear_cross_entropy(
       targets: [...] int class ids (same leading shape as hidden).
       valid: [...] bool; False rows contribute zero (padding / unmasked
         MLM positions). Default: all valid.
+      weight: [...] float; a row's negative log likelihood counts this
+        many times in the loss (a block-diffusion step's 1/t). Default:
+        once.
       block_vocab: vocab tile width (the live-memory knob).
-      mean: divide by the number of valid rows (like the model losses).
+      mean: divide by the number of valid rows (like the model losses);
+        False returns the (weighted) sum.
 
     Returns (loss, n_valid).
     """
@@ -181,7 +191,9 @@ def fused_linear_cross_entropy(
     n_valid = jnp.sum(va)
     denom = jnp.maximum(n_valid, 1).astype(jnp.float32)
     gscale = (1.0 / denom) if mean else jnp.float32(1.0)
-    loss = _fused_ce(x, w, t, contrib, gscale, int(block_vocab))
+    if weight is not None:
+        weight = weight.reshape(-1).astype(jnp.float32)
+    loss = _fused_ce(x, w, t, contrib, gscale, weight, int(block_vocab))
     return loss, n_valid
 
 

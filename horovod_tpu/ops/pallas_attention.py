@@ -35,6 +35,16 @@ blockwise online-softmax attention (Flash Attention) tiled for MXU/VMEM:
   diagonal tile of square blocks is, runs without a loop. Gauges
   `hvd_flash_tiles_per_call` / `hvd_flash_boundary_tiles_per_call`
   count a call's tiles and the masked ones;
+* a block-diffusion mask (`diffusion_block`) is one more classification
+  in `_tile_ranges` beside causal: over the 2T positions [noisy ; clean]
+  a noisy q tile runs the noisy kv tiles of its rows' blocks (masked),
+  the clean kv tiles wholly before them (unmasked) and those its first
+  block crosses (masked); a clean q tile runs the clean tiles
+  block-causally and no noisy one: T^2 + T*b of the 4T^2 pairs;
+* key-value heads may be fewer than query heads: forward and dq read the
+  head `h // (heads / kv_heads)` through their block maps, dkv writes
+  one partial a query head and the group's are summed outside, so no K
+  or V repeated to every query head exists in HBM;
 * f32 accumulators over bf16 inputs (MXU-native mixed precision);
 * the forward emits per-row logsumexp; the backward is two more flash
   kernels (dq over K/V tiles, dk/dv over Q tiles) that rebuild each
@@ -81,18 +91,51 @@ def _reference_attention(q, k, v, causal, scale, query_offset, key_offset):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(jnp.float32))
 
 
+def _block_of(positions, block: int):
+    """positions // block for non-negative int32 positions."""
+    if block & (block - 1) == 0:
+        return lax.shift_right_logical(positions, block.bit_length() - 1)
+    return lax.div(positions, jnp.int32(block))
+
+
+def _diffusion_tile_mask(block_q, block_k, q_base, k_base, half, block):
+    """The block-diffusion mask of one [block_q, block_k] tile that lies
+    in one half of the 2·`half` positions on either side (`half` is a
+    multiple of both block sizes). With blk(i) = (i mod half) // block, a
+    row sees a column iff both are noisy and blk is equal, or the column
+    is clean and its blk is less than the row's, or equal to it too where
+    the row is clean. A clean row sees no noisy column; `_tile_ranges`
+    runs no such tile."""
+    q_clean, k_clean = q_base // half, k_base // half  # 0 or 1, scalars
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    ahead = (_block_of(rows + (q_base - q_clean * half), block)
+             - _block_of(cols + (k_base - k_clean * half), block))
+    # noisy keys: the row's own block, 0 <= ahead <= 0; clean keys: a
+    # block before the row's, or the row's too for a clean row,
+    # 1 - q_clean <= ahead. As two compares against scalars (Mosaic
+    # selects no vector of booleans by a scalar)
+    least = k_clean * (1 - q_clean)
+    most = k_clean * (2 * half)
+    return jnp.logical_and(ahead >= least, ahead <= most)
+
+
 def _tile_mask(block_q, block_k, q_base, k_base, *, causal, q_offset,
-               k_offset, kv_len, padded):
+               k_offset, kv_len, padded, diffusion=None):
     """Validity mask for one [block_q, block_k] logits tile that
     `_tile_ranges` calls masked: the diagonal crosses it (`causal`) or it
     holds padded keys (`padded`, static: kv_len is less than the padded
-    length), so at least one of the two is set.
+    length), so at least one of the two is set; or, under `diffusion`
+    (half, block), the block-diffusion mask of the tile.
 
     `q_base`/`k_base` are the tile's local starting rows/cols; global
     positions add the caller's sequence offsets (ring attention). Row r
     sees column c iff q_offset + q_base + r >= k_offset + k_base + c: one
     compare of the iota difference r - c, the same in every tile, against
     a scalar."""
+    if diffusion:
+        return _diffusion_tile_mask(block_q, block_k, q_base, k_base,
+                                    *diffusion)
     cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     mask = None
     if causal:
@@ -130,8 +173,63 @@ def _clip(x, lo, hi):
     return min(max(x, lo), hi)
 
 
+def _diffusion_ranges(over, base, block_q, block_k, half, block):
+    """`_tile_ranges` under the block-diffusion mask over 2·`half`
+    positions [noisy ; clean], `half` a multiple of both block sizes,
+    blk(i) = (i mod half) // `block`. Arithmetic that holds for a traced
+    `base` and for a Python int alike.
+
+    `over == "kv"`, a q block with rows of blocks first..last:
+      * noisy: the noisy kv tiles that hold a key of those blocks, all
+        called masked (where `block` spans whole tiles the mask is all
+        true there: rare, and only a select lost);
+      * the clean kv tiles: unmasked while every key's block is before
+        the first row's (or is it, for a clean q block), then masked up
+        to the last row's block.
+    `over == "q"`, a kv block: noisy, the noisy q tiles of its keys'
+    blocks, masked; clean, the noisy q tiles from the first that has a
+    row past its first key's block, masked until every row is past its
+    last key's, then the clean q tiles the same way with a key's own
+    block seen too. The masked noisy q tiles are one range for both
+    kinds of kv block: one tile in every program where `block` divides
+    equal tiles."""
+    def ceil_div(a, b):
+        return -(-a // b)
+
+    clean = base // half  # 0 for a noisy block, 1 for a clean one
+    start = base - clean * half
+    if over == "kv":
+        n = half // block_k
+        first, last = start // block, (start + block_q - 1) // block
+        same_lo = (first * block) // block_k
+        same_hi = _clip((last * block + block - 1) // block_k + 1, 0, n)
+        unmasked = _clip(((first + clean) * block) // block_k, 0, n)
+        limit = _clip(ceil_div((last + clean) * block, block_k),
+                      unmasked, n)
+        return [(same_lo, same_lo + (1 - clean) * (same_hi - same_lo), True),
+                (n, n + unmasked, False), (n + unmasked, n + limit, True)]
+    n = half // block_q
+    first, last = start // block, (start + block_k - 1) // block
+    same_lo = (first * block) // block_q
+    same_hi = _clip((last * block + block - 1) // block_q + 1, 0, n)
+    # of a clean kv block: the first q tile with a row that sees its
+    # first key, and the first whose every row sees its last, among the
+    # noisy q tiles (a row sees the blocks before its own) and among the
+    # clean ones (and its own)
+    noisy_lo = _clip(((first + 1) * block) // block_q, 0, n)
+    noisy_all = _clip(ceil_div((last + 1) * block, block_q), noisy_lo, n)
+    clean_lo = _clip((first * block) // block_q, 0, n)
+    clean_all = _clip(ceil_div(last * block, block_q), clean_lo, n)
+    return [(same_lo + clean * (noisy_lo - same_lo),
+             same_hi + clean * (noisy_all - same_hi), True),
+            (noisy_all, noisy_all + clean * (n - noisy_all), False),
+            (n + clean_lo, n + clean_lo + clean * (clean_all - clean_lo),
+             True),
+            (n + clean_all, n + clean_all + clean * (n - clean_all), False)]
+
+
 def _tile_ranges(over, base, block_q, block_k, num_tiles, *, causal,
-                 q_offset, k_offset, kv_len, padded):
+                 q_offset, k_offset, kv_len, padded, diffusion=None):
     """The tiles one program runs, in the order it runs them, as
     `(lo, hi, masked)` ranges of tile indices. `over == "kv"`: the
     program owns the q block at local row `base` and streams the kv tiles
@@ -150,8 +248,11 @@ def _tile_ranges(over, base, block_q, block_k, num_tiles, *, causal,
     In an unmasked tile the mask would be all true, so it runs the body a
     non-causal, unpadded call runs. All three kernels take their ranges
     from here, so forward and backward can never cover different tiles,
-    and the gauges count them from here. Always two ranges, either of
-    which may be empty."""
+    and the gauges count them from here. Two ranges, either of which may
+    be empty; under `diffusion` (half, block) the three or four of
+    `_diffusion_ranges`."""
+    if diffusion:
+        return _diffusion_ranges(over, base, block_q, block_k, *diffusion)
     shift = q_offset - k_offset
     if over == "kv":
         whole = kv_len // block_k  # leading kv tiles with no padded key
@@ -186,12 +287,12 @@ def _every_program(over, own_blocks, block_q, block_k, num_tiles,
 
 
 def _trips(every):
-    """For each of the two ranges, the set of lengths it has over an
+    """For each of the ranges, the set of lengths it has over an
     instance's programs (`_every_program`): `_run_instances` leaves out a
     range that is empty in every program and does not loop over one that
     is one tile in every program."""
     return tuple(frozenset(r[n][1] - r[n][0] for r in every)
-                 for n in range(2))
+                 for n in range(len(every[0])))
 
 
 def _count_tiles(every):
@@ -201,13 +302,31 @@ def _count_tiles(every):
                       if masked)
 
 
-def _rows_may_see_no_key(*, causal, q_offset, k_offset, **_):
+def _rows_may_see_no_key(*, causal, q_offset, k_offset, diffusion=None,
+                         block_q=None, block_k=None, **_):
     """Whether a row can come to a tile with every key it has met so far
     masked, that tile's included. Only where queries start before the
     keys: otherwise every row sees key 0 (never a padded one) in tile 0,
     the first it runs, and from then on its running maximum is a score:
-    a masked lane's `exp(NEG_INF - m)` is exactly 0 without a select."""
+    a masked lane's `exp(NEG_INF - m)` is exactly 0 without a select.
+    Under a block-diffusion mask the first tile a q block runs holds a
+    key of every row's own block (a noisy row's own noisy key, a clean
+    row's clean one, or clean keys before it) where the blocks are equal
+    and whole diffusion blocks or whole parts of one; otherwise a row
+    may wait for its block's tile."""
+    if diffusion:
+        block = diffusion[1]
+        return block_q != block_k or (block_q % block and block % block_q)
     return causal and q_offset < k_offset
+
+
+def _kv_of(q_ref, k_ref):
+    """Maps a program's instance (batch, query head) to its place in the
+    program's K/V block, which holds one head for each
+    `query heads / kv heads` of the block's query heads (all of them
+    where the counts are equal)."""
+    heads_per_kv = q_ref.shape[1] // k_ref.shape[1]
+    return lambda i: (i[0], i[1] // heads_per_kv)
 
 
 def _run_instances(gb, gh, ranges, trips, start, tile, finish, mask):
@@ -269,7 +388,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     # BERT/encoder path) none does
     ranges = _tile_ranges("kv", q_base, block_q, block_k,
                           k_ref.shape[2] // block_k, **geometry)
-    empty_rows = _rows_may_see_no_key(**geometry)
+    empty_rows = _rows_may_see_no_key(block_q=block_q, block_k=block_k,
+                                      **geometry)
+    kv = _kv_of(q_ref, k_ref)
 
     def mask(kb):
         return _tile_mask(block_q, block_k, q_base, kb * block_k, **geometry)
@@ -286,8 +407,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
     def tile(i, kb, q, carry, mask):
         acc, m_prev, l_prev = carry
-        k_tile = k_ref[(*i, pl.ds(kb * block_k, block_k))]
-        v_tile = v_ref[(*i, pl.ds(kb * block_k, block_k))]
+        k_tile = k_ref[(*kv(i), pl.ds(kb * block_k, block_k))]
+        v_tile = v_ref[(*kv(i), pl.ds(kb * block_k, block_k))]
         s = _dot_nt(q, k_tile)
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
@@ -331,6 +452,7 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     q_base = pl.program_id(2) * block_q
     ranges = _tile_ranges("kv", q_base, block_q, block_k,
                           k_ref.shape[2] // block_k, **geometry)
+    kv = _kv_of(q_ref, k_ref)
 
     def mask(kb):
         return _tile_mask(block_q, block_k, q_base, kb * block_k, **geometry)
@@ -342,8 +464,8 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
     def tile(i, kb, fixed, acc, mask):
         q, do, lse, delta = fixed
-        k_tile = k_ref[(*i, pl.ds(kb * block_k, block_k))]
-        v_tile = v_ref[(*i, pl.ds(kb * block_k, block_k))]
+        k_tile = k_ref[(*kv(i), pl.ds(kb * block_k, block_k))]
+        v_tile = v_ref[(*kv(i), pl.ds(kb * block_k, block_k))]
         s = _dot_nt(q, k_tile)
         p = jnp.exp(s - lse[:, None])
         if mask is not None:
@@ -370,8 +492,13 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     """dK/dV for one kv block of gb x gh instances: stream Q/dO tiles.
 
     dV = Pᵀ·dO, dK = scale · dSᵀ·Q. Padded q rows carry dO == 0 and
-    Δ == 0, so they contribute exactly nothing to either sum."""
-    gb, gh, block_k, d = k_ref.shape
+    Δ == 0, so they contribute exactly nothing to either sum. An
+    instance is a (batch, query head): where key-value heads are fewer,
+    `dk_ref`/`dv_ref` hold one partial a query head, of its kv head's
+    block (`_kv_of`), and the caller sums a group's."""
+    gb, gh = q_ref.shape[:2]
+    block_k, d = k_ref.shape[2:]
+    kv = _kv_of(q_ref, k_ref)
     k_base = pl.program_id(2) * block_k
     # the K-padding mask guards this kv block's own padded rows; padded
     # q rows are harmless because their dO and Δ are zero — so the mask
@@ -386,7 +513,7 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     def start(i):
         zeros = jnp.zeros((block_k, d), jnp.float32)
-        return (k_ref[i], v_ref[i]), (zeros, zeros)
+        return (k_ref[kv(i)], v_ref[kv(i)]), (zeros, zeros)
 
     def tile(i, qb, kv, carry, mask):
         k, v = kv
@@ -472,7 +599,7 @@ def _vmem_bytes(rows, cols, itemsize):
 
 
 def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
-                           itemsize):
+                           itemsize, heads_per_kv=1):
     """The block `(gb, gh)` of consecutive (batch, head) instances one
     program of `kernel` ("fwd", "dq" or "dkv") handles, side by side:
     the largest that tiles [batch, heads] in rows (`gh` divides `heads`,
@@ -482,9 +609,13 @@ def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
     larger one does, which is the kernel of one instance a program.
 
     `own_rows` is the program's own block (block_q; block_k for dkv),
-    `other_rows` the padded length it streams over (Tk_p; Tq_p for dkv).
-    A function of shapes and dtype alone: short sequences get many
-    instances a program, long ones one, with nothing to set."""
+    `other_rows` the padded length it streams over (Tk_p; Tq_p for dkv),
+    `d` the head width the call has (a block is charged its lanes, 128
+    for 64 and for 128 alike). Where `heads_per_kv` query heads share a
+    key-value head, a block of heads is whole groups or a whole part of
+    one, so that its K/V block is whole heads. A function of shapes and
+    dtype alone: short sequences get many instances a program, long
+    ones one, with nothing to set."""
     n_own, n_other, n_stats, stats_side = _KERNEL_BLOCKS[kernel]
     stats_rows = own_rows if stats_side == "own" else other_rows
     per_instance = 2 * (
@@ -495,13 +626,30 @@ def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
     units = -(-own_rows // 128) * -(-other_rows // 128)
     most = min(_MOST_INSTANCES, _PROGRAM_TILE_UNITS // units,
                _VMEM_BLOCK_BUDGET // per_instance)
-    blocks = [(1, gh) for gh in range(1, heads + 1) if heads % gh == 0]
+    blocks = [(1, gh) for gh in range(1, heads + 1) if heads % gh == 0
+              and (gh % heads_per_kv == 0 or heads_per_kv % gh == 0)]
     blocks += [(gb, heads) for gb in range(2, batch + 1) if batch % gb == 0]
     return max((blk for blk in blocks if blk[0] * blk[1] <= most),
                key=lambda blk: blk[0] * blk[1], default=(1, 1))
 
 
-def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry):
+def _kv_block(gb, gh, heads_per_kv, rows, d, row_block):
+    """The BlockSpec of a K or V array `[B, heads / heads_per_kv, T, D]`
+    for programs of `gb x gh` (batch, query head) instances: the kv heads
+    of the block's query heads, `rows` of them from row block
+    `row_block(j)`. Equal head counts keep the map they always had."""
+    if heads_per_kv == 1:
+        return pl.BlockSpec((gb, gh, rows, d),
+                            lambda b, h, j: (b, h, row_block(j), 0))
+    gkh = max(gh // heads_per_kv, 1)
+    return pl.BlockSpec(
+        (gb, gkh, rows, d),
+        lambda b, h, j: (b, (h * gh) // (heads_per_kv * gkh),
+                         row_block(j), 0))
+
+
+def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry,
+          heads_per_kv=1):
     """`(gb, gh)`, the grid and the `trips` (`_trips`) of `kernel` over a
     padded [B, H, T, D] array of `shape` whose T is the program's own
     side, `other_rows` the padded length it streams over and `geometry`
@@ -516,7 +664,7 @@ def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry):
     own_rows, other_block = \
         (block_k, block_q) if over == "q" else (block_q, block_k)
     gb, gh = _instances_per_program(kernel, b, h, own_rows, other_rows, d,
-                                    itemsize)
+                                    itemsize, heads_per_kv)
     grid = (b // gb, h // gh, t // own_rows)
     every = _every_program(over, t // own_rows, block_q, block_k,
                            other_rows // other_block, **geometry)
@@ -527,23 +675,32 @@ def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry):
     return gb, gh, grid, _trips(every)
 
 
+def _geometry(causal, query_offset, key_offset, kv_len, tk_p, diffusion):
+    """What `_tile_ranges` and `_tile_mask` take of a call. `diffusion`
+    is the block length b of a block-diffusion mask over the tk_p = 2T
+    positions, which then stands in place of `causal`; 0 for none."""
+    return dict(causal=causal and not diffusion, q_offset=query_offset,
+                k_offset=key_offset, kv_len=kv_len, padded=kv_len < tk_p,
+                diffusion=(tk_p // 2, diffusion) if diffusion else None)
+
+
 def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
-                key_offset, block_q, block_k):
-    """Padded [B, H, Tq_p, D] x [B, H, Tk_p, D] → (out, lse); kv_len is
+                key_offset, block_q, block_k, diffusion=0):
+    """Padded [B, H, Tq_p, D] x [B, KH, Tk_p, D] → (out, lse); kv_len is
     the true (unpadded) key length. Grid (B / gb, H / gh, q-blocks): each
     program holds a block of gb x gh instances
     (`_instances_per_program`); 4-D arrays tile legally because (T, D)
     are the minor-most dims in this layout."""
     b, h, tq_p, d = qq.shape
-    tk_p = kk.shape[2]
-    geometry = dict(causal=causal, q_offset=query_offset,
-                    k_offset=key_offset, kv_len=kv_len, padded=kv_len < tk_p)
+    tk_p, heads_per_kv = kk.shape[2], h // kk.shape[1]
+    geometry = _geometry(causal, query_offset, key_offset, kv_len, tk_p,
+                         diffusion)
     gb, gh, grid, trips = _grid("fwd", qq.shape, block_q, block_k, tk_p,
-                                qq.dtype.itemsize, geometry)
+                                qq.dtype.itemsize, geometry, heads_per_kv)
     kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
                                scale=scale, geometry=geometry, trips=trips)
     rows = pl.BlockSpec((gb, gh, block_q, d), lambda b, h, j: (b, h, j, 0))
-    whole = pl.BlockSpec((gb, gh, tk_p, d), lambda b, h, j: (b, h, 0, 0))
+    whole = _kv_block(gb, gh, heads_per_kv, tk_p, d, lambda j: 0)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -561,13 +718,14 @@ def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
 )
 def _flash(q, k, v, causal, scale, query_offset, key_offset,
-           block_q, block_k):
-    """[B, H, T, D] flash attention core (bhtd layout)."""
+           block_q, block_k, diffusion):
+    """[B, H, T, D] x [B, KH, T, D] flash attention core (bhtd
+    layout)."""
     out, _ = _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
-                        block_q, block_k)
+                        block_q, block_k, diffusion)
     return out
 
 
@@ -576,9 +734,10 @@ def _flash(q, k, v, causal, scale, query_offset, key_offset,
 # model's layers call them with the same shapes, and tracing a kernel
 # body that holds several instances side by side costs as many times one
 # instance's.
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8), inline=True)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9),
+                   inline=True)
 def _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
-               block_q, block_k):
+               block_q, block_k, diffusion):
     tq, tk = q.shape[2], k.shape[2]
     qq = _pad_to(q, 2, block_q)
     kk = _pad_to(k, 2, block_k)
@@ -586,19 +745,21 @@ def _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
     out_p, lse_p = _flash_core(
         qq, kk, vv, tk, causal=causal, scale=scale,
         query_offset=query_offset, key_offset=key_offset,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, diffusion=diffusion,
     )
     out = out_p[:, :, :tq]
     return out, (q, k, v, out, lse_p[:, :, :, :tq])
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5), inline=True)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6),
+                   inline=True)
 def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
-               residuals, g):
+               diffusion, residuals, g):
     q, k, v = residuals[:3]
     out, lse = residuals[3:]
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    kh, tk = k.shape[1:3]
+    heads_per_kv = h // kh
     # Δ_i = Σ_d dO_i ∘ O_i — one cheap fused elementwise pass in XLA,
     # stored alongside lse as [B, H, 1, T]
     delta = jnp.sum(
@@ -612,16 +773,16 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
     vv = _pad_to(v, 2, block_k)
     tq_p, tk_p = qq.shape[2], kk.shape[2]
     itemsize = q.dtype.itemsize
-    geometry = dict(causal=causal, q_offset=query_offset,
-                    k_offset=key_offset, kv_len=tk, padded=tk < tk_p)
+    geometry = _geometry(causal, query_offset, key_offset, tk, tk_p,
+                         diffusion)
 
     gb, gh, grid, trips = _grid("dq", qq.shape, block_q, block_k, tk_p,
-                                itemsize, geometry)
+                                itemsize, geometry, heads_per_kv)
     dq_kernel = functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
                                   scale=scale, geometry=geometry, trips=trips)
     rows = pl.BlockSpec((gb, gh, block_q, d), lambda b, h, j: (b, h, j, 0))
     stats = pl.BlockSpec((gb, gh, 1, block_q), lambda b, h, j: (b, h, 0, j))
-    whole = pl.BlockSpec((gb, gh, tk_p, d), lambda b, h, j: (b, h, 0, 0))
+    whole = _kv_block(gb, gh, heads_per_kv, tk_p, d, lambda j: 0)
     dq = pl.pallas_call(
         dq_kernel,
         grid=grid,
@@ -631,18 +792,21 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
         interpret=interpret(),
     )(qq, do, lse_p, delta_p, kk, vv)
 
-    gb, gh, grid, trips = _grid("dkv", kk.shape, block_q, block_k, tq_p,
-                                itemsize, geometry)
+    # an instance is a (batch, query head): fewer kv heads get one
+    # partial dk, dv a query head, summed over each group below
+    gb, gh, grid, trips = _grid("dkv", (b, h, tk_p, d), block_q, block_k,
+                                tq_p, itemsize, geometry, heads_per_kv)
     dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
                                    scale=scale, geometry=geometry,
                                    trips=trips)
+    own = _kv_block(gb, gh, heads_per_kv, block_k, d, lambda j: j)
     rows = pl.BlockSpec((gb, gh, block_k, d), lambda b, h, j: (b, h, j, 0))
     stats = pl.BlockSpec((gb, gh, 1, tq_p), lambda b, h, j: (b, h, 0, 0))
     whole = pl.BlockSpec((gb, gh, tq_p, d), lambda b, h, j: (b, h, 0, 0))
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=grid,
-        in_specs=[rows, rows, whole, whole, stats, stats],
+        in_specs=[own, own, whole, whole, stats, stats],
         out_specs=[rows, rows],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tk_p, d), k.dtype),
@@ -650,6 +814,10 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
         ],
         interpret=interpret(),
     )(kk, vv, qq, do, lse_p, delta_p)
+    if heads_per_kv > 1:
+        dk, dv = (
+            jnp.sum(x.reshape(b, kh, heads_per_kv, tk_p, d), axis=2,
+                    dtype=jnp.float32).astype(x.dtype) for x in (dk, dv))
 
     return dq[:, :, :tq], dk[:, :, :tk], dv[:, :, :tk]
 
@@ -679,54 +847,76 @@ def _pick_block(requested, t):
 def flash_attention_bhtd(
     q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     query_offset: int = 0, key_offset: int = 0,
-    block_q: int = 512, block_k: int = 512,
+    block_q: int = 512, block_k: int = 512, diffusion_block: int = 0,
 ):
     """Flash attention over [B, H, T, D] tensors — the kernels' native
     layout ((T, D) minor dims tile legally on TPU). Layout-aware callers
     skip the transpose pairs the [B, T, H, D] wrapper needs. GQA kv heads
-    (fewer than q heads, matched on axis 1) are repeated here to full
-    head count, like the bthd wrapper does."""
+    (fewer than q heads, matched on axis 1) are read by the kernels' block
+    maps, a group of consecutive query heads to a kv head; they are never
+    repeated. `diffusion_block` b > 0: the T = 2·half positions are
+    [noisy ; clean] under the block-diffusion mask of block length b
+    (`_diffusion_tile_mask`), in place of `causal`; `half` has to be
+    whole tiles."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    if k.shape[1] != q.shape[1]:
-        rep = q.shape[1] // k.shape[1]
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
-    block_q = _pick_block(block_q, q.shape[2])
-    block_k = _pick_block(block_k, k.shape[2])
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} "
+                         f"key-value heads")
+    if diffusion_block:
+        half = q.shape[2] // 2
+        if (q.shape[2] != k.shape[2] or q.shape[2] % 2
+                or half % diffusion_block or query_offset or key_offset):
+            raise ValueError(
+                f"a block-diffusion mask of block {diffusion_block} takes "
+                f"q and k of the same 2T positions, T a multiple of the "
+                f"block, and no offsets; got {q.shape[2]} and {k.shape[2]}")
+        # tiles lie in one half: one block size, picked for the half
+        block_q = block_k = _pick_block(min(block_q, block_k), half)
+        if half % block_q:
+            raise ValueError(
+                f"a block-diffusion mask over 2 x {half} positions needs "
+                f"halves of whole tiles; the tile is {block_q}")
+    else:
+        block_q = _pick_block(block_q, q.shape[2])
+        block_k = _pick_block(block_k, k.shape[2])
     return _flash(
         q, k, v, causal, float(scale),
         int(query_offset), int(key_offset), int(block_q), int(block_k),
+        int(diffusion_block),
     )
 
 
 def flash_attention(
     q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     query_offset: int = 0, key_offset: int = 0,
-    block_q: int = 512, block_k: int = 512,
+    block_q: int = 512, block_k: int = 512, diffusion_block: int = 0,
 ):
     """Flash attention over [B, T, H, D] tensors (model layout).
 
-    kv heads may be fewer than q heads (GQA): they are repeated to match
-    (the repeat's own VJP sums the per-copy dK/dV back onto the shared
-    heads). `query_offset`/`key_offset` shift the global positions used
+    kv heads may be fewer than q heads (GQA): the kernels read a group's
+    shared head (no repeat), and dK/dV come back summed over the group.
+    `query_offset`/`key_offset` shift the global positions used
     for the causal mask — the hook ring attention uses for rotated KV
-    blocks."""
+    blocks. `diffusion_block`: see `flash_attention_bhtd`."""
     out = flash_attention_bhtd(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), causal=causal, scale=scale,
         query_offset=query_offset, key_offset=key_offset,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, diffusion_block=diffusion_block,
     )
     return out.transpose(0, 2, 1, 3)
 
 
 def make_flash_attention_fn(causal: bool = True, block_q: int = 512,
-                            block_k: int = 512):
+                            block_k: int = 512, diffusion_block: int = 0):
     """attention_fn for models.Transformer (pluggable attention slot).
     block_q/block_k expose the kernel tile sizes for sweeps
-    (HOROVOD_FLASH_BLOCK_Q/K env override them for quick experiments).
+    (HOROVOD_FLASH_BLOCK_Q/K env override them for quick experiments);
+    `diffusion_block` is a block-diffusion model's block length (its
+    `TransformerConfig.diffusion_block`), whose mask then stands in
+    place of `causal`.
 
     Measured dead end for the record: projecting q/k/v straight into the
     kernels' bhtd layout via einsum (skipping the transpose pairs XLA
@@ -740,6 +930,7 @@ def make_flash_attention_fn(causal: bool = True, block_q: int = 512,
 
     def fn(q, k, v):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k)
+                               block_k=block_k,
+                               diffusion_block=diffusion_block)
 
     return fn

@@ -378,7 +378,7 @@ class GenerationEngine:
         self.spec = KVCacheSpec(
             slots=int(slots), layers=cfg.num_layers,
             kv_heads=cfg.kv_heads, max_len=int(max_len),
-            head_dim=cfg.head_dim, dtype=parse_kv_dtype(kv_dtype),
+            head_dim=cfg.head_width, dtype=parse_kv_dtype(kv_dtype),
             block=int(kv_block), compute_dtype=cfg.dtype,
         )
         self._params = jax.device_put(params)

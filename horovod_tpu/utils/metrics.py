@@ -1033,6 +1033,33 @@ def record_flash_programs(kernel: str, instances_per_program: int,
         labelnames=("kernel",)).labels(kernel=kernel).set(boundary_tiles)
 
 
+def record_moe_rows(experts_held: int, router_width: int,
+                    rows_expected: float, rows_static: int) -> None:
+    """What one routed MLP (models/moe.py) was built for: the experts it
+    holds of the router's width, the rows even routing sends it in one
+    call (tokens x experts a token x held / width) and the rows each of
+    its expert products is sized for. Recorded at TRACE time like the
+    flash kernels' gauges above: arithmetic on the last traced call's
+    shapes, nothing inside the step."""
+    if not _enabled:
+        return
+    registry.gauge(
+        "hvd_moe_experts_held",
+        "Experts of the router's that the routed MLP holds").set(
+            experts_held)
+    registry.gauge(
+        "hvd_moe_router_width",
+        "Experts the routed MLP's router scores").set(router_width)
+    registry.gauge(
+        "hvd_moe_rows_expected",
+        "Rows even routing sends the held experts in one call").set(
+            rows_expected)
+    registry.gauge(
+        "hvd_moe_rows_static",
+        "Rows one expert product of the routed MLP is sized for").set(
+            rows_static)
+
+
 def record_overlap_window(frac: float) -> None:
     """The backward-interleaved scheduler's per-step overlap pin
     (ops/overlap.py): the fraction of backward compute the staged

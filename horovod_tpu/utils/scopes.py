@@ -16,3 +16,14 @@ HVD_PACK = "hvd_pack"            # gradients into fusion buckets
 HVD_ALLREDUCE = "hvd_allreduce"  # the collectives, barriers, casts, scaling
 HVD_UNPACK = "hvd_unpack"        # buckets back into a gradient tree
 HVD_INNER_UPDATE = "hvd_inner_update"  # the wrapped optimizer's update
+
+# models/moe.py, both directions: the router's scores, the choice, the
+# renormalisation, the ordering of the (token, choice) pairs, the gather
+# of their rows and the weighted combine; and the three expert products
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+# The scopes that are a layer's own: the benchmark's reduction
+# (benchmarks/scopes.classify) looks for these after the scopes above
+# and before Flax's module names, so `.../mlp/moe_experts/...` is layer
+# `moe_experts` and not `mlp`
+LAYER_SCOPES = (MOE_DISPATCH, MOE_EXPERTS)

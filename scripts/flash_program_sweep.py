@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import argparse
 import importlib.util
+import inspect
 import json
 import os
 import sys
@@ -123,6 +124,8 @@ def kernels(pa, shape):
     b, h, t, causal, _ = shape
     block = min(t, BLOCK)
     static = (causal, HEAD ** -0.5, 0, 0, block, block)
+    if "diffusion" in inspect.signature(pa._flash_bwd).parameters:
+        static += (0,)  # since PR 33: no block-diffusion mask
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v, do = (jax.random.normal(key, (b, h, t, HEAD), jnp.bfloat16)
                    for key in keys)

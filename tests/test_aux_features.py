@@ -1,4 +1,4 @@
-"""SyncBatchNorm, data loaders, callbacks, MoE (tier-2 style: 8-device
+"""SyncBatchNorm, data loaders, callbacks (tier-2 style: 8-device
 virtual mesh via conftest)."""
 
 import numpy as np
@@ -20,7 +20,6 @@ from horovod_tpu.callbacks import (
     LearningRateWarmupCallback,
     MetricAverageCallback,
 )
-from horovod_tpu.models import MoeMlp
 
 
 # ------------------------------------------------------- SyncBatchNorm
@@ -175,58 +174,6 @@ def test_metric_average_callback(hvd8):
     MetricAverageCallback().on_epoch_end(0, logs)
     assert logs["loss"] == pytest.approx(2.0)  # replicated world: identity
     assert logs["name"] == "x"
-
-
-# ------------------------------------------------------- MoE
-
-
-def _moe_apply_dense(layer, params, x):
-    y, aux = layer.apply({"params": params}, x)
-    return y, aux
-
-
-def test_moe_dense_output_is_gated_expert_mix(hvd8):
-    layer = MoeMlp(hidden_size=16, mlp_dim=32, num_experts=4, top_k=2,
-                   dtype=jnp.float32)
-    x = jnp.asarray(
-        np.random.RandomState(0).rand(12, 16), dtype=jnp.float32
-    )
-    params = layer.init(jax.random.PRNGKey(0), x)["params"]
-    y, aux = _moe_apply_dense(layer, params, x)
-    assert y.shape == x.shape
-    assert np.isfinite(float(aux)) and float(aux) >= 0
-
-
-def test_moe_expert_parallel_matches_dense(hvd8):
-    """EP path (all_to_all over ep axis) must produce the dense path's
-    output when capacity is ample."""
-    from jax.sharding import Mesh
-
-    devices = np.asarray(jax.devices()[:4]).reshape(4)
-    mesh = Mesh(devices, ("ep",))
-    layer = MoeMlp(hidden_size=8, mlp_dim=16, num_experts=4, top_k=2,
-                   capacity_factor=8.0, dtype=jnp.float32)
-    tokens = 16
-    x = jnp.asarray(
-        np.random.RandomState(1).rand(tokens, 8), dtype=jnp.float32
-    )
-    params = layer.init(jax.random.PRNGKey(0), x)["params"]
-    y_dense, _ = _moe_apply_dense(layer, params, x)
-
-    def fwd(p, xs):
-        y, aux = layer.apply({"params": p}, xs)
-        return y
-
-    with mesh:
-        y_ep = jax.jit(
-            shard_map(
-                fwd, mesh=mesh, in_specs=(P(), P("ep")), out_specs=P("ep"),
-                check_vma=False,
-            )
-        )(params, x)
-    np.testing.assert_allclose(
-        np.asarray(y_ep), np.asarray(y_dense), atol=2e-4
-    )
 
 
 def test_elastic_sampler_pad_shortfall_keeps_shards_equal():

@@ -150,3 +150,91 @@ def test_fused_causal_lm_loss_matches_model_loss():
     fused, n_fused = fused_causal_lm_loss(hidden, w, toks, block_vocab=32)
     assert int(n_ref) == int(n_fused)
     np.testing.assert_allclose(float(fused), float(ref), rtol=1e-5)
+
+
+# -- a row's own weight (a block-diffusion step's 1/t) ------------------------
+
+def _weighted_case(dtype=jnp.float32):
+    rng = np.random.RandomState(3)
+    N, H, V = 40, 32, 100
+    x = jnp.asarray(rng.normal(size=(2, N // 2, H)), dtype)
+    w = jnp.asarray(rng.normal(size=(H, V)) * 0.1, jnp.float32)
+    t = jnp.asarray(rng.randint(0, V, (2, N // 2)))
+    valid = jnp.asarray(rng.rand(2, N // 2) < 0.6)
+    weight = jnp.asarray(1.0 / rng.uniform(0.1, 1.0, (2, N // 2)),
+                         jnp.float32)
+    return x, w, t, valid, weight
+
+
+def _dense_weighted(x, w, t, valid, weight, mean):
+    logits = x.astype(jnp.float32) @ w
+    nll = jax.scipy.special.logsumexp(logits, -1) - jnp.take_along_axis(
+        logits, t[..., None], -1)[..., 0]
+    total = jnp.sum(jnp.where(valid, weight * nll, 0.0))
+    return total / jnp.maximum(jnp.sum(valid), 1) if mean else total
+
+
+@pytest.mark.parametrize("mean", [False, True])
+def test_per_row_weight_matches_the_dense_expression(mean):
+    """`weight` multiplies a row's nll in the loss and its row of both
+    backward products; `mean=False` returns the weighted sum."""
+    x, w, t, valid, weight = _weighted_case()
+
+    def fused(x, w):
+        return fused_linear_cross_entropy(
+            x, w, t, valid=valid, weight=weight, block_vocab=32,
+            mean=mean)[0]
+
+    def dense(x, w):
+        return _dense_weighted(x, w, t, valid, weight, mean)
+
+    got, (gx, gw) = jax.value_and_grad(fused, argnums=(0, 1))(x, w)
+    want, (wx, ww) = jax.value_and_grad(dense, argnums=(0, 1))(x, w)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    np.testing.assert_allclose(gx, wx, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(gw, ww, rtol=1e-4, atol=1e-6)
+    # a weight of one is no weight, and the count is the valid rows'
+    ones, n = fused_linear_cross_entropy(
+        x, w, t, valid=valid, weight=jnp.ones_like(weight), mean=mean)
+    plain, _ = fused_linear_cross_entropy(x, w, t, valid=valid, mean=mean)
+    assert float(ones) == pytest.approx(float(plain), rel=1e-6)
+    assert int(n) == int(valid.sum())
+
+
+def test_per_row_weight_is_data_and_gets_no_gradient():
+    x, w, t, valid, weight = _weighted_case()
+    g = jax.grad(lambda wt: fused_linear_cross_entropy(
+        x, w, t, valid=valid, weight=wt, mean=False)[0])(weight)
+    assert float(jnp.abs(g).max()) == 0.0
+
+
+def test_without_a_weight_the_loss_lowers_to_the_text_it_had():
+    """The accepted cells call the head without `weight`: the lowered
+    text of its value and gradient is the one of before the argument
+    (SHA-256 of `lower().as_text()` taken on the parent commit of PR 33
+    and on this tree, jax 0.9.0, the one installation here)."""
+    import hashlib
+
+    from horovod_tpu.ops.fused_cross_entropy import fused_causal_lm_loss
+
+    x = jnp.zeros((2, 16, 32), jnp.bfloat16)
+    w = jnp.zeros((32, 100), jnp.float32)
+    t = jnp.zeros((2, 16), jnp.int32)
+    m = jnp.zeros((2, 16), bool)
+    pinned = {
+        "0ccd44855bdc6f94b0083d07db43d964580182d5e0d80fc438a5bc9f33f8fbf3":
+            lambda x, w: fused_linear_cross_entropy(
+                x, w, t, valid=m, block_vocab=64)[0],
+        "ce6a2a1c79ebfa4bd8d5eb4d144de9fd95da5d61a9684a2408205b8d2e5dcf8b":
+            lambda x, w: fused_causal_lm_loss(x, w, t, block_vocab=64)[0],
+    }
+    for digest, fn in pinned.items():
+        text = jax.jit(jax.value_and_grad(fn, argnums=(0, 1))).lower(
+            x, w).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest() == digest
+    # and with one the text differs: the multiplications are there
+    weighted = jax.jit(jax.value_and_grad(
+        lambda x, w: fused_linear_cross_entropy(
+            x, w, t, valid=m, weight=jnp.ones((2, 16)), block_vocab=64)[0],
+        argnums=(0, 1))).lower(x, w).as_text()
+    assert hashlib.sha256(weighted.encode()).hexdigest() not in pinned

@@ -255,3 +255,54 @@ def test_three_flash_kernels_a_layer_where_their_readers_look(cell):
                        (readers.KERNEL_DQ, "backward", 1),
                        (readers.KERNEL_DKV, "backward", 2)]
         for i in range(2)}, kinds
+
+
+# -- the routed MLP's own scopes ---------------------------------------------
+
+ROUTED_CELL = "sdar_bd_s4096"
+
+
+def test_layer_scopes_are_the_routed_mlps_two():
+    assert scopes.LAYER_SCOPES == ("moe_dispatch", "moe_experts") == (
+        scopes.MOE_DISPATCH, scopes.MOE_EXPERTS)
+
+
+@pytest.mark.parametrize("scope", scopes.LAYER_SCOPES)
+def test_routed_mlp_is_named_in_both_directions(scope):
+    """The compiled tiny step of the routed cell names both scopes under
+    every layer's ``mlp``, forward and backward, and the benchmark's
+    reduction classes such a name as the scope's layer, not as ``mlp``;
+    the expert products (``ragged_dot``) are inside ``moe_experts`` and
+    the router, the choice, the gather and the combine inside
+    ``moe_dispatch``."""
+    from benchmarks import scopes as readers
+
+    if ROUTED_CELL not in _COMPILED:
+        _COMPILED[ROUTED_CELL] = compile_tiny_step(ROUTED_CELL, 1)
+    names = compiled(ROUTED_CELL)
+    for phase, marker in (("forward", "jvp(Transformer)"),
+                          ("backward", "transpose(jvp(Transformer))")):
+        for layer in range(6):
+            found = [o for o in having(names, f"/block_{layer}/mlp/")
+                     if scope in parts(o) and readers.classify(o)[0] == phase]
+            assert found, (scope, phase, layer)
+            assert all(marker in o for o in found)
+            assert {readers.classify(o) for o in found} == {(phase, scope)}
+    inside = {o.rsplit("/", 1)[-1].split(".")[0]
+              for o in names if scope in parts(o)}
+    if scope == scopes.MOE_EXPERTS:
+        # nothing of the dispatch is under the products' name
+        assert not inside & {"sort", "top_k", "gather", "scatter-add"}, \
+            inside
+    else:
+        assert {"sort", "gather"} <= inside or {"sort", "top_k"} <= inside, \
+            inside
+    # nothing of the routed MLP is left under the bare module name but
+    # the plumbing of the loop over the further products (its counter,
+    # its conditions, the cotangents' fan-in) and a reshape
+    bare = {o.rsplit("/", 1)[-1].split(".")[0] for o in names
+            if "/mlp/" in o and not parts(o) & set(scopes.LAYER_SCOPES)}
+    assert bare <= {"while", "cond", "closed_call", "lt", "gt", "mul", "sub",
+                    "iota", "dynamic_slice", "convert_element_type",
+                    "reshape", "remat2", "add_any", "add",
+                    "dynamic_update_slice", "mlp"}, bare
