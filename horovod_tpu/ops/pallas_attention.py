@@ -1,4 +1,4 @@
-"""Pallas TPU flash attention (forward + flash backward kernels).
+"""Pallas TPU flash attention (a forward and one backward kernel).
 
 The reference has no attention kernels (it wraps framework models;
 its native compute is limited to fusion-buffer/scale CUDA kernels,
@@ -6,17 +6,18 @@ its native compute is limited to fusion-buffer/scale CUDA kernels,
 TPU-first addition: the transformer family's hot op as Pallas kernels —
 blockwise online-softmax attention (Flash Attention) tiled for MXU/VMEM:
 
-* grid over (batch blocks, head blocks, query blocks): a program handles
-  a block of `gb x gh` consecutive (batch, head) instances side by side,
-  each exactly as a program of its own would, with K/V streaming through
-  VMEM in `block_k`-sized tiles. One short-sequence instance is a chain
-  of dependent steps (matrix product, row maximum, exponential, row sum,
-  matrix product) whose latencies leave the units idle; independent
-  instances in one loop body fill them. `_instances_per_program` picks
-  the block from the shapes and the dtype: the largest of 16 instances
-  at most whose work stays where more instances still pay and whose
-  double-buffered blocks fit a VMEM budget that holds under default
-  compiler options — 16 heads at T=128, 4 at T=512, 2 at T=1024. The
+* grid over (batch blocks, head blocks, query blocks; key-value blocks
+  in the backward): a program handles a block of `gb x gh` consecutive
+  (batch, head) instances side by side, each exactly as a program of its
+  own would, with K/V streaming through VMEM in `block_k`-sized tiles
+  (Q/dO in `block_q`-sized ones in the backward). One short-sequence
+  instance is a chain of dependent steps (matrix product, row maximum,
+  exponential, row sum, matrix product) whose latencies leave the units
+  idle; independent instances in one loop body fill them.
+  `_instances_per_program` picks the block from the shapes and the
+  dtype: the largest of 16 instances at most whose work stays where more
+  instances still pay and whose charge fits the kernel's VMEM budget —
+  16 heads at T=128, 4 at T=512, 2 at T=1024 (4 in the backward). The
   arrays stay [B, H, T, D]: seen as [B·H, T, D] the kernels ran the
   same, but the compiler laid the step around them out differently and
   lost more than they gained. Trace-time gauges
@@ -41,16 +42,21 @@ blockwise online-softmax attention (Flash Attention) tiled for MXU/VMEM:
   the clean kv tiles wholly before them (unmasked) and those its first
   block crosses (masked); a clean q tile runs the clean tiles
   block-causally and no noisy one: T^2 + T*b of the 4T^2 pairs;
-* key-value heads may be fewer than query heads: forward and dq read the
-  head `h // (heads / kv_heads)` through their block maps, dkv writes
-  one partial a query head and the group's are summed outside, so no K
-  or V repeated to every query head exists in HBM;
+* key-value heads may be fewer than query heads: both kernels read the
+  head `h // (heads / kv_heads)` through their block maps, the backward
+  writes one dk, dv partial a query head and the group's are summed
+  outside, so no K or V repeated to every query head exists in HBM;
 * f32 accumulators over bf16 inputs (MXU-native mixed precision);
-* the forward emits per-row logsumexp; the backward is two more flash
-  kernels (dq over K/V tiles, dk/dv over Q tiles) that rebuild each
-  probability tile from (q, k, lse) — the attention matrix is never
-  materialized in HBM in either direction, so training-time HBM traffic
-  stays O(T·D) instead of O(T²).
+* the forward emits per-row logsumexp; the backward is ONE more flash
+  kernel (since PR 34; two before, which each rebuilt every tile): a
+  program owns a kv block, streams the Q/dO tiles, rebuilds each
+  probability tile from (q, k, lse) once and makes dV, dK and dQ from
+  it, five matrix products and one exponential pass a tile. dK and dV
+  are the program's carries; dQ of all the instances' rows is summed in
+  an f32 VMEM scratch that lives across the kv axis of the grid (stated
+  sequential) and is rounded once, at the last kv block. The attention
+  matrix is never materialized in HBM in either direction, so
+  training-time HBM traffic stays O(T·D) instead of O(T²).
 
 Compiled by Mosaic on TPU; `interpret=True` only on the CPU test mesh, which
 runs the same kernel bodies (ops/_pallas.py).
@@ -65,7 +71,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU backend)
+from jax.experimental.pallas import tpu as pltpu
 
 from ._pallas import interpret
 
@@ -233,8 +239,8 @@ def _tile_ranges(over, base, block_q, block_k, num_tiles, *, causal,
     """The tiles one program runs, in the order it runs them, as
     `(lo, hi, masked)` ranges of tile indices. `over == "kv"`: the
     program owns the q block at local row `base` and streams the kv tiles
-    (forward, dq); `over == "q"`: it owns the kv block at local column
-    `base` and streams the q tiles (dkv). `base` is traced (from
+    (the forward); `over == "q"`: it owns the kv block at local column
+    `base` and streams the q tiles (the backward). `base` is traced (from
     `program_id`) or a Python int, `num_tiles` the padded streamed length
     in tiles, `padded` whether kv_len is less than the padded key length.
 
@@ -246,7 +252,7 @@ def _tile_ranges(over, base, block_q, block_k, num_tiles, *, causal,
                   q_offset + q0 < k_offset + k0 + block_k - 1,
                   or it holds a padded key: k0 + block_k > kv_len.
     In an unmasked tile the mask would be all true, so it runs the body a
-    non-causal, unpadded call runs. All three kernels take their ranges
+    non-causal, unpadded call runs. Both kernels take their ranges
     from here, so forward and backward can never cover different tiles,
     and the gauges count them from here. Two ranges, either of which may
     be empty; under `diffusion` (half, block) the three or four of
@@ -329,6 +335,11 @@ def _kv_of(q_ref, k_ref):
     return lambda i: (i[0], i[1] // heads_per_kv)
 
 
+def _instances(gb, gh):
+    """The (batch, head) places of a program's block of instances."""
+    return [(b, h) for b in range(gb) for h in range(gh)]
+
+
 def _run_instances(gb, gh, ranges, trips, start, tile, finish, mask):
     """The loops around one program's `gb x gh` (batch, head) instances.
     For each instance `i = (batch, head)` of the block: `fixed, carry =
@@ -354,7 +365,7 @@ def _run_instances(gb, gh, ranges, trips, start, tile, finish, mask):
     not: PERF.md section 6, PR 25). No instance's own operations or
     their order change, so results are the same bit for bit however
     many there are, and one is the kernel of one instance a program."""
-    instances = [(b, h) for b in range(gb) for h in range(gh)]
+    instances = _instances(gb, gh)
     fixed, carries = zip(*(start(i) for i in instances))
 
     for (lo, hi, masked), lengths in zip(ranges, trips):
@@ -378,7 +389,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
     q_ref: [gb, gh, block_q, D]; k_ref/v_ref: [gb, gh, Tk_padded, D];
     o_ref: [gb, gh, block_q, D]; lse_ref: [gb, gh, 1, block_q] f32 per-row
-    logsumexp of the scaled logits (the backward kernels rebuild P tiles
+    logsumexp of the scaled logits (the backward kernel rebuilds P tiles
     from it). `geometry` is what `_tile_ranges` takes, `trips` what
     `_trips` says of its ranges."""
     gb, gh, block_q, d = q_ref.shape
@@ -440,73 +451,55 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     _run_instances(gb, gh, ranges, trips, start, tile, finish, mask)
 
 
-def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                         dq_ref, *, block_k: int, scale: float,
-                         geometry: dict, trips: tuple):
-    """dQ for one q block of gb x gh instances: stream K/V tiles, rebuild
-    P from lse.
+def _flash_bwd_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                      dk_ref, dv_ref, dq_ref, *scratch, block_q: int,
+                      scale: float, geometry: dict, trips: tuple):
+    """dQ, dK and dV from one probability tile: the program owns one kv
+    block of gb x gh instances and streams the Q/dO tiles.
 
-    dS = P ∘ (dO·Vᵀ − Δ), dQ = scale · dS·K, with Δ = rowsum(dO ∘ O)
-    (zero on padded rows because dO is zero-padded)."""
-    gb, gh, block_q, d = q_ref.shape
-    q_base = pl.program_id(2) * block_q
-    ranges = _tile_ranges("kv", q_base, block_q, block_k,
-                          k_ref.shape[2] // block_k, **geometry)
+    A tile: S = QKᵀ, P = exp(S − lse) (masked only in the ranges
+    `_tile_ranges` marks), dP = dO·Vᵀ, dS = P ∘ (dP − Δ) with
+    Δ = rowsum(dO ∘ O), once; then dV += Pᵀ·dO, dK += dSᵀ·Q and
+    dQ[the tile's rows] += dS·K: five matrix products and one
+    exponential pass. dK and dV are the program's carries, scaled, cast
+    and written when its tiles are done. dQ of every row of the
+    instances is `dq_acc`, f32 [gb, gh, Tq_p, D] in VMEM, which lives
+    across the grid's kv axis (the last, stated sequential): zeroed at
+    kv block 0, added to a tile, and scaled and cast into `dq_ref` once,
+    at the last kv block: `dq_ref`'s block does not depend on the kv
+    axis, so it goes to HBM when the instances change. A row's dQ is
+    summed in f32 over all of its key tiles, in the order of the kv
+    axis, before its one rounding; a row no tile visits (a padded query,
+    one that sees no key) keeps the zero. Where the kv axis is one block
+    the call has no scratch: a tile's dS·K is its rows' whole dQ and is
+    scaled, cast and written at once, into a `dq_ref` zeroed first (the
+    scratch's zeroing, adding and reading back were 6 to 8% of the
+    kernel at T=128 and T=512).
+
+    Padded q rows carry dO == 0 and Δ == 0, so they add exactly nothing
+    to dK and dV. An instance is a (batch, query head): where key-value
+    heads are fewer, `dk_ref`/`dv_ref` hold one partial a query head, of
+    its kv head's block (`_kv_of`), and the caller sums a group's."""
+    gb, gh, tq_p, d = q_ref.shape
+    block_k = k_ref.shape[2]
     kv = _kv_of(q_ref, k_ref)
-
-    def mask(kb):
-        return _tile_mask(block_q, block_k, q_base, kb * block_k, **geometry)
-
-    def start(i):
-        q = (q_ref[i].astype(jnp.float32) * scale).astype(q_ref.dtype)
-        fixed = (q, do_ref[i], lse_ref[(*i, 0)], delta_ref[(*i, 0)])
-        return fixed, jnp.zeros((block_q, d), jnp.float32)
-
-    def tile(i, kb, fixed, acc, mask):
-        q, do, lse, delta = fixed
-        k_tile = k_ref[(*kv(i), pl.ds(kb * block_k, block_k))]
-        v_tile = v_ref[(*kv(i), pl.ds(kb * block_k, block_k))]
-        s = _dot_nt(q, k_tile)
-        p = jnp.exp(s - lse[:, None])
-        if mask is not None:
-            # masked lanes: exp(s - lse) is not 0, and may overflow to
-            # +inf (lse == NEG_INF rows, which no unmasked tile holds);
-            # the where() selects 0 before anything multiplies it
-            p = jnp.where(mask, p, 0.0)
-        dp = _dot_nt(do, v_tile)
-        ds = p * (dp - delta[:, None])
-        return acc + jnp.dot(
-            ds.astype(k_tile.dtype), k_tile,
-            preferred_element_type=jnp.float32,
-        )
-
-    def finish(i, fixed, acc):
-        dq_ref[i] = (acc * scale).astype(dq_ref.dtype)
-
-    _run_instances(gb, gh, ranges, trips, start, tile, finish, mask)
-
-
-def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, scale: float,
-                          geometry: dict, trips: tuple):
-    """dK/dV for one kv block of gb x gh instances: stream Q/dO tiles.
-
-    dV = Pᵀ·dO, dK = scale · dSᵀ·Q. Padded q rows carry dO == 0 and
-    Δ == 0, so they contribute exactly nothing to either sum. An
-    instance is a (batch, query head): where key-value heads are fewer,
-    `dk_ref`/`dv_ref` hold one partial a query head, of its kv head's
-    block (`_kv_of`), and the caller sums a group's."""
-    gb, gh = q_ref.shape[:2]
-    block_k, d = k_ref.shape[2:]
-    kv = _kv_of(q_ref, k_ref)
-    k_base = pl.program_id(2) * block_k
+    j = pl.program_id(2)
+    k_base = j * block_k
     # the K-padding mask guards this kv block's own padded rows; padded
     # q rows are harmless because their dO and Δ are zero — so the mask
     # is only needed for causal or padded-K tiles. q tiles entirely
     # above the diagonal (max(gq) < min(gk)) contribute nothing to this
     # kv block and do not run
-    ranges = _tile_ranges("q", k_base, block_q, block_k,
-                          q_ref.shape[2] // block_q, **geometry)
+    ranges = _tile_ranges("q", k_base, block_q, block_k, tq_p // block_q,
+                          **geometry)
+
+    dq_acc = scratch[0] if scratch else None
+    if dq_acc is None:
+        dq_ref[...] = jnp.zeros(dq_ref.shape, dq_ref.dtype)
+    else:
+        @pl.when(j == 0)
+        def _():
+            dq_acc[...] = jnp.zeros(dq_acc.shape, dq_acc.dtype)
 
     def mask(qb):
         return _tile_mask(block_q, block_k, qb * block_q, k_base, **geometry)
@@ -518,19 +511,28 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     def tile(i, qb, kv, carry, mask):
         k, v = kv
         dk_acc, dv_acc = carry
-        q_tile = q_ref[(*i, pl.ds(qb * block_q, block_q))]
-        do_tile = do_ref[(*i, pl.ds(qb * block_q, block_q))]
-        lse_tile = lse_ref[(*i, 0, pl.ds(qb * block_q, block_q))]
-        delta_tile = delta_ref[(*i, 0, pl.ds(qb * block_q, block_q))]
+        rows = pl.ds(qb * block_q, block_q)
+        q_tile = q_ref[(*i, rows)]
+        do_tile = do_ref[(*i, rows)]
+        lse_tile = lse_ref[(*i, 0, rows)]
+        delta_tile = delta_ref[(*i, 0, rows)]
         qs = (q_tile.astype(jnp.float32) * scale).astype(q_tile.dtype)
         s = _dot_nt(qs, k)
         p = jnp.exp(s - lse_tile[:, None])
         if mask is not None:
+            # masked lanes: exp(s - lse) is not 0, and may overflow to
+            # +inf (lse == NEG_INF rows, which no unmasked tile holds);
+            # the where() selects 0 before anything multiplies it
             p = jnp.where(mask, p, 0.0)
         dv_acc = dv_acc + _dot_tn(p.astype(do_tile.dtype), do_tile)
         dp = _dot_nt(do_tile, v)
-        ds = p * (dp - delta_tile[:, None])
-        dk_acc = dk_acc + _dot_tn(ds.astype(q_tile.dtype), q_tile)
+        ds = (p * (dp - delta_tile[:, None])).astype(q_tile.dtype)
+        dk_acc = dk_acc + _dot_tn(ds, q_tile)
+        dq = jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        if dq_acc is None:
+            dq_ref[(*i, rows)] = (dq * scale).astype(dq_ref.dtype)
+        else:
+            dq_acc[(*i, rows)] += dq
         return dk_acc, dv_acc
 
     def finish(i, kv, carry):
@@ -539,6 +541,19 @@ def _flash_bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dv_ref[i] = dv_acc.astype(dv_ref.dtype)
 
     _run_instances(gb, gh, ranges, trips, start, tile, finish, mask)
+
+    if dq_acc is None:
+        return
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        def cast(qb, _):
+            rows = pl.ds(qb * block_q, block_q)
+            for i in _instances(gb, gh):
+                dq_ref[(*i, rows)] = (dq_acc[(*i, rows)] * scale).astype(
+                    dq_ref.dtype)
+
+        lax.fori_loop(0, tq_p // block_q, cast, None)
 
 
 def _pad_to(x, axis, multiple):
@@ -553,40 +568,69 @@ def _pad_to(x, axis, multiple):
 
 # How many instances a program takes. Measured on a v5e (PERF.md section
 # 6, PR 25 and PR 28: kernels alone, head width 64, bf16, time of the
-# three kernels against one instance a program): what pays is instances
-# side by side, and it pays by how little one instance does, its
-# latencies being what the others fill — T=128 0.38x at 16 instances
+# three kernels of then against one instance a program): what pays is
+# instances side by side, and it pays by how little one instance does,
+# its latencies being what the others fill — T=128 0.38x at 16 instances
 # (0.43x at 8, 0.34x at 32), T=256 0.58x at 16, T=512 0.87x at 4 (0.85x
 # at 8), T=512 causal 0.88x at 4; two tiles of 512 x 512 for a q block,
-# T=1024: 0.986x at 2, unmasked or causal, and 4 causal do not fit VMEM.
-# Fewer programs alone, 16 instances in a loop, gave 0.83x at T=128 and
-# 0.99x at T=512.
+# T=1024: 0.986x at 2, unmasked or causal, and 4 causal did not fit the
+# VMEM those kernels had. Fewer programs alone, 16 instances in a loop,
+# gave 0.83x at T=128 and 0.99x at T=512.
+#
+# The one backward kernel (PR 34; `scripts/flash_program_sweep.py
+# --kernels bwd --parent`, the benchmark cells' shapes, bf16, ms a call
+# of the Mosaic call alone; every row the same bits as one instance a
+# program and as the two kernels it replaced, whose dq + dkv at what
+# they chose is the first column):
+#
+#   shape (B, H, T, D, mask)         two   one kernel, by instances
+#   104, 16,  128,  64, none        0.686  1: 1.093  2: 0.752  4: 0.634
+#                                          8: 0.598  16: 0.589  32: 0.589
+#    26, 16,  512,  64, none        1.085  1: 0.933  2: 0.881  4: 0.845
+#                                          8: 0.834
+#    16, 16, 1024,  64, causal      2.471  1: 1.831  2: 1.760  4: 1.671
+#     2, 32 over 4, 8192, 128, b=4 16.518  1: 11.904  2: 11.473
+#
+# and where the kv axis is one block, with a tile's dS·K written straight
+# into dq and no scratch: T=128 0.552 at 8, 0.542 at 16, 0.543 at 32;
+# T=512 0.834 at 2, 0.795 at 4, 0.784 at 8. More instances pay 3 to 5%
+# a doubling as far as measured, so the backward takes twice the
+# forward's work a program (4 at T=1024; the step compiles no longer for
+# it, 44.4 s against 48.0 for a described v5e), and its VMEM is its own:
+# 16 at T=128, 4 at T=512, 4 at T=1024, 1 at 8,192 positions (2 there
+# would be a 36 MiB charge).
 #
 # Work: in units of one 128 x 128 score tile over all of a block's tiles,
 # causal or not: a causal program runs half of them on average, and a
 # masked tile is only one the diagonal crosses or one that holds padded
 # keys, a compare and a select more. A program takes at most this much;
 # an instance that is more than half of it goes alone.
-_PROGRAM_TILE_UNITS = 64
+_PROGRAM_TILE_UNITS = {"fwd": 64, "bwd": 128}
 # Bodies side by side: each instance is one more body to trace and
 # compile, and at head width 64 the VMEM charge stops at 16 to 20.
 _MOST_INSTANCES = 16
 # VMEM: the pipeline keeps two copies of every block of a program, so a
 # program's blocks are charged twice, each padded to VMEM's tiles of 8
-# sublanes of 32 bits by 128 lanes. Compiled for a described v5e with no
-# compiler option (16 MiB of scoped VMEM a kernel), the smallest charge
-# Mosaic refused was 14.5 MiB and what it says it needs has run up to 1.1
-# times the charge (the kernels' own f32 tiles are on the same stack), so
-# half the 16 MiB is the budget: it holds with the options any caller
-# compiles with.
-_VMEM_BLOCK_BUDGET = 8 * 2**20
+# sublanes of 32 bits by 128 lanes; a scratch is charged once. Compiled
+# for a described v5e with no compiler option (16 MiB of scoped VMEM a
+# kernel), the smallest charge Mosaic refused was 14.5 MiB and what it
+# says it needs has run up to 1.1 times the charge (the kernels' own f32
+# tiles are on the same stack), so half the 16 MiB is the forward's
+# budget: it holds with the options any caller compiles with. The
+# backward call states its own limit, twice its charge and
+# `_VMEM_LIMIT_LEAST` at least, so its budget is all of the 16 MiB; an
+# instance that is over it goes alone, and its call asks for what that
+# takes (one instance at 8,192 positions and width 128 is charged
+# 18 MiB).
+_VMEM_BLOCK_BUDGET = {"fwd": 8 * 2**20, "bwd": 16 * 2**20}
+_VMEM_LIMIT_LEAST = 16 * 2**20
 
 # kernel -> (blocks of the program's own rows, blocks of all the rows of
-# the other side, row statistics (lse, Δ), which side those follow)
+# the other side, row statistics (lse, Δ), which side those follow, f32
+# scratch arrays of all the rows of the other side)
 _KERNEL_BLOCKS = {
-    "fwd": (2, 2, 1, "own"),     # q, o | k, v | lse
-    "dq": (3, 2, 2, "own"),      # q, dO, dq | k, v | lse, Δ
-    "dkv": (4, 2, 2, "other"),   # k, v, dk, dv | q, dO | lse, Δ
+    "fwd": (2, 2, 1, "own", 0),    # q, o | k, v | lse
+    "bwd": (4, 3, 2, "other", 1),  # k, v, dk, dv | q, dO, dq | lse, Δ | dq
 }
 
 
@@ -598,34 +642,42 @@ def _vmem_bytes(rows, cols, itemsize):
         * itemsize
 
 
-def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
-                           itemsize, heads_per_kv=1):
-    """The block `(gb, gh)` of consecutive (batch, head) instances one
-    program of `kernel` ("fwd", "dq" or "dkv") handles, side by side:
-    the largest that tiles [batch, heads] in rows (`gh` divides `heads`,
-    or is all of them with `gb` dividing `batch`), of `_MOST_INSTANCES`
-    at most, whose work stays within `_PROGRAM_TILE_UNITS` and whose
-    double-buffered blocks fit `_VMEM_BLOCK_BUDGET` — and (1, 1) when no
-    larger one does, which is the kernel of one instance a program.
-
-    `own_rows` is the program's own block (block_q; block_k for dkv),
-    `other_rows` the padded length it streams over (Tk_p; Tq_p for dkv),
-    `d` the head width the call has (a block is charged its lanes, 128
-    for 64 and for 128 alike). Where `heads_per_kv` query heads share a
-    key-value head, a block of heads is whole groups or a whole part of
-    one, so that its K/V block is whole heads. A function of shapes and
-    dtype alone: short sequences get many instances a program, long
-    ones one, with nothing to set."""
-    n_own, n_other, n_stats, stats_side = _KERNEL_BLOCKS[kernel]
+def _vmem_charge(kernel, own_rows, other_rows, d, itemsize):
+    """Bytes of VMEM one instance of `kernel` is charged: its blocks
+    (`_KERNEL_BLOCKS`) twice, for the pipeline's two copies, and its
+    scratch once."""
+    n_own, n_other, n_stats, stats_side, n_scratch = _KERNEL_BLOCKS[kernel]
     stats_rows = own_rows if stats_side == "own" else other_rows
-    per_instance = 2 * (
+    return 2 * (
         n_own * _vmem_bytes(own_rows, d, itemsize)
         + n_other * _vmem_bytes(other_rows, d, itemsize)
         + n_stats * _vmem_bytes(1, stats_rows, 4)
-    )
+    ) + n_scratch * _vmem_bytes(other_rows, d, 4)
+
+
+def _instances_per_program(kernel, batch, heads, own_rows, other_rows, d,
+                           itemsize, heads_per_kv=1):
+    """The block `(gb, gh)` of consecutive (batch, head) instances one
+    program of `kernel` ("fwd" or "bwd") handles, side by side: the
+    largest that tiles [batch, heads] in rows (`gh` divides `heads`, or
+    is all of them with `gb` dividing `batch`), of `_MOST_INSTANCES` at
+    most, whose work stays within the kernel's `_PROGRAM_TILE_UNITS` and
+    whose charge (`_vmem_charge`) fits its `_VMEM_BLOCK_BUDGET` — and
+    (1, 1) when no larger one does, which is the kernel of one instance
+    a program.
+
+    `own_rows` is the program's own block (block_q; block_k for the
+    backward), `other_rows` the padded length it streams over (Tk_p;
+    Tq_p for the backward), `d` the head width the call has (a block is
+    charged its lanes, 128 for 64 and for 128 alike). Where
+    `heads_per_kv` query heads share a key-value head, a block of heads
+    is whole groups or a whole part of one, so that its K/V block is
+    whole heads. A function of shapes and dtype alone: short sequences
+    get many instances a program, long ones one, with nothing to set."""
+    per_instance = _vmem_charge(kernel, own_rows, other_rows, d, itemsize)
     units = -(-own_rows // 128) * -(-other_rows // 128)
-    most = min(_MOST_INSTANCES, _PROGRAM_TILE_UNITS // units,
-               _VMEM_BLOCK_BUDGET // per_instance)
+    most = min(_MOST_INSTANCES, _PROGRAM_TILE_UNITS[kernel] // units,
+               _VMEM_BLOCK_BUDGET[kernel] // per_instance)
     blocks = [(1, gh) for gh in range(1, heads + 1) if heads % gh == 0
               and (gh % heads_per_kv == 0 or heads_per_kv % gh == 0)]
     blocks += [(gb, heads) for gb in range(2, batch + 1) if batch % gb == 0]
@@ -660,7 +712,7 @@ def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry,
     from ..utils import metrics
 
     b, h, t, d = shape
-    over = "q" if kernel == "dkv" else "kv"
+    over = "q" if kernel == "bwd" else "kv"
     own_rows, other_block = \
         (block_k, block_q) if over == "q" else (block_q, block_k)
     gb, gh = _instances_per_program(kernel, b, h, own_rows, other_rows, d,
@@ -776,42 +828,37 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
     geometry = _geometry(causal, query_offset, key_offset, tk, tk_p,
                          diffusion)
 
-    gb, gh, grid, trips = _grid("dq", qq.shape, block_q, block_k, tk_p,
-                                itemsize, geometry, heads_per_kv)
-    dq_kernel = functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
-                                  scale=scale, geometry=geometry, trips=trips)
-    rows = pl.BlockSpec((gb, gh, block_q, d), lambda b, h, j: (b, h, j, 0))
-    stats = pl.BlockSpec((gb, gh, 1, block_q), lambda b, h, j: (b, h, 0, j))
-    whole = _kv_block(gb, gh, heads_per_kv, tk_p, d, lambda j: 0)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=grid,
-        in_specs=[rows, rows, stats, stats, whole, whole],
-        out_specs=rows,
-        out_shape=jax.ShapeDtypeStruct((b, h, tq_p, d), q.dtype),
-        interpret=interpret(),
-    )(qq, do, lse_p, delta_p, kk, vv)
-
     # an instance is a (batch, query head): fewer kv heads get one
     # partial dk, dv a query head, summed over each group below
-    gb, gh, grid, trips = _grid("dkv", (b, h, tk_p, d), block_q, block_k,
+    gb, gh, grid, trips = _grid("bwd", (b, h, tk_p, d), block_q, block_k,
                                 tq_p, itemsize, geometry, heads_per_kv)
-    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                                   scale=scale, geometry=geometry,
-                                   trips=trips)
+    kernel = functools.partial(_flash_bwd_kernel, block_q=block_q,
+                               scale=scale, geometry=geometry, trips=trips)
     own = _kv_block(gb, gh, heads_per_kv, block_k, d, lambda j: j)
     rows = pl.BlockSpec((gb, gh, block_k, d), lambda b, h, j: (b, h, j, 0))
     stats = pl.BlockSpec((gb, gh, 1, tq_p), lambda b, h, j: (b, h, 0, 0))
     whole = pl.BlockSpec((gb, gh, tq_p, d), lambda b, h, j: (b, h, 0, 0))
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    dk, dv, dq = pl.pallas_call(
+        kernel,
         grid=grid,
         in_specs=[own, own, whole, whole, stats, stats],
-        out_specs=[rows, rows],
+        out_specs=[rows, rows, whole],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tk_p, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, tk_p, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, tq_p, d), q.dtype),
         ],
+        # one kv block: a tile's dS·K is its rows' whole dq, no scratch
+        scratch_shapes=[pltpu.VMEM((gb, gh, tq_p, d), jnp.float32)]
+        if grid[2] > 1 else [],
+        # dq is summed over the kv axis in the scratch: that axis runs
+        # in order on one core. The call states the VMEM it needs: its
+        # charge and as much again for the kernel's own f32 tiles, so
+        # that it does not depend on what the step is compiled with
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(_VMEM_LIMIT_LEAST, 2 * gb * gh * _vmem_charge(
+                "bwd", block_k, tq_p, d, itemsize))),
         interpret=interpret(),
     )(kk, vv, qq, do, lse_p, delta_p)
     if heads_per_kv > 1:

@@ -1004,8 +1004,10 @@ def record_wire_bytes(logical: int, sent: int) -> None:
 def record_flash_programs(kernel: str, instances_per_program: int,
                           programs: int, tiles: int,
                           boundary_tiles: int) -> None:
-    """The grid one flash-attention kernel ("fwd", "dq", "dkv") was
-    built with (ops/pallas_attention.py), the score tiles one call of it
+    """The grid one flash-attention kernel was built with
+    (ops/pallas_attention.py): "fwd", or "bwd", the one backward kernel
+    that makes dq, dk and dv (since PR 34; "dq" and "dkv" before, which
+    nothing records any more), the score tiles one call of it
     runs and those of them that pay for the mask: boundary tiles, which
     the causal diagonal crosses or which hold padded keys. Recorded at
     TRACE time like the fused collectives' breadcrumb: the kernels

@@ -94,8 +94,8 @@ def test_gradients_flow():
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_backward_matches_reference(causal):
-    """The flash backward kernels (dq + dkv rebuilt from lse) against the
-    materializing reference VJP."""
+    """The flash backward kernel (dq, dk and dv from probability tiles
+    rebuilt from lse) against the materializing reference VJP."""
     q = _rand((2, 96, 2, 32), 30)
     k = _rand((2, 96, 2, 32), 31)
     v = _rand((2, 96, 2, 32), 32)
@@ -219,7 +219,7 @@ def test_fully_masked_rows_output_zero():
 from horovod_tpu.ops import pallas_attention as pa  # noqa: E402
 from horovod_tpu.utils import metrics  # noqa: E402
 
-KERNELS = ("fwd", "dq", "dkv")
+KERNELS = ("fwd", "bwd")
 
 # name -> (q shape, kv shape [B, T, H, D], query_offset, key_offset, block)
 INSTANCE_CASES = {
@@ -300,34 +300,37 @@ def test_instances_per_program_match_reference(monkeypatch, flash_gauges,
     q_blocks, k_blocks = -(-q_shape[1] // block), -(-kv_shape[1] // block)
     assert flash_gauges() == {
         "fwd": (g, instances // g * q_blocks),
-        "dq": (g, instances // g * q_blocks),
-        "dkv": (g, instances // g * k_blocks)}
+        "bwd": (g, instances // g * k_blocks)}
 
 
 # cell -> (B, H, T, block, causal) of its attention calls at head width
-# 64 in bf16, and the instances a program each kernel gets there
+# 64 in bf16, and the instances a program the forward and the backward
+# kernel get there (the backward takes twice the work a program and has
+# its own VMEM: PR 34's sweep)
 CELL_SHAPES = {
-    "gpt2m_dp1": ((16, 16, 1024, 512, True), 2),
-    "gpt2m_dp4": ((16, 16, 1024, 512, True), 2),
-    "bertl_s512": ((26, 16, 512, 512, False), 4),
-    "bertl_s128": ((104, 16, 128, 128, False), 16),
+    "gpt2m_dp1": ((16, 16, 1024, 512, True), (2, 4)),
+    "gpt2m_dp4": ((16, 16, 1024, 512, True), (2, 4)),
+    "bertl_s512": ((26, 16, 512, 512, False), (4, 4)),
+    "bertl_s128": ((104, 16, 128, 128, False), (16, 16)),
 }
 
 
 @pytest.mark.parametrize("cell", CELL_SHAPES)
 def test_chooser_divides_and_stays_inside_its_limits(cell):
     (b, h, t, block, causal), expected = CELL_SHAPES[cell]
-    for kernel in KERNELS:
+    for kernel, want in zip(KERNELS, expected):
         gb, gh = pa._instances_per_program(kernel, b, h, block, t, 64, 2)
         g = gb * gh
-        assert g == expected and b % gb == 0 and h % gh == 0, (kernel, g)
+        assert g == want and b % gb == 0 and h % gh == 0, (kernel, g)
         assert gb == 1 or gh == h  # consecutive instances
-        n_own, n_other, _, _ = pa._KERNEL_BLOCKS[kernel]
-        blocks = 2 * g * (n_own * pa._vmem_bytes(block, 64, 2)
-                          + n_other * pa._vmem_bytes(t, 64, 2))
-        assert blocks <= pa._VMEM_BLOCK_BUDGET, (kernel, g, blocks)
+        n_own, n_other, _, _, n_scratch = pa._KERNEL_BLOCKS[kernel]
+        blocks = g * (2 * (n_own * pa._vmem_bytes(block, 64, 2)
+                           + n_other * pa._vmem_bytes(t, 64, 2))
+                      + n_scratch * pa._vmem_bytes(t, 64, 4))
+        assert blocks <= g * pa._vmem_charge(kernel, block, t, 64, 2) \
+            <= pa._VMEM_BLOCK_BUDGET[kernel], (kernel, g, blocks)
         units = (block // 128) * (t // 128)
-        assert g == 1 or g * units <= pa._PROGRAM_TILE_UNITS
+        assert g == 1 or g * units <= pa._PROGRAM_TILE_UNITS[kernel]
         assert g <= pa._MOST_INSTANCES
 
 
@@ -335,7 +338,7 @@ def test_chooser_divides_and_stays_inside_its_limits(cell):
     ("more heads than a program takes, and a prime number of them",
      (64, 17, 128, 128, 64, 2)),
     ("one instance is all the work a program should do",
-     (16, 16, 512, 2048, 64, 2)),
+     (16, 16, 512, 4096, 64, 2)),
     ("one instance fills the VMEM budget",
      (16, 16, 128, 128, 4096, 4)),
 ])
@@ -480,3 +483,184 @@ def test_gauges_count_tiles_and_boundary_tiles(flash_gauges, shape):
     assert flash_gauges("hvd_flash_tiles_per_call",
                         "hvd_flash_boundary_tiles_per_call") == \
         {kernel: want for kernel in KERNELS}
+
+
+# -- one backward kernel: dq, dk and dv from one probability tile ------------
+#
+# A program owns a kv block and streams the q tiles; dk and dv are its
+# carries, dq of all the instance's rows is summed in an f32 scratch that
+# lives across the grid's kv axis and is cast once, at the last kv block.
+
+
+def _bwd_case(b, h, tq, tk, d, seed, dtype=jnp.float32, kv_heads=None):
+    """q, k, v, dO in the kernels' [B, H, T, D] layout."""
+    shapes = ((b, h, tq, d), (b, kv_heads or h, tk, d),
+              (b, kv_heads or h, tk, d), (b, h, tq, d))
+    return tuple(_rand(s, seed + i).astype(dtype)
+                 for i, s in enumerate(shapes))
+
+
+# (block_q, block_k) of a sequence of 128: one block, 2, 4 and 8 kv
+# blocks, square and unequal
+CUTS = [(128, 128), (32, 128), (32, 64), (32, 32), (32, 16), (64, 64),
+        (64, 32), (16, 64), (128, 32)]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("block_q,block_k", CUTS)
+def test_backward_does_not_depend_on_how_the_sequence_is_cut(
+        causal, block_q, block_k):
+    """From one forward's residuals the three results are the same
+    however the backward cuts the sequence: a row's dq is one f32 sum
+    over its key tiles wherever the kv axis is cut, dk and dv one over
+    the q tiles. Within 1e-6 of the uncut call's for dq (2e-6 for dk
+    and dv, sums over both sequences' rows) and not to the bit: the
+    sums are split at other places, and the CPU's matrix product rounds
+    by the shape it is given (dk and dv keep their bits from 128 keys a
+    block to 64 and lose them at 32)."""
+    q, k, v, ct = _bwd_case(2, 2, 128, 128, 16, 90)
+    static = (causal, 0.25, 0, 0)
+    _, residuals = pa._flash_fwd(q, k, v, *static, 32, 32, 0)
+    whole = pa._flash_bwd(*static, 128, 128, 0, residuals, ct)
+    cut = pa._flash_bwd(*static, block_q, block_k, 0, residuals, ct)
+    for got, want, name in zip(cut, whole, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=0,
+            atol=1e-6 if name == "dq" else 2e-6, err_msg=name)
+    if block_q == 32 and block_k == 64:  # the q tiles of (32, 128)
+        for got, want in zip(cut[1:], pa._flash_bwd(
+                *static, 32, 128, 0, residuals, ct)[1:]):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.fixture
+def backward_calls(monkeypatch):
+    """What each `pallas_call` of the kernels' module returned, padded
+    rows and all (the module's functions slice them off)."""
+    seen = []
+    real = pa.pl.pallas_call
+
+    def spy(*a, **kw):
+        call = real(*a, **kw)
+
+        def run(*args):
+            seen.append(call(*args))
+            return seen[-1]
+
+        return run
+
+    monkeypatch.setattr(pa.pl, "pallas_call", spy)
+    return seen
+
+
+# name -> (Tq, Tk, key_offset, block, rows of q that see no key)
+ZERO_ROW_CASES = {
+    # rows 0..15 see no key, inside a tile that runs
+    "part_of_a_tile": (50, 70, 16, 32, 16),
+    # rows 0..39: q tile 0 runs in no kv block, its scratch rows are
+    # never added to; tile 1 holds rows of both kinds and the padding
+    "a_tile_no_program_runs": (50, 70, 40, 32, 40),
+    # every row: nothing runs at all, the scratch is zeroed and cast
+    "no_tile_at_all": (50, 70, 128, 32, 50),
+    # one kv block
+    "one_kv_block": (50, 30, 16, 32, 16),
+}
+
+
+@pytest.mark.parametrize("case", ZERO_ROW_CASES)
+@pytest.mark.parametrize("most", [1, None], ids=["g1", "gall"])
+def test_backward_rows_no_key_sees_are_exact_zeros_in_dq(
+        monkeypatch, backward_calls, most, case):
+    """Padded query rows and rows that see no key get exact zeros in dq,
+    in the padded array the call itself returns: the scratch is zeroed
+    at the first kv block of every block of instances (interpreted, it
+    starts as NaN, and with one instance a program it still holds the
+    instance before), not left."""
+    tq, tk, k_off, block, blind = ZERO_ROW_CASES[case]
+    if most is not None:
+        monkeypatch.setattr(pa, "_MOST_INSTANCES", most)
+    q, k, v, ct = _bwd_case(2, 2, tq, tk, 16, 100)
+    static = (True, 0.25, 0, k_off, block, block)
+    fwd, bwd = pa._flash_fwd.__wrapped__, pa._flash_bwd.__wrapped__
+    _, residuals = fwd(q, k, v, *static, 0)
+    dq, dk, dv = bwd(*static, 0, residuals, ct)
+    dk_p, dv_p, dq_p = backward_calls[-1]
+    assert dq_p.shape[2] == -(-tq // block) * block > tq
+    np.testing.assert_array_equal(np.asarray(dq_p[:, :, :tq]),
+                                  np.asarray(dq))
+    np.testing.assert_array_equal(np.asarray(dq_p[:, :, tq:]), 0.0)
+    np.testing.assert_array_equal(np.asarray(dq_p[:, :, :blind]), 0.0)
+    assert np.isfinite(np.asarray(dq_p)).all()
+    if blind < tq:
+        assert np.abs(np.asarray(dq[:, :, blind:])).min(axis=-1).max() > 0
+    # and the three against the reference
+    def ref(q, k, v):
+        out = _reference_attention(q, k, v, True, 0.25, 0, k_off)
+        sees = (jnp.arange(tq) >= k_off).astype(out.dtype)
+        return out * sees[None, None, :, None]
+
+    for got, want in zip((dq, dk, dv), jax.vjp(ref, q, k, v)[1](ct)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-4)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("most", [1, 2, 4, 8], ids=lambda g: f"g{g}")
+def test_backward_grouped_kv_heads_whole_and_split_over_programs(
+        monkeypatch, flash_gauges, most, causal):
+    """8 query heads over 2 key-value heads: a program's block of heads
+    is both groups (8), one whole group (4), half a group (2) or one
+    head (1), and dq, dk (the group's partials summed) and dv are the
+    reference's, and the same bits as one head a program."""
+    q, k, v, ct = (x.transpose(0, 2, 1, 3) for x in _bwd_case(
+        1, 8, 64, 64, 16, 110, kv_heads=2))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=32,
+                               block_k=32)
+
+    monkeypatch.setattr(pa, "_MOST_INSTANCES", most)
+    got = jax.vjp(flash, q, k, v)[1](ct)
+    assert flash_gauges()["bwd"] == (most, 8 // most * 2)
+    want = jax.vjp(lambda *a: _ref_btHD(*a, causal), q, k, v)[1](ct)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+    monkeypatch.setattr(pa, "_MOST_INSTANCES", 1)
+    pa._flash_fwd.clear_cache()
+    pa._flash_bwd.clear_cache()
+    for a, b in zip(got, jax.vjp(flash, q, k, v)[1](ct)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _rms_error(x, ref):
+    e = np.asarray(x, np.float32) - np.asarray(ref)
+    return float(np.sqrt((e ** 2).mean() / (np.asarray(ref) ** 2).mean()))
+
+
+@pytest.mark.parametrize("seed", [120, 130])
+def test_backward_dq_is_not_rounded_between_kv_blocks(seed):
+    """bf16 inputs over 32 kv blocks: dq against the f32 reference is
+    off by what one cast to bf16 allows (2^-8, relative, of the root
+    mean square: it reads 0.0024 with the operands' own rounding)
+    however many kv blocks there are. The control that has to fail
+    rounds dq a kv block: the same kernel called once a block of keys
+    and the bf16 partials added in bf16, which is what a dq partial
+    written a block, or a bf16 accumulator, would give (0.0073)."""
+    t, d, block_q, block_k = 256, 64, 64, 8
+    q, k, v, ct = _bwd_case(1, 2, t, t, d, seed, jnp.bfloat16)
+    static = (False, d ** -0.5, 0, 0, block_q, block_k, 0)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, ct)]
+    ref = jax.vjp(lambda q, k, v: _reference_attention(
+        q, k, v, *static[:4]), *f32[:3])[1](f32[3])[0]
+    _, residuals = pa._flash_fwd(q, k, v, *static)
+    dq = pa._flash_bwd(*static, residuals, ct)[0]
+    assert dq.dtype == jnp.bfloat16
+    assert _rms_error(dq, ref) < 2 ** -8
+
+    rounded = jnp.zeros_like(dq)
+    for j in range(0, t, block_k):
+        part = (q, k[:, :, j:j + block_k], v[:, :, j:j + block_k],
+                *residuals[3:])
+        rounded = rounded + pa._flash_bwd(*static, part, ct)[0]
+    assert rounded.dtype == jnp.bfloat16
+    assert _rms_error(rounded, ref) > 1.5 * 2 ** -8
