@@ -55,7 +55,9 @@ def main(argv=None, stats=None):
     p.add_argument("--hidden", type=int, default=0,
                    help="override width (0 = BERT-Large's 1024)")
     p.add_argument("--remat", action="store_true",
-                   help="per-block rematerialization (HBM-bound configs)")
+                   help="per-block rematerialization (HBM-bound configs); "
+                        "with --fused-ce the last block keeps its kernel "
+                        "and matmul results, its backward runs first")
     p.add_argument("--flash", action="store_true",
                    help="Pallas flash-attention kernels (fwd + bwd) in "
                         "place of XLA dot-product attention")

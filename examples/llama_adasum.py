@@ -56,7 +56,9 @@ def main(argv=None):
                    help="width (LLAMA2_7B has 4096)")
     p.add_argument("--vocab", type=int, default=2048,
                    help="vocab (LLAMA2_7B has 32000)")
-    p.add_argument("--remat", action="store_true")
+    p.add_argument("--remat", action="store_true",
+                   help="per-block rematerialization; every block here, "
+                        "the model builds the logits itself")
     p.add_argument("--flash", action="store_true",
                    help="Pallas flash-attention kernels (fwd + bwd)")
     p.add_argument("--bf16-allreduce", action="store_true",

@@ -12,7 +12,11 @@ TPU-first choices:
   * pluggable attention: `attention_fn` lets the parallel layer swap in
     ring attention (parallel/ring_attention.py) or Ulysses all-to-all
     (parallel/ulysses.py) without touching model code
-  * optional per-block remat (`jax.checkpoint`) for HBM-bound configs
+  * optional per-block remat (`jax.checkpoint`) for HBM-bound configs:
+    every block's forward runs again in the backward pass; the last
+    block, whose backward runs first, keeps its kernel calls' and
+    matrix products' results where the caller takes the hidden state
+    to its own head (`TransformerConfig.remat`)
   * params stay plain arrays; tensor/FSDP sharding rules live externally
     in parallel/sharding.py (path-pattern → PartitionSpec over dp/fsdp/tp
     axes) so pjit shards them and XLA inserts the collectives.
@@ -29,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..utils import scopes
+from ..utils import metrics, scopes
 from .moe import RoutedMlp
 
 
@@ -49,6 +53,20 @@ class TransformerConfig:
     activation: str = "gelu"  # "gelu" | "swiglu"
     causal: bool = True
     tie_embeddings: bool = True
+    # rematerialise the blocks (`nn.remat(Block)`): a block keeps only
+    # its input and its forward runs again right before its backward.
+    # The last block's backward is the first to run, with nothing
+    # between its two runs but the final norm and the head, so where
+    # `__call__(return_hidden=True)` hands the hidden state to a head of
+    # the caller's (the fused cross entropy, whose live memory is
+    # O(rows x one vocabulary block)) it keeps its kernel calls' and
+    # matrix products' results from its first run and rebuilds only the
+    # elementwise passes (`_last_block_keeps`; a last block kept WHOLE
+    # holds every float32 intermediate of rope and the norms at once
+    # and needs 3% more HBM than the rematerialised step). Where the
+    # model builds the [B, T, V] logits itself the step's peak sits at
+    # the head and anything kept adds to it, so there every block is
+    # rematerialised.
     remat: bool = False
     rope_theta: float = 10000.0
     layernorm_epsilon: float = 1e-5
@@ -349,6 +367,22 @@ class Block(nn.Module):
         return x + mlp(y)
 
 
+def _last_block_keeps(prim, *_, **__) -> bool:
+    """`jax.checkpoint` policy of the last block under `remat`: is this
+    primitive's result kept from the block's first run? Kept is what is
+    dear to rebuild and small to hold: the kernel calls' results (flash
+    attention's out and lse), the matrix products' (the projections,
+    the router's scores) and the router's choice (`top_k` and the sort
+    of the pairs, about a MiB each). Rebuilt are the elementwise passes
+    (norms, rope, SwiGLU), whose float32 intermediates are 2-4 times an
+    activation each and would all be alive at once if kept, and the
+    routed MLP's rows and expert products (compiled for the chip, the
+    step with `ragged_dot_general` kept too needs 1.1 GiB more: the
+    same primitive runs in the scan over further products; PERF.md
+    section 6, PR 40)."""
+    return prim.name in ("pallas_call", "dot_general", "top_k", "sort")
+
+
 class Transformer(nn.Module):
     """Decoder/encoder stack with LM head; covers GPT-2 (causal + learned
     pos), BERT (bidirectional) and Llama (causal + rope/rms/swiglu)."""
@@ -387,10 +421,20 @@ class Transformer(nn.Module):
             )
             x = x + pos_emb[positions].astype(cfg.dtype)
 
-        block = Block
-        if cfg.remat:
-            block = nn.remat(Block, static_argnums=())
+        # under `remat` the last block keeps its dear results where the
+        # caller takes the hidden state to a head of its own: its
+        # backward runs first, right after the head's (see
+        # `TransformerConfig.remat`)
+        kept = int(cfg.remat and return_hidden and cfg.num_layers > 0)
+        rematerialised = cfg.num_layers - kept if cfg.remat else 0
+        metrics.record_remat_blocks(rematerialised, kept)
         for i in range(cfg.num_layers):
+            block = Block
+            if i < rematerialised:
+                block = nn.remat(Block, static_argnums=())
+            elif kept:
+                block = nn.remat(Block, static_argnums=(),
+                                 policy=_last_block_keeps)
             if kv_cache is None:
                 # training/one-shot path: exact pre-cache call shape so
                 # remat'd and jitted programs lower identically

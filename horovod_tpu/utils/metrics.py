@@ -1062,6 +1062,26 @@ def record_moe_rows(experts_held: int, router_width: int,
             rows_static)
 
 
+def record_remat_blocks(rematerialised: int, kept: int) -> None:
+    """How `Transformer` (models/transformer.py) built its blocks under
+    `remat`: those whose whole forward runs again in the backward pass
+    and those that keep their kernel calls' and matrix products'
+    results from their first run (the last one, where the caller takes
+    the hidden state to its own head); 0 and 0 without `remat`.
+    Recorded at TRACE time like the gauges above: the last traced
+    call's, nothing inside the step."""
+    if not _enabled:
+        return
+    registry.gauge(
+        "hvd_remat_blocks",
+        "Blocks whose whole forward runs again in the backward "
+        "pass").set(rematerialised)
+    registry.gauge(
+        "hvd_remat_blocks_kept",
+        "Blocks under remat that keep their kernel and matmul results "
+        "(the last, before a caller's head)").set(kept)
+
+
 def record_overlap_window(frac: float) -> None:
     """The backward-interleaved scheduler's per-step overlap pin
     (ops/overlap.py): the fraction of backward compute the staged

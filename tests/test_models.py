@@ -94,16 +94,136 @@ def test_causality():
     )
 
 
-def test_remat_matches_no_remat():
-    cfg_r = dataclasses.replace(TINY_GPT, remat=True)
-    toks = jnp.ones((2, 8), dtype=jnp.int32)
-    m1, m2 = Transformer(TINY_GPT), Transformer(cfg_r)
-    params = m1.init(jax.random.PRNGKey(0), toks)
+def _remat_pair(num_layers):
+    """A tiny model without `remat` and with it, one parameter tree."""
+    cfg = dataclasses.replace(TINY_GPT, num_layers=num_layers)
+    plain = Transformer(cfg)
+    remat = Transformer(dataclasses.replace(cfg, remat=True))
+    toks = jnp.asarray(
+        np.random.RandomState(num_layers).randint(0, 128, size=(2, 8)),
+        dtype=jnp.int32)
+    return plain, remat, plain.init(jax.random.PRNGKey(0), toks), toks
+
+
+def _head_loss(model, return_hidden):
+    """A scalar loss over the model's own dense logits, or over the
+    hidden state handed to the fused cross entropy (the tied head)."""
+    from horovod_tpu.ops.fused_cross_entropy import \
+        fused_linear_cross_entropy
+
+    def loss(params, toks):
+        out = model.apply(params, toks, return_hidden=return_hidden)
+        if not return_hidden:
+            return causal_lm_loss(out, toks)[0]
+        w = params["params"]["tok_emb"]["embedding"].T
+        return fused_linear_cross_entropy(
+            out[:, :-1], w, toks[:, 1:], block_vocab=64)[0]
+    return loss
+
+
+def _eqns(jaxpr, name):
+    """Equations of primitive ``name`` in the jaxpr and those inside."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, name)
+
+
+def _grad_jaxpr(model, return_hidden, params, toks):
+    return jax.make_jaxpr(jax.grad(_head_loss(model, return_hidden)))(
+        params, toks).jaxpr
+
+
+def _paths(tree):
+    return [jax.tree_util.keystr(k)
+            for k, _ in jax.tree_util.tree_leaves_with_path(tree)]
+
+
+@pytest.mark.parametrize("return_hidden", [False, True])
+@pytest.mark.parametrize("num_layers", [1, 2, 3])
+def test_remat_matches_no_remat(num_layers, return_hidden):
+    """`remat` changes what is kept for the backward pass and nothing
+    that is computed: the outputs, the parameter tree's paths and every
+    leaf of a scalar loss's gradient are the un-rematerialised model's,
+    on the dense-logits path (every block rematerialised) and on the
+    hidden-state path (the last block kept)."""
+    plain, remat, params, toks = _remat_pair(num_layers)
     np.testing.assert_allclose(
-        np.asarray(m1.apply(params, toks)),
-        np.asarray(m2.apply(params, toks)),
+        np.asarray(plain.apply(params, toks, return_hidden=return_hidden)),
+        np.asarray(remat.apply(params, toks, return_hidden=return_hidden)),
         rtol=1e-5,
     )
+    assert _paths(remat.init(jax.random.PRNGKey(0), toks)) == _paths(params)
+    g_plain = jax.grad(_head_loss(plain, return_hidden))(params, toks)
+    g_remat = jax.grad(_head_loss(remat, return_hidden))(params, toks)
+    assert _paths(g_remat) == _paths(g_plain)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_plain),
+                            jax.tree_util.tree_leaves(g_remat)):
+        np.testing.assert_allclose(
+            np.asarray(b), np.asarray(a), rtol=1e-5, atol=1e-7,
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("return_hidden", [False, True])
+@pytest.mark.parametrize("num_layers", [1, 2, 3])
+def test_remat_keeps_the_last_block_before_a_callers_head(
+        num_layers, return_hidden):
+    """The gradient's jaxpr holds one `remat2` equation a block under
+    `remat` and none without. Where the model builds the logits itself
+    every one of them keeps nothing; where the caller takes the hidden
+    state to its own head the last block's has the policy that keeps
+    its matrix products, so the gradient runs a block's forward
+    products again once for every block but the last."""
+    from horovod_tpu.models.transformer import _last_block_keeps
+
+    plain, remat, params, toks = _remat_pair(num_layers)
+    j_plain = _grad_jaxpr(plain, return_hidden, params, toks)
+    j_remat = _grad_jaxpr(remat, return_hidden, params, toks)
+    assert not list(_eqns(j_plain, "remat2"))
+    policies = [e.params["policy"] for e in _eqns(j_remat, "remat2")]
+    kept = int(return_hidden)
+    # in the order the backward pass runs them: the last block first
+    assert policies == [_last_block_keeps] * kept \
+        + [None] * (num_layers - kept)
+
+    # a block's second run is seven products: q, k, v, scores, values,
+    # out and fc1 (fc2's result is dead code in it)
+    dots = [len(list(_eqns(j, "dot_general"))) for j in (j_plain, j_remat)]
+    assert dots[1] - dots[0] == 7 * (num_layers - kept)
+
+
+@pytest.mark.parametrize("remat,return_hidden,want", [
+    (True, True, (4, 1)), (True, False, (5, 0)),
+    (False, True, (0, 0)), (False, False, (0, 0))])
+def test_remat_gauges_say_what_was_rematerialised_and_kept(
+        remat, return_hidden, want):
+    """`hvd_remat_blocks` / `hvd_remat_blocks_kept`, set while the model
+    is traced, on both head paths and without `remat`."""
+    from horovod_tpu.utils import metrics
+
+    cfg = dataclasses.replace(TINY_GPT, num_layers=5, remat=remat)
+    model = Transformer(cfg)
+    toks = jnp.ones((1, 8), dtype=jnp.int32)
+    was = metrics.enabled()
+    metrics.enable()
+    metrics.registry.clear()
+    try:
+        jax.eval_shape(
+            lambda t: model.init(jax.random.PRNGKey(0), t,
+                                 return_hidden=return_hidden), toks)
+        snap = metrics.registry.snapshot()
+    finally:
+        metrics.registry.clear()
+        if not was:
+            metrics.disable()
+    got = {name: value for name, series in snap.items()
+           if name.startswith("hvd_remat_") for value in series.values()}
+    assert got == {"hvd_remat_blocks": want[0],
+                   "hvd_remat_blocks_kept": want[1]}
 
 
 def test_distributed_gpt2_train_step(hvd8):

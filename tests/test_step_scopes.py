@@ -275,6 +275,32 @@ def test_layer_scopes_are_the_routed_mlps_two():
         scopes.MOE_DISPATCH, scopes.MOE_EXPERTS)
 
 
+def test_remat_runs_the_flash_forward_again_in_every_block_but_the_last():
+    """The routed cell is the one built with ``remat``. Its tiny step
+    holds one forward flash call (out, lse) a layer in the forward
+    phase and one backward call (dk, dv, dq) a layer; the forward call
+    a rematerialised block runs again sits in the backward phase, where
+    ``attn_bwd_dkv_kernel_ms`` reads it beside the backward kernel, and
+    there is one for every block but the last, whose flash results are
+    kept from its first run (``TransformerConfig.remat``)."""
+    import jax
+
+    from benchmarks import scopes as readers
+
+    step, args = tiny_step(ROUTED_CELL, 1)
+    calls: dict = {}
+    for stack, eqn in pallas_calls(jax.make_jaxpr(step)(*args).jaxpr):
+        block = next(p for p in stack.split("/") if p.startswith("block_"))
+        phase, _ = readers.classify(stack + "/pallas_call")
+        calls.setdefault(block, []).append((phase, len(eqn.outvars)))
+    layers = 6
+    again = {f"block_{i}": [("forward", 2), ("backward", 2), ("backward", 3)]
+             for i in range(layers - 1)}
+    kept = {f"block_{layers - 1}": [("forward", 2), ("backward", 3)]}
+    assert {b: sorted(c, key=lambda x: (x[0] == "backward", x[1]))
+            for b, c in calls.items()} == {**again, **kept}, calls
+
+
 @pytest.mark.parametrize("scope", scopes.LAYER_SCOPES)
 def test_routed_mlp_is_named_in_both_directions(scope):
     """The compiled tiny step of the routed cell names both scopes under
