@@ -266,21 +266,26 @@ class Attention(nn.Module):
             nn.DenseGeneral, dtype=cfg.dtype, param_dtype=jnp.float32,
             use_bias=cfg.norm == "layernorm",
         )
-        q = dense(features=(H, D), name="query",
-                  kernel_init=nn.initializers.xavier_uniform())(x)
-        k = dense(features=(KH, D), name="key",
-                  kernel_init=nn.initializers.xavier_uniform())(x)
-        v = dense(features=(KH, D), name="value",
-                  kernel_init=nn.initializers.xavier_uniform())(x)
-        if cfg.qk_norm:
-            q = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
-                        name="q_norm")(q)
-            k = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
-                        name="k_norm")(k)
-        if cfg.position == "rope":
-            cos, sin = rope_frequencies(D, cfg.max_seq_len, cfg.rope_theta)
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+        # the scopes are names in the step's `op_name`s (utils/scopes.py),
+        # not modules: parameter paths are what they were
+        with jax.named_scope(scopes.ATTN_PROJ):
+            q = dense(features=(H, D), name="query",
+                      kernel_init=nn.initializers.xavier_uniform())(x)
+            k = dense(features=(KH, D), name="key",
+                      kernel_init=nn.initializers.xavier_uniform())(x)
+            v = dense(features=(KH, D), name="value",
+                      kernel_init=nn.initializers.xavier_uniform())(x)
+        with jax.named_scope(scopes.ATTN_PREP):
+            if cfg.qk_norm:
+                q = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
+                            name="q_norm")(q)
+                k = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
+                            name="k_norm")(k)
+            if cfg.position == "rope":
+                cos, sin = rope_frequencies(D, cfg.max_seq_len,
+                                            cfg.rope_theta)
+                q = apply_rope(q, cos, sin, positions)
+                k = apply_rope(k, cos, sin, positions)
         if kv_cache is not None:
             # autoregressive serving path (serving/decode.py): the
             # new tokens' K/V append into the slotted cache (quantized
@@ -309,12 +314,13 @@ class Attention(nn.Module):
                     "pre-mask the inputs or use the default attention"
                 )
             out = attn(q, k, v)
-        out = nn.DenseGeneral(
-            features=cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
-            param_dtype=jnp.float32, use_bias=cfg.norm == "layernorm",
-            name="out",
-            kernel_init=nn.initializers.xavier_uniform(),
-        )(out)
+        with jax.named_scope(scopes.ATTN_PROJ):
+            out = nn.DenseGeneral(
+                features=cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
+                param_dtype=jnp.float32, use_bias=cfg.norm == "layernorm",
+                name="out",
+                kernel_init=nn.initializers.xavier_uniform(),
+            )(out)
         return out
 
 

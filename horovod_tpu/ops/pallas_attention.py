@@ -73,6 +73,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils import scopes
 from ._pallas import interpret
 
 NEG_INF = -1e30
@@ -766,6 +767,7 @@ def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
             jax.ShapeDtypeStruct((b, h, 1, tq_p), jnp.float32),
         ],
         interpret=interpret(),
+        name=scopes.FLASH_FWD,
     )(qq, kk, vv)
 
 
@@ -791,16 +793,19 @@ def _flash(q, k, v, causal, scale, query_offset, key_offset,
 def _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
                block_q, block_k, diffusion):
     tq, tk = q.shape[2], k.shape[2]
-    qq = _pad_to(q, 2, block_q)
-    kk = _pad_to(k, 2, block_k)
-    vv = _pad_to(v, 2, block_k)
+    with jax.named_scope(scopes.ATTN_PREP):
+        qq = _pad_to(q, 2, block_q)
+        kk = _pad_to(k, 2, block_k)
+        vv = _pad_to(v, 2, block_k)
+    # the kernel call stands outside the scope (utils/scopes.py's rule)
     out_p, lse_p = _flash_core(
         qq, kk, vv, tk, causal=causal, scale=scale,
         query_offset=query_offset, key_offset=key_offset,
         block_q=block_q, block_k=block_k, diffusion=diffusion,
     )
-    out = out_p[:, :, :tq]
-    return out, (q, k, v, out, lse_p[:, :, :, :tq])
+    with jax.named_scope(scopes.ATTN_PREP):
+        out = out_p[:, :, :tq]
+        return out, (q, k, v, out, lse_p[:, :, :, :tq])
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6),
@@ -812,17 +817,18 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
     b, h, tq, d = q.shape
     kh, tk = k.shape[1:3]
     heads_per_kv = h // kh
-    # Δ_i = Σ_d dO_i ∘ O_i — one cheap fused elementwise pass in XLA,
-    # stored alongside lse as [B, H, 1, T]
-    delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )[:, :, None, :]
-    qq = _pad_to(q, 2, block_q)
-    do = _pad_to(g.astype(q.dtype), 2, block_q)
-    lse_p = _pad_to(lse, 3, block_q)
-    delta_p = _pad_to(delta, 3, block_q)
-    kk = _pad_to(k, 2, block_k)
-    vv = _pad_to(v, 2, block_k)
+    with jax.named_scope(scopes.ATTN_PREP):
+        # Δ_i = Σ_d dO_i ∘ O_i — one cheap fused elementwise pass in XLA,
+        # stored alongside lse as [B, H, 1, T]
+        delta = jnp.sum(
+            g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+        )[:, :, None, :]
+        qq = _pad_to(q, 2, block_q)
+        do = _pad_to(g.astype(q.dtype), 2, block_q)
+        lse_p = _pad_to(lse, 3, block_q)
+        delta_p = _pad_to(delta, 3, block_q)
+        kk = _pad_to(k, 2, block_k)
+        vv = _pad_to(v, 2, block_k)
     tq_p, tk_p = qq.shape[2], kk.shape[2]
     itemsize = q.dtype.itemsize
     geometry = _geometry(causal, query_offset, key_offset, tk, tk_p,
@@ -860,13 +866,15 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
             vmem_limit_bytes=max(_VMEM_LIMIT_LEAST, 2 * gb * gh * _vmem_charge(
                 "bwd", block_k, tq_p, d, itemsize))),
         interpret=interpret(),
+        name=scopes.FLASH_BWD,
     )(kk, vv, qq, do, lse_p, delta_p)
-    if heads_per_kv > 1:
-        dk, dv = (
-            jnp.sum(x.reshape(b, kh, heads_per_kv, tk_p, d), axis=2,
-                    dtype=jnp.float32).astype(x.dtype) for x in (dk, dv))
-
-    return dq[:, :, :tq], dk[:, :, :tk], dv[:, :, :tk]
+    with jax.named_scope(scopes.ATTN_PREP):
+        if heads_per_kv > 1:
+            dk, dv = (
+                jnp.sum(x.reshape(b, kh, heads_per_kv, tk_p, d), axis=2,
+                        dtype=jnp.float32).astype(x.dtype)
+                for x in (dk, dv))
+        return dq[:, :, :tq], dk[:, :, :tk], dv[:, :, :tk]
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -947,13 +955,15 @@ def flash_attention(
     `query_offset`/`key_offset` shift the global positions used
     for the causal mask — the hook ring attention uses for rotated KV
     blocks. `diffusion_block`: see `flash_attention_bhtd`."""
+    with jax.named_scope(scopes.ATTN_PREP):
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     out = flash_attention_bhtd(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=causal, scale=scale,
+        q, k, v, causal=causal, scale=scale,
         query_offset=query_offset, key_offset=key_offset,
         block_q=block_q, block_k=block_k, diffusion_block=diffusion_block,
     )
-    return out.transpose(0, 2, 1, 3)
+    with jax.named_scope(scopes.ATTN_PREP):
+        return out.transpose(0, 2, 1, 3)
 
 
 def make_flash_attention_fn(causal: bool = True, block_q: int = 512,

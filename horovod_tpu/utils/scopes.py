@@ -7,6 +7,13 @@ Flax already writes each module's name there (``block_3/attn``,
 ``ln_mlp``, ``tok_emb``); these name what no module covers. The
 benchmark's readers (``benchmarks/scopes.py``) import the same
 constants: a name changed here changes there.
+
+The rule the readers depend on (``tests/test_step_scopes.py`` holds
+it): **a ``pallas_call`` sits outside every ``LAYER_SCOPES`` name.** A
+flash call's ``op_name`` is ``.../attn/flash_fwd/pallas_call``: it stays in
+Flax's layer ``attn``, where the kernels' readers look, and what is
+left in layer ``attn`` outside the kernels is the plain-XLA attention's
+einsums and softmax.
 """
 
 # final hidden state to the loss, both directions, fused or dense
@@ -22,8 +29,28 @@ HVD_INNER_UPDATE = "hvd_inner_update"  # the wrapped optimizer's update
 # of their rows and the weighted combine; and the three expert products
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
+# models/transformer.Attention's four DenseGeneral calls (query, key,
+# value, out), both directions, and nothing else
+ATTN_PROJ = "attn_proj"
+# What attention does that is neither a projection nor a kernel: the q/k
+# norms, apply_rope, and everything ops/pallas_attention.py does around
+# its two pallas_calls, forward rule and backward rule (transposes into
+# and out of the kernels' layout, pads and slices, the sum of partial
+# dk/dv over a group's query heads, delta, casts)
+ATTN_PREP = "attn_prep"
 # The scopes that are a layer's own: the benchmark's reduction
 # (benchmarks/scopes.classify) looks for these after the scopes above
 # and before Flax's module names, so `.../mlp/moe_experts/...` is layer
-# `moe_experts` and not `mlp`
-LAYER_SCOPES = (MOE_DISPATCH, MOE_EXPERTS)
+# `moe_experts` and not `mlp`, and `.../attn/attn_proj/query/...` is
+# layer `attn_proj` and not `attn`. The dense MLP needs none: Flax's
+# `mlp` is its layer, and in a routed model `mlp` is what RoutedMlp
+# does outside its two scopes
+LAYER_SCOPES = (MOE_DISPATCH, MOE_EXPERTS, ATTN_PROJ, ATTN_PREP)
+
+# Kernel names, not layer scopes: the `name=` of the two flash
+# `pl.pallas_call`s (ops/pallas_attention.py). The TPU compiler names a
+# Mosaic call by it (`flash_fwd.3`) and it is the innermost scope of the
+# call's `op_name`. A forward call in the backward phase is one a
+# rematerialised block runs again
+FLASH_FWD = "flash_fwd"
+FLASH_BWD = "flash_bwd"

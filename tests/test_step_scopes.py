@@ -209,99 +209,222 @@ def test_little_is_left_unscoped(op_names):
     assert differentiated <= UNSCOPED_DIFFERENTIATED, differentiated
 
 
-def pallas_calls(jaxpr, outer=""):
-    """``(name stack, pallas_call equation)`` of every kernel call in
-    the jaxpr and the jaxprs inside it."""
+def equations(jaxpr, outer=""):
+    """``(name stack, equation)`` of every equation in the jaxpr and
+    the jaxprs inside it, a kernel's own body left out."""
     for eqn in jaxpr.eqns:
         stack = "/".join(
             p for p in (outer, str(eqn.source_info.name_stack)) if p)
+        yield stack, eqn
         if eqn.primitive.name == "pallas_call":
-            yield stack, eqn
+            continue
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from pallas_calls(inner, stack)
+                    yield from equations(inner, stack)
+
+
+_TRACED: dict = {}
+
+
+def traced(cell: str, n: int) -> list:
+    """``(op_name, equation)`` of the cell's tiny step as it is traced,
+    before any compiler fuses or drops an operation: the name stack
+    with the primitive's name, which is what lowering writes as
+    ``op_name``. Traced once a cell and process."""
+    import jax
+
+    if cell not in _TRACED:
+        step, args = tiny_step(cell, n)
+        _TRACED[cell] = [
+            (stack + "/" + eqn.primitive.name, eqn) for stack, eqn
+            in equations(jax.make_jaxpr(step)(*args).jaxpr)]
+    return _TRACED[cell]
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_three_flash_kernels_a_layer_where_their_readers_look(cell):
-    """Each ``pallas_call`` of the step is one Mosaic call on the chip,
-    named by the innermost scope of its ``op_name`` (interpreted here,
-    so the step's equations are read and not the CPU's HLO). The
-    benchmark's readers find the flash kernels by that stem, ``attn``,
-    and tell them apart by phase and by how many arrays they return
-    (``benchmarks/scopes.kernel_kind``). Since PR 34 a layer has exactly
-    two: the forward kernel with (out, lse) in the forward phase and ONE
-    backward call of three results (dk, dv, dq) in the backward phase,
-    which ``kernel_kind`` classes ``attn_bwd_dkv`` by its arity rule (a
-    Mosaic call under ``attn`` in the backward phase with a tuple
-    result), so ``attn_bwd_dkv_kernel_ms`` reads the whole backward
-    kernel and no call is left for ``attn_bwd_dq`` (one result), which
-    reads 0 until a ``benchmark`` PR renames the one and drops the
-    other. Neither call has a ``name=`` or a scope of its own. (The
-    test keeps the name it had with three kernels: the count of cases
-    is part of the tier-1 floor.)"""
-    import jax
-
+def test_two_flash_kernels_a_layer_where_their_readers_look(cell):
+    """Each ``pallas_call`` of the step is one Mosaic call on the chip
+    (interpreted here, so the step's equations are read and not the
+    CPU's HLO). A layer has exactly two: the forward kernel with (out,
+    lse) in the forward phase and ONE backward call of three results
+    (dk, dv, dq) in the backward phase. Since PR 41 each carries its
+    name from ``utils/scopes.py`` as ``name=``, which is the innermost
+    part of its name stack and on the chip its instruction's stem
+    (``flash_fwd.3``); it stands right under the Flax module ``attn``
+    and under no ``LAYER_SCOPES`` name, so the benchmark's readers
+    still class it as layer ``attn`` (``attn_kernel_ms`` by its layer,
+    ``benchmarks/scopes.kernel_kind`` by phase and arity: the backward
+    call is a tuple, so ``attn_bwd_dkv_kernel_ms`` reads it and no call
+    is left for ``attn_bwd_dq``), and ``benchmarks/kernel_names.py``
+    reads it by its name."""
     from benchmarks import scopes as readers
 
-    step, args = tiny_step(cell, CELLS[cell])
     kinds: dict = {}
-    for stack, eqn in pallas_calls(jax.make_jaxpr(step)(*args).jaxpr):
-        assert eqn.params["name"] is None, eqn.params["name"]
-        block, stem = stack.split("/")[-2:]
-        assert stem == "attn", stack
-        phase, layer = readers.classify(stack + "/pallas_call")
+    for op_name, eqn in traced(cell, CELLS[cell]):
+        if eqn.primitive.name != "pallas_call":
+            continue
+        block, stem, name, _ = op_name.split("/")[-4:]
+        assert stem == "attn", op_name
+        assert eqn.params["name"] == name, op_name
+        assert not parts(op_name) & set(scopes.LAYER_SCOPES), op_name
+        phase, layer = readers.classify(op_name)
+        assert layer == "attn", op_name
         results = len(eqn.outvars)
         kind = readers.kernel_kind(
             "call", phase, layer, {"call"}, {"call"} if results > 1 else ())
-        kinds.setdefault(block, []).append((kind, phase, results))
+        kinds.setdefault(block, []).append((name, kind, phase, results))
     assert kinds == {
-        f"block_{i}": [(readers.KERNEL_FWD, "forward", 2),
-                       (readers.KERNEL_DKV, "backward", 3)]
+        f"block_{i}": [
+            (scopes.FLASH_FWD, readers.KERNEL_FWD, "forward", 2),
+            (scopes.FLASH_BWD, readers.KERNEL_DKV, "backward", 3)]
         for i in range(2)}, kinds
-    assert not any(kind == readers.KERNEL_DQ
-                   for found in kinds.values() for kind, _, _ in found)
+
+
+# -- attention's own scopes --------------------------------------------------
+
+PROJECTIONS = {"query", "key", "value", "out"}
+ROUTED_CELL = "sdar_bd_s4096"
+EVERY_CELL = {**CELLS, ROUTED_CELL: 1}
+
+
+def under_attn(cell: str) -> list:
+    """``(op_name, equation)`` of what the cell's tiny step traces
+    under a Flax module ``attn``."""
+    return [(o, e) for o, e in traced(cell, EVERY_CELL[cell])
+            if "attn" in parts(o)]
+
+
+@pytest.mark.parametrize("cell", EVERY_CELL)
+@pytest.mark.parametrize("scope", [scopes.ATTN_PROJ, scopes.ATTN_PREP])
+def test_attention_scopes_are_named_in_both_directions(cell, scope):
+    from benchmarks import scopes as readers
+
+    found = [o for o, _ in under_attn(cell) if scope in parts(o)]
+    for phase, marker in (("forward", "jvp(Transformer)"),
+                          ("backward", "transpose(jvp(Transformer))")):
+        mine = [o for o in found if readers.classify(o)[0] == phase]
+        assert mine, (scope, phase)
+        assert all(marker in o for o in mine)
+        assert {readers.classify(o) for o in mine} == {(phase, scope)}
+        layers = 6 if cell == ROUTED_CELL else 2
+        assert {p for o in mine for p in parts(o)
+                if p.startswith("block_")} == {
+            f"block_{i}" for i in range(layers)}
+
+
+@pytest.mark.parametrize("cell", EVERY_CELL)
+def test_kernel_names_are_in_their_directions(cell):
+    from benchmarks import scopes as readers
+
+    phases = {name: {readers.classify(o)[0] for o, _ in under_attn(cell)
+                     if name in parts(o)}
+              for name in (scopes.FLASH_FWD, scopes.FLASH_BWD)}
+    again = {"backward"} if cell == ROUTED_CELL else set()  # `remat`
+    assert phases == {scopes.FLASH_FWD: {"forward"} | again,
+                      scopes.FLASH_BWD: {"backward"}}
+
+
+@pytest.mark.parametrize("cell", EVERY_CELL)
+def test_a_kernel_call_stands_outside_every_layer_scope(cell):
+    """The rule ``utils/scopes.py`` states: an ``op_name`` that holds
+    ``pallas_call`` or a kernel's name holds no ``LAYER_SCOPES`` name
+    and classes as layer ``attn``."""
+    from benchmarks import scopes as readers
+
+    kernels = [o for o, _ in traced(cell, EVERY_CELL[cell])
+               if parts(o) & {"pallas_call", scopes.FLASH_FWD,
+                              scopes.FLASH_BWD}]
+    assert kernels
+    for o in kernels:
+        assert not parts(o) & set(scopes.LAYER_SCOPES), o
+        assert readers.classify(o)[1] == "attn", o
+
+
+@pytest.mark.parametrize("cell", EVERY_CELL)
+def test_attention_is_projections_layout_work_and_kernels(cell):
+    """Under ``attn`` every operation of ``query``, ``key``, ``value``
+    and ``out`` is layer ``attn_proj`` and nothing else is; the q/k
+    norms, rope and the flash function's work around its calls are
+    ``attn_prep``; and what is left in layer ``attn`` is the kernel
+    calls alone."""
+    from benchmarks import scopes as readers
+
+    left = set()
+    for o, eqn in under_attn(cell):
+        layer = readers.classify(o)[1]
+        if parts(o) & PROJECTIONS:
+            assert layer == scopes.ATTN_PROJ, o
+        else:
+            assert layer != scopes.ATTN_PROJ, o
+        if parts(o) & {"q_norm", "k_norm"}:
+            assert layer == scopes.ATTN_PREP, o
+        if layer == "attn":
+            left.add(eqn.primitive.name)
+    # (`jax.checkpoint` marks a result its policy keeps with a
+    # `reduce_precision` to the result's own precision, named as the
+    # call that made it: the last block's flash output under `remat`)
+    assert {"pallas_call"} <= left <= {"pallas_call", "reduce_precision"}, \
+        left
+    prep = {e.primitive.name for o, e in under_attn(cell)
+            if readers.classify(o)[1] == scopes.ATTN_PREP}
+    # into and out of the kernels' layout in every cell; rope's halves
+    # and the norms' statistics where the model has them
+    assert "transpose" in prep, prep
+    if cell == ROUTED_CELL:
+        assert {"split", "concatenate", "rsqrt"} <= prep, prep
+        products = [o for o, e in under_attn(cell)
+                    if e.primitive.name == "dot_general"]
+        assert products and all(
+            readers.classify(o)[1] == scopes.ATTN_PROJ for o in products)
 
 
 # -- the routed MLP's own scopes ---------------------------------------------
 
-ROUTED_CELL = "sdar_bd_s4096"
-
-
-def test_layer_scopes_are_the_routed_mlps_two():
-    assert scopes.LAYER_SCOPES == ("moe_dispatch", "moe_experts") == (
-        scopes.MOE_DISPATCH, scopes.MOE_EXPERTS)
+def test_layer_scopes_are_the_routed_mlps_two_and_attentions_two():
+    assert scopes.LAYER_SCOPES == (
+        "moe_dispatch", "moe_experts", "attn_proj", "attn_prep") == (
+        scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.ATTN_PROJ,
+        scopes.ATTN_PREP)
+    # the kernels' names are no layer scopes
+    assert (scopes.FLASH_FWD, scopes.FLASH_BWD) == ("flash_fwd", "flash_bwd")
+    assert not {scopes.FLASH_FWD, scopes.FLASH_BWD} & set(
+        scopes.LAYER_SCOPES)
 
 
 def test_remat_runs_the_flash_forward_again_in_every_block_but_the_last():
     """The routed cell is the one built with ``remat``. Its tiny step
     holds one forward flash call (out, lse) a layer in the forward
     phase and one backward call (dk, dv, dq) a layer; the forward call
-    a rematerialised block runs again sits in the backward phase, where
-    ``attn_bwd_dkv_kernel_ms`` reads it beside the backward kernel, and
-    there is one for every block but the last, whose flash results are
-    kept from its first run (``TransformerConfig.remat``)."""
-    import jax
-
+    a rematerialised block runs again carries ``FLASH_FWD`` in the
+    backward phase, where ``attn_fwd_recompute_kernel_ms`` reads it by
+    that name (and ``attn_bwd_dkv_kernel_ms``, by arity, beside the
+    backward kernel), and there is one for every block but the last,
+    whose flash results are kept from its first run
+    (``TransformerConfig.remat``)."""
     from benchmarks import scopes as readers
 
-    step, args = tiny_step(ROUTED_CELL, 1)
     calls: dict = {}
-    for stack, eqn in pallas_calls(jax.make_jaxpr(step)(*args).jaxpr):
-        block = next(p for p in stack.split("/") if p.startswith("block_"))
-        phase, _ = readers.classify(stack + "/pallas_call")
-        calls.setdefault(block, []).append((phase, len(eqn.outvars)))
+    for op_name, eqn in traced(ROUTED_CELL, 1):
+        if eqn.primitive.name != "pallas_call":
+            continue
+        block = next(p for p in op_name.split("/") if p.startswith("block_"))
+        phase, _ = readers.classify(op_name)
+        assert eqn.params["name"] in parts(op_name)
+        calls.setdefault(block, []).append(
+            (phase, eqn.params["name"], len(eqn.outvars)))
     layers = 6
-    again = {f"block_{i}": [("forward", 2), ("backward", 2), ("backward", 3)]
-             for i in range(layers - 1)}
-    kept = {f"block_{layers - 1}": [("forward", 2), ("backward", 3)]}
-    assert {b: sorted(c, key=lambda x: (x[0] == "backward", x[1]))
+    first = ("forward", scopes.FLASH_FWD, 2)
+    second = ("backward", scopes.FLASH_FWD, 2)
+    back = ("backward", scopes.FLASH_BWD, 3)
+    again = {f"block_{i}": [first, second, back] for i in range(layers - 1)}
+    kept = {f"block_{layers - 1}": [first, back]}
+    assert {b: sorted(c, key=lambda x: (x[0] == "backward", x[2]))
             for b, c in calls.items()} == {**again, **kept}, calls
 
 
-@pytest.mark.parametrize("scope", scopes.LAYER_SCOPES)
+@pytest.mark.parametrize("scope", [scopes.MOE_DISPATCH, scopes.MOE_EXPERTS])
 def test_routed_mlp_is_named_in_both_directions(scope):
     """The compiled tiny step of the routed cell names both scopes under
     every layer's ``mlp``, forward and backward, and the benchmark's
