@@ -17,6 +17,10 @@ TPU-first choices:
     block, whose backward runs first, keeps its kernel calls' and
     matrix products' results where the caller takes the hidden state
     to its own head (`TransformerConfig.remat`)
+  * per-head q/k norms and rope run as one Pallas pass a direction, in
+    the flash kernels' layout, where a head is whole lane tiles and the
+    attention function offers that layout (`fuses_qk_prep`,
+    ops/attention_prep.py); as array passes everywhere else
   * params stay plain arrays; tensor/FSDP sharding rules live externally
     in parallel/sharding.py (path-pattern → PartitionSpec over dp/fsdp/tp
     axes) so pjit shards them and XLA inserts the collectives.
@@ -33,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import attention_prep
 from ..utils import metrics, scopes
 from .moe import RoutedMlp
 
@@ -59,7 +64,7 @@ class TransformerConfig:
     # between its two runs but the final norm and the head, so where
     # `__call__(return_hidden=True)` hands the hidden state to a head of
     # the caller's (the fused cross entropy, whose live memory is
-    # O(rows x one vocabulary block)) it keeps its kernel calls' and
+    # O(rows x one vocabulary block)) it keeps its flash calls' and
     # matrix products' results from its first run and rebuilds only the
     # elementwise passes (`_last_block_keeps`; a last block kept WHOLE
     # holds every float32 intermediate of rope and the norms at once
@@ -152,6 +157,17 @@ class RMSNorm(nn.Module):
             jnp.mean(xf * xf, axis=-1, keepdims=True) + self.epsilon
         )
         return (y * scale).astype(self.dtype)
+
+
+class _NormScale(nn.Module):
+    """`RMSNorm`'s parameter under `RMSNorm`'s path (``<name>/scale``,
+    float32 ones) without its arithmetic, for a caller that runs the
+    norm inside a pass of its own (`Attention`'s fused q/k pass)."""
+
+    @nn.compact
+    def __call__(self, width: int):
+        return self.param("scale", nn.initializers.ones, (width,),
+                          jnp.float32)
 
 
 def _norm(cfg: TransformerConfig, name: str):
@@ -253,6 +269,23 @@ def dot_product_attention(q, k, v, *, causal: bool, mask=None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def fuses_qk_prep(cfg: TransformerConfig, attention_fn,
+                  kv_cache=None) -> bool:
+    """Whether `Attention` runs its q/k norms, rope and the transposes
+    into the flash kernels' layout as the one pass of
+    `ops/attention_prep.py` and not as array passes. Decided by what
+    the call can observe: there is such work (q/k norms or rope), a head
+    is whole lane tiles (the pass slices heads out of lanes), the
+    attention function offers the kernels' layout (`from_bhtd`, which
+    `make_flash_attention_fn`'s has; ring, Ulysses and the default
+    attention take the model's layout), and no cache is being filled
+    (serving appends k in the model's layout)."""
+    return bool((cfg.qk_norm or cfg.position == "rope")
+                and kv_cache is None
+                and attention_prep.supports(cfg.head_width)
+                and hasattr(attention_fn, "from_bhtd"))
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     attention_fn: Optional[Callable] = None
@@ -262,6 +295,7 @@ class Attention(nn.Module):
         cfg = self.cfg
         B, T, _ = x.shape
         H, KH, D = cfg.num_heads, cfg.kv_heads, cfg.head_width
+        fused = fuses_qk_prep(cfg, self.attention_fn, kv_cache)
         dense = functools.partial(
             nn.DenseGeneral, dtype=cfg.dtype, param_dtype=jnp.float32,
             use_bias=cfg.norm == "layernorm",
@@ -276,16 +310,29 @@ class Attention(nn.Module):
             v = dense(features=(KH, D), name="value",
                       kernel_init=nn.initializers.xavier_uniform())(x)
         with jax.named_scope(scopes.ATTN_PREP):
-            if cfg.qk_norm:
-                q = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
-                            name="q_norm")(q)
-                k = RMSNorm(epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
-                            name="k_norm")(k)
-            if cfg.position == "rope":
-                cos, sin = rope_frequencies(D, cfg.max_seq_len,
-                                            cfg.rope_theta)
-                q = apply_rope(q, cos, sin, positions)
-                k = apply_rope(k, cos, sin, positions)
+            rope = rope_frequencies(D, cfg.max_seq_len, cfg.rope_theta) \
+                if cfg.position == "rope" else None
+            if fused:
+                # one pass: q and k come back normed and rotated in the
+                # kernels' [B, H, T, D]; the parameters are RMSNorm's
+                q_scale = k_scale = None
+                if cfg.qk_norm:
+                    q_scale = _NormScale(name="q_norm")(D)
+                    k_scale = _NormScale(name="k_norm")(D)
+                q, k = attention_prep.qk_prep(
+                    q, k, q_scale, k_scale,
+                    rope and attention_prep.rope_rows(*rope, positions),
+                    cfg.layernorm_epsilon)
+                v = v.transpose(0, 2, 1, 3)
+            else:
+                if cfg.qk_norm:
+                    q = RMSNorm(epsilon=cfg.layernorm_epsilon,
+                                dtype=cfg.dtype, name="q_norm")(q)
+                    k = RMSNorm(epsilon=cfg.layernorm_epsilon,
+                                dtype=cfg.dtype, name="k_norm")(k)
+                if rope:
+                    q = apply_rope(q, *rope, positions)
+                    k = apply_rope(k, *rope, positions)
         if kv_cache is not None:
             # autoregressive serving path (serving/decode.py): the
             # new tokens' K/V append into the slotted cache (quantized
@@ -306,7 +353,8 @@ class Attention(nn.Module):
                 diffusion_block=cfg.diffusion_block)
             out = attn(q, k, v, mask=mask)
         else:
-            attn = self.attention_fn
+            attn = self.attention_fn.from_bhtd if fused \
+                else self.attention_fn
             if mask is not None:
                 raise ValueError(
                     "a custom attention_fn (flash/ring/Ulysses) takes only "
@@ -373,11 +421,11 @@ class Block(nn.Module):
         return x + mlp(y)
 
 
-def _last_block_keeps(prim, *_, **__) -> bool:
+def _last_block_keeps(prim, *_, **params) -> bool:
     """`jax.checkpoint` policy of the last block under `remat`: is this
     primitive's result kept from the block's first run? Kept is what is
-    dear to rebuild and small to hold: the kernel calls' results (flash
-    attention's out and lse), the matrix products' (the projections,
+    dear to rebuild and small to hold: the two flash calls' results
+    (attention's out and lse), the matrix products' (the projections,
     the router's scores) and the router's choice (`top_k` and the sort
     of the pairs, about a MiB each). Rebuilt are the elementwise passes
     (norms, rope, SwiGLU), whose float32 intermediates are 2-4 times an
@@ -385,8 +433,15 @@ def _last_block_keeps(prim, *_, **__) -> bool:
     routed MLP's rows and expert products (compiled for the chip, the
     step with `ragged_dot_general` kept too needs 1.1 GiB more: the
     same primitive runs in the scan over further products; PERF.md
-    section 6, PR 40)."""
-    return prim.name in ("pallas_call", "dot_general", "top_k", "sort")
+    section 6, PR 40). A kernel call is kept by its name and not as a
+    `pallas_call`: the one pass of `ops/attention_prep.py` is a call
+    too, and its results (q and k after norm and rope in the kernels'
+    layout, 144 MiB in `sdar_bd_s4096`) are of the rebuilt kind, cheap
+    to make again from the kept projections (compiled with them kept:
+    +0.80% `step_hbm_gib` for at most ~2 ms; PR 40)."""
+    if prim.name == "pallas_call":
+        return params.get("name") in (scopes.FLASH_FWD, scopes.FLASH_BWD)
+    return prim.name in ("dot_general", "top_k", "sort")
 
 
 class Transformer(nn.Module):
@@ -434,6 +489,9 @@ class Transformer(nn.Module):
         kept = int(cfg.remat and return_hidden and cfg.num_layers > 0)
         rematerialised = cfg.num_layers - kept if cfg.remat else 0
         metrics.record_remat_blocks(rematerialised, kept)
+        fused = fuses_qk_prep(cfg, self.attention_fn, kv_cache)
+        metrics.record_attn_prep_layers(
+            cfg.num_layers * fused, cfg.num_layers * (not fused))
         for i in range(cfg.num_layers):
             block = Block
             if i < rematerialised:

@@ -943,6 +943,17 @@ def flash_attention_bhtd(
     )
 
 
+def flash_attention_from_bhtd(q, k, v, **kwargs):
+    """`flash_attention_bhtd` of q, k, v that are in the kernels'
+    ``[B, H, T, D]`` already, with the result in the model's
+    ``[B, T, H, D]``: for a caller that makes the kernels' layout itself
+    (`ops/attention_prep.qk_prep` writes q and k there in the pass that
+    norms and rotates them)."""
+    out = flash_attention_bhtd(q, k, v, **kwargs)
+    with jax.named_scope(scopes.ATTN_PREP):
+        return out.transpose(0, 2, 1, 3)
+
+
 def flash_attention(
     q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     query_offset: int = 0, key_offset: int = 0,
@@ -957,13 +968,11 @@ def flash_attention(
     blocks. `diffusion_block`: see `flash_attention_bhtd`."""
     with jax.named_scope(scopes.ATTN_PREP):
         q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = flash_attention_bhtd(
+    return flash_attention_from_bhtd(
         q, k, v, causal=causal, scale=scale,
         query_offset=query_offset, key_offset=key_offset,
         block_q=block_q, block_k=block_k, diffusion_block=diffusion_block,
     )
-    with jax.named_scope(scopes.ATTN_PREP):
-        return out.transpose(0, 2, 1, 3)
 
 
 def make_flash_attention_fn(causal: bool = True, block_q: int = 512,
@@ -975,19 +984,37 @@ def make_flash_attention_fn(causal: bool = True, block_q: int = 512,
     `TransformerConfig.diffusion_block`), whose mask then stands in
     place of `causal`.
 
+    The function takes q, k, v in the model's [B, T, H, D]; its
+    attribute `from_bhtd` is the same attention of q, k, v that are in
+    the kernels' [B, H, T, D] already (the result comes back in the
+    model's layout either way). `models.transformer.Attention` reads
+    the attribute as the offer of the kernels' layout: where it has per-
+    head q/k norms or rope to run anyway, one pass writes q and k there
+    (`ops/attention_prep.py`) and the three transposes in front of the
+    kernels are not made. A function without the attribute (ring,
+    Ulysses) is called with the model's layout as ever.
+
     Measured dead end for the record: projecting q/k/v straight into the
     kernels' bhtd layout via einsum (skipping the transpose pairs XLA
     materializes around each attention call) moved BERT-L throughput
-    -1.5% — XLA pays the same relayout inside the projection einsum. The
-    [B, T, H, D] wrapper + explicit transposes is the fast path."""
+    -1.5% — XLA pays the same relayout inside the projection einsum. So
+    where nothing stands between the projections and the kernels (no
+    q/k norms, no rope: the compiler folds the transposes into the
+    products' fusions, `attn_prep_ms` 0.0-0.2 ms in the dense cells)
+    the [B, T, H, D] wrapper + explicit transposes is the fast path;
+    where norms or rope stand there the transposes are passes of their
+    own over float32 copies, and `from_bhtd` behind the one pass is
+    (PERF.md section 6, PR 42)."""
     import os
 
-    block_q = int(os.environ.get("HOROVOD_FLASH_BLOCK_Q", block_q))
-    block_k = int(os.environ.get("HOROVOD_FLASH_BLOCK_K", block_k))
+    kwargs = dict(
+        causal=causal,
+        block_q=int(os.environ.get("HOROVOD_FLASH_BLOCK_Q", block_q)),
+        block_k=int(os.environ.get("HOROVOD_FLASH_BLOCK_K", block_k)),
+        diffusion_block=diffusion_block)
 
     def fn(q, k, v):
-        return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k,
-                               diffusion_block=diffusion_block)
+        return flash_attention(q, k, v, **kwargs)
 
+    fn.from_bhtd = functools.partial(flash_attention_from_bhtd, **kwargs)
     return fn

@@ -1082,6 +1082,28 @@ def record_remat_blocks(rematerialised: int, kept: int) -> None:
         "(the last, before a caller's head)").set(kept)
 
 
+def record_attn_prep_layers(fused: int, plain: int) -> None:
+    """How `Transformer` (models/transformer.py) built its attention
+    layers' work between the projections and the attention itself:
+    those that run q/k norms, rope and the transposes into the flash
+    kernels' layout as the one pass of `ops/attention_prep.py`, and
+    those that leave them to array passes (or have none: learned
+    positions and no q/k norms). A model is all one or all the other
+    (`models.transformer.fuses_qk_prep`). Recorded at TRACE time like
+    the gauges above: the last traced call's, nothing inside the
+    step."""
+    if not _enabled:
+        return
+    registry.gauge(
+        "hvd_attn_prep_fused_layers",
+        "Attention layers whose q/k norms, rope and layout are one "
+        "Pallas pass").set(fused)
+    registry.gauge(
+        "hvd_attn_prep_plain_layers",
+        "Attention layers that leave q/k norms, rope and layout to "
+        "array passes").set(plain)
+
+
 def record_overlap_window(frac: float) -> None:
     """The backward-interleaved scheduler's per-step overlap pin
     (ops/overlap.py): the fraction of backward compute the staged

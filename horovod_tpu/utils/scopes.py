@@ -9,11 +9,15 @@ benchmark's readers (``benchmarks/scopes.py``) import the same
 constants: a name changed here changes there.
 
 The rule the readers depend on (``tests/test_step_scopes.py`` holds
-it): **a ``pallas_call`` sits outside every ``LAYER_SCOPES`` name.** A
-flash call's ``op_name`` is ``.../attn/flash_fwd/pallas_call``: it stays in
-Flax's layer ``attn``, where the kernels' readers look, and what is
-left in layer ``attn`` outside the kernels is the plain-XLA attention's
-einsums and softmax.
+it): **a flash call (``FLASH_FWD`` / ``FLASH_BWD``) sits outside every
+``LAYER_SCOPES`` name; the q/k pass's call (``QK_PREP_FWD`` /
+``QK_PREP_BWD``) sits inside ``ATTN_PREP``.** A flash call's
+``op_name`` is ``.../attn/flash_fwd/pallas_call``: it stays in Flax's
+layer ``attn``, where the kernels' readers look (they take every Mosaic
+call of that layer for a flash kernel), and what is left in layer
+``attn`` outside the kernels is the plain-XLA attention's einsums and
+softmax. The q/k pass's is ``.../attn/attn_prep/qk_prep_fwd/pallas_call``:
+layer ``attn_prep``, whose time it is.
 """
 
 # final hidden state to the loss, both directions, fused or dense
@@ -32,11 +36,14 @@ MOE_EXPERTS = "moe_experts"
 # models/transformer.Attention's four DenseGeneral calls (query, key,
 # value, out), both directions, and nothing else
 ATTN_PROJ = "attn_proj"
-# What attention does that is neither a projection nor a kernel: the q/k
-# norms, apply_rope, and everything ops/pallas_attention.py does around
-# its two pallas_calls, forward rule and backward rule (transposes into
-# and out of the kernels' layout, pads and slices, the sum of partial
-# dk/dv over a group's query heads, delta, casts)
+# What attention does that is neither a projection nor a flash kernel:
+# the q/k norms and apply_rope, as array passes or as the one Pallas
+# pass a direction of ops/attention_prep.py (whose calls, QK_PREP_*
+# below, stand inside this scope) with the gather of rope's rows, and
+# everything ops/pallas_attention.py does around its two pallas_calls,
+# forward rule and backward rule (transposes into and out of the
+# kernels' layout, pads and slices, the sum of partial dk/dv over a
+# group's query heads, delta, casts)
 ATTN_PREP = "attn_prep"
 # The scopes that are a layer's own: the benchmark's reduction
 # (benchmarks/scopes.classify) looks for these after the scopes above
@@ -47,10 +54,15 @@ ATTN_PREP = "attn_prep"
 # does outside its two scopes
 LAYER_SCOPES = (MOE_DISPATCH, MOE_EXPERTS, ATTN_PROJ, ATTN_PREP)
 
-# Kernel names, not layer scopes: the `name=` of the two flash
-# `pl.pallas_call`s (ops/pallas_attention.py). The TPU compiler names a
-# Mosaic call by it (`flash_fwd.3`) and it is the innermost scope of the
-# call's `op_name`. A forward call in the backward phase is one a
-# rematerialised block runs again
+# Kernel names, not layer scopes: the `name=` of the program's
+# `pl.pallas_call`s. The TPU compiler names a Mosaic call by it
+# (`flash_fwd.3`) and it is the innermost scope of the call's `op_name`.
+# First the two flash calls (ops/pallas_attention.py). A forward call
+# in the backward phase is one a rematerialised block runs again
 FLASH_FWD = "flash_fwd"
 FLASH_BWD = "flash_bwd"
+# The one pass between the q and k projections and the flash kernels
+# (ops/attention_prep.py), forward and backward. Unlike the flash calls
+# these stand INSIDE `ATTN_PREP`: their time is attention's layout work
+QK_PREP_FWD = "qk_prep_fwd"
+QK_PREP_BWD = "qk_prep_bwd"

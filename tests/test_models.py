@@ -226,6 +226,40 @@ def test_remat_gauges_say_what_was_rematerialised_and_kept(
                    "hvd_remat_blocks_kept": want[1]}
 
 
+@pytest.mark.parametrize("flash,want", [(True, (3, 0)), (False, (0, 3))])
+def test_attn_prep_gauges_say_how_many_layers_took_the_one_pass(flash, want):
+    """`hvd_attn_prep_fused_layers` / `hvd_attn_prep_plain_layers`, set
+    while the model is traced (once a trace of the model, whatever
+    `remat` traces again): heads of 128 with q/k norms and rope run the
+    one pass of `ops/attention_prep.py` behind the flash function and
+    the array passes behind the default attention."""
+    from horovod_tpu.ops.pallas_attention import make_flash_attention_fn
+    from horovod_tpu.utils import metrics
+
+    cfg = dataclasses.replace(
+        TINY_LLAMA, num_layers=3, head_dim=128, qk_norm=True, remat=True)
+    model = Transformer(
+        cfg, attention_fn=make_flash_attention_fn() if flash else None)
+    toks = jnp.ones((1, 8), dtype=jnp.int32)
+    params = jax.eval_shape(Transformer(cfg).init, jax.random.PRNGKey(0),
+                            toks)
+    was = metrics.enabled()
+    metrics.enable()
+    metrics.registry.clear()
+    try:
+        jax.eval_shape(jax.grad(lambda p: jnp.sum(model.apply(
+            p, toks, return_hidden=True))), params)
+        snap = metrics.registry.snapshot()
+    finally:
+        metrics.registry.clear()
+        if not was:
+            metrics.disable()
+    got = {name: value for name, series in snap.items()
+           if name.startswith("hvd_attn_prep_") for value in series.values()}
+    assert got == {"hvd_attn_prep_fused_layers": want[0],
+                   "hvd_attn_prep_plain_layers": want[1]}
+
+
 def test_distributed_gpt2_train_step(hvd8):
     """End-to-end: tiny GPT-2 DP training step across the 8-device mesh
     with DistributedOptimizer — loss decreases."""

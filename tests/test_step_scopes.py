@@ -40,9 +40,9 @@ OWN_LINES = {"", "add", "div", "psum", "broadcast", "reduce_sum",
 UNSCOPED_DIFFERENTIATED = {"jvp()/transpose", "transpose(jvp())/transpose"}
 
 
-def tiny_step(cell: str, n: int):
+def tiny_step(cell: str, n: int, **model_sizes):
     """The cell's tiny step and its described arguments for ``n`` CPU
-    devices."""
+    devices; ``model_sizes`` stand in place of the tiny preset's."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -52,7 +52,8 @@ def tiny_step(cell: str, n: int):
     from benchmarks.jobs import dp_train
 
     found = harness.load_cell(cell)
-    sizes = {**found["config"]["model"], **found["config"]["tiny"]}
+    sizes = {**found["config"]["model"], **found["config"]["tiny"],
+             **model_sizes}
     traffic = {**found["traffic"], **found["traffic"]["tiny"]}
     run = harness.Run(
         started=time.perf_counter(), workload=cell, chips=n,
@@ -228,19 +229,20 @@ def equations(jaxpr, outer=""):
 _TRACED: dict = {}
 
 
-def traced(cell: str, n: int) -> list:
+def traced(cell: str, n: int, **model_sizes) -> list:
     """``(op_name, equation)`` of the cell's tiny step as it is traced,
     before any compiler fuses or drops an operation: the name stack
     with the primitive's name, which is what lowering writes as
-    ``op_name``. Traced once a cell and process."""
+    ``op_name``. Traced once a cell, sizes and process."""
     import jax
 
-    if cell not in _TRACED:
-        step, args = tiny_step(cell, n)
-        _TRACED[cell] = [
+    key = (cell, *sorted(model_sizes.items()))
+    if key not in _TRACED:
+        step, args = tiny_step(cell, n, **model_sizes)
+        _TRACED[key] = [
             (stack + "/" + eqn.primitive.name, eqn) for stack, eqn
             in equations(jax.make_jaxpr(step)(*args).jaxpr)]
-    return _TRACED[cell]
+    return _TRACED[key]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -252,8 +254,10 @@ def test_two_flash_kernels_a_layer_where_their_readers_look(cell):
     (dk, dv, dq) in the backward phase. Since PR 41 each carries its
     name from ``utils/scopes.py`` as ``name=``, which is the innermost
     part of its name stack and on the chip its instruction's stem
-    (``flash_fwd.3``); it stands right under the Flax module ``attn``
-    and under no ``LAYER_SCOPES`` name, so the benchmark's readers
+    (``flash_fwd.3``); a flash call stands right under the Flax module
+    ``attn`` and under no ``LAYER_SCOPES`` name (the tiny steps' heads
+    are 64 wide, so they hold no call of the q/k pass, which stands
+    under ``ATTN_PREP``: below), so the benchmark's readers
     still class it as layer ``attn`` (``attn_kernel_ms`` by its layer,
     ``benchmarks/scopes.kernel_kind`` by phase and arity: the backward
     call is a tuple, so ``attn_bwd_dkv_kernel_ms`` reads it and no call
@@ -326,20 +330,96 @@ def test_kernel_names_are_in_their_directions(cell):
                       scopes.FLASH_BWD: {"backward"}}
 
 
+FLASH_NAMES = {scopes.FLASH_FWD, scopes.FLASH_BWD}
+QK_PREP_NAMES = {scopes.QK_PREP_FWD, scopes.QK_PREP_BWD}
+# the routed cell's tiny step with heads as wide as the real one's: the
+# width at which `Attention` runs q/k norms, rope and the kernels' layout
+# as the one pass of `ops/attention_prep.py` (the tiny preset's 64 keeps
+# the array passes)
+WIDE = {"head_dim": 128}
+
+
 @pytest.mark.parametrize("cell", EVERY_CELL)
-def test_a_kernel_call_stands_outside_every_layer_scope(cell):
-    """The rule ``utils/scopes.py`` states: an ``op_name`` that holds
-    ``pallas_call`` or a kernel's name holds no ``LAYER_SCOPES`` name
-    and classes as layer ``attn``."""
+def test_a_flash_call_stands_outside_every_layer_scope(cell):
+    """The rule ``utils/scopes.py`` states: an ``op_name`` that holds a
+    flash kernel's name holds no ``LAYER_SCOPES`` name and classes as
+    layer ``attn``; and at the tiny preset's head width every
+    ``pallas_call`` is a flash call."""
     from benchmarks import scopes as readers
 
     kernels = [o for o, _ in traced(cell, EVERY_CELL[cell])
-               if parts(o) & {"pallas_call", scopes.FLASH_FWD,
-                              scopes.FLASH_BWD}]
+               if parts(o) & {"pallas_call", *FLASH_NAMES}]
     assert kernels
     for o in kernels:
+        assert parts(o) & FLASH_NAMES, o
         assert not parts(o) & set(scopes.LAYER_SCOPES), o
         assert readers.classify(o)[1] == "attn", o
+
+
+def test_the_qk_pass_stands_inside_attn_prep():
+    """The other half of the rule: the q/k pass's two calls sit under
+    ``ATTN_PREP``, so the benchmark books them in layer ``attn_prep``
+    (``attn_prep_ms`` holds the pass's own time) and no reader of the
+    flash kernels takes one for a flash kernel, by layer
+    (``kernel_kind``) or by name (``kernel_names``); the flash calls
+    beside them stay where they were. One forward call a layer in the
+    forward phase, one again in the backward phase for EVERY block
+    (``remat``'s five second runs, and the last block keeps the flash
+    calls' results but rebuilds the pass's), one backward call a
+    layer."""
+    from benchmarks import kernel_names, scopes as readers
+
+    assert not QK_PREP_NAMES & set(scopes.LAYER_SCOPES)
+    assert not QK_PREP_NAMES & set(kernel_names.kernel_names())
+    calls: dict = {}
+    for o, eqn in traced(ROUTED_CELL, 1, **WIDE):
+        if eqn.primitive.name != "pallas_call":
+            continue
+        name = eqn.params["name"]
+        phase, layer = readers.classify(o)
+        assert name in parts(o) and "attn" in parts(o), o
+        results = len(eqn.outvars)
+        kind = readers.kernel_kind(
+            "call", phase, layer, {"call"}, {"call"} if results > 1 else ())
+        if name in QK_PREP_NAMES:
+            assert scopes.ATTN_PREP in parts(o), o
+            assert layer == scopes.ATTN_PREP and kind is None, o
+        else:
+            assert name in FLASH_NAMES, o
+            assert not parts(o) & set(scopes.LAYER_SCOPES), o
+            assert layer == "attn" and kind is not None, o
+        calls[(name, phase)] = calls.get((name, phase), 0) + 1
+    layers = 6
+    assert calls == {
+        (scopes.QK_PREP_FWD, "forward"): layers,
+        (scopes.QK_PREP_FWD, "backward"): layers,
+        (scopes.QK_PREP_BWD, "backward"): layers,
+        (scopes.FLASH_FWD, "forward"): layers,
+        (scopes.FLASH_FWD, "backward"): layers - 1,
+        (scopes.FLASH_BWD, "backward"): layers}, calls
+
+
+def test_with_the_qk_pass_no_norm_or_rope_is_left_to_array_passes():
+    """At the real head width the routed cell's layer ``attn_prep``
+    holds the pass's calls, the gather of rope's rows, v's transpose and
+    the flash function's work around its calls; the norms' ``rsqrt`` and
+    rope's ``split`` are inside the pass. What is left in layer ``attn``
+    is the flash calls alone, and the parameters are where they were."""
+    from benchmarks import scopes as readers
+
+    wide = [(o, e) for o, e in traced(ROUTED_CELL, 1, **WIDE)
+            if "attn" in parts(o)]
+    prep = {e.primitive.name for o, e in wide
+            if readers.classify(o)[1] == scopes.ATTN_PREP}
+    assert {"pallas_call", "transpose", "gather"} <= prep, prep
+    assert not prep & {"rsqrt", "split"}, prep
+    left = {e.primitive.name for o, e in wide
+            if readers.classify(o)[1] == "attn"}
+    assert {"pallas_call"} <= left <= {"pallas_call", "reduce_precision"}, \
+        left
+    products = [o for o, e in wide if e.primitive.name == "dot_general"]
+    assert products and all(
+        readers.classify(o)[1] == scopes.ATTN_PROJ for o in products)
 
 
 @pytest.mark.parametrize("cell", EVERY_CELL)
