@@ -16,18 +16,52 @@ and the head is required at the masked positions of the noisy half.
 
 A step is counted from the configuration's ``model`` group as it is
 run, which may be one chip's share of a deployment, by kind of layer.
-The keys this file reads, and what a key's absence means (a test holds
-this list to the keys the functions touch):
 
-* ``hidden_size``, ``num_heads``, ``num_layers``, ``mlp_ratio``,
-  ``vocab_size``, ``causal``: required. ``num_layers`` counts the
-  leading dense layers and the layers after them, not the MTP layers;
-  ``vocab_size`` is the rows of the head that are held here.
-* ``diffusion_block``: the block length b of a block-diffusion mask,
-  which then stands in place of ``causal``; absent, no such mask.
-* ``num_kv_heads``: absent, as many as heads.
-* ``head_dim``: absent, ``hidden_size / num_heads``, which has to be
-  whole.
+**A kind of layer is a file**, ``benchmarks/layer_kinds/<kind>.py``,
+found by the name the ``model`` group gives under the run's root and
+then among the harness's own (:func:`load_kind`), as
+``harness.load_reference`` finds a family's reference: a new
+architecture brings its mixer's count as a new file, and a name no
+file has is refused by name, with the kinds there are. The ``model``
+group may state ``layer_types``: ``num_layers`` names, one a layer, in
+order (the leading dense layers included); absent, every layer is
+``attention``. What a layer has beside its mixer, its MLP (plain or
+routed, by ``dense_layers``), is counted here for every kind. A kind's
+file has (``tests/benchmarks/test_bench_kinds.py`` holds every file in
+the directory to this):
+
+* ``KEYS``: ``{key of the model group it reads: what the key stands
+  for and how the kind reads a group without it}``; beside them its
+  functions ask the group for ``hidden_size`` alone.
+* ``mixer_macs(model)``: multiply-adds a position of the mixer's dense
+  products and convolutions, what ``blocks`` counts beside the layer's
+  MLP.
+* ``mixing_flops(model, traffic)``: forward operations a data token of
+  what is not a dense product (scores and weighted values, a
+  recurrence), and ``BOOKED_UNDER``: the part of
+  ``forward_flops_per_token`` they are booked under, ``attention`` for
+  a kind that runs the attention kernels, the kind's own name
+  otherwise.
+* ``kernel_work(model, traffic)``: ``{"flops", "bytes"}``, the required
+  operations and least HBM bytes of ONE layer's kernel in one training
+  step on one chip, the same work whatever implements it; or None.
+* ``products(model)``: ``(rows in, columns out)`` of each of the mixer's
+  weights, for a projections' roofline; None where the kind has shapes
+  the file does not state.
+* ``SOURCE_NAMES``: how a source spells the kind in its
+  ``layer_types``; ``ROWS``: the ``published.Row``s that hold the
+  kind's own sizes to the source (``benchmarks/published.py`` holds
+  them for a file whose pattern names the kind).
+
+The keys this file itself reads, and what a key's absence means (a test
+holds this list, with the lists of the kinds, to the keys the functions
+touch):
+
+* ``hidden_size``, ``num_layers``, ``mlp_ratio``, ``vocab_size``:
+  required. ``num_layers`` counts the leading dense layers and the
+  layers after them, not the MTP layers; ``vocab_size`` is the rows of
+  the head that are held here.
+* ``layer_types``: absent, every layer is ``attention``.
 * ``activation``: ``swiglu`` has three matrices a MLP or an expert,
   anything else, or absent, two.
 * ``num_experts``: the router's width, as published; 0 or absent, every
@@ -41,21 +75,107 @@ this list to the keys the functions touch):
 * ``shared_experts``: experts every token goes through; absent, 0.
 * ``dense_layers``: leading layers with a plain MLP of
   ``hidden_size * mlp_ratio`` in a model that has experts; absent, 0.
-* ``kv_lora_rank``: keys and values come from a latent of this width
-  and a rope key shared by the heads; absent, plain projections.
-* ``q_lora_rank``: queries come through a latent of this width;
-  absent, null or 0, a full-rank query.
-* ``qk_nope_head_dim`` and ``qk_rope_head_dim``: their sum is the width
-  of a head's query and key; absent, ``head_dim``.
-* ``v_head_dim``: the width of a head's value and output; absent,
-  ``head_dim``.
-* ``mtp_layers``: further prediction heads (multi-token prediction);
-  absent, 0.
+* ``mtp_layers``: further prediction heads (multi-token prediction),
+  each a block whose mixer is ``attention``; absent, 0.
 """
 
 from __future__ import annotations
 
+import collections
+import importlib.util
+import os
+
 BF16_BYTES = 2
+HERE = os.path.dirname(os.path.abspath(__file__))
+KINDS_DIR = "layer_kinds"
+DEFAULT_KIND = "attention"
+# the root a run's files are found under, where it is not the
+# checkout (the tests' fixtures): ``harness.load_cell`` sets it, one
+# cell a process. A kind's file is looked for there first
+_kinds_root = None
+_loaded: dict = {}  # path -> module
+
+
+def kinds_root(root: str | None) -> None:
+    """Kinds are looked for under ``<root>/benchmarks/layer_kinds``
+    before the harness's own; None, the harness's own alone."""
+    global _kinds_root
+    _kinds_root = root
+
+
+def _kind_dirs() -> list:
+    dirs = [os.path.join(HERE, KINDS_DIR)]
+    if _kinds_root:
+        under = os.path.join(os.path.abspath(_kinds_root), "benchmarks",
+                             KINDS_DIR)
+        if under != dirs[0]:
+            dirs.insert(0, under)
+    return dirs
+
+
+def kinds_there() -> list:
+    """Names of the kinds that have a file, the run's root's and the
+    harness's own."""
+    return sorted({f[:-3] for d in _kind_dirs() if os.path.isdir(d)
+                   for f in os.listdir(d)
+                   if f.endswith(".py") and not f.startswith("_")})
+
+
+def load_kind(name: str):
+    """The module of one kind of layer (its contract is at the top of
+    this file), loaded from ``layer_kinds/<name>.py`` under the run's
+    root or else the harness's own; a name no file has is refused."""
+    # a kind's name is a file's: nothing that leads out of the directory
+    dirs = _kind_dirs() if str(name).isidentifier() else []
+    for path in (os.path.join(d, f"{name}.py") for d in dirs):
+        if path not in _loaded and os.path.isfile(path):
+            spec = importlib.util.spec_from_file_location(
+                f"benchmarks_layer_kind_{name}", path)
+            module = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(module)
+            _loaded[path] = module
+        if path in _loaded:
+            return _loaded[path]
+    raise ValueError(
+        f"layer kind {name!r}: no file benchmarks/{KINDS_DIR}/{name}.py "
+        f"under the run's root or the harness's own; the kinds there "
+        f"are {kinds_there()}. A new kind of layer comes as that file "
+        f"(its contract is at the top of benchmarks/flops.py)")
+
+
+def layer_kinds(model: dict) -> tuple:
+    """The kind of each of the ``num_layers`` layers, in order: the
+    ``model`` group's ``layer_types``, or ``attention`` for every
+    layer where it states none."""
+    layers = model["num_layers"]
+    pattern = model.get("layer_types")
+    if pattern is None:
+        return (DEFAULT_KIND,) * layers
+    if len(pattern) != layers or not all(
+            isinstance(k, str) for k in pattern):
+        raise ValueError(
+            f"layer_types names {len(pattern)} layers and num_layers is "
+            f"{layers}: it is one kind's name (a string) a layer, in "
+            f"order, the leading dense layers included")
+    return tuple(pattern)
+
+
+def layers_by_kind(model: dict) -> dict:
+    """``{kind's name: how many layers are of it}``, in the order the
+    pattern first names each."""
+    return dict(collections.Counter(layer_kinds(model)))
+
+
+def layers_of(model: dict, part: str = DEFAULT_KIND) -> dict:
+    """``{kind's module: how many layers}`` of the kinds whose mixing is
+    booked under ``part`` of :func:`forward_flops_per_token`:
+    ``attention`` is every kind that runs the attention kernels."""
+    found = {}
+    for name, count in layers_by_kind(model).items():
+        kind = load_kind(name)
+        if kind.BOOKED_UNDER == part:
+            found[kind] = count
+    return found
 
 
 def head_positions_per_token(traffic: dict, ahead: int = 1) -> float:
@@ -128,22 +248,9 @@ def v_head_dim(model: dict) -> int:
 
 
 def projection_macs(model: dict) -> int:
-    """Multiply-adds a token of one layer's attention projections."""
-    h, heads = model["hidden_size"], model["num_heads"]
-    qk, v = qk_head_dim(model), v_head_dim(model)
-    out = heads * v * h
-    if "kv_lora_rank" not in model:
-        # q at h x heads·qk, k at h x kv_heads·qk, v at h x kv_heads·v
-        return h * heads * qk + kv_heads(model) * h * (qk + v) + out
-    # latent attention: the query through its latent (or full rank); one
-    # latent and one rope key for all heads; keys' no-rope part and
-    # values expanded from the latent for every head
-    q_rank, kv_rank = model.get("q_lora_rank"), model["kv_lora_rank"]
-    nope, rope = model["qk_nope_head_dim"], model["qk_rope_head_dim"]
-    query = (h * q_rank + q_rank * heads * qk) if q_rank \
-        else h * heads * qk
-    return (query + h * (kv_rank + rope) + kv_rank * heads * (nope + v)
-            + out)
+    """Multiply-adds a token of one ``attention`` layer's projections
+    (``layer_kinds/attention.py``)."""
+    return load_kind(DEFAULT_KIND).mixer_macs(model)
 
 
 def mlp_macs(model: dict) -> tuple:
@@ -170,46 +277,50 @@ def mlp_macs(model: dict) -> tuple:
 
 
 def attention_flops_per_layer(model: dict, traffic: dict) -> float:
-    """Scores at the query-and-key width and weighted values at the
-    value width, a data token: 2·heads·width each for every pair the
-    mask shows (``visible_pairs``) of a sequence, over its T data
-    tokens: 2·T·heads·widths halved under a causal mask,
-    2·(T + b)·heads·widths under a block-diffusion one."""
-    return (2 * (visible_pairs(model, traffic) / traffic["seq_len"])
-            * model["num_heads"]
-            * (qk_head_dim(model) + v_head_dim(model)))
+    """Scores and weighted values of one ``attention`` layer, a data
+    token (``layer_kinds/attention.py``)."""
+    return load_kind(DEFAULT_KIND).mixing_flops(model, traffic)
 
 
 def forward_flops_per_token(model: dict, traffic: dict) -> dict:
     """Forward operations per data token, by part: ``blocks``, the
-    matrix multiplications of the ``num_layers`` layers
-    (``dense_layers`` of them with a plain MLP) at every position a
-    data token runs (``positions_per_token``); ``attention``, their
-    scores and weighted values over the pairs the mask shows; ``head``,
-    the vocabulary head at the positions that have a target; and, where
-    there are ``mtp_layers``, ``mtp``: for each one block of the last
-    kind, the product that takes the hidden state beside the next
-    token's embedding from 2h to h, its attention, and the head once
-    more at the positions that have a token two ahead. The keys read
-    are listed at the top of this file."""
+    matrix multiplications and convolutions of the ``num_layers``
+    layers, each its kind's mixer (``layer_kinds``) and its MLP
+    (``dense_layers`` of them a plain one), at every position a data
+    token runs (``positions_per_token``); ``attention``, the scores and
+    weighted values, over the pairs the mask shows, of the layers whose
+    kind books there; one further part, under its own name, for each
+    kind that books elsewhere (a recurrence); ``head``, the vocabulary
+    head at the positions that have a target; and, where there are
+    ``mtp_layers``, ``mtp``: for each one block of the last kind of
+    MLP with an ``attention`` mixer, the product that takes the hidden
+    state beside the next token's embedding from 2h to h, its
+    attention, and the head once more at the positions that have a
+    token two ahead. The keys read are listed at the top of this file
+    and in the kinds' ``KEYS``."""
     h = model["hidden_size"]
     layers = model["num_layers"]
     dense_layers = model.get("dense_layers", 0) \
         if model.get("num_experts", 0) else layers
-    projections = projection_macs(model)
     dense, last = mlp_macs(model)
     positions = positions_per_token(traffic)
+    mixers, mixing = 0, {}
+    for name, count in layers_by_kind(model).items():
+        kind = load_kind(name)
+        mixers += count * kind.mixer_macs(model)
+        mixing[kind.BOOKED_UNDER] = mixing.get(kind.BOOKED_UNDER, 0) \
+            + count * kind.mixing_flops(model, traffic)
     blocks = positions * 2 * (
-        layers * projections + dense_layers * dense
-        + (layers - dense_layers) * last)
-    attention = attention_flops_per_layer(model, traffic)
+        mixers + dense_layers * dense + (layers - dense_layers) * last)
     head = 2 * h * model["vocab_size"]
-    parts = {"blocks": blocks, "attention": layers * attention,
+    parts = {"blocks": blocks, **mixing,
              "head": head * head_positions_per_token(traffic)}
     if model.get("mtp_layers", 0):
+        attention = load_kind(DEFAULT_KIND)
         parts["mtp"] = model["mtp_layers"] * (
-            positions * (2 * (projections + last) + 2 * 2 * h * h)
-            + attention
+            positions * (2 * (attention.mixer_macs(model) + last)
+                         + 2 * 2 * h * h)
+            + attention.mixing_flops(model, traffic)
             + head * head_positions_per_token(traffic, ahead=2))
     return parts
 
@@ -220,29 +331,19 @@ def train_flops_per_token(model: dict, traffic: dict) -> float:
 
 def attention_kernel_work(model: dict, traffic: dict) -> dict:
     """Required operations and least HBM bytes of one training step's
-    attention on one chip, over ``num_layers`` and ``mtp_layers``.
-
-    Operations: forward QK^T and PV, backward dV, dP, dQ, dK: six
-    products per head over the pairs the mask shows
-    (``visible_pairs``), QK^T, dQ and dK over the query-and-key width,
-    PV, dV and dP over the value width (the flash backward's recomputed
-    scores are not required work). Bytes: forward reads q, k, v and
-    writes o; backward reads q, k, v, o, do and writes dq, dk, dv:
-    twelve bf16 arrays, each moved once, of P positions a sequence (T,
-    or 2T where a data token runs two). q and dq are (B, heads, P,
-    query-and-key width), o and do (B, heads, P, value width), k and dk
-    (B, kv_heads, P, query-and-key width), v and dv (B, kv_heads, P,
-    value width)."""
-    b = traffic["batch_per_chip"]
-    positions = positions_per_token(traffic) * traffic["seq_len"]
-    heads = model["num_heads"]
-    widths = qk_head_dim(model) + v_head_dim(model)
-    layers = model["num_layers"] + model.get("mtp_layers", 0)
-    flops = (layers * 3 * 2 * b * heads * visible_pairs(model, traffic)
-             * widths)
-    nbytes = (layers * 3 * (heads + kv_heads(model)) * b * positions
-              * widths * BF16_BYTES)
-    return {"flops": float(flops), "bytes": float(nbytes)}
+    attention on one chip: each layer's ``kernel_work`` over the layers
+    whose kind runs the attention kernels (``layers_of``), and
+    ``mtp_layers`` further ``attention`` layers."""
+    work = {"flops": 0.0, "bytes": 0.0}
+    counts = layers_of(model)
+    if model.get("mtp_layers", 0):
+        attention = load_kind(DEFAULT_KIND)
+        counts[attention] = counts.get(attention, 0) + model["mtp_layers"]
+    for kind, count in counts.items():
+        one = kind.kernel_work(model, traffic)
+        for key in work:
+            work[key] += float(count * one[key])
+    return work
 
 
 def roofline_seconds(work: dict, peak: dict) -> tuple[float, str]:

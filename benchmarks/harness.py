@@ -32,9 +32,12 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
     """The cell's entry of ``<root>/BENCHMARK.json`` with its
     configuration and traffic files, all found by name under ``root``
     (the checkout, but for the tests' fixture) and the configuration
-    held to its source (``benchmarks/published.py``)."""
-    from benchmarks import published
+    held to its source (``benchmarks/published.py``). A kind of layer
+    the configuration names is looked for under ``root`` first
+    (``flops.kinds_root``)."""
+    from benchmarks import flops, published
 
+    flops.kinds_root(root)
     bench = load_json(root, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:

@@ -105,6 +105,9 @@ WARMUP_STEPS = 4
 CHUNK_STEPS = 4
 LOSS_STEP = 16
 TRACED_STEPS = 6
+# batches a block-diffusion cell draws from its seed, to keep the one
+# whose weights average nearest 1 (``_block_diffusion_batch``)
+BALANCE_DRAWS = 32
 
 
 def make_model(model_sizes: dict, traffic: dict):
@@ -180,11 +183,20 @@ def _causal_lm_loss(heads, cfg, traffic):
 
 
 def _masked_lm_batch(rng, shape, model_sizes, traffic):
+    """``(tokens, labels, mask)``. Every seed labels the same number of
+    positions, ``mask_fraction`` of the batch rounded, at places the
+    seed draws: a Bernoulli mask labels 1,997 +- 41 of 13,312, and a
+    batch with more labels to learn is behind at every later step
+    (0.0009 nats a position at step 16 in ``bertl_s128``, PERF.md
+    section 6, PR 44)."""
     vocab = model_sizes["vocab_size"]
     tokens = rng.integers(0, vocab, shape, dtype=np.int32)
     labels = rng.integers(0, vocab, shape, dtype=np.int32)
-    mask = rng.random(shape) < traffic["mask_fraction"]
-    return tokens, labels, mask
+    size = shape[0] * shape[1]
+    mask = np.zeros(size, dtype=bool)
+    mask[rng.permutation(size)[:round(traffic["mask_fraction"] * size)]] \
+        = True
+    return tokens, labels, mask.reshape(shape)
 
 
 def _masked_lm_loss(heads, cfg, traffic):
@@ -207,7 +219,12 @@ def _block_diffusion_batch(rng, shape, model_sizes, traffic):
     the last, which is the mask token; for each sequence and block of
     ``diffusion_block`` tokens one noise level t ~ U(``t_min``, 1];
     ``m``, which of a block's tokens are masked, Bernoulli(t); ``w`` =
-    1/t, a masked token's weight in the loss."""
+    1/t, a masked token's weight in the loss. The mean of ``m * w``
+    over the batch scales its loss at every step (1 in expectation,
+    +- 1.4% at 8,192 tokens and ``t_min`` 0.1), so of BALANCE_DRAWS
+    batches from the seed the one whose mean is nearest 1 is taken:
+    every seed's batch weighs the same to about 0.05% (PERF.md section
+    6, PR 44)."""
     block, t_min = model_sizes.get("diffusion_block"), traffic["t_min"]
     if not block or shape[1] % block:
         raise ValueError(
@@ -216,11 +233,18 @@ def _block_diffusion_batch(rng, shape, model_sizes, traffic):
             f"diffusion_block {block!r}")
     if not 0.0 <= t_min < 1.0:
         raise ValueError(f"t_min {t_min!r} is not in [0, 1)")
-    x0 = rng.integers(0, model_sizes["vocab_size"] - 1, shape,
-                      dtype=np.int32)
-    level = 1.0 - (1.0 - t_min) * rng.random((shape[0], shape[1] // block))
-    level = np.repeat(level, block, axis=1)
-    return x0, rng.random(shape) < level, (1.0 / level).astype(np.float32)
+    best = None
+    for _ in range(BALANCE_DRAWS):
+        x0 = rng.integers(0, model_sizes["vocab_size"] - 1, shape,
+                          dtype=np.int32)
+        level = 1.0 - (1.0 - t_min) * rng.random(
+            (shape[0], shape[1] // block))
+        level = np.repeat(level, block, axis=1)
+        m, w = rng.random(shape) < level, (1.0 / level).astype(np.float32)
+        off = abs(float(np.mean(m * w, dtype=np.float64)) - 1.0)
+        if best is None or off < best[0]:
+            best = off, (x0, m, w)
+    return best[1]
 
 
 def _block_diffusion_loss(heads, cfg, traffic):
@@ -350,8 +374,9 @@ def choices_agreement(scores: dict, system: dict):
 def make_batch(model_sizes: dict, traffic: dict, n_seq: int, seed: int):
     """The seeded batch on the host, ``n_seq`` sequences: what the
     traffic's row of OBJECTIVES draws (uniform random tokens; for
-    masked LM labels and a Bernoulli mask too; for block diffusion the
-    clean tokens, which of them are masked, and their weights)."""
+    masked LM labels and a mask of a fixed count too; for block
+    diffusion the clean tokens, which of them are masked, and their
+    weights)."""
     return objective_of(traffic).batch(
         np.random.default_rng(seed), (n_seq, traffic["seq_len"]),
         model_sizes, traffic)

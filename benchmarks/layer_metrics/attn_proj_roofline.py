@@ -1,10 +1,14 @@
 """model: least time the chip could take for the step's attention
 projections over the measured ``attn_proj_ms``.
 
-Operations: ``benchmarks/flops.projection_macs`` (what ``mfu_pct``
-counts as the projections' part of ``blocks``) a position and layer,
-two operations a multiply-add, in three passes: forward, and backward
-into the activations and into the weights. Least bytes: each product's
+Operations: the ``mixer_macs`` of each layer whose kind runs the
+attention kernels (``benchmarks/layer_kinds``; what ``mfu_pct`` counts
+as those layers' part of ``blocks``; ``flops.projection_macs`` where
+every layer is ``attention``) a position, two operations a
+multiply-add, in three passes: forward, and backward into the
+activations and into the weights. A layer of another kind (a
+state-space mixer) has projections of its own, which are not under
+the ``attn_proj`` scope and are not counted here. Least bytes: each product's
 two operands and its result moved once in bf16, in each of its three
 passes. The larger of operations over peak FLOP/s and bytes over peak
 HBM bytes/s, over the measured time. Under ``remat`` the forward
@@ -17,16 +21,9 @@ from benchmarks.layer_metrics import attn_proj_ms
 
 
 def products(model: dict):
-    """``(rows in, columns out)`` of each projection's weight, as
-    ``flops.projection_macs`` counts them; None for latent attention
-    (``kv_lora_rank``), which no cell runs: the configuration that
-    brings it brings its products."""
-    if "kv_lora_rank" in model:
-        return None
-    h, heads = model["hidden_size"], model["num_heads"]
-    kv, qk, v = (flops.kv_heads(model), flops.qk_head_dim(model),
-                 flops.v_head_dim(model))
-    return [(h, heads * qk), (h, kv * qk), (h, kv * v), (heads * v, h)]
+    """``(rows in, columns out)`` of each projection's weight of an
+    ``attention`` layer; None for latent attention."""
+    return flops.load_kind(flops.DEFAULT_KIND).products(model)
 
 
 def positions_per_step(traffic: dict) -> int:
@@ -56,12 +53,26 @@ def share(run, what: str, work: dict, measured_ms: float) -> float:
     return 100.0 * 1e3 * least_s / measured_ms
 
 
+def work(model: dict, traffic: dict):
+    """Required operations and least bytes of the step's attention
+    projections, over the layers whose kind runs the attention kernels
+    (``flops.layers_of``); None where there is no such layer or one's
+    kind states no products."""
+    total = None
+    for kind, layers in flops.layers_of(model).items():
+        weights = kind.products(model)
+        if not weights:
+            return None
+        one = dense_work(kind.mixer_macs(model), weights,
+                         positions_per_step(traffic), layers)
+        total = one if total is None else {
+            key: total[key] + one[key] for key in one}
+    return total
+
+
 def read(run):
     measured_ms = attn_proj_ms.read(run)
-    model = run.model_sizes
-    weights = products(model)
-    if not measured_ms or not weights:
+    found = work(run.model_sizes, run.traffic) if measured_ms else None
+    if not found:
         return None
-    work = dense_work(flops.projection_macs(model), weights,
-                      positions_per_step(run.traffic), model["num_layers"])
-    return share(run, "attention projections'", work, measured_ms)
+    return share(run, "attention projections'", found, measured_ms)

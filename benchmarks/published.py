@@ -33,6 +33,19 @@ the key:
   token) never, and neither what makes a mechanism what it is (how
   many shared experts, leading dense layers and further prediction
   heads, how the chosen experts' weights are scaled);
+* a source's ``layer_types``, one name a published layer, holds the
+  ``model`` group's (:func:`hold_pattern`): each name is some kind's
+  spelling (``SOURCE_NAMES`` of a file in ``benchmarks/layer_kinds``);
+  the file's ``layer_period`` really is a period of the published list
+  after the leading dense layers; the run pattern is the first layers
+  of the published one (and, by the rule of depth above, the dense
+  layers and whole periods of it); and a model of more than one kind
+  of layer keeps at least four layers after the dense ones, experts or
+  none. In either form of file the source's ``layer_types`` is the
+  published list whole, and the ``model`` group's says what is run. The
+  ``ROWS`` of a kind hold that kind's own sizes for a file whose
+  pattern names it. A file with no ``layer_types`` is held as it was
+  before a layer had a kind;
 * a key of the source that no row knows is refused, unless the file's
   ``not_held`` lists it with a word on why it says nothing of the
   shape: ``{"rope_theta": "a constant of the position code"}``. In the
@@ -90,6 +103,14 @@ ROWS = (
     # gives the experts no width of their own
     Row(("n_inner", "intermediate_size"), WIDTH,
         lambda m: m["hidden_size"] * m["mlp_ratio"]),
+    # a source that names the MLP every token goes through apart from
+    # its experts' width: the plain MLP where there are no experts, the
+    # shared experts together where there are
+    Row(("shared_intermediate_size",), WIDTH,
+        lambda m: (m.get("shared_experts", 0) * m.get(
+            "expert_mlp_dim", m["hidden_size"] * m["mlp_ratio"])
+            if m.get("num_experts") else
+            m["hidden_size"] * m["mlp_ratio"])),
     Row(("moe_intermediate_size",), WIDTH,
         lambda m: m.get("expert_mlp_dim")),
     # the experts held here; the router keeps the published width
@@ -124,6 +145,10 @@ ROWS = (
     Row(("tie_word_embeddings",), WIDTH, lambda m: m["tie_embeddings"]),
 )
 KNOWN = {key for row in ROWS for key in row.keys}
+PATTERN = "layer_types"  # as a source spells it and as the model group
+PATTERNED_FLOOR = (
+    4, "four layers after the leading dense ones in a model of more "
+    "than one kind of layer")
 # what a source means by null, where it means something
 NULL_MEANS = {
     # GPT-2's config.json: four times the width
@@ -172,7 +197,14 @@ def check(entry: dict, body: dict) -> None:
 
     grouped = "published" in body
     source, model = source_of(body), body["model"]
-    for row in ROWS:
+    rows, known, patterned = ROWS, KNOWN, False
+    if PATTERN in source or PATTERN in model:
+        kinds, patterned = hold_pattern(body, source, model, cuts,
+                                        grouped, refuse)
+        rows = ROWS + tuple(
+            row for kind in kinds for row in kind.ROWS)
+        known = {key for row in rows for key in row.keys} | {PATTERN}
+    for row in rows:
         for key in row.keys:
             value = _source_value(key, source)
             if value is None:
@@ -216,24 +248,105 @@ def check(entry: dict, body: dict) -> None:
                        f"(`layer_period` {period})")
             least, floor = row.floor(cut["published"], model) \
                 if row.floor else (0, "")
+            if row.kind == DEPTH and patterned and least < \
+                    model.get("dense_layers", 0) + PATTERNED_FLOOR[0]:
+                least, floor = (model.get("dense_layers", 0)
+                                + PATTERNED_FLOOR[0], PATTERNED_FLOOR[1])
             if cut["held"] < least:
                 refuse(key, f"held {cut['held']} is under the floor of "
                        f"a cut, {least}: {floor}")
-    for key in sorted(set(cuts) - KNOWN):
+    for key in sorted(set(cuts) - known):
         refuse(key, "`reduced` names a key that no row of "
                "benchmarks/published.py maps to the model group")
     for key in sorted(set(cuts) - set(source)):
         refuse(key, "`reduced` names a key the source does not have")
     not_held = body.get("not_held", {})
-    for key in sorted(set(source) - KNOWN - set(not_held)):
+    for key in sorted(set(source) - known - set(not_held)):
         refuse(key, "no row of benchmarks/published.py maps this key "
                "of the source to the model group: list it in the "
                "file's `not_held` with why it says nothing of the "
                "shape, or a `benchmark` PR adds the row")
     for key in sorted(not_held):
-        if key in KNOWN or key not in source or not not_held[key]:
+        if key in known or key not in source or not not_held[key]:
             refuse(key, "`not_held` lists, each with its reason, keys "
                    "the source has and no row knows")
+
+
+def hold_pattern(body, source, model, cuts, grouped, refuse):
+    """Holds the ``model`` group's pattern of layers to the source's
+    ``layer_types`` (the rule is at the top of this file) and returns
+    ``(the modules of the kinds the run pattern names, whether the
+    model has more than one kind of layer)``."""
+    if PATTERN in cuts:
+        refuse(PATTERN, "is never listed in `reduced`: the source's "
+               "list stays whole and the depth's key says the cut")
+    try:
+        run = flops.layer_kinds(model)
+        kinds = [flops.load_kind(name) for name in dict.fromkeys(run)]
+        for kind in kinds:  # a group its kind cannot count is refused here
+            kind.mixer_macs(model)
+    except KeyError as e:
+        refuse(PATTERN, f"the model group lacks {e}, which a kind of "
+               f"layer it names reads")
+    except ValueError as e:
+        refuse(PATTERN, str(e))
+    listed = source.get(PATTERN)
+    if listed is None:
+        if set(run) != {flops.DEFAULT_KIND}:
+            refuse(PATTERN, f"the model group names the kinds "
+                   f"{sorted(set(run))} and the source has no "
+                   f"`{PATTERN}` to hold them to")
+        return kinds, False
+    spelt = {spelling: name for name in flops.kinds_there()
+             for spelling in flops.load_kind(name).SOURCE_NAMES}
+    for spelling in listed:
+        if spelling not in spelt:
+            refuse(PATTERN, f"the source names a layer {spelling!r}, "
+                   f"which no kind in benchmarks/layer_kinds spells; "
+                   f"they spell {sorted(spelt)}")
+    published = tuple(spelt[spelling] for spelling in listed)
+    depth = next((_published_value(key, source, cuts, grouped)
+                  for row in ROWS if row.kind == DEPTH
+                  for key in row.keys if key in source), None)
+    if depth != len(published):
+        refuse(PATTERN, f"the source's list names {len(published)} "
+               f"layers and its depth is {depth!r}: in either form of "
+               f"file it is the published list, whole")
+    dense = model.get("dense_layers", 0)
+    period = body.get("layer_period", 1)
+    after = published[dense:]
+    if not isinstance(period, int) or period < 1 or any(
+            kind != after[i % period] for i, kind in enumerate(after)):
+        refuse("layer_period", f"{period!r} is no period of the "
+               f"published pattern after its {dense} leading dense "
+               f"layer(s): {_runs(after)}")
+    # that a cut depth is the dense layers and whole periods is the
+    # depth row's rule, below in `check`
+    if run != published[:len(run)]:
+        refuse(PATTERN, f"the model group runs {_runs(run)}, which is "
+               f"not the first {len(run)} layers of the published "
+               f"{_runs(published)}")
+    return kinds, len(set(published)) > 1
+
+
+def _runs(pattern) -> str:
+    """A pattern by its runs: ``5 x mamba2, attention, 4 x mamba2``."""
+    runs = []
+    for kind in pattern:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    return ", ".join(f"{n} x {kind}" if n > 1 else kind
+                     for kind, n in runs)
+
+
+def _published_value(key, source, cuts, grouped):
+    """What was published of a key that may be cut: in the top-level
+    form the file's own key holds what is run."""
+    if key in cuts and not grouped:
+        return cuts[key]["published"]
+    return _source_value(key, source)
 
 
 def _deployment(body: dict) -> bool:

@@ -259,6 +259,54 @@ def test_block_diffusion_batch_from_the_seed():
         == pytest.approx(0.6)
 
 
+# Every seed draws work of one difficulty (PERF.md section 6, PR 44):
+# ``loss_step_16`` is compared from seed to seed, and what a batch's
+# own make-up adds to it is no property of the program. Seeds as large
+# as the driver's among them
+SEEDS = (0, 1, 7, 2_147_483_647, 2_147_483_653, 2_200_000_001)
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_masked_lm_batch_labels_the_same_count_on_every_seed(seed):
+    traffic = {"objective": "masked_lm", "seq_len": 128,
+               "mask_fraction": 0.15}
+    tokens, labels, mask = dp_train.make_batch(
+        {"vocab_size": 30522}, traffic, 104, seed)
+    assert tokens.shape == labels.shape == mask.shape == (104, 128)
+    assert (tokens.dtype, labels.dtype, mask.dtype) == (
+        np.int32, np.int32, bool)
+    assert int(mask.sum()) == round(0.15 * 104 * 128) == 1997
+    # at places the seed draws, not a stride: a row's count varies
+    assert len(set(mask.sum(axis=1).tolist())) > 5
+    again = dp_train.make_batch({"vocab_size": 30522}, traffic, 104, seed)
+    assert all(np.array_equal(a, b)
+               for a, b in zip((tokens, labels, mask), again))
+    other = dp_train.make_batch(
+        {"vocab_size": 30522}, traffic, 104, seed + 1)[2]
+    assert not np.array_equal(mask, other) and int(other.sum()) == 1997
+    # the two sequences of the reference check are held alike
+    assert int(dp_train.make_batch(
+        {"vocab_size": 30522}, traffic, 2, seed)[2].sum()) == 38
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_block_diffusion_batch_weighs_the_same_on_every_seed(
+        seed, monkeypatch):
+    # the cell's size: two sequences of 4,096, t_min 0.1. One draw's
+    # weights average 1 +- 1.4% (variance ln(1/t_min)/(1 - t_min) - 1
+    # a token); the nearest of BALANCE_DRAWS is within a fifth of that
+    def weight(n=2):
+        x0, m, w = bd_batch(n=n, t=4096, t_min=0.1, vocab=18992,
+                            seed=seed)
+        return float(np.mean(m * w, dtype=np.float64))
+
+    assert dp_train.BALANCE_DRAWS == 32
+    assert abs(weight() - 1.0) < 0.003
+    monkeypatch.setattr(dp_train, "BALANCE_DRAWS", 1)
+    single = [abs(weight(n) - 1.0) for n in (2, 3, 5, 6, 7)]
+    assert max(single) > 0.003  # what one draw leaves to chance
+
+
 def test_block_diffusion_batch_refuses_what_it_cannot_noise():
     with pytest.raises(ValueError, match="seq_len 254 is no multiple of "
                                          ".*diffusion_block 4"):

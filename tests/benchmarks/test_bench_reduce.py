@@ -507,11 +507,15 @@ class Recording(dict):
 
 def test_flops_docstring_lists_every_key_it_reads():
     """The next key is not read in silence either: what the module's
-    docstring lists is what its functions ask a model group for, over
-    the kinds of model there are."""
+    docstring lists, with the ``KEYS`` of the one kind of layer these
+    models have (``layer_kinds/attention.py``: the list was one until a
+    layer's kind became a file), is what the functions ask a model
+    group for, over the kinds of model there are."""
     listed = set(re.findall(
         r"``(\w+)``", flops.__doc__.split("absence means")[1]))
-    listed -= {"swiglu"}  # a value of ``activation``, not a key
+    listed -= {"swiglu", "attention"}  # values, not keys
+    kinds = set(flops.load_kind("attention").KEYS)
+    assert not listed & kinds
     asked = set()
     for model in (SHARE, {**SHARE, "q_lora_rank": None}, EXPERT_LAYER,
                   GQA_LAYER, {**GQA_LAYER, "head_dim": 64},
@@ -520,10 +524,13 @@ def test_flops_docstring_lists_every_key_it_reads():
         flops.train_flops_per_token(model, SEQ_4096)
         flops.attention_kernel_work(model, SEQ_4096)
         asked |= model.asked
+    listed |= kinds
     assert asked == listed, (asked - listed, listed - asked)
     # and the share's file states every one of them but the plain head
-    # width its latent attention has no use for, and a mask it has not
-    assert listed - set(SHARE) == {"head_dim", "diffusion_block"}
+    # width its latent attention has no use for, a mask it has not, and
+    # a pattern: its layers are all of the one kind
+    assert listed - set(SHARE) == {"head_dim", "diffusion_block",
+                                   "layer_types"}
 
 
 def test_attention_kernel_work_of_two_head_widths_and_a_second_head():

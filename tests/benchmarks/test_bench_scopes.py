@@ -129,24 +129,26 @@ def layer_scopes(monkeypatch):
 
 
 def test_a_layers_scope_comes_from_the_program(layer_scopes):
+    # names the program does not list (it lists the routed MLP's and
+    # attention's own since PR 33 and PR 41)
+    assert not {"ssm_scan", "ssm_gate"} & set(program.LAYER_SCOPES)
     under_mlp = ("jit(step_fn)/shard_map/jvp(Transformer)/block_0/mlp/"
-                 "moe_experts/dot_general")
-    router = ("jit(step_fn)/transpose(jvp(Transformer))/block_0/mlp/"
-              "moe_router/reduce_sum")
-    # a program that lists no layer scopes: Flax's module names, as ever
-    assert not getattr(program, "LAYER_SCOPES", ())
+                 "ssm_scan/dot_general")
+    gate = ("jit(step_fn)/transpose(jvp(Transformer))/block_0/mlp/"
+            "ssm_gate/reduce_sum")
+    # a name the program does not list: Flax's module names, as ever
     assert scopes.classify(under_mlp) == ("forward", "mlp")
-    layer_scopes("moe_router", "moe_experts")
-    assert scopes.classify(under_mlp) == ("forward", "moe_experts")
-    assert scopes.classify(router) == ("backward", "moe_router")
+    layer_scopes("ssm_gate", "ssm_scan")
+    assert scopes.classify(under_mlp) == ("forward", "ssm_scan")
+    assert scopes.classify(gate) == ("backward", "ssm_gate")
     # after the five scopes there are: the loss head keeps what is its
     assert scopes.classify(
-        "jit(step_fn)/jvp(loss_head)/moe_experts/dot_general") == (
+        "jit(step_fn)/jvp(loss_head)/ssm_scan/dot_general") == (
         "forward", "loss_head")
     # and a reader of the layer is one line over scopes.read
     found = scopes.tables({0: device()}, "step_fn", HLO.replace(
-        "block_0/ln_attn/mul", "block_0/mlp/moe_experts/mul"))
-    assert us(found, lambda p, l, k: l == "moe_experts") == 10
+        "block_0/ln_attn/mul", "block_0/mlp/ssm_scan/mul"))
+    assert us(found, lambda p, l, k: l == "ssm_scan") == 10
     assert us(found, lambda p, l, k: l == "norm") == 0
 
 

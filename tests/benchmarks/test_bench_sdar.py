@@ -194,13 +194,15 @@ def test_readers_read_their_scope_and_nothing_without_one(monkeypatch):
         assert reader.read(a_run(scope_tables=tables)) is None
 
 
-def test_the_three_metrics_are_the_cells_alone():
+def test_the_three_metrics_are_the_routed_cells_alone():
+    # (named `..._are_the_cells_alone` until PR 44, and asserted too that
+    # the three were the last of `per_layer`: false once any PR adds a
+    # metric, since the driver takes new entries at the end of a list)
     mine = [m for m in BENCH["per_layer"] if m.get("workloads") == [CELL]]
     assert [(m["name"], m["unit"], m["layer"], m["source"]) for m in mine] \
         == [("moe_experts_ms", "ms/step", "model", "device_trace"),
             ("moe_dispatch_ms", "ms/step", "model", "device_trace"),
             ("moe_experts_roofline", "%", "kernels", "device_trace")]
-    assert BENCH["per_layer"][-3:] == mine
     assert all(m["moves"] == "tokens_per_s_per_chip" for m in mine)
     for cell in ("gpt2m_dp1", "bertl_s128"):
         names = {m["name"] for m in harness.load_cell(cell)["per_layer"]}
