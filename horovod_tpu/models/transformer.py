@@ -1,4 +1,6 @@
-"""Transformer model family: GPT-2, BERT-Large, Llama.
+"""Transformer model family: GPT-2, BERT-Large, Llama, and stacks whose
+layers differ in kind (`TransformerConfig.layer_types`: an `attention`
+layer or a `mamba2` state-space layer, models/mamba.py).
 
 Benchmark vehicles from BASELINE.json configs: BERT-Large pretraining
 (tokens/sec/chip), Adasum on Llama-2-7B, elastic GPT-2. The reference
@@ -39,7 +41,30 @@ import numpy as np
 
 from ..ops import attention_prep
 from ..utils import metrics, scopes
+from .mamba import Mamba2Mixer
 from .moe import RoutedMlp
+
+# the kinds of layer a `Block` builds, as `layer_types` names them (the
+# spelling of the benchmark's `model` group and of its
+# `benchmarks/layer_kinds/<kind>.py`)
+ATTENTION, MAMBA2 = "attention", "mamba2"
+LAYER_KINDS = (ATTENTION, MAMBA2)
+
+
+class LayerTypes(tuple):
+    """`TransformerConfig.layer_types` as it is kept: a tuple of kinds'
+    names (hashable, as a frozen configuration has to be) that equals
+    the list a JSON file holds of the same names, so a configuration
+    built from a file's keyword arguments reads back equal to them."""
+
+    def __eq__(self, other):
+        return tuple.__eq__(self, tuple(other)) \
+            if isinstance(other, list) else tuple.__eq__(self, other)
+
+    def __ne__(self, other):
+        return not self == other
+
+    __hash__ = tuple.__hash__
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,6 +120,59 @@ class TransformerConfig:
     experts_per_token: int = 0
     expert_mlp_dim: Optional[int] = None
     norm_topk_prob: bool = False
+    # the kind of each layer's mixer, one name a layer in order
+    # (LAYER_KINDS): None = every layer `attention`. A `mamba2` layer
+    # has a state-space mixer (models/mamba.py) where an `attention`
+    # layer has its `Attention`; the norms, the residuals and the MLP
+    # are the same
+    layer_types: Optional[tuple] = None
+    # the state-space mixer's sizes: heads of the recurrence, a head's
+    # width, the state's width N, d_inner over hidden_size (which has to
+    # equal heads x width), taps of the depthwise convolution, groups
+    # that share B and C, positions of a chunk of the scan
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_expand: int = 2
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 256
+    # four constants some models scale their streams by; each default
+    # is neutral and adds no operation. The token embedding's output x
+    # this; every block's two branches x this before they join the
+    # residual; the attention scores x this in place of 1/sqrt(head
+    # width) (None = that); the final norm's output / this, in front of
+    # the head whoever runs it (the model's logits, or a caller's fused
+    # cross entropy on `return_hidden=True`)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            return
+        kinds = LayerTypes(self.layer_types)
+        unknown = sorted(set(kinds) - set(LAYER_KINDS))
+        if unknown or len(kinds) != self.num_layers:
+            raise ValueError(
+                f"layer_types names {len(kinds)} layers"
+                + (f", {unknown} among them," if unknown else "")
+                + f" and num_layers is {self.num_layers}: it is one of "
+                f"{LAYER_KINDS} a layer, in order")
+        if MAMBA2 in kinds and self.mamba_expand * self.hidden_size \
+                != self.mamba_n_heads * self.mamba_d_head:
+            raise ValueError(
+                f"mamba_expand {self.mamba_expand} x hidden_size "
+                f"{self.hidden_size} is not mamba_n_heads "
+                f"{self.mamba_n_heads} x mamba_d_head "
+                f"{self.mamba_d_head}: the inner stream has one width")
+        object.__setattr__(self, "layer_types", kinds)
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """The kind of each of the `num_layers` layers, in order."""
+        return self.layer_types or (ATTENTION,) * self.num_layers
 
     @property
     def head_width(self) -> int:
@@ -333,6 +411,12 @@ class Attention(nn.Module):
                 if rope:
                     q = apply_rope(q, *rope, positions)
                     k = apply_rope(k, *rope, positions)
+            if cfg.attention_multiplier is not None:
+                # every attention here scales its scores by 1/sqrt(D):
+                # the model's own scale rides on q (0.125 for a
+                # multiplier of 1/64 at D = 64, exact in any dtype)
+                q = q * jnp.asarray(
+                    cfg.attention_multiplier * np.sqrt(D), q.dtype)
         if kv_cache is not None:
             # autoregressive serving path (serving/decode.py): the
             # new tokens' K/V append into the slotted cache (quantized
@@ -396,17 +480,51 @@ class Mlp(nn.Module):
                      kernel_init=nn.initializers.xavier_uniform())(h)
 
 
+# what serving lacks for a state-space layer (`Block` raises it where a
+# cache is handed to one; serving/decode.py refuses the model earlier)
+MAMBA2_HAS_NO_CACHE = (
+    "a `mamba2` layer cannot decode through a key-value cache: it keeps "
+    "no keys and values but a recurrent state (heads x d_head x d_state) "
+    "and the convolution's last d_conv - 1 inputs a sequence, and "
+    "serving/decode's slotted cache holds neither")
+
+
+def scaled(x, multiplier: float):
+    """`x` times one of the model's stream multipliers
+    (`embedding_multiplier`, `residual_multiplier`, 1 /
+    `logits_scaling`), in `x`'s dtype; a neutral 1.0 adds no
+    operation."""
+    if multiplier == 1.0:
+        return x
+    return x * jnp.asarray(multiplier, x.dtype)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     attention_fn: Optional[Callable] = None
+    # the layer's mixer (LAYER_KINDS): `Attention` under the module name
+    # `attn`, or the state-space mixer under `mamba`
+    kind: str = ATTENTION
 
     @nn.compact
     def __call__(self, x, positions, mask=None, kv_cache=None, layer=0):
         cfg = self.cfg
         y = _norm(cfg, "ln_attn")(x)
-        x = x + Attention(cfg, attention_fn=self.attention_fn,
-                          name="attn")(y, positions, mask,
-                                       kv_cache=kv_cache, layer=layer)
+        if self.kind == MAMBA2:
+            if kv_cache is not None:
+                raise ValueError(MAMBA2_HAS_NO_CACHE)
+            mixed = Mamba2Mixer(
+                hidden_size=cfg.hidden_size, n_heads=cfg.mamba_n_heads,
+                d_head=cfg.mamba_d_head, d_state=cfg.mamba_d_state,
+                d_conv=cfg.mamba_d_conv, n_groups=cfg.mamba_n_groups,
+                chunk_size=cfg.mamba_chunk_size,
+                epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
+                name="mamba")(y)
+        else:
+            mixed = Attention(cfg, attention_fn=self.attention_fn,
+                              name="attn")(y, positions, mask,
+                                           kv_cache=kv_cache, layer=layer)
+        x = x + scaled(mixed, cfg.residual_multiplier)
         y = _norm(cfg, "ln_mlp")(x)
         if cfg.num_experts:
             mlp = RoutedMlp(
@@ -418,7 +536,7 @@ class Block(nn.Module):
                 name="mlp")
         else:
             mlp = Mlp(cfg, name="mlp")
-        return x + mlp(y)
+        return x + scaled(mlp(y), cfg.residual_multiplier)
 
 
 def _last_block_keeps(prim, *_, **params) -> bool:
@@ -472,7 +590,7 @@ class Transformer(nn.Module):
             param_dtype=jnp.float32, name="tok_emb",
             embedding_init=nn.initializers.normal(0.02),
         )
-        x = emb(tokens)
+        x = scaled(emb(tokens), cfg.embedding_multiplier)
         if cfg.position == "learned":
             pos_emb = self.param(
                 "pos_emb",
@@ -489,26 +607,31 @@ class Transformer(nn.Module):
         kept = int(cfg.remat and return_hidden and cfg.num_layers > 0)
         rematerialised = cfg.num_layers - kept if cfg.remat else 0
         metrics.record_remat_blocks(rematerialised, kept)
+        kinds = cfg.layer_kinds
+        metrics.record_layer_kinds(
+            {kind: kinds.count(kind) for kind in dict.fromkeys(kinds)})
         fused = fuses_qk_prep(cfg, self.attention_fn, kv_cache)
+        attention_layers = kinds.count(ATTENTION)
         metrics.record_attn_prep_layers(
-            cfg.num_layers * fused, cfg.num_layers * (not fused))
-        for i in range(cfg.num_layers):
+            attention_layers * fused, attention_layers * (not fused))
+        for i, kind in enumerate(kinds):
             block = Block
             if i < rematerialised:
                 block = nn.remat(Block, static_argnums=())
             elif kept:
                 block = nn.remat(Block, static_argnums=(),
                                  policy=_last_block_keeps)
+            block = block(cfg, attention_fn=self.attention_fn, kind=kind,
+                          name=f"block_{i}")
             if kv_cache is None:
                 # training/one-shot path: exact pre-cache call shape so
                 # remat'd and jitted programs lower identically
-                x = block(cfg, attention_fn=self.attention_fn,
-                          name=f"block_{i}")(x, positions, mask)
+                x = block(x, positions, mask)
             else:
-                x = block(cfg, attention_fn=self.attention_fn,
-                          name=f"block_{i}")(x, positions, mask,
-                                             kv_cache=kv_cache, layer=i)
-        x = _norm(cfg, "ln_final")(x)
+                x = block(x, positions, mask, kv_cache=kv_cache, layer=i)
+        # on the hidden state, so that the model's own head and a
+        # caller's fused cross entropy read the same scaled state
+        x = scaled(_norm(cfg, "ln_final")(x), 1.0 / cfg.logits_scaling)
         if return_hidden:
             # pre-head activations for the fused LM-head cross-entropy
             # (ops/fused_cross_entropy.py) — the [B, T, V] logits are

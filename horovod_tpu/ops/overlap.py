@@ -203,7 +203,7 @@ def transformer_lm_stages(model, tokens, loss_fn, positions=None,
     """
     import flax.linen as nn
 
-    from ..models.transformer import Block, _norm
+    from ..models.transformer import Block, _norm, scaled
 
     cfg = model.cfg
     attention_fn = model.attention_fn
@@ -218,7 +218,8 @@ def transformer_lm_stages(model, tokens, loss_fn, positions=None,
     )
 
     def embed_fwd(sub, carry):
-        x = emb_mod.apply({"params": sub["tok_emb"]}, tokens)
+        x = scaled(emb_mod.apply({"params": sub["tok_emb"]}, tokens),
+                   cfg.embedding_multiplier)
         if cfg.position == "learned":
             x = x + sub["pos_emb"][positions].astype(cfg.dtype)
         return x
@@ -228,18 +229,19 @@ def transformer_lm_stages(model, tokens, loss_fn, positions=None,
     stages = [Stage("embed", embed_keys, embed_fwd)]
 
     block_cls = nn.remat(Block, static_argnums=()) if cfg.remat else Block
-    for i in range(cfg.num_layers):
+    for i, kind in enumerate(cfg.layer_kinds):
         key = f"block_{i}"
 
-        def blk_fwd(sub, carry, _key=key):
-            return block_cls(cfg, attention_fn=attention_fn).apply(
-                {"params": sub[_key]}, carry, positions, mask)
+        def blk_fwd(sub, carry, _key=key, _kind=kind):
+            return block_cls(
+                cfg, attention_fn=attention_fn, kind=_kind).apply(
+                    {"params": sub[_key]}, carry, positions, mask)
 
         stages.append(Stage(key, (key,), blk_fwd))
 
     def head_fwd(sub, carry):
-        x = _norm(cfg, "ln_final").apply({"params": sub["ln_final"]},
-                                         carry)
+        x = scaled(_norm(cfg, "ln_final").apply(
+            {"params": sub["ln_final"]}, carry), 1.0 / cfg.logits_scaling)
         if cfg.tie_embeddings:
             logits = emb_mod.apply({"params": sub["tok_emb"]}, x,
                                    method=nn.Embed.attend)

@@ -164,6 +164,19 @@ def _lm_pipeline_pieces(cfg, rest, attention_fn, tokens,
 
 
 def _check_pp(cfg, mesh, who):
+    kinds = sorted(set(cfg.layer_kinds))
+    if kinds != ["attention"] or (cfg.embedding_multiplier,
+                                  cfg.logits_scaling) != (1.0, 1.0):
+        # a stage runs its layers as ONE block scanned over stacked
+        # parameters (`stack_block_params`), and embeds and heads apart
+        raise ValueError(
+            f"{who} stacks a stage's blocks and scans one `attention` "
+            f"block over them; this model has layers of kinds {kinds} "
+            f"(embedding_multiplier {cfg.embedding_multiplier}, "
+            f"logits_scaling {cfg.logits_scaling}). What is missing: a "
+            f"stage that holds a stack of unlike blocks (a tree a kind, "
+            f"run in the pattern's order) and the stream's two scalings "
+            f"in `_EmbedOnly` / `_HeadOnly`")
     assert "pp" in mesh.shape, (
         f"{who} needs a 'pp' mesh axis; got {mesh.axis_names}")
     S = mesh.shape["pp"]

@@ -345,6 +345,13 @@ class GenerationEngine:
             raise ValueError(
                 "autoregressive generation needs a causal LM "
                 "(TransformerConfig.causal=True)")
+        from ..models.transformer import MAMBA2, MAMBA2_HAS_NO_CACHE
+
+        if MAMBA2 in cfg.layer_kinds:
+            mamba2 = [i for i, kind in enumerate(cfg.layer_kinds)
+                      if kind == MAMBA2]
+            raise ValueError(f"layers {mamba2} of this model are "
+                             f"state-space layers: " + MAMBA2_HAS_NO_CACHE)
         if getattr(cfg, "remat", False):
             # remat exists to trade activation memory for backward
             # recompute; inference has no backward, and nn.remat

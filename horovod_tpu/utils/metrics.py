@@ -1104,6 +1104,43 @@ def record_attn_prep_layers(fused: int, plain: int) -> None:
         "array passes").set(plain)
 
 
+def record_layer_kinds(counts: dict) -> None:
+    """How many layers of each kind `Transformer` (models/transformer.py)
+    built: `{"attention": n, "mamba2": m}` by
+    `TransformerConfig.layer_types` (every layer `attention` where it
+    states none). Recorded at TRACE time like the gauges above: the last
+    traced call's, nothing inside the step."""
+    if not _enabled:
+        return
+    family = registry.gauge(
+        "hvd_layers", "Layers of the model by the kind of their mixer",
+        labelnames=("kind",))
+    for kind, count in counts.items():
+        family.labels(kind=kind).set(count)
+
+
+def record_mamba_scan(chunk: int, chunks: int, state_bytes: int) -> None:
+    """What one state-space mixer's scan (models/mamba.ssd_scan) was
+    built for: the positions of a chunk (the configuration's, or the
+    sequence where that is shorter), the chunks a sequence is cut into
+    (padding included) and the bytes of the float32 state a sequence
+    carries between them (heads x d_head x d_state x 4). Recorded at
+    TRACE time like the gauges above: the last traced call's, nothing
+    inside the step."""
+    if not _enabled:
+        return
+    registry.gauge(
+        "hvd_mamba_chunk",
+        "Positions in one chunk of the state-space scan").set(chunk)
+    registry.gauge(
+        "hvd_mamba_chunks_per_sequence",
+        "Chunks the state-space scan cuts a sequence into").set(chunks)
+    registry.gauge(
+        "hvd_mamba_state_bytes_per_sequence",
+        "Bytes of float32 state a sequence carries between "
+        "chunks").set(state_bytes)
+
+
 def record_overlap_window(frac: float) -> None:
     """The backward-interleaved scheduler's per-step overlap pin
     (ops/overlap.py): the fraction of backward compute the staged

@@ -45,14 +45,33 @@ ATTN_PROJ = "attn_proj"
 # kernels' layout, pads and slices, the sum of partial dk/dv over a
 # group's query heads, delta, casts)
 ATTN_PREP = "attn_prep"
+# models/mamba.Mamba2Mixer, both directions; every operation of the Flax
+# module `mamba` lies in exactly one of the four (tests/test_step_scopes.py).
+# The input and output projections and nothing else
+MAMBA_PROJ = "mamba_proj"
+# the causal depthwise convolution with its bias, silu, the splits of
+# the input projection's result and of the convolution's, dt's softplus
+MAMBA_CONV = "mamba_conv"
+# everything from x, dt, B, C to y: a * dt and its cumulative sums, the
+# decay tiles, the products inside a chunk, the states between chunks,
+# D x. Plain `jnp` / `lax` today; a kernel that takes its place carries
+# a `name=` below and stands outside every LAYER_SCOPES name, as the
+# flash calls do
+MAMBA_SCAN = "mamba_scan"
+# the gate y * silu(z) and the norm over the whole inner width
+MAMBA_GATE = "mamba_gate"
 # The scopes that are a layer's own: the benchmark's reduction
 # (benchmarks/scopes.classify) looks for these after the scopes above
 # and before Flax's module names, so `.../mlp/moe_experts/...` is layer
 # `moe_experts` and not `mlp`, and `.../attn/attn_proj/query/...` is
-# layer `attn_proj` and not `attn`. The dense MLP needs none: Flax's
+# layer `attn_proj` and not `attn`, and everything under the Flax module
+# `mamba` is one of the four `mamba_*` layers (a Mosaic call of layer
+# `attn` is a flash kernel to the kernels' readers, so the state-space
+# mixer's module is never named `attn`). The dense MLP needs none: Flax's
 # `mlp` is its layer, and in a routed model `mlp` is what RoutedMlp
 # does outside its two scopes
-LAYER_SCOPES = (MOE_DISPATCH, MOE_EXPERTS, ATTN_PROJ, ATTN_PREP)
+LAYER_SCOPES = (MOE_DISPATCH, MOE_EXPERTS, ATTN_PROJ, ATTN_PREP,
+                MAMBA_PROJ, MAMBA_CONV, MAMBA_SCAN, MAMBA_GATE)
 
 # Kernel names, not layer scopes: the `name=` of the program's
 # `pl.pallas_call`s. The TPU compiler names a Mosaic call by it

@@ -462,11 +462,13 @@ def test_attention_is_projections_layout_work_and_kernels(cell):
 
 # -- the routed MLP's own scopes ---------------------------------------------
 
-def test_layer_scopes_are_the_routed_mlps_two_and_attentions_two():
+def test_layer_scopes_are_the_routed_mlps_two_attentions_two_the_mixers_four():
     assert scopes.LAYER_SCOPES == (
-        "moe_dispatch", "moe_experts", "attn_proj", "attn_prep") == (
+        "moe_dispatch", "moe_experts", "attn_proj", "attn_prep",
+        "mamba_proj", "mamba_conv", "mamba_scan", "mamba_gate") == (
         scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.ATTN_PROJ,
-        scopes.ATTN_PREP)
+        scopes.ATTN_PREP, scopes.MAMBA_PROJ, scopes.MAMBA_CONV,
+        scopes.MAMBA_SCAN, scopes.MAMBA_GATE)
     # the kernels' names are no layer scopes
     assert (scopes.FLASH_FWD, scopes.FLASH_BWD) == ("flash_fwd", "flash_bwd")
     assert not {scopes.FLASH_FWD, scopes.FLASH_BWD} & set(
@@ -543,3 +545,84 @@ def test_routed_mlp_is_named_in_both_directions(scope):
                     "iota", "dynamic_slice", "convert_element_type",
                     "reshape", "remat2", "add_any", "add",
                     "dynamic_update_slice", "mlp"}, bare
+
+
+# -- the state-space mixer's four scopes --------------------------------------
+
+HYBRID_CELL = "granite_h_lm"
+MAMBA_SCOPES = {scopes.MAMBA_PROJ, scopes.MAMBA_CONV, scopes.MAMBA_SCAN,
+                scopes.MAMBA_GATE}
+# the tiny preset's pattern: three state-space layers around one
+# attention layer
+MAMBA_BLOCKS, ATTN_BLOCKS = {"block_0", "block_1", "block_3"}, {"block_2"}
+
+
+def test_every_operation_of_the_state_space_mixer_is_in_one_scope():
+    """The rule ``utils/scopes.py`` states for the Flax module
+    ``mamba``: each of its operations, in both directions and in a
+    rematerialised block's second run, lies in exactly one of
+    ``mamba_proj``, ``mamba_conv``, ``mamba_scan`` and ``mamba_gate``,
+    which is then its layer for the benchmark's readers; none is a
+    Pallas call, and none is classed ``attn`` (a Mosaic call of that
+    layer is a flash kernel to the kernels' readers)."""
+    from benchmarks import scopes as readers
+
+    assert MAMBA_SCOPES <= set(scopes.LAYER_SCOPES)
+    under = [(o, e) for o, e in traced(HYBRID_CELL, 1)
+             if "mamba" in parts(o)]
+    assert under
+    seen: dict = {}
+    for o, eqn in under:
+        mine = parts(o) & MAMBA_SCOPES
+        assert len(mine) == 1, o
+        assert not parts(o) & {"attn", *FLASH_NAMES, *QK_PREP_NAMES}, o
+        assert eqn.primitive.name != "pallas_call", o
+        phase, layer = readers.classify(o)
+        assert {layer} == mine, o
+        block = next(p for p in parts(o) if p.startswith("block_"))
+        seen.setdefault((layer, phase), set()).add(block)
+    assert seen == {(scope, phase): MAMBA_BLOCKS
+                    for scope in MAMBA_SCOPES
+                    for phase in ("forward", "backward")}, seen
+    # and outside the module nothing carries one of the four names
+    assert not [o for o, _ in traced(HYBRID_CELL, 1)
+                if parts(o) & MAMBA_SCOPES and "mamba" not in parts(o)]
+
+
+def test_the_state_space_scopes_hold_what_they_say():
+    """The projections' scope holds the two products and nothing of the
+    scan; the scan's holds the cumulative sums, the exponentials and
+    the loop between chunks; the convolution's holds dt's softplus."""
+    by_scope: dict = {}
+    for o, eqn in traced(HYBRID_CELL, 1):
+        for scope in parts(o) & MAMBA_SCOPES:
+            by_scope.setdefault(scope, set()).add(eqn.primitive.name)
+    assert "dot_general" in by_scope[scopes.MAMBA_PROJ]
+    assert not by_scope[scopes.MAMBA_PROJ] & {"cumsum", "exp", "scan",
+                                               "logistic"}
+    assert {"cumsum", "exp", "scan", "dot_general"} <= \
+        by_scope[scopes.MAMBA_SCAN]
+    assert {"logistic", "pad"} <= by_scope[scopes.MAMBA_CONV]
+    assert "dot_general" not in by_scope[scopes.MAMBA_CONV]
+    assert {"rsqrt", "logistic"} <= by_scope[scopes.MAMBA_GATE]
+    assert "dot_general" not in by_scope[scopes.MAMBA_GATE]
+
+
+def test_the_hybrids_attention_layer_keeps_attentions_names():
+    """One attention layer among the state-space ones: its projections
+    and its two flash calls stand where every cell's do, in its block
+    alone."""
+    from benchmarks import scopes as readers
+
+    names = [o for o, _ in traced(HYBRID_CELL, 1) if "attn" in parts(o)]
+    assert {p for o in names for p in parts(o)
+            if p.startswith("block_")} == ATTN_BLOCKS
+    assert {readers.classify(o)[1] for o in names} == {
+        "attn", scopes.ATTN_PROJ, scopes.ATTN_PREP}
+    calls = [(e.params["name"], readers.classify(o)[0])
+             for o, e in traced(HYBRID_CELL, 1)
+             if e.primitive.name == "pallas_call"]
+    # block_2 is rematerialised: its forward kernel runs again
+    assert sorted(calls) == [
+        (scopes.FLASH_BWD, "backward"), (scopes.FLASH_FWD, "backward"),
+        (scopes.FLASH_FWD, "forward")]
