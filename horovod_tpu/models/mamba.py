@@ -24,26 +24,42 @@ output is a masked, decayed product over the chunk's inputs, (C B^T *
 decay) X, matrix products on the MXU; between chunks a state is
 carried. What is float32: dt, a * dt, their cumulative sums, every
 exponential, the carried state and every product's accumulator. The
-products' operands are the model's dtype (bf16 in training). JAX
-differentiates it; no kernel of the program's own is in it.
+products' operands are the model's dtype (bf16 in training).
 
-Memory: a chunk's decay tile is `chunk x chunk` a head, [H, T / chunk,
-chunk, chunk] float32 a layer (0.5 GiB at 64 heads and 8,192
-positions). Under `remat` a block's second run is scheduled into its
-backward pass piece by piece and the tiles do not add up: compiled for
-the chip, the benchmark's step needs 0.5% more with the scan whole than
-with the heads run in blocks of eight under a checkpoint of their own,
-and runs a forward scan less a layer (PERF.md section 6, PR 45). The
-last block under `remat` keeps its matrix products' results
-(`models/transformer._last_block_keeps`), the scan's among them; the
-decay tiles are no product's result and are rebuilt.
+Two forms of it, chosen by what `ssd_scan` sees in its arguments and by
+nothing else (`ops/ssd_scan.supports`: a chunk and a state that are
+whole lane tiles, heads of a group in blocks that are whole tiles, a
+floating dtype; `scan_runs_as_kernels`): where the shape admits it,
+one Pallas kernel forward and one backward (`ops/ssd_scan.py`, a
+`jax.custom_vjp`), in which a head's decay tile, `C B^T`, their product
+and the chunk's state live in VMEM and only x, dt, the cumulative sums,
+B, C, y and the states between chunks cross HBM; elsewhere
+`_scan_chunks`, plain `lax` that JAX differentiates, which is also what
+the kernels' tests compare with. The roundings stand at the same places
+in both. `_log_decay_sums` (and a * dt) stay outside the kernels in
+float32, and JAX differentiates them.
+
+Memory: in the plain form a chunk's decay tile is `chunk x chunk` a
+head, [H, T / chunk, chunk, chunk] float32 a layer (0.5 GiB at 64 heads
+and 8,192 positions), written to HBM and read back; the kernels never
+write a tile. Their forward call returns y and the state every chunk
+starts from ([T / chunk, d_state, H * d_head] float32, 64 MiB there),
+the backward's residual beside the call's own arguments. The last block
+under `remat` keeps its matrix products' results
+(`models/transformer._last_block_keeps`) and the forward kernel's, by
+its name, so that it does not run it a second time; every other
+rematerialised block runs the forward kernel twice, as it ran the plain
+scan twice.
 
 Scopes (`utils/scopes.LAYER_SCOPES`; every operation of the module lies
 in exactly one): `mamba_proj` the two projections, `mamba_conv` the
 convolution, silu, the splits and dt's softplus, `mamba_scan` everything
-from x, dt, B, C to y, `mamba_gate` the gate and the norm. Trace-time
-gauges (`utils/metrics.record_mamba_scan`): `hvd_mamba_chunk`,
-`hvd_mamba_chunks_per_sequence`, `hvd_mamba_state_bytes_per_sequence`.
+from x, dt, B, C to y, the two kernels' calls included (`SSD_SCAN_FWD`,
+`SSD_SCAN_BWD` stand inside it), `mamba_gate` the gate and the norm.
+Trace-time gauges (`utils/metrics.record_mamba_scan`): `hvd_mamba_chunk`,
+`hvd_mamba_chunks_per_sequence`, `hvd_mamba_state_bytes_per_sequence`;
+and, set by `Transformer` for a model that has such layers,
+`hvd_mamba_scan_kernel_layers` / `hvd_mamba_scan_plain_layers`.
 """
 
 from __future__ import annotations
@@ -57,6 +73,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import ssd_scan as ssd_kernels
 from ..utils import metrics, scopes
 
 # dt at initialisation is log-uniform in this range (Mamba-2's own
@@ -153,12 +170,31 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int):
     metrics.record_mamba_scan(
         chunk, padded // chunk, h * p * n * jnp.dtype(jnp.float32).itemsize)
     per_group = h // groups
-    y = _scan_chunks(
-        x.reshape(bsz, padded, groups, per_group, p),
-        dt.reshape(bsz, padded, groups, per_group),
-        a.reshape(groups, per_group), b, c, chunk)
-    y = y.reshape(bsz, padded, h, p) + d[:, None] * x.astype(jnp.float32)
+    if scan_runs_as_kernels(padded, chunk, p, n, per_group, x.dtype):
+        cum = _log_decay_sums(
+            (dt * a).reshape(bsz, padded // chunk, chunk, h))
+        # in the layout the convolution leaves and the gate reads: seen
+        # as [.., H, P] an array of [.., H * P] is another tiling of
+        # memory
+        y = ssd_kernels.ssd_chunks(
+            x.reshape(bsz, padded, h * p), dt,
+            cum.reshape(bsz, padded, h), b, c, d, chunk).reshape(x.shape)
+    else:
+        y = _scan_chunks(
+            x.reshape(bsz, padded, groups, per_group, p),
+            dt.reshape(bsz, padded, groups, per_group),
+            a.reshape(groups, per_group), b, c, chunk
+        ).reshape(bsz, padded, h, p) + d[:, None] * x.astype(jnp.float32)
     return y[:, :t] if pad else y
+
+
+def scan_runs_as_kernels(t: int, chunk: int, d_head: int, d_state: int,
+                         heads_in_group: int, dtype) -> bool:
+    """Whether `ssd_scan` hands a sequence of `t` positions to the
+    kernels of `ops/ssd_scan.py`: decided by shapes alone, with the
+    chunk a shorter sequence is cut to."""
+    return ssd_kernels.supports(min(chunk, t), d_head, d_state,
+                                heads_in_group, dtype)
 
 
 def causal_conv(x, kernel, bias):

@@ -41,7 +41,7 @@ import numpy as np
 
 from ..ops import attention_prep
 from ..utils import metrics, scopes
-from .mamba import Mamba2Mixer
+from .mamba import Mamba2Mixer, scan_runs_as_kernels
 from .moe import RoutedMlp
 
 # the kinds of layer a `Block` builds, as `layer_types` names them (the
@@ -556,9 +556,14 @@ def _last_block_keeps(prim, *_, **params) -> bool:
     too, and its results (q and k after norm and rope in the kernels'
     layout, 144 MiB in `sdar_bd_s4096`) are of the rebuilt kind, cheap
     to make again from the kept projections (compiled with them kept:
-    +0.80% `step_hbm_gib` for at most ~2 ms; PR 40)."""
+    +0.80% `step_hbm_gib` for at most ~2 ms; PR 40). The state-space
+    scan's forward kernel (`ops/ssd_scan.py`) is of the kept kind, as
+    the plain scan's products were: y and the states between chunks
+    (128 + 64 MiB in `granite_h_lm`), so that the last block does not
+    run it a second time."""
     if prim.name == "pallas_call":
-        return params.get("name") in (scopes.FLASH_FWD, scopes.FLASH_BWD)
+        return params.get("name") in (scopes.FLASH_FWD, scopes.FLASH_BWD,
+                                      scopes.SSD_SCAN_FWD)
     return prim.name in ("dot_general", "top_k", "sort")
 
 
@@ -614,6 +619,15 @@ class Transformer(nn.Module):
         attention_layers = kinds.count(ATTENTION)
         metrics.record_attn_prep_layers(
             attention_layers * fused, attention_layers * (not fused))
+        state_space_layers = kinds.count(MAMBA2)
+        if state_space_layers:
+            as_kernels = scan_runs_as_kernels(
+                T, cfg.mamba_chunk_size, cfg.mamba_d_head,
+                cfg.mamba_d_state, cfg.mamba_n_heads // cfg.mamba_n_groups,
+                cfg.dtype)
+            metrics.record_mamba_scan_layers(
+                state_space_layers * as_kernels,
+                state_space_layers * (not as_kernels))
         for i, kind in enumerate(kinds):
             block = Block
             if i < rematerialised:

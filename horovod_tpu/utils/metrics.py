@@ -1141,6 +1141,27 @@ def record_mamba_scan(chunk: int, chunks: int, state_bytes: int) -> None:
         "chunks").set(state_bytes)
 
 
+def record_mamba_scan_layers(kernels: int, plain: int) -> None:
+    """How `Transformer` (models/transformer.py) built its state-space
+    layers' recurrences: those that run as the two kernels of
+    `ops/ssd_scan.py`, and those whose shape the kernels do not take
+    and that run the plain chunked form (`models/mamba._scan_chunks`).
+    A model is all one or all the other
+    (`models/mamba.scan_runs_as_kernels`: by shapes alone). Recorded at
+    TRACE time like the gauges above, and only for a model that has
+    such layers."""
+    if not _enabled:
+        return
+    registry.gauge(
+        "hvd_mamba_scan_kernel_layers",
+        "State-space layers whose recurrence is one Pallas kernel a "
+        "direction").set(kernels)
+    registry.gauge(
+        "hvd_mamba_scan_plain_layers",
+        "State-space layers whose recurrence is plain array "
+        "operations").set(plain)
+
+
 def record_overlap_window(frac: float) -> None:
     """The backward-interleaved scheduler's per-step overlap pin
     (ops/overlap.py): the fraction of backward compute the staged

@@ -11,13 +11,16 @@ constants: a name changed here changes there.
 The rule the readers depend on (``tests/test_step_scopes.py`` holds
 it): **a flash call (``FLASH_FWD`` / ``FLASH_BWD``) sits outside every
 ``LAYER_SCOPES`` name; the q/k pass's call (``QK_PREP_FWD`` /
-``QK_PREP_BWD``) sits inside ``ATTN_PREP``.** A flash call's
+``QK_PREP_BWD``) sits inside ``ATTN_PREP``, and the state-space
+scan's (``SSD_SCAN_FWD`` / ``SSD_SCAN_BWD``) inside ``MAMBA_SCAN``.** A flash call's
 ``op_name`` is ``.../attn/flash_fwd/pallas_call``: it stays in Flax's
 layer ``attn``, where the kernels' readers look (they take every Mosaic
 call of that layer for a flash kernel), and what is left in layer
 ``attn`` outside the kernels is the plain-XLA attention's einsums and
 softmax. The q/k pass's is ``.../attn/attn_prep/qk_prep_fwd/pallas_call``:
-layer ``attn_prep``, whose time it is.
+layer ``attn_prep``, whose time it is; the scan's is
+``.../mamba/mamba_scan/ssd_scan_fwd/pallas_call``: layer ``mamba_scan``,
+likewise.
 """
 
 # final hidden state to the loss, both directions, fused or dense
@@ -54,9 +57,13 @@ MAMBA_PROJ = "mamba_proj"
 MAMBA_CONV = "mamba_conv"
 # everything from x, dt, B, C to y: a * dt and its cumulative sums, the
 # decay tiles, the products inside a chunk, the states between chunks,
-# D x. Plain `jnp` / `lax` today; a kernel that takes its place carries
-# a `name=` below and stands outside every LAYER_SCOPES name, as the
-# flash calls do
+# D x: as the two kernels of ops/ssd_scan.py (SSD_SCAN_* below) with
+# the cumulative sums, the layouts of dt and D x around them, or as
+# plain `jnp` / `lax` where the shape is not one the kernels take. The
+# kernels' calls stand INSIDE this scope, forward rule and backward
+# rule: `mamba_scan_ms` selects by this layer and nothing else, so a
+# call outside it would be layer `other` to the readers, and every
+# operation of the module lies in one of the four
 MAMBA_SCAN = "mamba_scan"
 # the gate y * silu(z) and the norm over the whole inner width
 MAMBA_GATE = "mamba_gate"
@@ -85,3 +92,10 @@ FLASH_BWD = "flash_bwd"
 # these stand INSIDE `ATTN_PREP`: their time is attention's layout work
 QK_PREP_FWD = "qk_prep_fwd"
 QK_PREP_BWD = "qk_prep_bwd"
+# The chunked state-space recurrence (ops/ssd_scan.py), forward and
+# backward. Like the q/k pass's these stand INSIDE their layer's scope,
+# `MAMBA_SCAN`: their time is the scan's, and the scan's reader
+# (benchmarks/layer_metrics/mamba_scan_ms.py) takes the layer whole. A
+# forward call in the backward phase is a rematerialised block's
+SSD_SCAN_FWD = "ssd_scan_fwd"
+SSD_SCAN_BWD = "ssd_scan_bwd"

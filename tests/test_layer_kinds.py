@@ -22,7 +22,7 @@ from benchmarks import harness  # noqa: E402
 from horovod_tpu.models import mamba, transformer  # noqa: E402
 from horovod_tpu.models.transformer import (  # noqa: E402
     Transformer, TransformerConfig, causal_lm_loss)
-from horovod_tpu.utils import metrics  # noqa: E402
+from horovod_tpu.utils import metrics, scopes  # noqa: E402
 
 REFERENCE = harness.load_reference("state_space_hybrid_lm")
 PATTERN = ["mamba2", "mamba2", "attention", "mamba2"]
@@ -221,7 +221,40 @@ def test_the_gauges_at_the_cells_shape():
         "hvd_mamba_chunk": 256, "hvd_mamba_chunks_per_sequence": 32,
         "hvd_mamba_state_bytes_per_sequence": 64 * 64 * 128 * 4,
         "hvd_attn_prep_fused_layers": 0, "hvd_attn_prep_plain_layers": 1,
+        "hvd_mamba_scan_kernel_layers": 9, "hvd_mamba_scan_plain_layers": 0,
         "hvd_remat_blocks": 9, "hvd_remat_blocks_kept": 1}
+
+
+def test_the_tiny_pattern_runs_the_plain_scan_and_says_so():
+    """Heads of 16 over a state of 8 in chunks of 16: no shape the
+    scan's kernels take. A model with no state-space layer sets neither
+    gauge."""
+    _, model, params, tokens = built()
+    got = gauges(lambda: jax.eval_shape(
+        lambda p: model.apply({"params": p}, tokens), params))
+    assert (got["hvd_mamba_scan_kernel_layers"][""],
+            got["hvd_mamba_scan_plain_layers"][""]) == (0, 3)
+    cfg = dataclasses.replace(transformer.GPT2_SMALL, **TINY)
+    plain = Transformer(cfg)
+    got = gauges(lambda: jax.eval_shape(
+        plain.init, jax.random.PRNGKey(0), jnp.zeros((2, 16), jnp.int32)))
+    assert not [name for name in got if name.startswith("hvd_mamba_")]
+
+
+@pytest.mark.parametrize("name,kept", [
+    ("ssd_scan_fwd", True), ("ssd_scan_bwd", False), ("flash_fwd", True),
+    ("flash_bwd", True), ("qk_prep_fwd", False)])
+def test_the_last_block_keeps_the_scans_forward_call(name, kept):
+    """`_last_block_keeps` by kernel name: y and the states between
+    chunks are kept from the block's first run, as the flash calls'
+    results are; the backward kernel's are nothing a second run
+    makes."""
+    class Prim:
+        name = "pallas_call"
+
+    assert name in {scopes.SSD_SCAN_FWD, scopes.SSD_SCAN_BWD,
+                    scopes.FLASH_FWD, scopes.FLASH_BWD, scopes.QK_PREP_FWD}
+    assert transformer._last_block_keeps(Prim(), name=name) is kept
 
 
 def test_the_real_configuration_has_the_parameters_the_issue_counted():
@@ -295,7 +328,9 @@ def test_serving_refuses_a_state_space_layer_and_says_what_is_missing():
 # `tests/test_step_scopes.tiny_step` builds them, and four presets of
 # `models/transformer.py` no cell runs. With no new key set a
 # configuration lowers to the same text, so no number a cell prints can
-# have moved
+# have moved. PR 46 (the scan's kernels, one more name in
+# `_last_block_keeps`, two gauges for a model with `mamba2` layers)
+# leaves all nine as they were
 PARENT_LOWERED = {
     "gpt2m_dp1":
         "cca38d2c0a7ce2ca00e1ea5711e301ebefc16f951e4472d5ff65984aa35c9ce1",

@@ -40,9 +40,10 @@ OWN_LINES = {"", "add", "div", "psum", "broadcast", "reduce_sum",
 UNSCOPED_DIFFERENTIATED = {"jvp()/transpose", "transpose(jvp())/transpose"}
 
 
-def tiny_step(cell: str, n: int, **model_sizes):
+def tiny_step(cell: str, n: int, seq_len=None, **model_sizes):
     """The cell's tiny step and its described arguments for ``n`` CPU
-    devices; ``model_sizes`` stand in place of the tiny preset's."""
+    devices; ``model_sizes`` stand in place of the tiny preset's, and
+    ``seq_len`` in place of the tiny traffic's."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -55,6 +56,8 @@ def tiny_step(cell: str, n: int, **model_sizes):
     sizes = {**found["config"]["model"], **found["config"]["tiny"],
              **model_sizes}
     traffic = {**found["traffic"], **found["traffic"]["tiny"]}
+    if seq_len:
+        traffic["seq_len"] = seq_len
     run = harness.Run(
         started=time.perf_counter(), workload=cell, chips=n,
         traffic=traffic, model_sizes=sizes, seed=0, seconds=0,
@@ -229,16 +232,16 @@ def equations(jaxpr, outer=""):
 _TRACED: dict = {}
 
 
-def traced(cell: str, n: int, **model_sizes) -> list:
+def traced(cell: str, n: int, seq_len=None, **model_sizes) -> list:
     """``(op_name, equation)`` of the cell's tiny step as it is traced,
     before any compiler fuses or drops an operation: the name stack
     with the primitive's name, which is what lowering writes as
     ``op_name``. Traced once a cell, sizes and process."""
     import jax
 
-    key = (cell, *sorted(model_sizes.items()))
+    key = (cell, seq_len, *sorted(model_sizes.items()))
     if key not in _TRACED:
-        step, args = tiny_step(cell, n, **model_sizes)
+        step, args = tiny_step(cell, n, seq_len, **model_sizes)
         _TRACED[key] = [
             (stack + "/" + eqn.primitive.name, eqn) for stack, eqn
             in equations(jax.make_jaxpr(step)(*args).jaxpr)]
@@ -606,6 +609,59 @@ def test_the_state_space_scopes_hold_what_they_say():
     assert "dot_general" not in by_scope[scopes.MAMBA_CONV]
     assert {"rsqrt", "logistic"} <= by_scope[scopes.MAMBA_GATE]
     assert "dot_general" not in by_scope[scopes.MAMBA_GATE]
+
+
+# the tiny preset at the least shape the scan's kernels take
+# (`ops/ssd_scan.supports`): one chunk of 128 positions, eight heads of
+# 32, a state of 128
+KERNEL_SHAPE = dict(seq_len=128, mamba_n_heads=8, mamba_d_head=32,
+                    mamba_d_state=128, mamba_chunk_size=128)
+SSD_SCAN_NAMES = {scopes.SSD_SCAN_FWD, scopes.SSD_SCAN_BWD}
+
+
+def test_the_scans_kernels_stand_inside_the_scans_scope():
+    """The rule ``utils/scopes.py`` states for ``SSD_SCAN_*``: at a
+    shape the kernels take, every state-space layer has its forward
+    call in the forward phase, the backward call in the backward phase
+    and, in a rematerialised block, the forward call again there; each
+    carries its name, lies under the Flax module ``mamba`` inside
+    ``mamba_scan``, and is layer ``mamba_scan`` to the benchmark's
+    readers (``mamba_scan_ms`` selects by that layer and nothing else).
+    The last block keeps its forward call's results
+    (``_last_block_keeps``). Every other operation of the module still
+    lies in exactly one of the four scopes."""
+    from benchmarks import scopes as readers
+
+    assert not SSD_SCAN_NAMES & set(scopes.LAYER_SCOPES)
+    calls: dict = {}
+    for o, eqn in traced(HYBRID_CELL, 1, **KERNEL_SHAPE):
+        if "mamba" not in parts(o):
+            continue
+        assert len(parts(o) & MAMBA_SCOPES) == 1, o
+        phase, layer = readers.classify(o)
+        assert {layer} == parts(o) & MAMBA_SCOPES, o
+        if eqn.primitive.name != "pallas_call":
+            continue
+        assert layer == scopes.MAMBA_SCAN, o
+        assert eqn.params["name"] in parts(o) & SSD_SCAN_NAMES, o
+        # the scan's scope stands before the kernel's name and once
+        assert o.count(scopes.MAMBA_SCAN) == 1 and o.index(
+            scopes.MAMBA_SCAN) < o.index(eqn.params["name"]), o
+        block = next(p for p in parts(o) if p.startswith("block_"))
+        calls.setdefault(block, []).append((phase, eqn.params["name"]))
+    again = [("backward", scopes.SSD_SCAN_BWD),
+             ("backward", scopes.SSD_SCAN_FWD),
+             ("forward", scopes.SSD_SCAN_FWD)]
+    assert {b: sorted(c) for b, c in calls.items()} == {
+        "block_0": again, "block_1": again,
+        "block_3": [again[0], again[2]]}, calls
+
+
+def test_the_tiny_hybrid_preset_has_no_kernel_under_mamba():
+    """Its shape (heads of 64 over a state of 16, chunks of 32) is not
+    one the kernels take: the plain form, as before."""
+    assert not [o for o, e in traced(HYBRID_CELL, 1)
+                if "mamba" in parts(o) and e.primitive.name == "pallas_call"]
 
 
 def test_the_hybrids_attention_layer_keeps_attentions_names():
