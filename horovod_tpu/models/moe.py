@@ -10,7 +10,20 @@ of it anyway:
 
 * the router keeps its published width: scores `softmax(W_r x)` in
   float32 over all `num_experts`, the top `experts_per_token` of them,
-  their weights renormalised over the chosen where `norm_topk_prob`;
+  their weights renormalised over the chosen where `norm_topk_prob`.
+  With `score_func` "sigmoid" the scores are `s = sigmoid(W_r x)`, each
+  expert's own, and the choice is the top k of `s + b`: `b`
+  (`expert_bias`) is one float32 number an expert that corrects the
+  choice and never the weight, zeros at the seed, a leaf of the
+  parameter tree behind `stop_gradient` (so it travels with the
+  parameters and no optimizer moves it: its gradient is zero; the rule
+  that moves it between steps by the experts' load is a trainer's and
+  is not built here). The weights are `s` at the chosen, over their sum
+  where `norm_topk_prob`, times `routed_scaling_factor`;
+* `shared_experts` > 0: one SwiGLU MLP of `shared_experts * mlp_dim`
+  that every token goes through (`shared_gate`, `shared_up`,
+  `shared_down`), computed once a chip and added to the routed sum. In
+  a deployment every chip computes it alike, and it counts once;
 * a share (`experts_held < num_experts`) has no exchange, and two things
   follow from that. **Its router is not trained**: the gradient through
   a token's weights needs the result of every expert the token chose,
@@ -50,15 +63,19 @@ of it anyway:
 * each token's choices are sown into the Flax collection `choices`
   (int32 `[..., experts_per_token]`); no auxiliary term is sown.
 
-Scopes (`utils/scopes.LAYER_SCOPES`): `moe_dispatch` names the router,
-the choice, the ordering, the gather and the weighted combine;
-`moe_experts` the three expert products. Trace-time gauges
-(`utils/metrics.record_moe_rows`): `hvd_moe_experts_held`,
-`hvd_moe_router_width`, `hvd_moe_rows_expected`, `hvd_moe_rows_static`.
+Scopes (`utils/scopes.LAYER_SCOPES`): `moe_dispatch` names the router
+(the sigmoid, the correction, the renormalisation and the scale too),
+the choice, the ordering, the gather, the weighted combine and the sum
+with the shared expert; `moe_experts` the three expert products;
+`moe_shared` the shared expert's three products and its activation.
+Trace-time gauges (`utils/metrics.record_moe_rows`):
+`hvd_moe_experts_held`, `hvd_moe_router_width`, `hvd_moe_rows_expected`,
+`hvd_moe_rows_static`, `hvd_moe_shared_experts`, `hvd_moe_score_func`.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -134,6 +151,12 @@ class RoutedMlp(nn.Module):
     norm_topk_prob: bool = True
     first_expert: int = 0
     dtype: Any = jnp.bfloat16
+    # "softmax" over all experts, or "sigmoid" of each with the choice
+    # corrected by `expert_bias`; what the chosen weights are multiplied
+    # by; shared experts of `mlp_dim` every token goes through
+    score_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    shared_experts: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -147,7 +170,8 @@ class RoutedMlp(nn.Module):
         tokens = x.reshape(-1, h)
         t = tokens.shape[0]
         expected, static, most = rows_static(t, k, held, e)
-        metrics.record_moe_rows(held, e, expected, static)
+        metrics.record_moe_rows(held, e, expected, static,
+                                self.shared_experts, self.score_func)
 
         # every expert a xavier-uniform matrix of its own
         init = nn.initializers.xavier_uniform(
@@ -172,10 +196,24 @@ class RoutedMlp(nn.Module):
             if held < e:
                 # no exchange, no gradient through the scores (above)
                 logits = lax.stop_gradient(logits)
-            weights, chosen = lax.top_k(jax.nn.softmax(logits, -1), k)
+            if self.score_func == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                bias = self.param("expert_bias", nn.initializers.zeros,
+                                  (e,), jnp.float32)
+                _, chosen = lax.top_k(
+                    scores + lax.stop_gradient(bias), k)
+                weights = jnp.take_along_axis(scores, chosen, -1)
+            else:
+                weights, chosen = lax.top_k(jax.nn.softmax(logits, -1), k)
             if self.norm_topk_prob:
                 weights = weights / jnp.sum(weights, -1, keepdims=True)
-            self.sow("choices", "experts", chosen.reshape(*lead, k))
+            if self.routed_scaling_factor != 1.0:
+                weights = weights * self.routed_scaling_factor
+            if not self.is_initializing():
+                # an initialisation returns every collection: a choice
+                # sown there would keep the whole forward pass alive in
+                # a program that is asked for the parameters alone
+                self.sow("choices", "experts", chosen.reshape(*lead, k))
             # the (token, choice) pairs, held-expert-major: a pair's key
             # is its expert's place here, `held` where it lives elsewhere
             local = chosen.reshape(-1) - self.first_expert
@@ -234,5 +272,18 @@ class RoutedMlp(nn.Module):
                 routed > static,
                 lambda y: lax.scan(further, y, jnp.arange(1, chunks))[0],
                 lambda y: y, y)
+        if self.shared_experts:
+            with jax.named_scope(scopes.MOE_SHARED):
+                dense = functools.partial(
+                    nn.Dense, use_bias=False, dtype=self.dtype,
+                    param_dtype=jnp.float32,
+                    kernel_init=nn.initializers.xavier_uniform())
+                width = self.shared_experts * self.mlp_dim
+                rows = tokens.astype(self.dtype)
+                hidden = nn.silu(dense(width, name="shared_gate")(rows)) \
+                    * dense(width, name="shared_up")(rows)
+                shared = dense(h, name="shared_down")(hidden)
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                y = y + shared.astype(jnp.float32)
         with jax.named_scope(scopes.MOE_DISPATCH):
             return y.astype(x.dtype).reshape(*lead, h)

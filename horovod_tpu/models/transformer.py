@@ -1,6 +1,9 @@
 """Transformer model family: GPT-2, BERT-Large, Llama, and stacks whose
 layers differ in kind (`TransformerConfig.layer_types`: an `attention`
-layer or a `mamba2` state-space layer, models/mamba.py).
+layer, a `window_attention` layer whose queries see a sliding window of
+keys, or a `mamba2` state-space layer, models/mamba.py), with a routed
+MLP (models/moe.py) behind leading dense layers where the model has
+experts.
 
 Benchmark vehicles from BASELINE.json configs: BERT-Large pretraining
 (tokens/sec/chip), Adasum on Llama-2-7B, elastic GPT-2. The reference
@@ -47,8 +50,11 @@ from .moe import RoutedMlp
 # the kinds of layer a `Block` builds, as `layer_types` names them (the
 # spelling of the benchmark's `model` group and of its
 # `benchmarks/layer_kinds/<kind>.py`)
-ATTENTION, MAMBA2 = "attention", "mamba2"
-LAYER_KINDS = (ATTENTION, MAMBA2)
+ATTENTION, MAMBA2, WINDOW_ATTENTION = \
+    "attention", "mamba2", "window_attention"
+LAYER_KINDS = (ATTENTION, MAMBA2, WINDOW_ATTENTION)
+# the kinds whose mixer is `Attention`
+ATTENTION_KINDS = (ATTENTION, WINDOW_ATTENTION)
 
 
 class LayerTypes(tuple):
@@ -111,21 +117,46 @@ class TransformerConfig:
     # positions, and b = diffusion_block the block length of the mask
     # (`diffusion_mask`) that stands in place of `causal`; 0 = no such mask
     diffusion_block: int = 0
-    # routed MLP (models/moe.py) in every layer where num_experts > 0:
-    # the router's width, how many of its experts this chip holds (None
-    # = all), the experts a token is sent to, one expert's width (None
-    # = mlp_dim) and whether a token's chosen weights are renormalised
+    # routed MLP (models/moe.py) where num_experts > 0, in every layer
+    # after the first `dense_layers` (those keep the plain MLP): the
+    # router's width, how many of its experts this chip holds (None =
+    # all), the experts a token is sent to, one expert's width (None =
+    # mlp_dim), whether a token's chosen weights are renormalised, how
+    # the router scores ("softmax" over all experts, or "sigmoid" of
+    # each with a correction of the choice, models/moe.py), what the
+    # chosen weights are multiplied by, and how many experts of that
+    # width every token goes through beside the routed ones (one SwiGLU
+    # MLP of `shared_experts * expert_mlp_dim`)
     num_experts: int = 0
     experts_held: Optional[int] = None
     experts_per_token: int = 0
     expert_mlp_dim: Optional[int] = None
     norm_topk_prob: bool = False
+    dense_layers: int = 0
+    score_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    shared_experts: int = 0
     # the kind of each layer's mixer, one name a layer in order
     # (LAYER_KINDS): None = every layer `attention`. A `mamba2` layer
     # has a state-space mixer (models/mamba.py) where an `attention`
-    # layer has its `Attention`; the norms, the residuals and the MLP
-    # are the same
+    # layer has its `Attention`; a `window_attention` layer is an
+    # `attention` layer whose query q sees key k where 0 <= q - k <
+    # `sliding_window` (under `causal`). The norms, the residuals and
+    # the MLP are the same
     layer_types: Optional[tuple] = None
+    sliding_window: int = 0
+    # the kinds of layer whose attention rotates q and k where
+    # `position` is "rope" (None = every attention layer): a model may
+    # give the position code to its window layers alone
+    rope_kinds: Optional[tuple] = None
+    # the heads' output times sigmoid(W_g x), W_g `hidden x heads *
+    # head_dim` without bias on the normed input that q, k and v are
+    # made of, before the output projection
+    attn_output_gate: bool = False
+    # four norms a block: `x + N2(Attn(N1 x))`, `x + N4(Mlp(N3 x))`
+    # (N2 and N4 on the two branches before they join the residual)
+    # where False is `x + Attn(N1 x)`, `x + Mlp(N3 x)`
+    post_norms: bool = False
     # the state-space mixer's sizes: heads of the recurrence, a head's
     # width, the state's width N, d_inner over hidden_size (which has to
     # equal heads x width), taps of the depthwise convolution, groups
@@ -150,6 +181,24 @@ class TransformerConfig:
     logits_scaling: float = 1.0
 
     def __post_init__(self):
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"score_func {self.score_func!r}: the routed MLP scores "
+                f"by 'softmax' or 'sigmoid'")
+        if not 0 <= self.dense_layers <= self.num_layers:
+            raise ValueError(
+                f"dense_layers {self.dense_layers} of num_layers "
+                f"{self.num_layers}")
+        if self.rope_kinds is not None:
+            rope_kinds = LayerTypes(self.rope_kinds)
+            if set(rope_kinds) - set(ATTENTION_KINDS) \
+                    or self.position != "rope":
+                raise ValueError(
+                    f"rope_kinds {list(rope_kinds)} with position "
+                    f"{self.position!r}: it names the kinds among "
+                    f"{ATTENTION_KINDS} that rotate q and k under "
+                    f"position 'rope'")
+            object.__setattr__(self, "rope_kinds", rope_kinds)
         if self.layer_types is None:
             return
         kinds = LayerTypes(self.layer_types)
@@ -167,12 +216,30 @@ class TransformerConfig:
                 f"{self.hidden_size} is not mamba_n_heads "
                 f"{self.mamba_n_heads} x mamba_d_head "
                 f"{self.mamba_d_head}: the inner stream has one width")
+        if WINDOW_ATTENTION in kinds and (
+                self.sliding_window <= 0 or not self.causal
+                or self.diffusion_block):
+            raise ValueError(
+                f"a `window_attention` layer sees the sliding_window "
+                f"latest positions under a causal mask: sliding_window "
+                f"{self.sliding_window}, causal {self.causal}, "
+                f"diffusion_block {self.diffusion_block}")
         object.__setattr__(self, "layer_types", kinds)
 
     @property
     def layer_kinds(self) -> tuple:
         """The kind of each of the `num_layers` layers, in order."""
         return self.layer_types or (ATTENTION,) * self.num_layers
+
+    def rotates(self, kind: str) -> bool:
+        """Whether a layer of `kind` rotates its q and k (rope)."""
+        return self.position == "rope" and (
+            self.rope_kinds is None or kind in self.rope_kinds)
+
+    def routes(self, layer: int) -> bool:
+        """Whether layer `layer` has the routed MLP (the first
+        `dense_layers` keep the plain one)."""
+        return bool(self.num_experts) and layer >= self.dense_layers
 
     @property
     def head_width(self) -> int:
@@ -256,6 +323,16 @@ def _norm(cfg: TransformerConfig, name: str):
                         param_dtype=jnp.float32, name=name)
 
 
+def _joined(cfg: TransformerConfig, x, branch, name: str):
+    """The residual stream `x` with a block's `branch` joined to it;
+    with `post_norms` the branch goes through a norm of its own first
+    (the Flax module `name`, under the scope `POST_NORM`)."""
+    if cfg.post_norms:
+        with jax.named_scope(scopes.POST_NORM):
+            branch = _norm(cfg, name)(branch)
+    return x + scaled(branch, cfg.residual_multiplier)
+
+
 def rope_frequencies(head_dim: int, max_len: int, theta: float):
     inv = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
     t = np.arange(max_len)
@@ -318,13 +395,14 @@ def diffusion_mask(positions: int, block: int):
 
 
 def dot_product_attention(q, k, v, *, causal: bool, mask=None,
-                          diffusion_block: int = 0):
+                          diffusion_block: int = 0, window: int = 0):
     """Default attention: q,k,v [B, T, H, D] -> [B, T, H, D].
 
     float32 softmax accumulation on bf16 inputs (TPU-stable). Swappable via
     `attention_fn` for ring/Ulysses sequence parallelism. With
     `diffusion_block` the mask is `diffusion_mask` and `causal` is not
-    read.
+    read. With `window` w a causal query q sees key k where
+    0 <= q - k < w.
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -340,7 +418,12 @@ def dot_product_attention(q, k, v, *, causal: bool, mask=None,
                            logits, -1e30)
     elif causal:
         cm = jnp.tril(jnp.ones((Tq, Tk), dtype=bool))
+        if window:
+            cm = cm & ~jnp.tril(jnp.ones((Tq, Tk), dtype=bool), -window)
         logits = jnp.where(cm[None, None], logits, -1e30)
+    elif window:
+        raise ValueError(f"a window of {window} positions is a causal "
+                         f"mask's")
     if mask is not None:
         logits = jnp.where(mask[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
@@ -348,32 +431,50 @@ def dot_product_attention(q, k, v, *, causal: bool, mask=None,
 
 
 def fuses_qk_prep(cfg: TransformerConfig, attention_fn,
-                  kv_cache=None) -> bool:
-    """Whether `Attention` runs its q/k norms, rope and the transposes
-    into the flash kernels' layout as the one pass of
-    `ops/attention_prep.py` and not as array passes. Decided by what
-    the call can observe: there is such work (q/k norms or rope), a head
+                  kv_cache=None, kind: str = ATTENTION) -> bool:
+    """Whether the `Attention` of a layer of `kind` runs its q/k norms,
+    rope and the transposes into the flash kernels' layout as the one
+    pass of `ops/attention_prep.py` and not as array passes. Decided by
+    what the call can observe: there is such work (q/k norms, or rope in
+    a layer of this kind), a head
     is whole lane tiles (the pass slices heads out of lanes), the
     attention function offers the kernels' layout (`from_bhtd`, which
     `make_flash_attention_fn`'s has; ring, Ulysses and the default
     attention take the model's layout), and no cache is being filled
     (serving appends k in the model's layout)."""
-    return bool((cfg.qk_norm or cfg.position == "rope")
+    return bool((cfg.qk_norm or cfg.rotates(kind))
                 and kv_cache is None
                 and attention_prep.supports(cfg.head_width)
                 and hasattr(attention_fn, "from_bhtd"))
 
 
+# what serving lacks for the forms below (`cache_gaps`, which
+# `Transformer.__call__(kv_cache=)` and serving/decode.py refuse by)
+WINDOW_HAS_NO_CACHE = (
+    "a `window_attention` layer cannot decode through the key-value "
+    "cache: serving/decode's slotted cache keeps every position of a "
+    "slot and its validity mask is the causal one; a window's cache (the "
+    "sliding_window latest rows a layer, and a mask that forgets) is not "
+    "built")
+GATE_HAS_NO_CACHE = (
+    "an attention output gate (`attn_output_gate`) is not run through "
+    "the key-value cache: serving/decode has been held to no model with "
+    "one")
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     attention_fn: Optional[Callable] = None
+    # ATTENTION or WINDOW_ATTENTION (ATTENTION_KINDS)
+    kind: str = ATTENTION
 
     @nn.compact
     def __call__(self, x, positions, mask=None, kv_cache=None, layer=0):
         cfg = self.cfg
         B, T, _ = x.shape
         H, KH, D = cfg.num_heads, cfg.kv_heads, cfg.head_width
-        fused = fuses_qk_prep(cfg, self.attention_fn, kv_cache)
+        window = cfg.sliding_window if self.kind == WINDOW_ATTENTION else 0
+        fused = fuses_qk_prep(cfg, self.attention_fn, kv_cache, self.kind)
         dense = functools.partial(
             nn.DenseGeneral, dtype=cfg.dtype, param_dtype=jnp.float32,
             use_bias=cfg.norm == "layernorm",
@@ -387,9 +488,14 @@ class Attention(nn.Module):
                       kernel_init=nn.initializers.xavier_uniform())(x)
             v = dense(features=(KH, D), name="value",
                       kernel_init=nn.initializers.xavier_uniform())(x)
+            if cfg.attn_output_gate:
+                # the gate's product among the layer's projections: the
+                # benchmark counts it with them (`attn_proj_roofline`)
+                gate = dense(features=(H, D), name="gate",
+                             kernel_init=nn.initializers.xavier_uniform())(x)
         with jax.named_scope(scopes.ATTN_PREP):
             rope = rope_frequencies(D, cfg.max_seq_len, cfg.rope_theta) \
-                if cfg.position == "rope" else None
+                if cfg.rotates(self.kind) else None
             if fused:
                 # one pass: q and k come back normed and rotated in the
                 # kernels' [B, H, T, D]; the parameters are RMSNorm's
@@ -434,7 +540,7 @@ class Attention(nn.Module):
         elif self.attention_fn is None:
             attn = functools.partial(
                 dot_product_attention, causal=cfg.causal,
-                diffusion_block=cfg.diffusion_block)
+                diffusion_block=cfg.diffusion_block, window=window)
             out = attn(q, k, v, mask=mask)
         else:
             attn = self.attention_fn.from_bhtd if fused \
@@ -445,7 +551,14 @@ class Attention(nn.Module):
                     "(q, k, v) and would silently drop the padding mask; "
                     "pre-mask the inputs or use the default attention"
                 )
-            out = attn(q, k, v)
+            # a window is an argument of the call: the layers of a
+            # model share one function (`make_flash_attention_fn`), and
+            # one that takes no window (ring, Ulysses) says so itself
+            out = attn(q, k, v, window=window) if window else attn(q, k, v)
+        if cfg.attn_output_gate:
+            with jax.named_scope(scopes.ATTN_PREP):
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(cfg.dtype)
         with jax.named_scope(scopes.ATTN_PROJ):
             out = nn.DenseGeneral(
                 features=cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
@@ -489,6 +602,36 @@ MAMBA2_HAS_NO_CACHE = (
     "serving/decode's slotted cache holds neither")
 
 
+ROUTED_FORMS_HAVE_NO_CACHE = (
+    "serving/decode has been held to no routed MLP that scores by "
+    "sigmoid with a corrected choice, scales its weights, has a shared "
+    "expert or stands behind leading dense layers")
+
+
+def cache_gaps(cfg: TransformerConfig) -> list:
+    """What of `cfg` the key-value cache path (serving/decode,
+    `Transformer.__call__(kv_cache=)`) cannot run, each by the field's
+    name with what is missing; empty where it can run all of it. A
+    state-space layer is refused apart (MAMBA2_HAS_NO_CACHE)."""
+    kinds = cfg.layer_kinds
+    gaps = []
+    if WINDOW_ATTENTION in kinds:
+        gaps.append(
+            f"layers {[i for i, k in enumerate(kinds) if k == WINDOW_ATTENTION]}"
+            f" are `window_attention` layers: " + WINDOW_HAS_NO_CACHE)
+    if cfg.attn_output_gate:
+        gaps.append("attn_output_gate: " + GATE_HAS_NO_CACHE)
+    forms = {"score_func": cfg.score_func != "softmax",
+             "routed_scaling_factor": cfg.routed_scaling_factor != 1.0,
+             "shared_experts": cfg.shared_experts > 0,
+             "dense_layers": cfg.dense_layers > 0}
+    named = [f"{name} {getattr(cfg, name)!r}"
+             for name, stated in forms.items() if stated]
+    if cfg.num_experts and named:
+        gaps.append(", ".join(named) + ": " + ROUTED_FORMS_HAVE_NO_CACHE)
+    return gaps
+
+
 def scaled(x, multiplier: float):
     """`x` times one of the model's stream multipliers
     (`embedding_multiplier`, `residual_multiplier`, 1 /
@@ -503,8 +646,13 @@ class Block(nn.Module):
     cfg: TransformerConfig
     attention_fn: Optional[Callable] = None
     # the layer's mixer (LAYER_KINDS): `Attention` under the module name
-    # `attn`, or the state-space mixer under `mamba`
+    # `attn` (of this kind: a window layer's is told so), or the
+    # state-space mixer under `mamba`
     kind: str = ATTENTION
+    # whether the layer's MLP is the routed one (`cfg.routes(layer)`, as
+    # `Transformer` and ops/overlap build their blocks); None = wherever
+    # the model has experts, for a caller that builds one block alone
+    routed: Optional[bool] = None
 
     @nn.compact
     def __call__(self, x, positions, mask=None, kv_cache=None, layer=0):
@@ -522,21 +670,27 @@ class Block(nn.Module):
                 name="mamba")(y)
         else:
             mixed = Attention(cfg, attention_fn=self.attention_fn,
+                              kind=self.kind,
                               name="attn")(y, positions, mask,
                                            kv_cache=kv_cache, layer=layer)
-        x = x + scaled(mixed, cfg.residual_multiplier)
+        x = _joined(cfg, x, mixed, "ln_post_attn")
         y = _norm(cfg, "ln_mlp")(x)
-        if cfg.num_experts:
+        routed = bool(cfg.num_experts) if self.routed is None \
+            else self.routed
+        if routed:
             mlp = RoutedMlp(
                 num_experts=cfg.num_experts,
                 experts_held=cfg.experts_held or cfg.num_experts,
                 experts_per_token=cfg.experts_per_token,
                 mlp_dim=cfg.expert_mlp_dim or cfg.mlp_dim,
-                norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype,
+                norm_topk_prob=cfg.norm_topk_prob,
+                score_func=cfg.score_func,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                shared_experts=cfg.shared_experts, dtype=cfg.dtype,
                 name="mlp")
         else:
             mlp = Mlp(cfg, name="mlp")
-        return x + scaled(mlp(y), cfg.residual_multiplier)
+        return _joined(cfg, x, mlp(y), "ln_post_mlp")
 
 
 def _last_block_keeps(prim, *_, **params) -> bool:
@@ -588,6 +742,9 @@ class Transformer(nn.Module):
         is byte-identical to the pre-cache model."""
         cfg = self.cfg
         B, T = tokens.shape
+        gaps = cache_gaps(cfg) if kv_cache is not None else ()
+        if gaps:
+            raise ValueError("; ".join(gaps))
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
         emb = nn.Embed(
@@ -615,10 +772,10 @@ class Transformer(nn.Module):
         kinds = cfg.layer_kinds
         metrics.record_layer_kinds(
             {kind: kinds.count(kind) for kind in dict.fromkeys(kinds)})
-        fused = fuses_qk_prep(cfg, self.attention_fn, kv_cache)
-        attention_layers = kinds.count(ATTENTION)
+        fused = [fuses_qk_prep(cfg, self.attention_fn, kv_cache, kind)
+                 for kind in kinds if kind in ATTENTION_KINDS]
         metrics.record_attn_prep_layers(
-            attention_layers * fused, attention_layers * (not fused))
+            sum(fused), len(fused) - sum(fused))
         state_space_layers = kinds.count(MAMBA2)
         if state_space_layers:
             as_kernels = scan_runs_as_kernels(
@@ -636,7 +793,7 @@ class Transformer(nn.Module):
                 block = nn.remat(Block, static_argnums=(),
                                  policy=_last_block_keeps)
             block = block(cfg, attention_fn=self.attention_fn, kind=kind,
-                          name=f"block_{i}")
+                          routed=cfg.routes(i), name=f"block_{i}")
             if kv_cache is None:
                 # training/one-shot path: exact pre-cache call shape so
                 # remat'd and jitted programs lower identically

@@ -232,9 +232,11 @@ def transformer_lm_stages(model, tokens, loss_fn, positions=None,
     for i, kind in enumerate(cfg.layer_kinds):
         key = f"block_{i}"
 
-        def blk_fwd(sub, carry, _key=key, _kind=kind):
+        def blk_fwd(sub, carry, _key=key, _kind=kind,
+                    _routed=cfg.routes(i)):
             return block_cls(
-                cfg, attention_fn=attention_fn, kind=_kind).apply(
+                cfg, attention_fn=attention_fn, kind=_kind,
+                routed=_routed).apply(
                     {"params": sub[_key]}, carry, positions, mask)
 
         stages.append(Stage(key, (key,), blk_fwd))

@@ -42,6 +42,14 @@ blockwise online-softmax attention (Flash Attention) tiled for MXU/VMEM:
   the clean kv tiles wholly before them (unmasked) and those its first
   block crosses (masked); a clean q tile runs the clean tiles
   block-causally and no noisy one: T^2 + T*b of the 4T^2 pairs;
+* a sliding window (`window` = w: query q sees key k where
+  0 <= q - k < w) is a fourth classification (`_window_ranges`): the
+  tiles wholly before the window are not run, those either edge crosses
+  are masked, the rest run the unmasked body; at T = 8,192, w = 2,048
+  and tiles of 512 a forward call runs 70 tiles where the diagonal alone
+  runs 136. Gauges `hvd_flash_window` /
+  `hvd_flash_window_tiles_per_call` /
+  `hvd_flash_window_causal_tiles_per_call` say what a windowed call got;
 * key-value heads may be fewer than query heads: both kernels read the
   head `h // (heads / kv_heads)` through their block maps, the backward
   writes one dk, dv partial a query head and the group's are summed
@@ -128,18 +136,20 @@ def _diffusion_tile_mask(block_q, block_k, q_base, k_base, half, block):
 
 
 def _tile_mask(block_q, block_k, q_base, k_base, *, causal, q_offset,
-               k_offset, kv_len, padded, diffusion=None):
+               k_offset, kv_len, padded, diffusion=None, window=0):
     """Validity mask for one [block_q, block_k] logits tile that
-    `_tile_ranges` calls masked: the diagonal crosses it (`causal`) or it
-    holds padded keys (`padded`, static: kv_len is less than the padded
-    length), so at least one of the two is set; or, under `diffusion`
-    (half, block), the block-diffusion mask of the tile.
+    `_tile_ranges` calls masked: the diagonal crosses it (`causal`), an
+    edge of the `window` does, or it holds padded keys (`padded`, static:
+    kv_len is less than the padded length), so at least one of them is
+    set; or, under `diffusion` (half, block), the block-diffusion mask
+    of the tile.
 
     `q_base`/`k_base` are the tile's local starting rows/cols; global
     positions add the caller's sequence offsets (ring attention). Row r
     sees column c iff q_offset + q_base + r >= k_offset + k_base + c: one
     compare of the iota difference r - c, the same in every tile, against
-    a scalar."""
+    a scalar; under a window of w also iff that difference of positions
+    is less than w, a second compare of the same iota difference."""
     if diffusion:
         return _diffusion_tile_mask(block_q, block_k, q_base, k_base,
                                     *diffusion)
@@ -147,7 +157,11 @@ def _tile_mask(block_q, block_k, q_base, k_base, *, causal, q_offset,
     mask = None
     if causal:
         rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        mask = (rows - cols) >= (k_offset + k_base) - (q_offset + q_base)
+        ahead = rows - cols
+        behind = (k_offset + k_base) - (q_offset + q_base)
+        mask = ahead >= behind
+        if window:
+            mask = jnp.logical_and(mask, ahead < behind + window)
     if padded:
         real = cols < kv_len - k_base
         mask = real if mask is None else jnp.logical_and(mask, real)
@@ -235,8 +249,56 @@ def _diffusion_ranges(over, base, block_q, block_k, half, block):
             (n + clean_all, n + clean_all + clean * (n - clean_all), False)]
 
 
+def _window_ranges(over, base, block_q, block_k, num_tiles, *, window,
+                   q_offset, k_offset, kv_len, padded):
+    """`_tile_ranges` under a causal mask with a window of `window`
+    positions: row q sees key k iff 0 <= q - k < window, on global
+    positions. Arithmetic that holds for a traced `base` and for a
+    Python int alike. Three ranges, in the order of the tiles: masked
+    (the window's far edge crosses them), unmasked, masked (the diagonal
+    crosses them, or they hold padded keys); the tile of rows
+    [q0, q0 + block_q) and columns [k0, k0 + block_k), with s =
+    q_offset - k_offset,
+      * runs      iff its last row sees its first key under the diagonal,
+                  s + q0 + block_q - 1 >= k0, and its first row sees its
+                  last key inside the window,
+                  s + q0 - (k0 + block_k - 1) < window;
+      * is masked iff its first row does not see its last key under the
+                  diagonal, s + q0 < k0 + block_k - 1, or its last row
+                  does not see its first key inside the window,
+                  s + q0 + block_q - 1 - k0 >= window, or it holds a
+                  padded key.
+    A window narrower than a tile leaves no unmasked one."""
+    shift = q_offset - k_offset
+    if over == "kv":
+        whole = kv_len // block_k  # leading kv tiles with no padded key
+        limit = _clip((shift + base + block_q - 1) // block_k + 1,
+                      0, num_tiles)
+        first = _clip((shift + base - window + 1) // block_k, 0, limit)
+        inside = _clip((shift + base + block_q - 1 - window) // block_k + 1,
+                       first, limit)
+        under = _clip((shift + base + 1) // block_k, 0, whole)
+        under = _clip(under, inside, limit)
+        return [(first, inside, True), (inside, under, False),
+                (under, limit, True)]
+    first = _clip((base - shift) // block_q, 0, num_tiles)
+    # the q tiles past the last whose first row sees the block's last
+    # key inside the window
+    limit = _clip((window + base + block_k - 2 - shift) // block_q + 1,
+                  first, num_tiles)
+    # ceil((k0 + block_k - 1 - shift) / block_q)
+    under = _clip(-((shift - base - block_k + 1) // block_q), first, limit)
+    inside = _clip((window + base - shift) // block_q, under, limit)
+    if padded:  # the kv block with the padded keys: every tile masked
+        under = _clip(under, limit * (base + block_k > kv_len), limit)
+        inside = _clip(inside, under, limit)
+    return [(first, under, True), (under, inside, False),
+            (inside, limit, True)]
+
+
 def _tile_ranges(over, base, block_q, block_k, num_tiles, *, causal,
-                 q_offset, k_offset, kv_len, padded, diffusion=None):
+                 q_offset, k_offset, kv_len, padded, diffusion=None,
+                 window=0):
     """The tiles one program runs, in the order it runs them, as
     `(lo, hi, masked)` ranges of tile indices. `over == "kv"`: the
     program owns the q block at local row `base` and streams the kv tiles
@@ -257,9 +319,14 @@ def _tile_ranges(over, base, block_q, block_k, num_tiles, *, causal,
     from here, so forward and backward can never cover different tiles,
     and the gauges count them from here. Two ranges, either of which may
     be empty; under `diffusion` (half, block) the three or four of
-    `_diffusion_ranges`."""
+    `_diffusion_ranges`, under a `window` the three of `_window_ranges`."""
     if diffusion:
         return _diffusion_ranges(over, base, block_q, block_k, *diffusion)
+    if window:
+        return _window_ranges(over, base, block_q, block_k, num_tiles,
+                              window=window, q_offset=q_offset,
+                              k_offset=k_offset, kv_len=kv_len,
+                              padded=padded)
     shift = q_offset - k_offset
     if over == "kv":
         whole = kv_len // block_k  # leading kv tiles with no padded key
@@ -310,7 +377,7 @@ def _count_tiles(every):
 
 
 def _rows_may_see_no_key(*, causal, q_offset, k_offset, diffusion=None,
-                         block_q=None, block_k=None, **_):
+                         block_q=None, block_k=None, window=0, **_):
     """Whether a row can come to a tile with every key it has met so far
     masked, that tile's included. Only where queries start before the
     keys: otherwise every row sees key 0 (never a padded one) in tile 0,
@@ -320,10 +387,14 @@ def _rows_may_see_no_key(*, causal, q_offset, k_offset, diffusion=None,
     key of every row's own block (a noisy row's own noisy key, a clean
     row's clean one, or clean keys before it) where the blocks are equal
     and whole diffusion blocks or whole parts of one; otherwise a row
-    may wait for its block's tile."""
+    may wait for its block's tile. Under a window the first tile a q
+    block runs is the one its FIRST row's window begins in, and a later
+    row's window may begin past that tile's end."""
     if diffusion:
         block = diffusion[1]
         return block_q != block_k or (block_q % block and block % block_q)
+    if window:
+        return True
     return causal and q_offset < k_offset
 
 
@@ -709,7 +780,8 @@ def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry,
     what `_tile_ranges` takes. Recorded in the trace-time gauges (all of
     it is static, so nothing runs in the step), by kernel: instances a
     program, programs a call, and the tiles a call runs with those of
-    them that are masked."""
+    them that are masked; for a call under a window, the window and the
+    tiles the diagonal alone would have run beside those it runs."""
     from ..utils import metrics
 
     b, h, t, d = shape
@@ -722,23 +794,33 @@ def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry,
     every = _every_program(over, t // own_rows, block_q, block_k,
                            other_rows // other_block, **geometry)
     tiles, masked_tiles = _count_tiles(every)
+    window, causal_tiles = geometry["window"], 0
+    if window:
+        # what the diagonal alone would have run of the same call
+        causal_tiles = b * h * _count_tiles(_every_program(
+            over, t // own_rows, block_q, block_k,
+            other_rows // other_block, **{**geometry, "window": 0}))[0]
     metrics.record_flash_programs(
         kernel, gb * gh, grid[0] * grid[1] * grid[2], b * h * tiles,
-        b * h * masked_tiles)
+        b * h * masked_tiles, window=window, causal_tiles=causal_tiles)
     return gb, gh, grid, _trips(every)
 
 
-def _geometry(causal, query_offset, key_offset, kv_len, tk_p, diffusion):
+def _geometry(causal, query_offset, key_offset, kv_len, tk_p, diffusion,
+              window=0):
     """What `_tile_ranges` and `_tile_mask` take of a call. `diffusion`
     is the block length b of a block-diffusion mask over the tk_p = 2T
-    positions, which then stands in place of `causal`; 0 for none."""
+    positions, which then stands in place of `causal`; 0 for none.
+    `window` is the positions a causal query sees, itself among them; 0
+    for all before it."""
     return dict(causal=causal and not diffusion, q_offset=query_offset,
                 k_offset=key_offset, kv_len=kv_len, padded=kv_len < tk_p,
-                diffusion=(tk_p // 2, diffusion) if diffusion else None)
+                diffusion=(tk_p // 2, diffusion) if diffusion else None,
+                window=window)
 
 
 def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
-                key_offset, block_q, block_k, diffusion=0):
+                key_offset, block_q, block_k, diffusion=0, window=0):
     """Padded [B, H, Tq_p, D] x [B, KH, Tk_p, D] → (out, lse); kv_len is
     the true (unpadded) key length. Grid (B / gb, H / gh, q-blocks): each
     program holds a block of gb x gh instances
@@ -747,7 +829,7 @@ def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
     b, h, tq_p, d = qq.shape
     tk_p, heads_per_kv = kk.shape[2], h // kk.shape[1]
     geometry = _geometry(causal, query_offset, key_offset, kv_len, tk_p,
-                         diffusion)
+                         diffusion, window)
     gb, gh, grid, trips = _grid("fwd", qq.shape, block_q, block_k, tk_p,
                                 qq.dtype.itemsize, geometry, heads_per_kv)
     kernel = functools.partial(_flash_fwd_kernel, block_k=block_k,
@@ -772,14 +854,14 @@ def _flash_core(qq, kk, vv, kv_len, causal, scale, query_offset,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
 )
 def _flash(q, k, v, causal, scale, query_offset, key_offset,
-           block_q, block_k, diffusion):
+           block_q, block_k, diffusion, window):
     """[B, H, T, D] x [B, KH, T, D] flash attention core (bhtd
     layout)."""
     out, _ = _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
-                        block_q, block_k, diffusion)
+                        block_q, block_k, diffusion, window)
     return out
 
 
@@ -788,10 +870,10 @@ def _flash(q, k, v, causal, scale, query_offset, key_offset,
 # model's layers call them with the same shapes, and tracing a kernel
 # body that holds several instances side by side costs as many times one
 # instance's.
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9),
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10),
                    inline=True)
 def _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
-               block_q, block_k, diffusion):
+               block_q, block_k, diffusion, window):
     tq, tk = q.shape[2], k.shape[2]
     with jax.named_scope(scopes.ATTN_PREP):
         qq = _pad_to(q, 2, block_q)
@@ -802,16 +884,17 @@ def _flash_fwd(q, k, v, causal, scale, query_offset, key_offset,
         qq, kk, vv, tk, causal=causal, scale=scale,
         query_offset=query_offset, key_offset=key_offset,
         block_q=block_q, block_k=block_k, diffusion=diffusion,
+        window=window,
     )
     with jax.named_scope(scopes.ATTN_PREP):
         out = out_p[:, :, :tq]
         return out, (q, k, v, out, lse_p[:, :, :, :tq])
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6),
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7),
                    inline=True)
 def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
-               diffusion, residuals, g):
+               diffusion, window, residuals, g):
     q, k, v = residuals[:3]
     out, lse = residuals[3:]
     b, h, tq, d = q.shape
@@ -832,7 +915,7 @@ def _flash_bwd(causal, scale, query_offset, key_offset, block_q, block_k,
     tq_p, tk_p = qq.shape[2], kk.shape[2]
     itemsize = q.dtype.itemsize
     geometry = _geometry(causal, query_offset, key_offset, tk, tk_p,
-                         diffusion)
+                         diffusion, window)
 
     # an instance is a (batch, query head): fewer kv heads get one
     # partial dk, dv a query head, summed over each group below
@@ -903,6 +986,7 @@ def flash_attention_bhtd(
     q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     query_offset: int = 0, key_offset: int = 0,
     block_q: int = 512, block_k: int = 512, diffusion_block: int = 0,
+    window: int = 0,
 ):
     """Flash attention over [B, H, T, D] tensors — the kernels' native
     layout ((T, D) minor dims tile legally on TPU). Layout-aware callers
@@ -912,13 +996,23 @@ def flash_attention_bhtd(
     repeated. `diffusion_block` b > 0: the T = 2·half positions are
     [noisy ; clean] under the block-diffusion mask of block length b
     (`_diffusion_tile_mask`), in place of `causal`; `half` has to be
-    whole tiles."""
+    whole tiles. `window` w > 0: a causal query sees the w latest
+    positions, itself among them (key k of query q where 0 <= q - k < w,
+    on global positions); a window that no query's distance to its first
+    key reaches is the causal call, bit for bit."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if q.shape[1] % k.shape[1]:
         raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} "
                          f"key-value heads")
+    if window:
+        if window < 0 or not causal or diffusion_block:
+            raise ValueError(
+                f"a window of {window} positions is a causal mask's: "
+                f"causal {causal}, diffusion_block {diffusion_block}")
+        if window > query_offset + q.shape[2] - 1 - key_offset:
+            window = 0  # every key at or before a query is inside it
     if diffusion_block:
         half = q.shape[2] // 2
         if (q.shape[2] != k.shape[2] or q.shape[2] % 2
@@ -939,7 +1033,7 @@ def flash_attention_bhtd(
     return _flash(
         q, k, v, causal, float(scale),
         int(query_offset), int(key_offset), int(block_q), int(block_k),
-        int(diffusion_block),
+        int(diffusion_block), int(window),
     )
 
 
@@ -958,6 +1052,7 @@ def flash_attention(
     q, k, v, *, causal: bool = True, scale: Optional[float] = None,
     query_offset: int = 0, key_offset: int = 0,
     block_q: int = 512, block_k: int = 512, diffusion_block: int = 0,
+    window: int = 0,
 ):
     """Flash attention over [B, T, H, D] tensors (model layout).
 
@@ -965,13 +1060,14 @@ def flash_attention(
     shared head (no repeat), and dK/dV come back summed over the group.
     `query_offset`/`key_offset` shift the global positions used
     for the causal mask — the hook ring attention uses for rotated KV
-    blocks. `diffusion_block`: see `flash_attention_bhtd`."""
+    blocks. `diffusion_block`, `window`: see `flash_attention_bhtd`."""
     with jax.named_scope(scopes.ATTN_PREP):
         q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     return flash_attention_from_bhtd(
         q, k, v, causal=causal, scale=scale,
         query_offset=query_offset, key_offset=key_offset,
         block_q=block_q, block_k=block_k, diffusion_block=diffusion_block,
+        window=window,
     )
 
 
@@ -982,7 +1078,10 @@ def make_flash_attention_fn(causal: bool = True, block_q: int = 512,
     (HOROVOD_FLASH_BLOCK_Q/K env override them for quick experiments);
     `diffusion_block` is a block-diffusion model's block length (its
     `TransformerConfig.diffusion_block`), whose mask then stands in
-    place of `causal`.
+    place of `causal`. A window is no argument here but one of the call
+    (`fn(q, k, v, window=w)`, `fn.from_bhtd(q, k, v, window=w)`): a model
+    hands every layer this one function, and its window layers differ
+    from its full ones by that argument alone.
 
     The function takes q, k, v in the model's [B, T, H, D]; its
     attribute `from_bhtd` is the same attention of q, k, v that are in
@@ -1013,8 +1112,8 @@ def make_flash_attention_fn(causal: bool = True, block_q: int = 512,
         block_k=int(os.environ.get("HOROVOD_FLASH_BLOCK_K", block_k)),
         diffusion_block=diffusion_block)
 
-    def fn(q, k, v):
-        return flash_attention(q, k, v, **kwargs)
+    def fn(q, k, v, window: int = 0):
+        return flash_attention(q, k, v, window=window, **kwargs)
 
     fn.from_bhtd = functools.partial(flash_attention_from_bhtd, **kwargs)
     return fn

@@ -166,17 +166,29 @@ def _lm_pipeline_pieces(cfg, rest, attention_fn, tokens,
 def _check_pp(cfg, mesh, who):
     kinds = sorted(set(cfg.layer_kinds))
     if kinds != ["attention"] or (cfg.embedding_multiplier,
-                                  cfg.logits_scaling) != (1.0, 1.0):
+                                  cfg.logits_scaling) != (1.0, 1.0) \
+            or (cfg.num_experts and cfg.dense_layers):
         # a stage runs its layers as ONE block scanned over stacked
         # parameters (`stack_block_params`), and embeds and heads apart
         raise ValueError(
             f"{who} stacks a stage's blocks and scans one `attention` "
             f"block over them; this model has layers of kinds {kinds} "
             f"(embedding_multiplier {cfg.embedding_multiplier}, "
-            f"logits_scaling {cfg.logits_scaling}). What is missing: a "
-            f"stage that holds a stack of unlike blocks (a tree a kind, "
-            f"run in the pattern's order) and the stream's two scalings "
-            f"in `_EmbedOnly` / `_HeadOnly`")
+            f"logits_scaling {cfg.logits_scaling}, dense_layers "
+            f"{cfg.dense_layers} in front of its routed ones). What is "
+            f"missing: a stage that holds a stack of unlike blocks (a "
+            f"tree a kind and a tree for the leading dense layers, run "
+            f"in the pattern's order) and the stream's two scalings in "
+            f"`_EmbedOnly` / `_HeadOnly`")
+    unheld = [f"{name} {getattr(cfg, name)!r}" for name, neutral in (
+        ("attn_output_gate", False), ("post_norms", False),
+        ("score_func", "softmax"), ("routed_scaling_factor", 1.0),
+        ("shared_experts", 0)) if getattr(cfg, name) != neutral]
+    if unheld:
+        raise ValueError(
+            f"{who} has been held to no model with "
+            f"{', '.join(unheld)}: its stacked block and its schedule "
+            f"are tested on the plain block alone")
     assert "pp" in mesh.shape, (
         f"{who} needs a 'pp' mesh axis; got {mesh.axis_names}")
     S = mesh.shape["pp"]

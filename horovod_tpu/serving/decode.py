@@ -352,6 +352,12 @@ class GenerationEngine:
                       if kind == MAMBA2]
             raise ValueError(f"layers {mamba2} of this model are "
                              f"state-space layers: " + MAMBA2_HAS_NO_CACHE)
+        from ..models.transformer import cache_gaps
+
+        gaps = cache_gaps(cfg)
+        if gaps:
+            raise ValueError("this model cannot be served: "
+                             + "; ".join(gaps))
         if getattr(cfg, "remat", False):
             # remat exists to trade activation memory for backward
             # recompute; inference has no backward, and nn.remat

@@ -1003,7 +1003,8 @@ def record_wire_bytes(logical: int, sent: int) -> None:
 
 def record_flash_programs(kernel: str, instances_per_program: int,
                           programs: int, tiles: int,
-                          boundary_tiles: int) -> None:
+                          boundary_tiles: int, window: int = 0,
+                          causal_tiles: int = 0) -> None:
     """The grid one flash-attention kernel was built with
     (ops/pallas_attention.py): "fwd", or "bwd", the one backward kernel
     that makes dq, dk and dv (since PR 34; "dq" and "dkv" before, which
@@ -1013,9 +1014,29 @@ def record_flash_programs(kernel: str, instances_per_program: int,
     TRACE time like the fused collectives' breadcrumb: the kernels
     choose instances a program and class their tiles from the shapes,
     statically, so the gauges say what the last traced call of each
-    kernel got and nothing runs in the step."""
+    kernel got and nothing runs in the step. A call under a sliding
+    `window` also leaves, in three gauges only such a call sets (a
+    model's full layers trace their calls beside them), the window, the
+    tiles it runs and the tiles the diagonal alone would have run of
+    the same call (`causal_tiles`): their ratio is what the window's
+    tile range saves."""
     if not _enabled:
         return
+    if window:
+        registry.gauge(
+            "hvd_flash_window",
+            "Positions a query sees in the last traced flash call under "
+            "a sliding window", labelnames=("kernel",)).labels(
+                kernel=kernel).set(window)
+        registry.gauge(
+            "hvd_flash_window_tiles_per_call",
+            "Score tiles one flash call under a sliding window runs",
+            labelnames=("kernel",)).labels(kernel=kernel).set(tiles)
+        registry.gauge(
+            "hvd_flash_window_causal_tiles_per_call",
+            "Score tiles the causal range alone would run of a flash "
+            "call under a sliding window",
+            labelnames=("kernel",)).labels(kernel=kernel).set(causal_tiles)
     registry.gauge(
         "hvd_flash_instances_per_program",
         "(batch, head) instances one program of the flash kernel handles",
@@ -1036,11 +1057,15 @@ def record_flash_programs(kernel: str, instances_per_program: int,
 
 
 def record_moe_rows(experts_held: int, router_width: int,
-                    rows_expected: float, rows_static: int) -> None:
+                    rows_expected: float, rows_static: int,
+                    shared_experts: int = 0,
+                    score_func: str = "softmax") -> None:
     """What one routed MLP (models/moe.py) was built for: the experts it
     holds of the router's width, the rows even routing sends it in one
-    call (tokens x experts a token x held / width) and the rows each of
-    its expert products is sized for. Recorded at TRACE time like the
+    call (tokens x experts a token x held / width), the rows each of
+    its expert products is sized for, the shared experts every token
+    goes through beside the routed ones and the function that scores
+    (the gauge's label; its value is 1). Recorded at TRACE time like the
     flash kernels' gauges above: arithmetic on the last traced call's
     shapes, nothing inside the step."""
     if not _enabled:
@@ -1060,6 +1085,14 @@ def record_moe_rows(experts_held: int, router_width: int,
         "hvd_moe_rows_static",
         "Rows one expert product of the routed MLP is sized for").set(
             rows_static)
+    registry.gauge(
+        "hvd_moe_shared_experts",
+        "Shared experts every token of the routed MLP goes through").set(
+            shared_experts)
+    registry.gauge(
+        "hvd_moe_score_func",
+        "The function the routed MLP's router scores by (label)",
+        labelnames=("score_func",)).labels(score_func=score_func).set(1)
 
 
 def record_remat_blocks(rematerialised: int, kept: int) -> None:

@@ -31,13 +31,19 @@ HVD_ALLREDUCE = "hvd_allreduce"  # the collectives, barriers, casts, scaling
 HVD_UNPACK = "hvd_unpack"        # buckets back into a gradient tree
 HVD_INNER_UPDATE = "hvd_inner_update"  # the wrapped optimizer's update
 
-# models/moe.py, both directions: the router's scores, the choice, the
-# renormalisation, the ordering of the (token, choice) pairs, the gather
-# of their rows and the weighted combine; and the three expert products
+# models/moe.py, both directions: the router's scores (softmax, or
+# sigmoid with the choice's correction), the choice, the
+# renormalisation and the scale, the ordering of the (token, choice)
+# pairs, the gather of their rows, the weighted combine and the sum with
+# the shared expert's result; and the three expert products
 MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
-# models/transformer.Attention's four DenseGeneral calls (query, key,
-# value, out), both directions, and nothing else
+# the shared expert every token goes through beside the routed ones:
+# its three products and its activation, and nothing else
+MOE_SHARED = "moe_shared"
+# models/transformer.Attention's DenseGeneral calls (query, key, value,
+# out and, where the model has an output gate, gate), both directions,
+# and nothing else
 ATTN_PROJ = "attn_proj"
 # What attention does that is neither a projection nor a flash kernel:
 # the q/k norms and apply_rope, as array passes or as the one Pallas
@@ -46,8 +52,14 @@ ATTN_PROJ = "attn_proj"
 # everything ops/pallas_attention.py does around its two pallas_calls,
 # forward rule and backward rule (transposes into and out of the
 # kernels' layout, pads and slices, the sum of partial dk/dv over a
-# group's query heads, delta, casts)
+# group's query heads, delta, casts); and the output gate's sigmoid and
+# its multiply with the heads' output
 ATTN_PREP = "attn_prep"
+# the norms on a block's two branches before they join the residual
+# (models/transformer.Block with `post_norms`: Flax modules
+# `ln_post_attn`, `ln_post_mlp`), both directions. `ln_attn`, `ln_mlp`
+# and `ln_final` are Flax's names and the benchmark's layer `norm`
+POST_NORM = "post_norm"
 # models/mamba.Mamba2Mixer, both directions; every operation of the Flax
 # module `mamba` lies in exactly one of the four (tests/test_step_scopes.py).
 # The input and output projections and nothing else
@@ -76,9 +88,10 @@ MAMBA_GATE = "mamba_gate"
 # `attn` is a flash kernel to the kernels' readers, so the state-space
 # mixer's module is never named `attn`). The dense MLP needs none: Flax's
 # `mlp` is its layer, and in a routed model `mlp` is what RoutedMlp
-# does outside its two scopes
+# does outside its three scopes
 LAYER_SCOPES = (MOE_DISPATCH, MOE_EXPERTS, ATTN_PROJ, ATTN_PREP,
-                MAMBA_PROJ, MAMBA_CONV, MAMBA_SCAN, MAMBA_GATE)
+                MAMBA_PROJ, MAMBA_CONV, MAMBA_SCAN, MAMBA_GATE,
+                MOE_SHARED, POST_NORM)
 
 # Kernel names, not layer scopes: the `name=` of the program's
 # `pl.pallas_call`s. The TPU compiler names a Mosaic call by it

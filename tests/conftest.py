@@ -39,75 +39,6 @@ def pytest_configure(config):
     )
 
 
-# A test this PR may not edit (a `model_config` PR edits nothing under
-# `tests/benchmarks`) and that BENCHMARK.json, as the driver's benchmark
-# check takes it, makes false. The test runs as it is and is reported
-# ``xfailed`` with its reason; ``strict`` makes it fail loudly once it
-# passes, which is when the ``benchmark`` PR that makes the edit named
-# here deletes the entry. Keyed by file and the test's name, a
-# parametrised case's with its id (``test_x[case]``): only that case is
-# marked, the others still count as passes.
-#
-# All six (PR 45) say "the benchmark as it stood at PR 44": five cells,
-# none of them patterned, no reference of a state-space family. They are
-# false for any sixth cell whose layers differ in kind, whatever the
-# program does. ISSUE 45 named the first five; the sixth it missed: it
-# asks for the reference under the family's name the fixture states.
-KNOWN_FALSE = {
-    ("benchmarks/test_bench_kinds.py",
-     "test_the_pin_is_of_the_five_cells_and_the_two_shares"):
-        "asserts that the pinned counts of a7a5f5c name every cell of "
-        "BENCHMARK.json; `granite_h_lm` is a sixth cell, made after the "
-        "pin. The edit: pin the new cell's counts too "
-        "(tests/benchmarks/data/flops_pinned_*.json), or hold the pin "
-        "to the list of cells it was made of",
-    ("benchmarks/test_bench_kinds.py",
-     "test_a_file_with_no_pattern_is_held_by_the_files_own_rows_alone"):
-        "asserts that no configuration of BENCHMARK.json states "
-        "`layer_types`; `granite-4.0-h-micro` does. The edit: run the "
-        "loop over the files that state no pattern",
-    ("benchmarks/test_bench_names.py", "test_new_metric_entry[mlp_ms]"):
-        "takes DENSE_CELLS as every cell but the routed one; the entry "
-        "lists the four dense cells of PR 41 and a program PR may not "
-        "edit an entry. The edit: DENSE_CELLS from the model groups, "
-        "and `granite_h_lm` appended to `mlp_ms`'s `workloads` (its MLP "
-        "is dense SwiGLU; PERF.md section 7)",
-    ("benchmarks/test_bench_names.py",
-     "test_new_metric_entry[mlp_roofline]"):
-        "as `test_new_metric_entry[mlp_ms]`, for `mlp_roofline`",
-    ("benchmarks/test_bench_harness.py",
-     "test_every_key_of_a_source_is_held_or_listed[granite-4.0-h-micro]"):
-        "compares the file's `not_held` with the source's keys less "
-        "`published.KNOWN`, the rows of published.py alone; "
-        "`published.check` itself also reads the rows of the kinds a "
-        "pattern names (`layer_kinds/mamba2.ROWS` hold the seven "
-        "`mamba_*` keys and `layer_types`). The edit: KNOWN joined with "
-        "the kinds' rows, as `check` joins them",
-    ("benchmarks/test_bench_kinds.py",
-     "test_the_hybrid_is_held_to_its_source_and_refused_by_the_keys_name"
-     "[None-None-None]"):
-        "its last lines assert that no BENCHMARK.json names the "
-        "fixture's entry (still true) and that no reference of its "
-        "`family` exists under any root; ISSUE 45 asks for the cell's "
-        "reference as benchmarks/reference/state_space_hybrid_lm.py, "
-        "the family that fixture names, so that file now exists. The "
-        "fifteen cases that refuse a fault by the key's name still "
-        "pass, and tests/benchmarks/test_bench_granite.py holds what "
-        "the case held of the fixture's body. The edit: drop the "
-        "assertion about the reference",
-}
-
-
-def pytest_collection_modifyitems(config, items):
-    here = os.path.dirname(os.path.abspath(__file__))
-    for item in items:
-        path = os.path.relpath(str(item.path), here).replace(os.sep, "/")
-        why = KNOWN_FALSE.get((path, item.name)) or KNOWN_FALSE.get(
-            (path, getattr(item, "originalname", item.name)))
-        if why:
-            item.add_marker(pytest.mark.xfail(reason=why, strict=True))
-
-
 def pytest_terminal_summary(terminalreporter, exitstatus, config):
     """Real-mode integration skips are an environment regression, not
     routine noise (VERDICT r5 weak #7: r4 ran these green, the bench

@@ -330,7 +330,8 @@ def test_serving_refuses_a_state_space_layer_and_says_what_is_missing():
 # configuration lowers to the same text, so no number a cell prints can
 # have moved. PR 46 (the scan's kernels, one more name in
 # `_last_block_keeps`, two gauges for a model with `mamba2` layers)
-# leaves all nine as they were
+# leaves all nine as they were, and so does PR 48 (a window, a gate,
+# four norms, the routed MLP's new forms: every default is neutral)
 PARENT_LOWERED = {
     "gpt2m_dp1":
         "cca38d2c0a7ce2ca00e1ea5711e301ebefc16f951e4472d5ff65984aa35c9ce1",

@@ -520,16 +520,16 @@ def test_backward_does_not_depend_on_how_the_sequence_is_cut(
     block to 64 and lose them at 32)."""
     q, k, v, ct = _bwd_case(2, 2, 128, 128, 16, 90)
     static = (causal, 0.25, 0, 0)
-    _, residuals = pa._flash_fwd(q, k, v, *static, 32, 32, 0)
-    whole = pa._flash_bwd(*static, 128, 128, 0, residuals, ct)
-    cut = pa._flash_bwd(*static, block_q, block_k, 0, residuals, ct)
+    _, residuals = pa._flash_fwd(q, k, v, *static, 32, 32, 0, 0)
+    whole = pa._flash_bwd(*static, 128, 128, 0, 0, residuals, ct)
+    cut = pa._flash_bwd(*static, block_q, block_k, 0, 0, residuals, ct)
     for got, want, name in zip(cut, whole, ("dq", "dk", "dv")):
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=0,
             atol=1e-6 if name == "dq" else 2e-6, err_msg=name)
     if block_q == 32 and block_k == 64:  # the q tiles of (32, 128)
         for got, want in zip(cut[1:], pa._flash_bwd(
-                *static, 32, 128, 0, residuals, ct)[1:]):
+                *static, 32, 128, 0, 0, residuals, ct)[1:]):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -582,8 +582,8 @@ def test_backward_rows_no_key_sees_are_exact_zeros_in_dq(
     q, k, v, ct = _bwd_case(2, 2, tq, tk, 16, 100)
     static = (True, 0.25, 0, k_off, block, block)
     fwd, bwd = pa._flash_fwd.__wrapped__, pa._flash_bwd.__wrapped__
-    _, residuals = fwd(q, k, v, *static, 0)
-    dq, dk, dv = bwd(*static, 0, residuals, ct)
+    _, residuals = fwd(q, k, v, *static, 0, 0)
+    dq, dk, dv = bwd(*static, 0, 0, residuals, ct)
     dk_p, dv_p, dq_p = backward_calls[-1]
     assert dq_p.shape[2] == -(-tq // block) * block > tq
     np.testing.assert_array_equal(np.asarray(dq_p[:, :, :tq]),
@@ -648,7 +648,7 @@ def test_backward_dq_is_not_rounded_between_kv_blocks(seed):
     written a block, or a bf16 accumulator, would give (0.0073)."""
     t, d, block_q, block_k = 256, 64, 64, 8
     q, k, v, ct = _bwd_case(1, 2, t, t, d, seed, jnp.bfloat16)
-    static = (False, d ** -0.5, 0, 0, block_q, block_k, 0)
+    static = (False, d ** -0.5, 0, 0, block_q, block_k, 0, 0)
     f32 = [x.astype(jnp.float32) for x in (q, k, v, ct)]
     ref = jax.vjp(lambda q, k, v: _reference_attention(
         q, k, v, *static[:4]), *f32[:3])[1](f32[3])[0]

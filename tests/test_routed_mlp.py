@@ -244,7 +244,9 @@ def test_trace_time_gauges_say_what_the_layer_was_built_for():
     got = {name: value for name, series in snap.items()
            if name.startswith("hvd_moe_") for value in series.values()}
     assert got == {"hvd_moe_experts_held": 16, "hvd_moe_router_width": 128,
-                   "hvd_moe_rows_expected": 48, "hvd_moe_rows_static": 384}
+                   "hvd_moe_rows_expected": 48, "hvd_moe_rows_static": 384,
+                   "hvd_moe_shared_experts": 0, "hvd_moe_score_func": 1}
+    assert set(snap["hvd_moe_score_func"]) == {"softmax"}
 
 
 def test_a_layer_is_refused_experts_it_cannot_hold():

@@ -466,12 +466,17 @@ def test_attention_is_projections_layout_work_and_kernels(cell):
 # -- the routed MLP's own scopes ---------------------------------------------
 
 def test_layer_scopes_are_the_routed_mlps_two_attentions_two_the_mixers_four():
+    """And, since PR 48, the shared expert's and the post-norms', last:
+    an entry put first or in the middle would read as a change to what
+    was there."""
     assert scopes.LAYER_SCOPES == (
         "moe_dispatch", "moe_experts", "attn_proj", "attn_prep",
-        "mamba_proj", "mamba_conv", "mamba_scan", "mamba_gate") == (
+        "mamba_proj", "mamba_conv", "mamba_scan", "mamba_gate",
+        "moe_shared", "post_norm") == (
         scopes.MOE_DISPATCH, scopes.MOE_EXPERTS, scopes.ATTN_PROJ,
         scopes.ATTN_PREP, scopes.MAMBA_PROJ, scopes.MAMBA_CONV,
-        scopes.MAMBA_SCAN, scopes.MAMBA_GATE)
+        scopes.MAMBA_SCAN, scopes.MAMBA_GATE, scopes.MOE_SHARED,
+        scopes.POST_NORM)
     # the kernels' names are no layer scopes
     assert (scopes.FLASH_FWD, scopes.FLASH_BWD) == ("flash_fwd", "flash_bwd")
     assert not {scopes.FLASH_FWD, scopes.FLASH_BWD} & set(
@@ -682,3 +687,147 @@ def test_the_hybrids_attention_layer_keeps_attentions_names():
     assert sorted(calls) == [
         (scopes.FLASH_BWD, "backward"), (scopes.FLASH_FWD, "backward"),
         (scopes.FLASH_FWD, "forward")]
+
+
+# -- the window-and-full, gated, sigmoid-routed share (PR 48) -----------------
+
+GATED_CELL = "trinity_mini_s8192"
+# its pattern: two leading dense layers, window layers but for block_3
+WINDOW_BLOCKS = {"block_0", "block_1", "block_2", "block_4", "block_5"}
+ROUTED_BLOCKS = {"block_2", "block_3", "block_4", "block_5"}
+
+
+def block_of(op_name: str) -> str:
+    return next(p for p in parts(op_name) if p.startswith("block_"))
+
+
+def test_the_gates_product_is_a_projection_and_its_sigmoid_layout_work():
+    """``attn_proj_roofline`` counts five products a layer since PR 47:
+    the gate's `dot_general` stands inside ``ATTN_PROJ`` in every layer,
+    both directions, and the sigmoid and the multiply with the heads'
+    output inside ``ATTN_PREP``; nothing of the gate is left under the
+    bare ``attn``."""
+    from benchmarks import scopes as readers
+
+    names = [o for o, _ in traced(GATED_CELL, 1) if "attn" in parts(o)]
+    gate = [o for o in names if "gate" in parts(o)]
+    assert {block_of(o) for o in gate} == {f"block_{i}" for i in range(6)}
+    assert {readers.classify(o)[1] for o in gate} == {scopes.ATTN_PROJ}
+    for phase in ("forward", "backward"):
+        assert [o for o in gate if o.endswith("dot_general")
+                and readers.classify(o)[0] == phase]
+    logistic = [o for o in names if o.endswith("/logistic")]
+    assert len({block_of(o) for o in logistic}) == 6
+    assert {readers.classify(o)[1] for o in logistic} == {scopes.ATTN_PREP}
+    # layer `attn` holds the kernels' calls and nothing else
+    bare = [(o, e) for o, e in traced(GATED_CELL, 1)
+            if "attn" in parts(o) and readers.classify(o)[1] == "attn"]
+    assert bare and all(e.primitive.name == "pallas_call" or
+                        o.split("/")[-2] in (scopes.FLASH_FWD,
+                                             scopes.FLASH_BWD)
+                        for o, e in bare), [o for o, _ in bare][:5]
+
+
+def test_the_windowed_flash_calls_keep_their_names_and_their_layer():
+    """A window layer's calls are ``flash_fwd`` / ``flash_bwd`` as a
+    full layer's are, outside every ``LAYER_SCOPES`` name, so the six
+    attention readers read them as they are; the window reaches the
+    kernels as the call's static argument, in the window layers alone;
+    under ``remat`` every block but the last runs its forward again."""
+    from benchmarks import scopes as readers
+
+    calls: dict = {}
+    for o, eqn in traced(GATED_CELL, 1):
+        if eqn.primitive.name != "pallas_call":
+            continue
+        phase, layer = readers.classify(o)
+        assert layer == "attn" and not parts(o) & set(scopes.LAYER_SCOPES)
+        assert eqn.params["name"] in parts(o)
+        calls.setdefault(block_of(o), []).append(
+            (phase, eqn.params["name"]))
+    again = [("backward", scopes.FLASH_BWD), ("backward", scopes.FLASH_FWD),
+             ("forward", scopes.FLASH_FWD)]
+    assert {b: sorted(c) for b, c in calls.items()} == {
+        **{f"block_{i}": again for i in range(5)},
+        "block_5": [again[0], again[2]]}, calls
+
+
+def test_the_window_layers_are_what_the_windows_reader_takes():
+    """``attn_window_kernel_ms`` takes the Mosaic calls of layer
+    ``attn`` under a ``block_<i>`` the model group calls
+    ``window_attention``: on the traced names, the five window blocks'
+    calls and not block_3's."""
+    from benchmarks import harness
+    from benchmarks.layer_metrics import attn_window_kernel_ms as reader
+
+    found = harness.load_cell(GATED_CELL)
+    sizes = {**found["config"]["model"], **found["config"]["tiny"]}
+    layers = reader.window_layers(sizes)
+    assert {f"block_{i}" for i in layers} == WINDOW_BLOCKS
+    lines = ["HloModule jit_step_fn", "",
+             "ENTRY %main (p: bf16[8,128]) -> bf16[8,128] {",
+             "  %p = bf16[8,128]{1,0} parameter(0)"]
+    kernels = [o for o, e in traced(GATED_CELL, 1)
+               if e.primitive.name == "pallas_call"]
+    for i, o in enumerate(kernels):
+        lines.append(
+            f'  %call.{i} = bf16[8,128]{{1,0}} custom-call(%p), '
+            f'custom_call_target="tpu_custom_call", '
+            f'metadata={{op_name="{o}"}}')
+    lines += ["  ROOT %r = bf16[8,128]{1,0} copy(%p)", "}"]
+    taken = reader.window_calls("\n".join(lines), layers)
+    assert len(kernels) == 17 and len(taken) == 14
+    assert {block_of(kernels[int(c.split(".")[1])])
+            for c in taken} == WINDOW_BLOCKS
+    assert reader.window_calls("\n".join(lines), []) == {}
+
+
+@pytest.mark.parametrize("scope,modules", [
+    (scopes.MOE_SHARED, {"shared_gate", "shared_up", "shared_down"}),
+    (scopes.POST_NORM, {"ln_post_attn", "ln_post_mlp"}),
+])
+def test_the_shared_expert_and_the_post_norms_are_layers_of_their_own(
+        scope, modules):
+    """Both directions, in every block that has them; the benchmark's
+    reduction classes the names as the scope's layer, not as ``mlp`` or
+    ``other``; ``ln_attn`` and ``ln_mlp`` stay layer ``norm``."""
+    from benchmarks import scopes as readers
+
+    names = [o for o, _ in traced(GATED_CELL, 1) if scope in parts(o)]
+    blocks = ROUTED_BLOCKS if scope == scopes.MOE_SHARED else {
+        f"block_{i}" for i in range(6)}
+    for phase in ("forward", "backward"):
+        mine = [o for o in names if readers.classify(o)[0] == phase]
+        assert {block_of(o) for o in mine} == blocks, (scope, phase)
+        assert {readers.classify(o)[1] for o in mine} == {scope}
+    assert {p for o in names for p in parts(o)} >= modules
+    # every operation of those modules is inside the scope
+    everything = [o for o, _ in traced(GATED_CELL, 1)
+                  if parts(o) & modules]
+    assert everything and all(scope in parts(o) for o in everything)
+    pre = [o for o, _ in traced(GATED_CELL, 1)
+           if parts(o) & {"ln_attn", "ln_mlp"}]
+    assert {readers.classify(o)[1] for o in pre} == {"norm"}
+
+
+def test_the_sigmoid_routers_arithmetic_is_dispatch():
+    """The sigmoid, the correction, the choice, the renormalisation and
+    the scale stand inside ``MOE_DISPATCH``; the shared expert's
+    products are no part of ``MOE_EXPERTS``; the dense layers' ``mlp``
+    is the bare module."""
+    from benchmarks import scopes as readers
+
+    mlp = [(o, e) for o, e in traced(GATED_CELL, 1) if "mlp" in parts(o)]
+    dispatch = {o.rsplit("/", 1)[-1] for o, _ in mlp
+                if scopes.MOE_DISPATCH in parts(o)}
+    assert {"logistic", "top_k", "sort", "gather"} <= dispatch, dispatch
+    experts = {o.rsplit("/", 1)[-1] for o, _ in mlp
+               if scopes.MOE_EXPERTS in parts(o)}
+    assert "ragged_dot_general" in experts or "ragged_dot" in experts
+    assert not [o for o, _ in mlp if scopes.MOE_EXPERTS in parts(o)
+                and scopes.MOE_SHARED in parts(o)]
+    dense = [o for o, _ in mlp if block_of(o) in ("block_0", "block_1")]
+    assert dense and {readers.classify(o)[1] for o in dense} == {"mlp"}
+    assert not [o for o, _ in mlp if block_of(o) in ROUTED_BLOCKS
+                and o.endswith("dot_general")
+                and readers.classify(o)[1] == "mlp"]
