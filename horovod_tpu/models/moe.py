@@ -52,8 +52,12 @@ of it anyway:
 * its cost follows the rows routed here, not tokens x choices. The
   (token, choice) pairs are ordered held-expert-major by one sort of
   integers; the rows of the first `rows_static` of them are gathered
-  and go through three `jax.lax.ragged_dot`s whose groups are the
-  experts, and each row's result is added to its token's, weighted.
+  and go through three grouped products whose groups are the experts
+  (`expert_product`: the Pallas kernels of `ops/grouped_matmul.py`,
+  which read the float32 experts in place, where the shape is one they
+  take; `jax.lax.ragged_dot` behind a cast of the experts where it is
+  not: a width that is no whole lane tile, rows that are no whole row
+  tile), and each row's result is added to its token's, weighted.
   `rows_static` is the rows even routing sends here (all of them where
   every expert is held); rows short of it ride the last group with
   weight zero. Pairs beyond `rows_static` are taken by further products
@@ -66,11 +70,18 @@ of it anyway:
 Scopes (`utils/scopes.LAYER_SCOPES`): `moe_dispatch` names the router
 (the sigmoid, the correction, the renormalisation and the scale too),
 the choice, the ordering, the gather, the weighted combine and the sum
-with the shared expert; `moe_experts` the three expert products;
-`moe_shared` the shared expert's three products and its activation.
+with the shared expert; `moe_experts` the three expert products, the
+kernels' calls among them in both directions (`GROUPED_MATMUL_FWD`,
+`GROUPED_MATMUL_DW`; no scope is opened in `ops/grouped_matmul.py`:
+this layer's reaches the forward rule and, carried by JAX to the
+call's transpose, the backward rule); `moe_shared` the shared expert's
+three products and its activation.
 Trace-time gauges (`utils/metrics.record_moe_rows`):
 `hvd_moe_experts_held`, `hvd_moe_router_width`, `hvd_moe_rows_expected`,
-`hvd_moe_rows_static`, `hvd_moe_shared_experts`, `hvd_moe_score_func`.
+`hvd_moe_rows_static`, `hvd_moe_shared_experts`, `hvd_moe_score_func`;
+and of the model (`utils/metrics.record_moe_expert_layers`, set by
+`models/transformer.Transformer` from `experts_run_as_kernels`):
+`hvd_moe_expert_kernel_layers` / `hvd_moe_expert_plain_layers`.
 """
 
 from __future__ import annotations
@@ -84,6 +95,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import grouped_matmul
 from ..utils import metrics, scopes
 
 # rows_static is a multiple of this (the MXU's rows, and a tile of
@@ -132,11 +144,29 @@ def expert_product(rows, weights, groups):
     """`[rows, a] x [experts, a, c] -> [rows, c]`: each row through the
     matrix of the expert whose group it lies in (`groups` are the
     experts' row counts, in order). Both sides in the rows' dtype,
-    accumulated in float32 on the MXU, returned in the rows' dtype. One
-    function, so that a control can stand a lower precision in its
-    place (`scripts/routed_readings.py`)."""
+    accumulated in float32 on the MXU, returned in the rows' dtype.
+    Which form runs is decided by what the operands show and nothing
+    else: the kernels of `ops/grouped_matmul.py`, which round each
+    expert in VMEM as the cast does, where `supports` takes the shape;
+    `lax.ragged_dot` behind the cast where it does not. One function,
+    so that a control can stand a lower precision in its place
+    (`scripts/routed_readings.py`)."""
+    if grouped_matmul.supports(*rows.shape, weights.shape[2], rows.dtype):
+        return grouped_matmul.grouped_matmul(rows, weights, groups)
     return lax.ragged_dot(rows, weights.astype(rows.dtype), groups,
                           preferred_element_type=rows.dtype)
+
+
+def experts_run_as_kernels(tokens: int, experts_per_token: int,
+                           experts_held: int, num_experts: int,
+                           hidden: int, mlp_dim: int, dtype) -> bool:
+    """Whether a `RoutedMlp` over `tokens` tokens hands its three
+    products to the kernels of `ops/grouped_matmul.py`: decided by
+    shapes alone (`supports` asks the same of `hidden -> mlp_dim` and of
+    `mlp_dim -> hidden`)."""
+    static = rows_static(tokens, experts_per_token, experts_held,
+                         num_experts)[1]
+    return grouped_matmul.supports(static, hidden, mlp_dim, dtype)
 
 
 class RoutedMlp(nn.Module):
@@ -259,18 +289,29 @@ class RoutedMlp(nn.Module):
             # Pairs past the first product's, where routing sends so many
             # here: one product of the same size for each `static` of
             # them, added into the same sum. Both conditions hand the sum
-            # on untouched where they do not hold, and a further product
-            # keeps nothing for the backward pass but its number and its
-            # rows' tokens (a scan would stack what each of them kept)
+            # on untouched where they do not hold. An iteration, its
+            # condition included, is rematerialised: it keeps nothing
+            # for the backward pass but its number, and what never
+            # changes (the tokens, the order, the float32 experts the
+            # kernels read in place) stays the loop's constants. With
+            # the condition outside the rematerialised part, its
+            # results carried what a taken branch kept: a scan stacks
+            # those iteration by iteration, the experts among them, and
+            # where routing sends no further rows here the outer
+            # condition's other branch has to hand the stacks on as
+            # zeros: 7 GB of them a step in `sdar_bd_s4096`, 11 with
+            # the experts in float32 (PERF.md section 6, PR 49)
+            @jax.checkpoint
             def further(total, c):
                 return lax.cond(
                     c * static < routed,
-                    lambda total: combine(total, *jax.checkpoint(chunk)(c)),
-                    lambda total: total, total), None
+                    lambda total: combine(total, *chunk(c)),
+                    lambda total: total, total)
 
             y = lax.cond(
                 routed > static,
-                lambda y: lax.scan(further, y, jnp.arange(1, chunks))[0],
+                lambda y: lax.scan(lambda total, c: (further(total, c), None),
+                                   y, jnp.arange(1, chunks))[0],
                 lambda y: y, y)
         if self.shared_experts:
             with jax.named_scope(scopes.MOE_SHARED):

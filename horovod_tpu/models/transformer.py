@@ -45,7 +45,7 @@ import numpy as np
 from ..ops import attention_prep
 from ..utils import metrics, scopes
 from .mamba import Mamba2Mixer, scan_runs_as_kernels
-from .moe import RoutedMlp
+from .moe import RoutedMlp, experts_run_as_kernels
 
 # the kinds of layer a `Block` builds, as `layer_types` names them (the
 # spelling of the benchmark's `model` group and of its
@@ -785,6 +785,16 @@ class Transformer(nn.Module):
             metrics.record_mamba_scan_layers(
                 state_space_layers * as_kernels,
                 state_space_layers * (not as_kernels))
+        routed_layers = sum(cfg.routes(i) for i in range(cfg.num_layers))
+        if routed_layers:
+            as_kernels = experts_run_as_kernels(
+                B * T, cfg.experts_per_token,
+                cfg.experts_held or cfg.num_experts, cfg.num_experts,
+                cfg.hidden_size, cfg.expert_mlp_dim or cfg.mlp_dim,
+                cfg.dtype)
+            metrics.record_moe_expert_layers(
+                routed_layers * as_kernels,
+                routed_layers * (not as_kernels))
         for i, kind in enumerate(kinds):
             block = Block
             if i < rematerialised:

@@ -1095,6 +1095,27 @@ def record_moe_rows(experts_held: int, router_width: int,
         labelnames=("score_func",)).labels(score_func=score_func).set(1)
 
 
+def record_moe_expert_layers(kernels: int, plain: int) -> None:
+    """How `Transformer` (models/transformer.py) built its routed
+    layers' expert products: those that run as the kernels of
+    `ops/grouped_matmul.py`, and those whose shape the kernels do not
+    take and that run `lax.ragged_dot` behind a cast of the experts. A
+    model is all one or all the other
+    (`models/moe.experts_run_as_kernels`: by shapes alone). Recorded at
+    TRACE time like the gauges above, and only for a model that has
+    such layers."""
+    if not _enabled:
+        return
+    registry.gauge(
+        "hvd_moe_expert_kernel_layers",
+        "Routed layers whose expert products are the grouped-matmul "
+        "Pallas kernels").set(kernels)
+    registry.gauge(
+        "hvd_moe_expert_plain_layers",
+        "Routed layers whose expert products are ragged_dot behind a "
+        "cast of the experts").set(plain)
+
+
 def record_remat_blocks(rematerialised: int, kept: int) -> None:
     """How `Transformer` (models/transformer.py) built its blocks under
     `remat`: those whose whole forward runs again in the backward pass

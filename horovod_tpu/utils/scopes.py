@@ -11,8 +11,10 @@ constants: a name changed here changes there.
 The rule the readers depend on (``tests/test_step_scopes.py`` holds
 it): **a flash call (``FLASH_FWD`` / ``FLASH_BWD``) sits outside every
 ``LAYER_SCOPES`` name; the q/k pass's call (``QK_PREP_FWD`` /
-``QK_PREP_BWD``) sits inside ``ATTN_PREP``, and the state-space
-scan's (``SSD_SCAN_FWD`` / ``SSD_SCAN_BWD``) inside ``MAMBA_SCAN``.** A flash call's
+``QK_PREP_BWD``) sits inside ``ATTN_PREP``, the state-space scan's
+(``SSD_SCAN_FWD`` / ``SSD_SCAN_BWD``) inside ``MAMBA_SCAN``, and the
+routed experts' products' (``GROUPED_MATMUL_FWD`` /
+``GROUPED_MATMUL_DW``) inside ``MOE_EXPERTS``.** A flash call's
 ``op_name`` is ``.../attn/flash_fwd/pallas_call``: it stays in Flax's
 layer ``attn``, where the kernels' readers look (they take every Mosaic
 call of that layer for a flash kernel), and what is left in layer
@@ -20,7 +22,11 @@ call of that layer for a flash kernel), and what is left in layer
 softmax. The q/k pass's is ``.../attn/attn_prep/qk_prep_fwd/pallas_call``:
 layer ``attn_prep``, whose time it is; the scan's is
 ``.../mamba/mamba_scan/ssd_scan_fwd/pallas_call``: layer ``mamba_scan``,
-likewise.
+likewise; an expert product's is
+``.../mlp/moe_experts/grouped_matmul/pallas_call``: layer
+``moe_experts``, whose reader takes the layer by its scope (a call
+outside it would be layer ``other``, and the layer's roofline share
+would count work whose time it does not see).
 """
 
 # final hidden state to the loss, both directions, fused or dense
@@ -112,3 +118,12 @@ QK_PREP_BWD = "qk_prep_bwd"
 # forward call in the backward phase is a rematerialised block's
 SSD_SCAN_FWD = "ssd_scan_fwd"
 SSD_SCAN_BWD = "ssd_scan_bwd"
+# The routed experts' products (ops/grouped_matmul.py): the product
+# itself (gate, up, down; in the backward phase a rematerialised
+# block's, or with the weight transposed the gradient into the rows)
+# and the gradient into the weights. These stand INSIDE `MOE_EXPERTS`,
+# forward rule and backward rule: the layer's reader
+# (benchmarks/layer_metrics/moe_experts_ms.py) takes the layer by its
+# scope
+GROUPED_MATMUL_FWD = "grouped_matmul"
+GROUPED_MATMUL_DW = "grouped_matmul_dw"

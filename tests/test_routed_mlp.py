@@ -249,6 +249,43 @@ def test_trace_time_gauges_say_what_the_layer_was_built_for():
     assert set(snap["hvd_moe_score_func"]) == {"softmax"}
 
 
+@pytest.mark.parametrize("sizes,want", [
+    # 256 tokens x 4 choices x 4 of 16 held = 256 rows, rounded to one
+    # row tile of 512; hidden 128 and experts of 256: a shape the
+    # kernels take (`ops/grouped_matmul.supports`)
+    (dict(hidden_size=128, expert_mlp_dim=256), (2, 0)),
+    # the same rows through experts of 64: no whole lane tile
+    (dict(hidden_size=128, expert_mlp_dim=64), (0, 2)),
+    # two dense layers first: one routed layer is left of three
+    (dict(hidden_size=128, expert_mlp_dim=256, num_layers=3,
+          dense_layers=2), (1, 0)),
+])
+def test_the_models_gauges_say_how_many_layers_products_are_kernels(
+        sizes, want):
+    """`hvd_moe_expert_kernel_layers` / `hvd_moe_expert_plain_layers`,
+    set while the model is traced, from shapes alone
+    (`moe.experts_run_as_kernels`)."""
+    cfg = TransformerConfig(**{**SIZES, "max_seq_len": 128, **sizes})
+    model = Transformer(cfg)
+    toks = jnp.zeros((2, 128), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), toks)
+    was = metrics.enabled()
+    metrics.enable()
+    metrics.registry.clear()
+    try:
+        jax.eval_shape(lambda p: model.apply(p, toks), params)
+        snap = metrics.registry.snapshot()
+    finally:
+        metrics.registry.clear()
+        if not was:
+            metrics.disable()
+    got = {name: value for name, series in snap.items()
+           if name.startswith("hvd_moe_expert_")
+           for value in series.values()}
+    assert got == {"hvd_moe_expert_kernel_layers": want[0],
+                   "hvd_moe_expert_plain_layers": want[1]}
+
+
 def test_a_layer_is_refused_experts_it_cannot_hold():
     params, x = seeded()
     with pytest.raises(ValueError, match="experts_held 16 from "

@@ -335,6 +335,7 @@ def test_kernel_names_are_in_their_directions(cell):
 
 FLASH_NAMES = {scopes.FLASH_FWD, scopes.FLASH_BWD}
 QK_PREP_NAMES = {scopes.QK_PREP_FWD, scopes.QK_PREP_BWD}
+GROUPED_MATMUL_NAMES = {scopes.GROUPED_MATMUL_FWD, scopes.GROUPED_MATMUL_DW}
 # the routed cell's tiny step with heads as wide as the real one's: the
 # width at which `Attention` runs q/k norms, rope and the kernels' layout
 # as the one pass of `ops/attention_prep.py` (the tiny preset's 64 keeps
@@ -347,11 +348,13 @@ def test_a_flash_call_stands_outside_every_layer_scope(cell):
     """The rule ``utils/scopes.py`` states: an ``op_name`` that holds a
     flash kernel's name holds no ``LAYER_SCOPES`` name and classes as
     layer ``attn``; and at the tiny preset's head width every
-    ``pallas_call`` is a flash call."""
+    ``pallas_call`` under a module ``attn`` is a flash call (the routed
+    cell's expert products are calls too, under ``mlp``)."""
     from benchmarks import scopes as readers
 
     kernels = [o for o, _ in traced(cell, EVERY_CELL[cell])
-               if parts(o) & {"pallas_call", *FLASH_NAMES}]
+               if parts(o) & {"pallas_call", *FLASH_NAMES}
+               and not parts(o) & GROUPED_MATMUL_NAMES]
     assert kernels
     for o in kernels:
         assert parts(o) & FLASH_NAMES, o
@@ -376,7 +379,8 @@ def test_the_qk_pass_stands_inside_attn_prep():
     assert not QK_PREP_NAMES & set(kernel_names.kernel_names())
     calls: dict = {}
     for o, eqn in traced(ROUTED_CELL, 1, **WIDE):
-        if eqn.primitive.name != "pallas_call":
+        if eqn.primitive.name != "pallas_call" \
+                or eqn.params["name"] in GROUPED_MATMUL_NAMES:
             continue
         name = eqn.params["name"]
         phase, layer = readers.classify(o)
@@ -497,7 +501,8 @@ def test_remat_runs_the_flash_forward_again_in_every_block_but_the_last():
 
     calls: dict = {}
     for op_name, eqn in traced(ROUTED_CELL, 1):
-        if eqn.primitive.name != "pallas_call":
+        if eqn.primitive.name != "pallas_call" \
+                or eqn.params["name"] in GROUPED_MATMUL_NAMES:
             continue
         block = next(p for p in op_name.split("/") if p.startswith("block_"))
         phase, _ = readers.classify(op_name)
@@ -519,7 +524,8 @@ def test_routed_mlp_is_named_in_both_directions(scope):
     """The compiled tiny step of the routed cell names both scopes under
     every layer's ``mlp``, forward and backward, and the benchmark's
     reduction classes such a name as the scope's layer, not as ``mlp``;
-    the expert products (``ragged_dot``) are inside ``moe_experts`` and
+    the expert products (the interpreted kernels of
+    ``ops/grouped_matmul.py`` at this shape) are inside ``moe_experts`` and
     the router, the choice, the gather and the combine inside
     ``moe_dispatch``."""
     from benchmarks import scopes as readers
@@ -553,6 +559,45 @@ def test_routed_mlp_is_named_in_both_directions(scope):
                     "iota", "dynamic_slice", "convert_element_type",
                     "reshape", "remat2", "add_any", "add",
                     "dynamic_update_slice", "mlp"}, bare
+
+
+def test_the_expert_products_kernels_stand_inside_moe_experts():
+    """The rule ``utils/scopes.py`` states for ``GROUPED_MATMUL_*``. The
+    routed cell's tiny preset is a shape the kernels take (512 rows of
+    128 through experts of 768: ``ops/grouped_matmul.supports``), so
+    every layer's expert products are kernel calls: each carries its
+    name, lies under the Flax module ``mlp`` inside ``moe_experts``
+    (once, and before the call's own name) and is layer ``moe_experts``
+    to the benchmark's readers in both phases
+    (``moe_experts_ms`` selects by that layer). A layer's forward phase
+    holds the three products and the further products' three; its
+    backward phase the second run's gate and up (the down product's
+    result is no residual), the three gradients into the rows, which
+    are products too, and the three into the weights, each for the main
+    path and for the further products. The last block is no exception:
+    ``_last_block_keeps`` keeps none of them. No ``ragged_dot`` is
+    left."""
+    from benchmarks import scopes as readers
+
+    assert not GROUPED_MATMUL_NAMES & set(scopes.LAYER_SCOPES)
+    calls: dict = {}
+    for o, eqn in traced(ROUTED_CELL, 1):
+        assert "ragged_dot" not in eqn.primitive.name, o
+        if eqn.primitive.name != "pallas_call" \
+                or eqn.params["name"] not in GROUPED_MATMUL_NAMES:
+            continue
+        assert "mlp" in parts(o) and eqn.params["name"] in parts(o), o
+        phase, layer = readers.classify(o)
+        assert layer == scopes.MOE_EXPERTS, o
+        assert o.count(scopes.MOE_EXPERTS + "/") == 1 and o.index(
+            scopes.MOE_EXPERTS) < o.index(eqn.params["name"]), o
+        block = next(p for p in parts(o) if p.startswith("block_"))
+        calls.setdefault(block, []).append((phase, eqn.params["name"]))
+    a_layer = [("backward", scopes.GROUPED_MATMUL_FWD)] * 10 \
+        + [("backward", scopes.GROUPED_MATMUL_DW)] * 6 \
+        + [("forward", scopes.GROUPED_MATMUL_FWD)] * 6
+    assert {b: sorted(c) for b, c in calls.items()} == {
+        f"block_{i}": a_layer for i in range(6)}, calls
 
 
 # -- the state-space mixer's four scopes --------------------------------------
