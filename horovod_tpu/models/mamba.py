@@ -56,10 +56,12 @@ in exactly one): `mamba_proj` the two projections, `mamba_conv` the
 convolution, silu, the splits and dt's softplus, `mamba_scan` everything
 from x, dt, B, C to y, the two kernels' calls included (`SSD_SCAN_FWD`,
 `SSD_SCAN_BWD` stand inside it), `mamba_gate` the gate and the norm.
-Trace-time gauges (`utils/metrics.record_mamba_scan`): `hvd_mamba_chunk`,
-`hvd_mamba_chunks_per_sequence`, `hvd_mamba_state_bytes_per_sequence`;
-and, set by `Transformer` for a model that has such layers,
-`hvd_mamba_scan_kernel_layers` / `hvd_mamba_scan_plain_layers`.
+Trace-time gauges (set by `ssd_scan`, the last traced call's):
+`hvd_mamba_chunk`, `hvd_mamba_chunks_per_sequence`,
+`hvd_mamba_state_bytes_per_sequence`; and, set by
+`models/transformer._report` for a model that has such layers (from
+`Mamba2Mixer.scans_as_kernels`), `hvd_mamba_scan_kernel_layers` /
+`hvd_mamba_scan_plain_layers`.
 """
 
 from __future__ import annotations
@@ -167,8 +169,20 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int):
         x, dt, b, c = (jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (
             z.ndim - 2)) for z in (x, dt, b, c))
     padded = t + pad
-    metrics.record_mamba_scan(
-        chunk, padded // chunk, h * p * n * jnp.dtype(jnp.float32).itemsize)
+    # trace-time gauges: the chunk (the configuration's, or the sequence
+    # where that is shorter), the chunks with the padding, and the
+    # float32 state between them (heads x d_head x d_state x 4)
+    metrics.trace_gauge(
+        "hvd_mamba_chunk",
+        "Positions in one chunk of the state-space scan", chunk)
+    metrics.trace_gauge(
+        "hvd_mamba_chunks_per_sequence",
+        "Chunks the state-space scan cuts a sequence into",
+        padded // chunk)
+    metrics.trace_gauge(
+        "hvd_mamba_state_bytes_per_sequence",
+        "Bytes of float32 state a sequence carries between chunks",
+        h * p * n * jnp.dtype(jnp.float32).itemsize)
     per_group = h // groups
     if scan_runs_as_kernels(padded, chunk, p, n, per_group, x.dtype):
         cum = _log_decay_sums(
@@ -239,6 +253,12 @@ class Mamba2Mixer(nn.Module):
     chunk_size: int = 256
     epsilon: float = 1e-5
     dtype: Any = jnp.bfloat16
+
+    def scans_as_kernels(self, t: int) -> bool:
+        """`scan_runs_as_kernels` of this mixer over `t` positions."""
+        return scan_runs_as_kernels(
+            t, self.chunk_size, self.d_head, self.d_state,
+            self.n_heads // self.n_groups, self.dtype)
 
     @nn.compact
     def __call__(self, u):

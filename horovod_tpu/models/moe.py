@@ -76,11 +76,11 @@ kernels' calls among them in both directions (`GROUPED_MATMUL_FWD`,
 this layer's reaches the forward rule and, carried by JAX to the
 call's transpose, the backward rule); `moe_shared` the shared expert's
 three products and its activation.
-Trace-time gauges (`utils/metrics.record_moe_rows`):
-`hvd_moe_experts_held`, `hvd_moe_router_width`, `hvd_moe_rows_expected`,
-`hvd_moe_rows_static`, `hvd_moe_shared_experts`, `hvd_moe_score_func`;
-and of the model (`utils/metrics.record_moe_expert_layers`, set by
-`models/transformer.Transformer` from `experts_run_as_kernels`):
+Trace-time gauges (`RoutedMlp._report`, what the last traced call of a
+routed MLP was built for): `hvd_moe_experts_held`,
+`hvd_moe_router_width`, `hvd_moe_rows_expected`, `hvd_moe_rows_static`,
+`hvd_moe_shared_experts`, `hvd_moe_score_func`; and of the model
+(`models/transformer._report`, from `RoutedMlp.experts_as_kernels`):
 `hvd_moe_expert_kernel_layers` / `hvd_moe_expert_plain_layers`.
 """
 
@@ -188,6 +188,39 @@ class RoutedMlp(nn.Module):
     routed_scaling_factor: float = 1.0
     shared_experts: int = 0
 
+    def experts_as_kernels(self, tokens: int, hidden: int) -> bool:
+        """`experts_run_as_kernels` of this layer over `tokens` tokens
+        of width `hidden`."""
+        return experts_run_as_kernels(
+            tokens, self.experts_per_token, self.experts_held,
+            self.num_experts, hidden, self.mlp_dim, self.dtype)
+
+    def _report(self, rows_expected: float, rows_static: int) -> None:
+        """The trace-time gauges of one routed MLP: arithmetic on the
+        last traced call's shapes, nothing inside the step."""
+        for name, help, value in (
+                ("hvd_moe_experts_held",
+                 "Experts of the router's that the routed MLP holds",
+                 self.experts_held),
+                ("hvd_moe_router_width",
+                 "Experts the routed MLP's router scores",
+                 self.num_experts),
+                ("hvd_moe_rows_expected",
+                 "Rows even routing sends the held experts in one call",
+                 rows_expected),
+                ("hvd_moe_rows_static",
+                 "Rows one expert product of the routed MLP is sized for",
+                 rows_static),
+                ("hvd_moe_shared_experts",
+                 "Shared experts every token of the routed MLP goes "
+                 "through", self.shared_experts)):
+            metrics.trace_gauge(name, help, value)
+        # the function that scores is the gauge's label; its value is 1
+        metrics.trace_gauge(
+            "hvd_moe_score_func",
+            "The function the routed MLP's router scores by (label)", 1,
+            score_func=self.score_func)
+
     @nn.compact
     def __call__(self, x):
         *lead, h = x.shape
@@ -200,8 +233,7 @@ class RoutedMlp(nn.Module):
         tokens = x.reshape(-1, h)
         t = tokens.shape[0]
         expected, static, most = rows_static(t, k, held, e)
-        metrics.record_moe_rows(held, e, expected, static,
-                                self.shared_experts, self.score_func)
+        self._report(expected, static)
 
         # every expert a xavier-uniform matrix of its own
         init = nn.initializers.xavier_uniform(

@@ -44,8 +44,8 @@ import numpy as np
 
 from ..ops import attention_prep
 from ..utils import metrics, scopes
-from .mamba import Mamba2Mixer, scan_runs_as_kernels
-from .moe import RoutedMlp, experts_run_as_kernels
+from .mamba import Mamba2Mixer
+from .moe import RoutedMlp
 
 # the kinds of layer a `Block` builds, as `layer_types` names them (the
 # spelling of the benchmark's `model` group and of its
@@ -89,7 +89,7 @@ class TransformerConfig:
     activation: str = "gelu"  # "gelu" | "swiglu"
     causal: bool = True
     tie_embeddings: bool = True
-    # rematerialise the blocks (`nn.remat(Block)`): a block keeps only
+    # rematerialise the blocks (`build_block`): a block keeps only
     # its input and its forward runs again right before its backward.
     # The last block's backward is the first to run, with nothing
     # between its two runs but the final norm and the head, so where
@@ -253,6 +253,29 @@ class TransformerConfig:
     def mlp_dim(self) -> int:
         return int(self.hidden_size * self.mlp_ratio)
 
+    def routed_mlp(self, **kw) -> RoutedMlp:
+        """The routed MLP of these sizes: a routed layer's (`Block`
+        names it `mlp`), or one built only to be asked what it would
+        run (`parent=None`)."""
+        return RoutedMlp(
+            num_experts=self.num_experts,
+            experts_held=self.experts_held or self.num_experts,
+            experts_per_token=self.experts_per_token,
+            mlp_dim=self.expert_mlp_dim or self.mlp_dim,
+            norm_topk_prob=self.norm_topk_prob,
+            score_func=self.score_func,
+            routed_scaling_factor=self.routed_scaling_factor,
+            shared_experts=self.shared_experts, dtype=self.dtype, **kw)
+
+    def mamba2_mixer(self, **kw) -> Mamba2Mixer:
+        """The state-space mixer of these sizes, as `routed_mlp`."""
+        return Mamba2Mixer(
+            hidden_size=self.hidden_size, n_heads=self.mamba_n_heads,
+            d_head=self.mamba_d_head, d_state=self.mamba_d_state,
+            d_conv=self.mamba_d_conv, n_groups=self.mamba_n_groups,
+            chunk_size=self.mamba_chunk_size,
+            epsilon=self.layernorm_epsilon, dtype=self.dtype, **kw)
+
 
 # -- named configs ----------------------------------------------------------
 
@@ -284,6 +307,45 @@ LLAMA3_8B = TransformerConfig(
     norm="rmsnorm", position="rope", activation="swiglu",
     tie_embeddings=False, rope_theta=500000.0,
 )
+
+
+# -- the layer pattern -------------------------------------------------------
+
+# what `remat` does with a block (`LayerSpec.remat`; None = nothing):
+# its whole forward runs again in the backward pass, or it keeps from
+# its first run what `_last_block_keeps` keeps and rebuilds the rest
+REBUILD_ALL, KEEP_PRODUCTS = "rebuild_all", "keep_products"
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What one layer of the stack is built as. `layer_specs` decides
+    it and `build_block` builds it; whoever walks the layers (the model,
+    ops/overlap's stages, parallel/pipeline's stage, `cache_gaps`)
+    reads these, and not `layer_types`, `dense_layers`, `num_experts`
+    or `remat` of the configuration."""
+
+    index: int
+    kind: str  # the mixer, one of LAYER_KINDS
+    routed: bool  # the routed MLP (models/moe.py) and not the plain one
+    remat: Optional[str] = None  # None, REBUILD_ALL or KEEP_PRODUCTS
+
+
+def layer_specs(cfg: TransformerConfig,
+                callers_head: bool = False) -> tuple:
+    """The `LayerSpec` of each of `cfg`'s layers, in order.
+    `callers_head`: the caller takes the hidden state to a head of its
+    own (`Transformer.__call__(return_hidden=True)`), so that under
+    `remat` the last block, whose backward runs first, right after the
+    head's, keeps its dear results (see `TransformerConfig.remat`);
+    where the [B, T, V] logits are built every block is rebuilt whole."""
+    kept = int(cfg.remat and callers_head and cfg.num_layers > 0)
+    rematerialised = cfg.num_layers - kept if cfg.remat else 0
+    return tuple(
+        LayerSpec(i, kind, cfg.routes(i),
+                  REBUILD_ALL if i < rematerialised
+                  else KEEP_PRODUCTS if kept else None)
+        for i, kind in enumerate(cfg.layer_kinds))
 
 
 # -- building blocks --------------------------------------------------------
@@ -593,8 +655,8 @@ class Mlp(nn.Module):
                      kernel_init=nn.initializers.xavier_uniform())(h)
 
 
-# what serving lacks for a state-space layer (`Block` raises it where a
-# cache is handed to one; serving/decode.py refuses the model earlier)
+# what serving lacks for a state-space layer (`cache_gaps`; `Block`
+# raises it too where a cache is handed to one)
 MAMBA2_HAS_NO_CACHE = (
     "a `mamba2` layer cannot decode through a key-value cache: it keeps "
     "no keys and values but a recurrent state (heads x d_head x d_state) "
@@ -610,15 +672,21 @@ ROUTED_FORMS_HAVE_NO_CACHE = (
 
 def cache_gaps(cfg: TransformerConfig) -> list:
     """What of `cfg` the key-value cache path (serving/decode,
-    `Transformer.__call__(kv_cache=)`) cannot run, each by the field's
-    name with what is missing; empty where it can run all of it. A
-    state-space layer is refused apart (MAMBA2_HAS_NO_CACHE)."""
-    kinds = cfg.layer_kinds
+    `Transformer.__call__(kv_cache=)`) cannot run, each by the layers'
+    or the field's name with what is missing; empty where it can run
+    all of it."""
+    specs = layer_specs(cfg)
+
+    def layers(kind):
+        return [spec.index for spec in specs if spec.kind == kind]
+
     gaps = []
-    if WINDOW_ATTENTION in kinds:
-        gaps.append(
-            f"layers {[i for i, k in enumerate(kinds) if k == WINDOW_ATTENTION]}"
-            f" are `window_attention` layers: " + WINDOW_HAS_NO_CACHE)
+    if layers(MAMBA2):
+        gaps.append(f"layers {layers(MAMBA2)} of this model are "
+                    f"state-space layers: " + MAMBA2_HAS_NO_CACHE)
+    if layers(WINDOW_ATTENTION):
+        gaps.append(f"layers {layers(WINDOW_ATTENTION)} are "
+                    f"`window_attention` layers: " + WINDOW_HAS_NO_CACHE)
     if cfg.attn_output_gate:
         gaps.append("attn_output_gate: " + GATE_HAS_NO_CACHE)
     forms = {"score_func": cfg.score_func != "softmax",
@@ -644,52 +712,31 @@ def scaled(x, multiplier: float):
 
 class Block(nn.Module):
     cfg: TransformerConfig
+    # the layer's mixer (`spec.kind`): `Attention` under the module name
+    # `attn` (of that kind: a window layer's is told so) or the
+    # state-space mixer under `mamba`; its MLP under `mlp`, the routed
+    # one where `spec.routed`
+    spec: LayerSpec
     attention_fn: Optional[Callable] = None
-    # the layer's mixer (LAYER_KINDS): `Attention` under the module name
-    # `attn` (of this kind: a window layer's is told so), or the
-    # state-space mixer under `mamba`
-    kind: str = ATTENTION
-    # whether the layer's MLP is the routed one (`cfg.routes(layer)`, as
-    # `Transformer` and ops/overlap build their blocks); None = wherever
-    # the model has experts, for a caller that builds one block alone
-    routed: Optional[bool] = None
 
     @nn.compact
-    def __call__(self, x, positions, mask=None, kv_cache=None, layer=0):
-        cfg = self.cfg
+    def __call__(self, x, positions, mask=None, kv_cache=None):
+        cfg, spec = self.cfg, self.spec
         y = _norm(cfg, "ln_attn")(x)
-        if self.kind == MAMBA2:
+        if spec.kind == MAMBA2:
             if kv_cache is not None:
                 raise ValueError(MAMBA2_HAS_NO_CACHE)
-            mixed = Mamba2Mixer(
-                hidden_size=cfg.hidden_size, n_heads=cfg.mamba_n_heads,
-                d_head=cfg.mamba_d_head, d_state=cfg.mamba_d_state,
-                d_conv=cfg.mamba_d_conv, n_groups=cfg.mamba_n_groups,
-                chunk_size=cfg.mamba_chunk_size,
-                epsilon=cfg.layernorm_epsilon, dtype=cfg.dtype,
-                name="mamba")(y)
+            mixed = cfg.mamba2_mixer(name="mamba")(y)
         else:
             mixed = Attention(cfg, attention_fn=self.attention_fn,
-                              kind=self.kind,
+                              kind=spec.kind,
                               name="attn")(y, positions, mask,
-                                           kv_cache=kv_cache, layer=layer)
+                                           kv_cache=kv_cache,
+                                           layer=spec.index)
         x = _joined(cfg, x, mixed, "ln_post_attn")
         y = _norm(cfg, "ln_mlp")(x)
-        routed = bool(cfg.num_experts) if self.routed is None \
-            else self.routed
-        if routed:
-            mlp = RoutedMlp(
-                num_experts=cfg.num_experts,
-                experts_held=cfg.experts_held or cfg.num_experts,
-                experts_per_token=cfg.experts_per_token,
-                mlp_dim=cfg.expert_mlp_dim or cfg.mlp_dim,
-                norm_topk_prob=cfg.norm_topk_prob,
-                score_func=cfg.score_func,
-                routed_scaling_factor=cfg.routed_scaling_factor,
-                shared_experts=cfg.shared_experts, dtype=cfg.dtype,
-                name="mlp")
-        else:
-            mlp = Mlp(cfg, name="mlp")
+        mlp = cfg.routed_mlp(name="mlp") if spec.routed \
+            else Mlp(cfg, name="mlp")
         return _joined(cfg, x, mlp(y), "ln_post_mlp")
 
 
@@ -721,6 +768,182 @@ def _last_block_keeps(prim, *_, **params) -> bool:
     return prim.name in ("dot_general", "top_k", "sort")
 
 
+def build_block(cfg: TransformerConfig, spec: LayerSpec,
+                attention_fn: Optional[Callable] = None,
+                name: Optional[str] = None) -> Block:
+    """The block of layer `spec`, rematerialised as the spec says: the
+    one place the package wraps a `Block` in `nn.remat`. Inside a
+    compact method it is that module's child `name`; with no parent
+    (ops/overlap's stages, parallel/pipeline's stage) it is applied
+    alone over the layer's sub-tree of the parameters."""
+    block = Block
+    if spec.remat is not None:
+        block = nn.remat(
+            Block, static_argnums=(),
+            policy=_last_block_keeps if spec.remat == KEEP_PRODUCTS
+            else None)
+    return block(cfg, spec, attention_fn=attention_fn, name=name)
+
+
+# The stack's two ends, each written once as a function that makes its
+# Flax children in the compact method that calls it: `Transformer`'s
+# own (so that `tok_emb`, `pos_emb`, `ln_final` and `lm_head` stay at
+# the top of the parameter tree, beside the `block_<i>`), or `Embedding`
+# / `LmHead`'s, which run one end alone over its sub-tree.
+
+def _token_embedding(cfg: TransformerConfig) -> nn.Embed:
+    """`tok_emb`: the tokens' embedding and, under `tie_embeddings`,
+    the head's matrix. A compact method builds it once and hands it to
+    whichever ends it runs."""
+    return nn.Embed(
+        cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+        param_dtype=jnp.float32, name="tok_emb",
+        embedding_init=nn.initializers.normal(0.02),
+    )
+
+
+def _embed(module: nn.Module, emb: nn.Embed, tokens, positions):
+    """Tokens to the stream the first block reads; `module` is the
+    compact method's, which holds `pos_emb`."""
+    cfg = module.cfg
+    x = scaled(emb(tokens), cfg.embedding_multiplier)
+    if cfg.position == "learned":
+        pos_emb = module.param(
+            "pos_emb",
+            nn.initializers.normal(0.02),
+            (cfg.max_seq_len, cfg.hidden_size),
+            jnp.float32,
+        )
+        x = x + pos_emb[positions].astype(cfg.dtype)
+    return x
+
+
+def _final_norm(cfg: TransformerConfig, x):
+    """The last block's output as a head reads it: the scaling is on
+    the hidden state, so that the model's own head and a caller's fused
+    cross entropy read the same state."""
+    return scaled(_norm(cfg, "ln_final")(x), 1.0 / cfg.logits_scaling)
+
+
+def _logits(cfg: TransformerConfig, emb: Optional[nn.Embed], x):
+    """The LM head on `_final_norm`'s output (`emb` is read under
+    `tie_embeddings` alone). The matmul stays in the model compute
+    dtype (bf16 on the MXU fast path — an f32 [B,T,H]x[H,V] here is
+    the single largest matmul in the model at a fraction of peak); the
+    loss fns upcast the logits to f32 for logsumexp stability."""
+    with jax.named_scope(scopes.LOSS_HEAD):
+        if cfg.tie_embeddings:
+            return emb.attend(x)
+        return nn.Dense(
+            cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+            param_dtype=jnp.float32, name="lm_head",
+            kernel_init=nn.initializers.normal(0.02),
+        )(x)
+
+
+class Embedding(nn.Module):
+    """`Transformer`'s embedding alone, over the `embedding_keys` of
+    its parameters."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, tokens, positions):
+        return _embed(self, _token_embedding(self.cfg), tokens, positions)
+
+
+class LmHead(nn.Module):
+    """`Transformer`'s final norm and head alone, over the `head_keys`
+    of its parameters."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        emb = _token_embedding(cfg) if cfg.tie_embeddings else None
+        return _logits(cfg, emb, _final_norm(cfg, x))
+
+
+def embedding_keys(cfg: TransformerConfig) -> tuple:
+    """The top-level keys of the parameters that `_embed` reads."""
+    return ("tok_emb",) + (("pos_emb",) if cfg.position == "learned"
+                           else ())
+
+
+def head_keys(cfg: TransformerConfig) -> tuple:
+    """The top-level keys that `_final_norm` and `_logits` read: an
+    untied head never reads `tok_emb`."""
+    return ("ln_final", "tok_emb" if cfg.tie_embeddings else "lm_head")
+
+
+def _report(cfg: TransformerConfig, specs: tuple, attention_fn, kv_cache,
+            n_tokens: int, n_positions: int) -> None:
+    """The stack's trace-time gauges (docs/metrics.md): what the last
+    traced call of the model built, over `n_tokens` tokens in sequences
+    of `n_positions`; arithmetic on the specs and the shapes, nothing
+    inside the step."""
+    if not metrics.enabled():
+        return
+    # blocks whose whole forward runs again in the backward pass, and
+    # those that keep their kernel calls' and matrix products' results
+    # (the last one, before a caller's head); 0 and 0 without `remat`
+    metrics.trace_gauge(
+        "hvd_remat_blocks",
+        "Blocks whose whole forward runs again in the backward pass",
+        sum(spec.remat == REBUILD_ALL for spec in specs))
+    metrics.trace_gauge(
+        "hvd_remat_blocks_kept",
+        "Blocks under remat that keep their kernel and matmul results "
+        "(the last, before a caller's head)",
+        sum(spec.remat == KEEP_PRODUCTS for spec in specs))
+    kinds = [spec.kind for spec in specs]
+    for kind in dict.fromkeys(kinds):
+        metrics.trace_gauge(
+            "hvd_layers", "Layers of the model by the kind of their mixer",
+            kinds.count(kind), kind=kind)
+    # attention layers that run q/k norms, rope and the transposes into
+    # the flash kernels' layout as the one pass of ops/attention_prep.py,
+    # and those that leave them to array passes (or have none)
+    fused = [fuses_qk_prep(cfg, attention_fn, kv_cache, kind)
+             for kind in kinds if kind in ATTENTION_KINDS]
+    metrics.trace_gauge(
+        "hvd_attn_prep_fused_layers",
+        "Attention layers whose q/k norms, rope and layout are one "
+        "Pallas pass", sum(fused))
+    metrics.trace_gauge(
+        "hvd_attn_prep_plain_layers",
+        "Attention layers that leave q/k norms, rope and layout to "
+        "array passes", len(fused) - sum(fused))
+    # A model's state-space layers, and its routed ones, are all one
+    # form or all the other (by shapes alone), and the gauges are set
+    # only for a model that has such layers
+    state_space_layers = kinds.count(MAMBA2)
+    if state_space_layers:
+        as_kernels = cfg.mamba2_mixer(parent=None).scans_as_kernels(
+            n_positions)
+        metrics.trace_gauge(
+            "hvd_mamba_scan_kernel_layers",
+            "State-space layers whose recurrence is one Pallas kernel a "
+            "direction", state_space_layers * as_kernels)
+        metrics.trace_gauge(
+            "hvd_mamba_scan_plain_layers",
+            "State-space layers whose recurrence is plain array "
+            "operations", state_space_layers * (not as_kernels))
+    routed_layers = sum(spec.routed for spec in specs)
+    if routed_layers:
+        as_kernels = cfg.routed_mlp(parent=None).experts_as_kernels(
+            n_tokens, cfg.hidden_size)
+        metrics.trace_gauge(
+            "hvd_moe_expert_kernel_layers",
+            "Routed layers whose expert products are the grouped-matmul "
+            "Pallas kernels", routed_layers * as_kernels)
+        metrics.trace_gauge(
+            "hvd_moe_expert_plain_layers",
+            "Routed layers whose expert products are ragged_dot behind a "
+            "cast of the experts", routed_layers * (not as_kernels))
+
+
 class Transformer(nn.Module):
     """Decoder/encoder stack with LM head; covers GPT-2 (causal + learned
     pos), BERT (bidirectional) and Llama (causal + rope/rms/swiglu)."""
@@ -747,92 +970,27 @@ class Transformer(nn.Module):
             raise ValueError("; ".join(gaps))
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-        emb = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name="tok_emb",
-            embedding_init=nn.initializers.normal(0.02),
-        )
-        x = scaled(emb(tokens), cfg.embedding_multiplier)
-        if cfg.position == "learned":
-            pos_emb = self.param(
-                "pos_emb",
-                nn.initializers.normal(0.02),
-                (cfg.max_seq_len, cfg.hidden_size),
-                jnp.float32,
-            )
-            x = x + pos_emb[positions].astype(cfg.dtype)
-
-        # under `remat` the last block keeps its dear results where the
-        # caller takes the hidden state to a head of its own: its
-        # backward runs first, right after the head's (see
-        # `TransformerConfig.remat`)
-        kept = int(cfg.remat and return_hidden and cfg.num_layers > 0)
-        rematerialised = cfg.num_layers - kept if cfg.remat else 0
-        metrics.record_remat_blocks(rematerialised, kept)
-        kinds = cfg.layer_kinds
-        metrics.record_layer_kinds(
-            {kind: kinds.count(kind) for kind in dict.fromkeys(kinds)})
-        fused = [fuses_qk_prep(cfg, self.attention_fn, kv_cache, kind)
-                 for kind in kinds if kind in ATTENTION_KINDS]
-        metrics.record_attn_prep_layers(
-            sum(fused), len(fused) - sum(fused))
-        state_space_layers = kinds.count(MAMBA2)
-        if state_space_layers:
-            as_kernels = scan_runs_as_kernels(
-                T, cfg.mamba_chunk_size, cfg.mamba_d_head,
-                cfg.mamba_d_state, cfg.mamba_n_heads // cfg.mamba_n_groups,
-                cfg.dtype)
-            metrics.record_mamba_scan_layers(
-                state_space_layers * as_kernels,
-                state_space_layers * (not as_kernels))
-        routed_layers = sum(cfg.routes(i) for i in range(cfg.num_layers))
-        if routed_layers:
-            as_kernels = experts_run_as_kernels(
-                B * T, cfg.experts_per_token,
-                cfg.experts_held or cfg.num_experts, cfg.num_experts,
-                cfg.hidden_size, cfg.expert_mlp_dim or cfg.mlp_dim,
-                cfg.dtype)
-            metrics.record_moe_expert_layers(
-                routed_layers * as_kernels,
-                routed_layers * (not as_kernels))
-        for i, kind in enumerate(kinds):
-            block = Block
-            if i < rematerialised:
-                block = nn.remat(Block, static_argnums=())
-            elif kept:
-                block = nn.remat(Block, static_argnums=(),
-                                 policy=_last_block_keeps)
-            block = block(cfg, attention_fn=self.attention_fn, kind=kind,
-                          routed=cfg.routes(i), name=f"block_{i}")
+        emb = _token_embedding(cfg)
+        x = _embed(self, emb, tokens, positions)
+        specs = layer_specs(cfg, callers_head=return_hidden)
+        _report(cfg, specs, self.attention_fn, kv_cache, B * T, T)
+        for spec in specs:
+            block = build_block(cfg, spec, self.attention_fn,
+                                name=f"block_{spec.index}")
             if kv_cache is None:
                 # training/one-shot path: exact pre-cache call shape so
                 # remat'd and jitted programs lower identically
                 x = block(x, positions, mask)
             else:
-                x = block(x, positions, mask, kv_cache=kv_cache, layer=i)
-        # on the hidden state, so that the model's own head and a
-        # caller's fused cross entropy read the same scaled state
-        x = scaled(_norm(cfg, "ln_final")(x), 1.0 / cfg.logits_scaling)
+                x = block(x, positions, mask, kv_cache=kv_cache)
+        x = _final_norm(cfg, x)
         if return_hidden:
             # pre-head activations for the fused LM-head cross-entropy
             # (ops/fused_cross_entropy.py) — the [B, T, V] logits are
             # never materialized on that path. Initialize with the
             # default return_hidden=False so head params exist.
             return x
-        # LM head matmul stays in the model compute dtype (bf16 on the
-        # MXU fast path — an f32 [B,T,H]x[H,V] here is the single
-        # largest matmul in the model at a fraction of peak); the loss
-        # fns upcast the logits to f32 for logsumexp stability.
-        with jax.named_scope(scopes.LOSS_HEAD):
-            if cfg.tie_embeddings:
-                logits = emb.attend(x)
-            else:
-                logits = nn.Dense(
-                    cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=jnp.float32, name="lm_head",
-                    kernel_init=nn.initializers.normal(0.02),
-                )(x)
-        return logits
+        return _logits(cfg, emb, x)
 
 
 # -- task heads / losses ----------------------------------------------------

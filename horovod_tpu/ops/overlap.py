@@ -193,71 +193,42 @@ def transformer_lm_stages(model, tokens, loss_fn, positions=None,
                           mask=None) -> List[Stage]:
     """Decompose a ``models.transformer.Transformer`` forward + loss
     into backward segments: embed → block_0..N → head(+loss). Built
-    from the SAME flax building blocks the monolithic ``model.apply``
-    uses (standalone ``Block``/``Embed``/norm applies over the
-    corresponding param subtrees), so composing the stages reproduces
-    the monolithic forward op-for-op — the property the bitwise
+    from the SAME pieces the monolithic ``model.apply`` is made of
+    (models/transformer.py: ``Embedding``, ``build_block`` over
+    ``layer_specs``, ``LmHead``, each applied alone over its sub-tree
+    of the parameters), so composing the stages reproduces the
+    monolithic forward op-for-op — the property the bitwise
     schedule-on/off parity tests rest on.
 
     ``loss_fn(logits) -> scalar`` closes over the labels/targets.
     """
-    import flax.linen as nn
-
-    from ..models.transformer import Block, _norm, scaled
+    from ..models.transformer import (
+        Embedding, LmHead, build_block, embedding_keys, head_keys,
+        layer_specs)
 
     cfg = model.cfg
-    attention_fn = model.attention_fn
     B, T = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
 
-    emb_mod = nn.Embed(
-        cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-        param_dtype=jnp.float32, name="tok_emb",
-        embedding_init=nn.initializers.normal(0.02),
-    )
-
     def embed_fwd(sub, carry):
-        x = scaled(emb_mod.apply({"params": sub["tok_emb"]}, tokens),
-                   cfg.embedding_multiplier)
-        if cfg.position == "learned":
-            x = x + sub["pos_emb"][positions].astype(cfg.dtype)
-        return x
+        return Embedding(cfg).apply({"params": sub}, tokens, positions)
 
-    embed_keys = ("tok_emb",) + (
-        ("pos_emb",) if cfg.position == "learned" else ())
-    stages = [Stage("embed", embed_keys, embed_fwd)]
+    stages = [Stage("embed", embedding_keys(cfg), embed_fwd)]
 
-    block_cls = nn.remat(Block, static_argnums=()) if cfg.remat else Block
-    for i, kind in enumerate(cfg.layer_kinds):
-        key = f"block_{i}"
+    for spec in layer_specs(cfg):
+        key = f"block_{spec.index}"
 
-        def blk_fwd(sub, carry, _key=key, _kind=kind,
-                    _routed=cfg.routes(i)):
-            return block_cls(
-                cfg, attention_fn=attention_fn, kind=_kind,
-                routed=_routed).apply(
-                    {"params": sub[_key]}, carry, positions, mask)
+        def blk_fwd(sub, carry, _key=key, _spec=spec):
+            return build_block(cfg, _spec, model.attention_fn).apply(
+                {"params": sub[_key]}, carry, positions, mask)
 
         stages.append(Stage(key, (key,), blk_fwd))
 
     def head_fwd(sub, carry):
-        x = scaled(_norm(cfg, "ln_final").apply(
-            {"params": sub["ln_final"]}, carry), 1.0 / cfg.logits_scaling)
-        if cfg.tie_embeddings:
-            logits = emb_mod.apply({"params": sub["tok_emb"]}, x,
-                                   method=nn.Embed.attend)
-        else:
-            logits = nn.Dense(
-                cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                param_dtype=jnp.float32, name="lm_head",
-                kernel_init=nn.initializers.normal(0.02),
-            ).apply({"params": sub["lm_head"]}, x)
-        return loss_fn(logits)
+        return loss_fn(LmHead(cfg).apply({"params": sub}, carry))
 
-    head_keys = ("ln_final",) + (
-        ("tok_emb",) if cfg.tie_embeddings else ("lm_head",))
-    stages.append(Stage("head", head_keys, head_fwd))
+    stages.append(Stage("head", head_keys(cfg), head_fwd))
     return stages
 
 

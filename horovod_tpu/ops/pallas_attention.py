@@ -777,10 +777,13 @@ def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry,
     """`(gb, gh)`, the grid and the `trips` (`_trips`) of `kernel` over a
     padded [B, H, T, D] array of `shape` whose T is the program's own
     side, `other_rows` the padded length it streams over and `geometry`
-    what `_tile_ranges` takes. Recorded in the trace-time gauges (all of
-    it is static, so nothing runs in the step), by kernel: instances a
-    program, programs a call, and the tiles a call runs with those of
-    them that are masked; for a call under a window, the window and the
+    what `_tile_ranges` takes. Recorded in the trace-time gauges
+    (`utils/metrics.trace_gauge`: all of it is static, so nothing runs
+    in the step) by `kernel`, "fwd" or "bwd", the one backward kernel
+    that makes dq, dk and dv: instances a program, programs a call, and
+    the score tiles a call runs with those of them that pay for the
+    mask (boundary tiles, which the causal diagonal crosses or which
+    hold padded keys); for a call under a window, the window and the
     tiles the diagonal alone would have run beside those it runs."""
     from ..utils import metrics
 
@@ -800,9 +803,34 @@ def _grid(kernel, shape, block_q, block_k, other_rows, itemsize, geometry,
         causal_tiles = b * h * _count_tiles(_every_program(
             over, t // own_rows, block_q, block_k,
             other_rows // other_block, **{**geometry, "window": 0}))[0]
-    metrics.record_flash_programs(
-        kernel, gb * gh, grid[0] * grid[1] * grid[2], b * h * tiles,
-        b * h * masked_tiles, window=window, causal_tiles=causal_tiles)
+    gauges = [
+        ("hvd_flash_instances_per_program",
+         "(batch, head) instances one program of the flash kernel handles",
+         gb * gh),
+        ("hvd_flash_programs_per_call",
+         "Programs in the grid of one call of the flash kernel",
+         grid[0] * grid[1] * grid[2]),
+        ("hvd_flash_tiles_per_call",
+         "Score tiles one call of the flash kernel runs", b * h * tiles),
+        ("hvd_flash_boundary_tiles_per_call",
+         "Tiles of one call of the flash kernel that are masked",
+         b * h * masked_tiles)]
+    if window:
+        # three gauges only a call under a window sets (a model's full
+        # layers trace their calls beside them): the ratio of the last
+        # two is what the window's tile range saves
+        gauges += [
+            ("hvd_flash_window",
+             "Positions a query sees in the last traced flash call under "
+             "a sliding window", window),
+            ("hvd_flash_window_tiles_per_call",
+             "Score tiles one flash call under a sliding window runs",
+             b * h * tiles),
+            ("hvd_flash_window_causal_tiles_per_call",
+             "Score tiles the causal range alone would run of a flash "
+             "call under a sliding window", causal_tiles)]
+    for name, help, value in gauges:
+        metrics.trace_gauge(name, help, value, kernel=kernel)
     return gb, gh, grid, _trips(every)
 
 

@@ -31,6 +31,7 @@ the transposed program.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Callable, Optional
 
@@ -39,7 +40,9 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models.transformer import TransformerConfig
+from ..models.transformer import (
+    Embedding, LmHead, TransformerConfig, build_block, embedding_keys,
+    head_keys, layer_specs)
 
 
 def stack_block_params(params: dict, prefix: str = "block_"):
@@ -133,53 +136,56 @@ def gpipe(
     return outs.reshape((B,) + h.shape[1:])
 
 
-def _lm_pipeline_pieces(cfg, rest, attention_fn, tokens,
+def _lm_pipeline_pieces(cfg, mesh, who, params, attention_fn, tokens,
                         num_microbatches):
     """Shared plumbing for the GPipe and 1F1B LM entry points: the
-    param-tree split (embed / head), the single-block apply closure,
-    and the position arrays. One place to change if the Transformer
-    param layout grows a key — a divergence here would silently drop a
-    parameter's gradient in one path."""
+    param-tree split (stacked blocks / embed / head), the single-block
+    apply closure, and the position arrays, all from the pieces
+    `Transformer` itself is made of (models/transformer.py), so that a
+    key the param layout grows reaches both schedules or neither."""
+    spec = _check_pp(cfg, mesh, who)
+    stacked, rest = stack_block_params(params)
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-    embed_params = {
-        k: rest[k] for k in ("tok_emb", "pos_emb") if k in rest
-    }
+    embed_params = {k: rest[k] for k in embedding_keys(cfg)}
     # untied models never read tok_emb in the head — including it would
     # make 1F1B carry + psum a dead vocab x hidden zero-grad buffer
-    head_keys = (("ln_final", "tok_emb") if cfg.tie_embeddings
-                 else ("ln_final", "lm_head"))
-    head_params = {k: rest[k] for k in head_keys if k in rest}
+    head_params = {k: rest[k] for k in head_keys(cfg)}
 
     def block_apply(p_block, h, pos):
-        return _BlockOnly(cfg, attention_fn=attention_fn).apply(
-            {"params": {"block_0": p_block}}, h, pos
-        )
+        return build_block(cfg, spec, attention_fn).apply(
+            {"params": p_block}, h, pos)
 
     # positions per MICROBATCH: activations flow through the schedule
     # in [B/M, T, H] slices and every microbatch shares the same arange
     # rows, so one slice serves all ticks
     pos_mb = positions[: B // num_microbatches]
-    return embed_params, head_params, block_apply, positions, pos_mb
+    return (stacked, embed_params, head_params, block_apply, positions,
+            pos_mb)
 
 
 def _check_pp(cfg, mesh, who):
-    kinds = sorted(set(cfg.layer_kinds))
-    if kinds != ["attention"] or (cfg.embedding_multiplier,
-                                  cfg.logits_scaling) != (1.0, 1.0) \
-            or (cfg.num_experts and cfg.dense_layers):
-        # a stage runs its layers as ONE block scanned over stacked
-        # parameters (`stack_block_params`), and embeds and heads apart
+    """The one `LayerSpec` every stage scans over its stacked blocks
+    (`stack_block_params`; every stage runs the same traced program),
+    or a refusal where the model's layers are not all built alike or
+    the schedule has not been held to what the block holds."""
+    # not `callers_head`: under `remat` every block is rebuilt whole, as
+    # in the serial model that builds the logits (a pipelined big model
+    # without remat would OOM where the serial path fits), and the last
+    # block is built like the others
+    specs = layer_specs(cfg)
+    if len({dataclasses.replace(spec, index=0) for spec in specs}) > 1:
+        routed = [spec.index for spec in specs if spec.routed]
         raise ValueError(
-            f"{who} stacks a stage's blocks and scans one `attention` "
-            f"block over them; this model has layers of kinds {kinds} "
-            f"(embedding_multiplier {cfg.embedding_multiplier}, "
-            f"logits_scaling {cfg.logits_scaling}, dense_layers "
-            f"{cfg.dense_layers} in front of its routed ones). What is "
-            f"missing: a stage that holds a stack of unlike blocks (a "
-            f"tree a kind and a tree for the leading dense layers, run "
-            f"in the pattern's order) and the stream's two scalings in "
-            f"`_EmbedOnly` / `_HeadOnly`")
+            f"{who} stacks a stage's blocks and scans one block over "
+            f"them; this model's layers are not built alike: their "
+            f"kinds are {sorted({spec.kind for spec in specs})}"
+            + (f" and layers {routed} alone have the routed MLP, behind "
+               f"leading `dense_layers`"
+               if 0 < len(routed) < len(specs) else "")
+            + ". What is missing: a stage that holds a stack of unlike "
+            "blocks (a tree a kind and a tree for the leading dense "
+            "layers, run in the pattern's order)")
     unheld = [f"{name} {getattr(cfg, name)!r}" for name, neutral in (
         ("attn_output_gate", False), ("post_norms", False),
         ("score_func", "softmax"), ("routed_scaling_factor", 1.0),
@@ -194,7 +200,7 @@ def _check_pp(cfg, mesh, who):
     S = mesh.shape["pp"]
     assert cfg.num_layers % S == 0, (
         f"{cfg.num_layers} layers not divisible by {S} pipeline stages")
-    return S
+    return specs[0]
 
 
 def pipeline_lm_apply(
@@ -211,13 +217,11 @@ def pipeline_lm_apply(
     embedding + positions + final norm + head run replicated outside
     the pipelined region. Returns logits [B, T, V].
     """
-    stacked, rest = stack_block_params(params)
-    _check_pp(cfg, mesh, "pipeline_lm_apply")
-    embed_params, head_params, block_apply, positions, pos_mb = (
-        _lm_pipeline_pieces(cfg, rest, attention_fn, tokens,
-                            num_microbatches))
+    stacked, embed_params, head_params, block_apply, positions, pos_mb = (
+        _lm_pipeline_pieces(cfg, mesh, "pipeline_lm_apply", params,
+                            attention_fn, tokens, num_microbatches))
 
-    h = _EmbedOnly(cfg).apply({"params": embed_params}, tokens, positions)
+    h = Embedding(cfg).apply({"params": embed_params}, tokens, positions)
 
     pipelined = shard_map(
         functools.partial(
@@ -230,7 +234,7 @@ def pipeline_lm_apply(
         check_vma=False,
     )
     h = pipelined(stacked, h, pos_mb)
-    return _HeadOnly(cfg).apply({"params": head_params}, h)
+    return LmHead(cfg).apply({"params": head_params}, h)
 
 
 def one_f_one_b(
@@ -421,14 +425,13 @@ def pipeline_lm_train_step_1f1b(
     the serial model exactly."""
     from ..models.transformer import causal_lm_loss
 
-    stacked, rest = stack_block_params(params)
-    _check_pp(cfg, mesh, "pipeline_lm_train_step_1f1b")
     M = num_microbatches
-    embed_params, head_params, block_apply, positions, pos_mb = (
-        _lm_pipeline_pieces(cfg, rest, attention_fn, tokens, M))
+    stacked, embed_params, head_params, block_apply, positions, pos_mb = (
+        _lm_pipeline_pieces(cfg, mesh, "pipeline_lm_train_step_1f1b",
+                            params, attention_fn, tokens, M))
 
     def loss_head_fn(hp, y_mb, toks_mb):
-        logits = _HeadOnly(cfg).apply({"params": hp}, y_mb)
+        logits = LmHead(cfg).apply({"params": hp}, y_mb)
         mean, n = causal_lm_loss(logits, toks_mb)
         # UNCLAMPED valid count for the summed denominator:
         # causal_lm_loss clamps n to >= 1 (safe for its own mean), but a
@@ -440,7 +443,7 @@ def pipeline_lm_train_step_1f1b(
         return mean * n, n_raw  # (sum, count) — see one_f_one_b's contract
 
     def embed_fwd(ep):
-        return _EmbedOnly(cfg).apply({"params": ep}, tokens, positions)
+        return Embedding(cfg).apply({"params": ep}, tokens, positions)
 
     h, embed_vjp = jax.vjp(embed_fwd, embed_params)
 
@@ -467,77 +470,3 @@ def pipeline_lm_train_step_1f1b(
             grads[k] = (jax.tree_util.tree_map(jnp.add, grads[k], g)
                         if k in grads else g)
     return loss_sum / count, grads
-
-
-# -- param-aligned sub-modules --------------------------------------------
-#
-# The pipeline needs to run the model's three phases separately (embed,
-# one block, head). Flax allows a single compact method per Module, so
-# instead of method views these are standalone modules whose submodule
-# NAMES match the Transformer's param tree exactly — the same subtrees
-# bind unchanged.
-
-import flax.linen as nn
-
-
-class _EmbedOnly(nn.Module):
-    cfg: TransformerConfig
-
-    @nn.compact
-    def __call__(self, tokens, positions):
-        cfg = self.cfg
-        emb = nn.Embed(
-            cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name="tok_emb",
-            embedding_init=nn.initializers.normal(0.02),
-        )
-        x = emb(tokens)
-        if cfg.position == "learned":
-            pos_emb = self.param(
-                "pos_emb",
-                nn.initializers.normal(0.02),
-                (cfg.max_seq_len, cfg.hidden_size), jnp.float32,
-            )
-            x = x + pos_emb[positions].astype(cfg.dtype)
-        return x
-
-
-class _BlockOnly(nn.Module):
-    cfg: TransformerConfig
-    attention_fn: Optional[Callable] = None
-
-    @nn.compact
-    def __call__(self, h, positions):
-        from ..models.transformer import Block
-
-        block = Block
-        if self.cfg.remat:
-            # honor the config exactly like Transformer.__call__ — a
-            # pipelined big model without remat would OOM where the
-            # serial path fits
-            block = nn.remat(Block, static_argnums=())
-        return block(self.cfg, attention_fn=self.attention_fn,
-                     name="block_0")(h, positions, None)
-
-
-class _HeadOnly(nn.Module):
-    cfg: TransformerConfig
-
-    @nn.compact
-    def __call__(self, h):
-        from ..models.transformer import _norm
-
-        cfg = self.cfg
-        x = _norm(cfg, "ln_final")(h)
-        if cfg.tie_embeddings:
-            emb = nn.Embed(
-                cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                param_dtype=jnp.float32, name="tok_emb",
-                embedding_init=nn.initializers.normal(0.02),
-            )
-            return emb.attend(x)
-        return nn.Dense(
-            cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-            param_dtype=jnp.float32, name="lm_head",
-            kernel_init=nn.initializers.normal(0.02),
-        )(x)

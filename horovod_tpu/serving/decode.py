@@ -345,29 +345,18 @@ class GenerationEngine:
             raise ValueError(
                 "autoregressive generation needs a causal LM "
                 "(TransformerConfig.causal=True)")
-        from ..models.transformer import MAMBA2, MAMBA2_HAS_NO_CACHE
-
-        if MAMBA2 in cfg.layer_kinds:
-            mamba2 = [i for i, kind in enumerate(cfg.layer_kinds)
-                      if kind == MAMBA2]
-            raise ValueError(f"layers {mamba2} of this model are "
-                             f"state-space layers: " + MAMBA2_HAS_NO_CACHE)
-        from ..models.transformer import cache_gaps
+        from ..models.transformer import Transformer, cache_gaps
 
         gaps = cache_gaps(cfg)
         if gaps:
             raise ValueError("this model cannot be served: "
                              + "; ".join(gaps))
-        if getattr(cfg, "remat", False):
-            # remat exists to trade activation memory for backward
-            # recompute; inference has no backward, and nn.remat
-            # cannot abstractify the SlottedKVCache carrier — a
-            # remat-trained checkpoint must still serve
-            from ..models.transformer import Transformer
-
-            cfg = dataclasses.replace(cfg, remat=False)
-            model = Transformer(cfg,
-                                attention_fn=model.attention_fn)
+        # rematerialisation trades activation memory for backward
+        # recompute; inference has no backward, and a rematerialised
+        # block cannot abstractify the SlottedKVCache carrier — a
+        # remat-trained checkpoint must still serve
+        cfg = dataclasses.replace(cfg, remat=False)
+        model = Transformer(cfg, attention_fn=model.attention_fn)
         sk = serving_knobs()
         if slots is None or max_len is None:
             # largest configured (slots, max_len) bucket: the decode

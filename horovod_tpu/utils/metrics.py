@@ -951,9 +951,9 @@ def record_fusion_groups(direct_bytes: int, packed_bytes: int,
     groups of arrays (ops/fusion.pack_groups_by_plan): the bytes and
     the count of the leaves that ride their bucket's all-reduce in
     their own shape, and the bytes still flattened and concatenated.
-    Recorded at TRACE time, like the flash kernels' gauges below:
-    arithmetic on the tree's shapes, the last traced tree's, nothing
-    inside the step."""
+    Recorded at TRACE time, like `trace_gauge`'s below: arithmetic on
+    the tree's shapes, the last traced tree's, nothing inside the
+    step."""
     if not _enabled:
         return
     registry.gauge(
@@ -1001,219 +1001,18 @@ def record_wire_bytes(logical: int, sent: int) -> None:
     step_stats.add_wire(int(logical), int(sent))
 
 
-def record_flash_programs(kernel: str, instances_per_program: int,
-                          programs: int, tiles: int,
-                          boundary_tiles: int, window: int = 0,
-                          causal_tiles: int = 0) -> None:
-    """The grid one flash-attention kernel was built with
-    (ops/pallas_attention.py): "fwd", or "bwd", the one backward kernel
-    that makes dq, dk and dv (since PR 34; "dq" and "dkv" before, which
-    nothing records any more), the score tiles one call of it
-    runs and those of them that pay for the mask: boundary tiles, which
-    the causal diagonal crosses or which hold padded keys. Recorded at
-    TRACE time like the fused collectives' breadcrumb: the kernels
-    choose instances a program and class their tiles from the shapes,
-    statically, so the gauges say what the last traced call of each
-    kernel got and nothing runs in the step. A call under a sliding
-    `window` also leaves, in three gauges only such a call sets (a
-    model's full layers trace their calls beside them), the window, the
-    tiles it runs and the tiles the diagonal alone would have run of
-    the same call (`causal_tiles`): their ratio is what the window's
-    tile range saves."""
+def trace_gauge(name: str, help: str, value, **labels) -> None:
+    """Set one gauge at TRACE time, like the fused collectives'
+    breadcrumb: the caller has worked `value` out from static shapes
+    while its program was traced, so the gauge says what the last
+    traced call built and nothing runs in the step. The module that
+    knows the fact declares the gauge (its name, help text and
+    labels, listed in docs/metrics.md); this is only the enabled check
+    and the registry."""
     if not _enabled:
         return
-    if window:
-        registry.gauge(
-            "hvd_flash_window",
-            "Positions a query sees in the last traced flash call under "
-            "a sliding window", labelnames=("kernel",)).labels(
-                kernel=kernel).set(window)
-        registry.gauge(
-            "hvd_flash_window_tiles_per_call",
-            "Score tiles one flash call under a sliding window runs",
-            labelnames=("kernel",)).labels(kernel=kernel).set(tiles)
-        registry.gauge(
-            "hvd_flash_window_causal_tiles_per_call",
-            "Score tiles the causal range alone would run of a flash "
-            "call under a sliding window",
-            labelnames=("kernel",)).labels(kernel=kernel).set(causal_tiles)
-    registry.gauge(
-        "hvd_flash_instances_per_program",
-        "(batch, head) instances one program of the flash kernel handles",
-        labelnames=("kernel",)).labels(kernel=kernel).set(
-            instances_per_program)
-    registry.gauge(
-        "hvd_flash_programs_per_call",
-        "Programs in the grid of one call of the flash kernel",
-        labelnames=("kernel",)).labels(kernel=kernel).set(programs)
-    registry.gauge(
-        "hvd_flash_tiles_per_call",
-        "Score tiles one call of the flash kernel runs",
-        labelnames=("kernel",)).labels(kernel=kernel).set(tiles)
-    registry.gauge(
-        "hvd_flash_boundary_tiles_per_call",
-        "Tiles of one call of the flash kernel that are masked",
-        labelnames=("kernel",)).labels(kernel=kernel).set(boundary_tiles)
-
-
-def record_moe_rows(experts_held: int, router_width: int,
-                    rows_expected: float, rows_static: int,
-                    shared_experts: int = 0,
-                    score_func: str = "softmax") -> None:
-    """What one routed MLP (models/moe.py) was built for: the experts it
-    holds of the router's width, the rows even routing sends it in one
-    call (tokens x experts a token x held / width), the rows each of
-    its expert products is sized for, the shared experts every token
-    goes through beside the routed ones and the function that scores
-    (the gauge's label; its value is 1). Recorded at TRACE time like the
-    flash kernels' gauges above: arithmetic on the last traced call's
-    shapes, nothing inside the step."""
-    if not _enabled:
-        return
-    registry.gauge(
-        "hvd_moe_experts_held",
-        "Experts of the router's that the routed MLP holds").set(
-            experts_held)
-    registry.gauge(
-        "hvd_moe_router_width",
-        "Experts the routed MLP's router scores").set(router_width)
-    registry.gauge(
-        "hvd_moe_rows_expected",
-        "Rows even routing sends the held experts in one call").set(
-            rows_expected)
-    registry.gauge(
-        "hvd_moe_rows_static",
-        "Rows one expert product of the routed MLP is sized for").set(
-            rows_static)
-    registry.gauge(
-        "hvd_moe_shared_experts",
-        "Shared experts every token of the routed MLP goes through").set(
-            shared_experts)
-    registry.gauge(
-        "hvd_moe_score_func",
-        "The function the routed MLP's router scores by (label)",
-        labelnames=("score_func",)).labels(score_func=score_func).set(1)
-
-
-def record_moe_expert_layers(kernels: int, plain: int) -> None:
-    """How `Transformer` (models/transformer.py) built its routed
-    layers' expert products: those that run as the kernels of
-    `ops/grouped_matmul.py`, and those whose shape the kernels do not
-    take and that run `lax.ragged_dot` behind a cast of the experts. A
-    model is all one or all the other
-    (`models/moe.experts_run_as_kernels`: by shapes alone). Recorded at
-    TRACE time like the gauges above, and only for a model that has
-    such layers."""
-    if not _enabled:
-        return
-    registry.gauge(
-        "hvd_moe_expert_kernel_layers",
-        "Routed layers whose expert products are the grouped-matmul "
-        "Pallas kernels").set(kernels)
-    registry.gauge(
-        "hvd_moe_expert_plain_layers",
-        "Routed layers whose expert products are ragged_dot behind a "
-        "cast of the experts").set(plain)
-
-
-def record_remat_blocks(rematerialised: int, kept: int) -> None:
-    """How `Transformer` (models/transformer.py) built its blocks under
-    `remat`: those whose whole forward runs again in the backward pass
-    and those that keep their kernel calls' and matrix products'
-    results from their first run (the last one, where the caller takes
-    the hidden state to its own head); 0 and 0 without `remat`.
-    Recorded at TRACE time like the gauges above: the last traced
-    call's, nothing inside the step."""
-    if not _enabled:
-        return
-    registry.gauge(
-        "hvd_remat_blocks",
-        "Blocks whose whole forward runs again in the backward "
-        "pass").set(rematerialised)
-    registry.gauge(
-        "hvd_remat_blocks_kept",
-        "Blocks under remat that keep their kernel and matmul results "
-        "(the last, before a caller's head)").set(kept)
-
-
-def record_attn_prep_layers(fused: int, plain: int) -> None:
-    """How `Transformer` (models/transformer.py) built its attention
-    layers' work between the projections and the attention itself:
-    those that run q/k norms, rope and the transposes into the flash
-    kernels' layout as the one pass of `ops/attention_prep.py`, and
-    those that leave them to array passes (or have none: learned
-    positions and no q/k norms). A model is all one or all the other
-    (`models.transformer.fuses_qk_prep`). Recorded at TRACE time like
-    the gauges above: the last traced call's, nothing inside the
-    step."""
-    if not _enabled:
-        return
-    registry.gauge(
-        "hvd_attn_prep_fused_layers",
-        "Attention layers whose q/k norms, rope and layout are one "
-        "Pallas pass").set(fused)
-    registry.gauge(
-        "hvd_attn_prep_plain_layers",
-        "Attention layers that leave q/k norms, rope and layout to "
-        "array passes").set(plain)
-
-
-def record_layer_kinds(counts: dict) -> None:
-    """How many layers of each kind `Transformer` (models/transformer.py)
-    built: `{"attention": n, "mamba2": m}` by
-    `TransformerConfig.layer_types` (every layer `attention` where it
-    states none). Recorded at TRACE time like the gauges above: the last
-    traced call's, nothing inside the step."""
-    if not _enabled:
-        return
-    family = registry.gauge(
-        "hvd_layers", "Layers of the model by the kind of their mixer",
-        labelnames=("kind",))
-    for kind, count in counts.items():
-        family.labels(kind=kind).set(count)
-
-
-def record_mamba_scan(chunk: int, chunks: int, state_bytes: int) -> None:
-    """What one state-space mixer's scan (models/mamba.ssd_scan) was
-    built for: the positions of a chunk (the configuration's, or the
-    sequence where that is shorter), the chunks a sequence is cut into
-    (padding included) and the bytes of the float32 state a sequence
-    carries between them (heads x d_head x d_state x 4). Recorded at
-    TRACE time like the gauges above: the last traced call's, nothing
-    inside the step."""
-    if not _enabled:
-        return
-    registry.gauge(
-        "hvd_mamba_chunk",
-        "Positions in one chunk of the state-space scan").set(chunk)
-    registry.gauge(
-        "hvd_mamba_chunks_per_sequence",
-        "Chunks the state-space scan cuts a sequence into").set(chunks)
-    registry.gauge(
-        "hvd_mamba_state_bytes_per_sequence",
-        "Bytes of float32 state a sequence carries between "
-        "chunks").set(state_bytes)
-
-
-def record_mamba_scan_layers(kernels: int, plain: int) -> None:
-    """How `Transformer` (models/transformer.py) built its state-space
-    layers' recurrences: those that run as the two kernels of
-    `ops/ssd_scan.py`, and those whose shape the kernels do not take
-    and that run the plain chunked form (`models/mamba._scan_chunks`).
-    A model is all one or all the other
-    (`models/mamba.scan_runs_as_kernels`: by shapes alone). Recorded at
-    TRACE time like the gauges above, and only for a model that has
-    such layers."""
-    if not _enabled:
-        return
-    registry.gauge(
-        "hvd_mamba_scan_kernel_layers",
-        "State-space layers whose recurrence is one Pallas kernel a "
-        "direction").set(kernels)
-    registry.gauge(
-        "hvd_mamba_scan_plain_layers",
-        "State-space layers whose recurrence is plain array "
-        "operations").set(plain)
+    family = registry.gauge(name, help, labelnames=tuple(labels))
+    (family.labels(**labels) if labels else family).set(value)
 
 
 def record_overlap_window(frac: float) -> None:

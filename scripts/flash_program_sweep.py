@@ -245,9 +245,12 @@ def main(argv=None):
         return 1
     from horovod_tpu.utils import metrics
 
-    # a parent's kernels call this commit's recorder, with its own
-    # arguments; nothing reads the gauges here
-    metrics.record_flash_programs = lambda *a, **kw: None
+    # a parent's kernels set their gauges through this commit's
+    # `utils/metrics` (their relative import lands here), with their own
+    # arguments: `trace_gauge` since PR 52, `record_flash_programs`
+    # before it; nothing reads the gauges here
+    metrics.trace_gauge = metrics.record_flash_programs = \
+        lambda *a, **kw: None
     parent = load_parent(args.parent) if args.parent else None
     chooser = pa._instances_per_program
     only = tuple(args.kernels.split(","))

@@ -275,12 +275,21 @@ def test_the_real_configuration_has_the_parameters_the_issue_counted():
 
 # -- the callers that assume one kind of block -------------------------------
 
-def test_the_overlap_stages_give_each_block_its_kind():
+@pytest.mark.parametrize("changes", [
+    dict(remat=False),
+    # every block rebuilt whole, as the model that builds the logits
+    dict(remat=True),
+    # a plain MLP in the first layer and routed ones behind it
+    dict(remat=False, num_experts=4, experts_per_token=2,
+         expert_mlp_dim=32, dense_layers=1, norm_topk_prob=True),
+], ids=["patterned", "rematerialised", "routed_behind_a_dense_layer"])
+def test_the_overlap_stages_give_each_block_its_kind(changes):
     """`ops/overlap.transformer_lm_stages` composes to the model's own
-    forward pass on a patterned model, multipliers included."""
+    forward pass on a patterned model, multipliers included: its blocks
+    are `build_block` of the model's own `layer_specs`."""
     from horovod_tpu.ops import overlap
 
-    _, model, params, tokens = built(remat=False)
+    _, model, params, tokens = built(**changes)
     stages = overlap.transformer_lm_stages(
         model, tokens, lambda logits: causal_lm_loss(logits, tokens)[0])
     assert [s.name for s in stages] == [
@@ -290,6 +299,48 @@ def test_the_overlap_stages_give_each_block_its_kind():
         carry = stage.fwd({k: params[k] for k in stage.keys}, carry)
     assert float(carry) == pytest.approx(
         float(system_loss(model, params, tokens)), rel=1e-6)
+
+
+# the five configurations of `benchmarks/configs`: the kinds of their
+# layers in order (None = every layer `attention`), the layers with the
+# routed MLP, and whether the configuration rematerialises
+CONFIGURED = {
+    "gpt2-medium": (None, [], False),
+    "bert-large": (None, [], False),
+    "sdar-30b-a3b-chat": (None, [0, 1, 2, 3, 4, 5], True),
+    "granite-4.0-h-micro": (
+        ["mamba2"] * 5 + ["attention"] + ["mamba2"] * 4, [], True),
+    "trinity-mini": (
+        ["window_attention"] * 3 + ["attention"] + ["window_attention"] * 2,
+        [2, 3, 4, 5], True),
+}
+
+
+@pytest.mark.parametrize("callers_head", [False, True])
+@pytest.mark.parametrize("name", CONFIGURED)
+def test_the_specs_of_a_benchmark_configuration(name, callers_head):
+    """`layer_specs` of each configuration a cell runs, as the model
+    that builds the logits and as the cells' step, which takes the
+    hidden state to the fused cross entropy: only there, and only under
+    `remat`, the last block keeps its products."""
+    kinds, routed, remat = CONFIGURED[name]
+    cfg = TransformerConfig(**harness.load_json(
+        ROOT, "benchmarks", "configs", name + ".json")["model"])
+    specs = transformer.layer_specs(cfg, callers_head=callers_head)
+    n = cfg.num_layers
+    assert [spec.index for spec in specs] == list(range(n))
+    assert [spec.kind for spec in specs] == (kinds or ["attention"] * n)
+    assert [spec.index for spec in specs if spec.routed] == routed
+    kept = int(remat and callers_head)
+    assert [spec.remat for spec in specs] == (
+        [transformer.REBUILD_ALL] * (n - kept)
+        + [transformer.KEEP_PRODUCTS] * kept if remat else [None] * n)
+    # and the block is built as its spec says: `nn.remat`'s class in
+    # place of `Block` itself
+    for spec in specs:
+        block = transformer.build_block(cfg, spec)
+        assert (type(block) is not transformer.Block) == remat
+        assert block.spec == spec
 
 
 def test_the_pipeline_refuses_a_stack_of_unlike_blocks():
@@ -339,7 +390,9 @@ def test_serving_refuses_a_state_space_layer_and_says_what_is_missing():
 # and an iteration of the loop over further products is rematerialised
 # with its condition (`models/moe.py`: the float32 experts stay the
 # loop's constants); the eight others build no routed MLP and are the
-# parent's text
+# parent's text. PR 52 adds the two newest cells' tiny steps, made on
+# its parent d932e39 before it moved anything, and leaves the nine as
+# they were: it builds the stack in one place and changes no step
 PARENT_LOWERED = {
     "gpt2m_dp1":
         "cca38d2c0a7ce2ca00e1ea5711e301ebefc16f951e4472d5ff65984aa35c9ce1",
@@ -359,9 +412,14 @@ PARENT_LOWERED = {
         "e8376d1268d665b48e9a80eb80679319cce1ee6620d1c7dced3e58bd475e1cc9",
     "bert_base_tiny":
         "bf9f6c46b9bd9ff37578aad801f97e779aa48d0c912348ee532623df87402a00",
+    "granite_h_lm":
+        "4b07e2731f152d51ec6a8c9891e9ac71cc50e9563bb752554a1682c9f223d5b7",
+    "trinity_mini_s8192":
+        "6970441779e73079530fcb2cae7879500990adadb7c260666d63a769859d806f",
 }
 CELL_DEVICES = {"gpt2m_dp1": 1, "gpt2m_dp4": 4, "bertl_s512": 1,
-                "bertl_s128": 1, "sdar_bd_s4096": 1}
+                "bertl_s128": 1, "sdar_bd_s4096": 1, "granite_h_lm": 1,
+                "trinity_mini_s8192": 1}
 TINY = dict(vocab_size=64, num_layers=2, num_heads=4, hidden_size=32,
             max_seq_len=16)
 PRESETS = {

@@ -228,7 +228,18 @@ def test_data_axes_helper(hvd8):
 # (beyond the reference: SURVEY.md §2.5 lists PP as absent in Horovod)
 
 
-def test_pipeline_matches_serial_forward_and_grads():
+@pytest.mark.parametrize("changes", [
+    {},
+    # the stream's two scalings: the pipeline's embedding and head are
+    # the serial model's own pieces (models/transformer.py)
+    dict(embedding_multiplier=12.0, logits_scaling=8.0),
+    # a stage scans one block over its stack whatever the layers' kind,
+    # where all of them are built alike
+    dict(layer_types=("window_attention",) * 4, sliding_window=8),
+    dict(layer_types=("mamba2",) * 4, position="none", mamba_n_heads=4,
+         mamba_d_head=32, mamba_d_state=8, mamba_chunk_size=16),
+], ids=["plain", "both_multipliers", "all_window_attention", "all_mamba2"])
+def test_pipeline_matches_serial_forward_and_grads(changes):
     """GPipe over pp=4 must be numerically the serial model: same
     logits, same gradients through the ppermute schedule."""
     import dataclasses
@@ -243,7 +254,7 @@ def test_pipeline_matches_serial_forward_and_grads():
 
     cfg = dataclasses.replace(
         GPT2_SMALL, num_layers=4, hidden_size=64, num_heads=2,
-        vocab_size=96, max_seq_len=32, dtype=jnp.float32,
+        vocab_size=96, max_seq_len=32, dtype=jnp.float32, **changes,
     )
     model = Transformer(cfg)
     B, T = 8, 32
